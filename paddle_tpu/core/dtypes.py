@@ -13,13 +13,9 @@ from __future__ import annotations
 
 import numpy as np
 
-try:  # jax is the compute backend; numpy fallback keeps module importable
-    import jax.numpy as jnp
+import jax.numpy as jnp
 
-    _BF16 = jnp.bfloat16
-except Exception:  # pragma: no cover
-    jnp = None
-    _BF16 = None
+_BF16 = jnp.bfloat16
 
 # canonical name -> numpy dtype object
 _NAME_TO_NP = {
@@ -45,7 +41,7 @@ def convert_dtype(dtype) -> str:
     if isinstance(dtype, str):
         name = dtype
     else:
-        name = np.dtype(dtype).name if _BF16 is None or dtype != _BF16 else "bfloat16"
+        name = np.dtype(dtype).name if dtype != _BF16 else "bfloat16"
     if name == "bfloat16":
         return name
     if name not in _NAME_TO_NP:
@@ -59,8 +55,6 @@ def convert_dtype(dtype) -> str:
 def to_numpy_dtype(dtype):
     name = convert_dtype(dtype)
     if name == "bfloat16":
-        if _BF16 is None:
-            raise ValueError("bfloat16 requires jax")
         return _BF16
     return _NAME_TO_NP[name]
 

@@ -30,12 +30,20 @@ class Place:
         return f"{type(self).__name__}({self.device_id})"
 
     def jax_device(self):
+        """The jax device this place names. Raises UnavailableError when
+        the process has no device of the place's kind — a TPUPlace never
+        resolves to a CPU device (tests and CPU runs get their place from
+        default_place(), which picks CPUPlace there)."""
         import jax
 
         devs = [d for d in jax.devices() if self._match(d)]
         if not devs:
-            # fall back to default backend (e.g. CPU-only test runs)
-            devs = jax.devices()
+            from ..errors import UnavailableError
+
+            raise UnavailableError(
+                f"{self!r}: this process has no {self.device_type} device "
+                f"(jax.devices() = {jax.devices()})"
+            )
         return devs[self.device_id % len(devs)]
 
     def _match(self, dev) -> bool:
@@ -64,12 +72,9 @@ CUDAPlace = TPUPlace
 
 @functools.lru_cache(maxsize=None)
 def _has_accelerator() -> bool:
-    try:
-        import jax
+    import jax
 
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:  # pragma: no cover
-        return False
+    return any(d.platform != "cpu" for d in jax.devices())
 
 
 def is_compiled_with_tpu() -> bool:
@@ -83,7 +88,7 @@ def default_place() -> Place:
 def tpu_places(device_ids=None):
     import jax
 
-    devs = [d for d in jax.devices() if d.platform != "cpu"] or jax.devices()
+    devs = [d for d in jax.devices() if d.platform != "cpu"]
     ids = range(len(devs)) if device_ids is None else device_ids
     return [TPUPlace(i) for i in ids]
 

@@ -13,9 +13,8 @@ Reference parity: imperative/tracer.cc:45 (TraceOp), basic_engine.cc:159
   tracer paid a fresh jax.vjp trace + op-by-op eager dispatch per op,
   22x the static executor on small shapes, tools/bench_dygraph.py): the
   fused forward+vjp of each op is jax.jit-compiled once per (op_type,
-  attrs, input avals) — the vjp closure is a PYTREE (tree_util.Partial
-  on older jax, jax._src.api.VJP on 0.9+; detected structurally, never
-  by type), so it crosses the jit boundary as residual outputs. backward()
+  attrs, input avals) — jax.vjp's closure is a PYTREE (residual arrays
+  as leaves), so it crosses the jit boundary as residual outputs. backward()
   applies tape closures through one shared jitted apply. This is the
   compiled analog of the reference's generated pybind fast paths
   (op_function_generator.cc) plus its dygraph kernel cache.
@@ -213,9 +212,8 @@ class Tracer:
                 return flat
 
             flat, vjp_fn = jax.vjp(fwd, diff_vals)
-            # vjp_fn is a pytree (whatever type this jax returns), so
-            # it crosses the jit boundary (residuals as outputs,
-            # structure static) — see _apply_vjp's structural detection
+            # vjp_fn is a pytree, so it crosses the jit boundary
+            # (residuals as outputs, structure static) — see _apply_vjp
             return flat, vjp_fn
 
         def fwd_only(raw, seed_v):
@@ -272,13 +270,11 @@ class Tracer:
 
 
 # one shared jitted apply for tape closures: jax.vjp's closure is a
-# PYTREE (tree_util.Partial historically; jax._src.api.VJP since 0.9 —
-# residual arrays as leaves, stable treedef across calls), so jax.jit
-# caches per (closure structure, cotangent avals) and the backward sweep
-# dispatches compiled code per tape entry. Detection is by pytree-ness,
-# not type name: an isinstance(Partial) gate silently routed EVERY
-# backward through the eager per-primitive fallback on jax 0.9 (measured
-# 87% of the tiny-block step).
+# PYTREE (residual arrays as leaves, stable treedef across calls), so
+# jax.jit caches per (closure structure, cotangent avals) and the backward
+# sweep dispatches compiled code per tape entry. Tape entries that are
+# plain python closures (VarBase.__getitem__, dygraph_to_static) are told
+# apart by pytree-ness, not by type name, and applied eagerly.
 _apply_vjp_jit = jax.jit(lambda f, cts: f(cts))
 _jittable_closure_types: dict = {}
 

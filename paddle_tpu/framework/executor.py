@@ -157,6 +157,9 @@ class Executor:
         # when the env is absent)
         _timeline.ensure_publisher()
         self.place = place if place is not None else default_place()
+        # a place names a device kind: asking for one this process does
+        # not have fails here (UnavailableError), never at a CPU's pace
+        self.place.jax_device()
         self._cache = OrderedDict()
         # (compiled, fresh_compile, (host_s, device_s) | None) of the
         # last run — consumed once by _note_perf
@@ -475,14 +478,15 @@ class Executor:
             return False
 
     # ------------------------------------------------------------------
-    def flops(self, program=None, feed=None, fetch_list=None, scope=None):
-        """XLA's static FLOP count for ONE step of `program` with this
-        feed — the compiled executable's cost analysis (reference role:
-        the per-op cost tooling of operators/benchmark/op_tester.cc).
-        Reuses the executor's compile cache; run the same (program, feed)
-        once first for a warm lookup. Pallas custom-call FLOPs are NOT
-        visible to XLA — callers benchmarking hand kernels must add that
-        term analytically (bench.py does for the attention kernels)."""
+    def lower(self, program=None, feed=None, fetch_list=None, scope=None):
+        """``jax.stages.Lowered`` for ONE step of `program` with this feed
+        and fetch set: the same jitted function ``run`` dispatches (mesh
+        programs included), state read from the scope, nothing executed.
+        ``.compile()`` on it gives XLA's cost and memory analysis and the
+        optimized HLO (``as_text()``: which collectives the partitioner
+        put in, and whether a Pallas kernel is there — ``tpu_custom_call``).
+        Reuses the executor's compile cache, so a ``run`` of the same
+        step after ``.compile()`` was seen not to compile again (PR 21)."""
         (program, scope, block, feed_arrays, _feed_sig, fetch_names,
          key) = self._prepared(program, feed, fetch_list, scope)
         compiled = self._cache.get(key)
@@ -500,12 +504,19 @@ class Executor:
         from ..core.random import prng_impl
 
         step_key = jax.random.key(0, impl=prng_impl())
-        lowered = compiled.fn.lower(
-            feed_arrays, state_mut, state_ro, step_key
-        )
-        ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
+        return compiled.fn.lower(feed_arrays, state_mut, state_ro, step_key)
+
+    def flops(self, program=None, feed=None, fetch_list=None, scope=None):
+        """XLA's static FLOP count for ONE step of `program` with this
+        feed — the compiled executable's cost analysis (reference role:
+        the per-op cost tooling of operators/benchmark/op_tester.cc).
+        Reuses the executor's compile cache; run the same (program, feed)
+        once first for a warm lookup. Pallas custom-call FLOPs are NOT
+        visible to XLA — callers benchmarking hand kernels must add that
+        term analytically (bench.py does for the attention kernels)."""
+        ca = self.lower(
+            program, feed, fetch_list, scope
+        ).compile().cost_analysis()
         if not ca or "flops" not in ca:
             # "backend reports no cost data" is NOT "zero-FLOP program":
             # callers deriving MFU from this must not read a silent 0.0
@@ -537,32 +548,11 @@ class Executor:
         static plan (``Program.estimate().peak_bytes``) is cross-checked
         against (``tools/perf_report.py --check-memory``). Returns None —
         with a counter bump — when the backend reports nothing."""
-        (program, scope, block, feed_arrays, _feed_sig, fetch_names,
-         key) = self._prepared(program, feed, fetch_list, scope)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            compiled = self._compile(
-                program, block, set(feed_arrays), fetch_names, scope
-            )
-            self._cache[key] = compiled
-        state_ro = {
-            n: self._from_scope(scope, n, block) for n in compiled.state_ro
-        }
-        state_mut = {
-            n: self._from_scope(scope, n, block) for n in compiled.state_mut
-        }
-        from ..core.random import prng_impl
-
-        step_key = jax.random.key(0, impl=prng_impl())
-        lowered = compiled.fn.lower(
-            feed_arrays, state_mut, state_ro, step_key
-        )
+        lowered = self.lower(program, feed, fetch_list, scope)
         try:
             ma = lowered.compile().memory_analysis()
         except Exception:
             ma = None
-        if isinstance(ma, (list, tuple)):
-            ma = ma[0] if ma else None
         fields = {
             "argument_bytes": "argument_size_in_bytes",
             "output_bytes": "output_size_in_bytes",
@@ -637,14 +627,16 @@ class Executor:
         # cache serializes incompletely (XLA:CPU leaves backend
         # function-registry entries behind — "Function ... not found" on
         # deserialize). So the serialization pass never touches the serving
-        # jit OR the disk cache: disable the persistent cache, reset its
-        # module-global handle, re-trace the block into a FRESH jit object,
-        # and AOT-compile that.
-        from jax._src import compilation_cache as _cc
+        # jit OR the disk cache: switch the persistent cache off (its
+        # directory stays as placed), reset its module-global handle,
+        # re-trace the block into a FRESH jit object, and AOT-compile that.
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
 
-        cache_dir = jax.config.jax_compilation_cache_dir
+        cache_on = jax.config.jax_enable_compilation_cache
         try:
-            jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_enable_compilation_cache", False)
             _cc.reset_cache()
             compiled = self._compile(
                 program, block, set(feed_arrays), fetch_names, scope
@@ -661,7 +653,7 @@ class Executor:
                                         step_key)
             payload, in_tree, out_tree = se.serialize(lowered.compile())
         finally:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            jax.config.update("jax_enable_compilation_cache", cache_on)
             _cc.reset_cache()
         blob = {
             "payload": payload,
@@ -874,10 +866,7 @@ class Executor:
                         f"[while tracing op #{i} {op.type!r} created at "
                         f"{op.attr('__loc__', '<unknown>')}]"
                     )
-                    if hasattr(e, "add_note"):  # PEP 678, python >= 3.11
-                        e.add_note(note)
-                    elif e.args and isinstance(e.args[0], str):
-                        e.args = (e.args[0] + "\n" + note,) + e.args[1:]
+                    e.add_note(note)
                     raise
                 if check_nan:
                     bad = jnp.zeros((), bool)

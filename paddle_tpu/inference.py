@@ -65,7 +65,8 @@ class AnalysisConfig:
     def set_optim_cache_dir(self, path):
         """Persist XLA compilations under `path` (reference
         SetOptimCacheDir): the first process pays the compile, later ones
-        load from disk.
+        load from disk. Where ``JAX_COMPILATION_CACHE_DIR`` is set, that
+        directory is used instead (core/compile_cache.py).
 
         PROCESS-GLOBAL: the XLA compilation cache is a jax.config knob, so
         every compile in the process (other predictors, training code)
@@ -142,7 +143,7 @@ class Predictor:
             )
         self._config = config
         if config._optim_cache_dir:
-            import jax
+            from .core import compile_cache
 
             global _applied_optim_cache_dir
             new_dir = os.path.abspath(config._optim_cache_dir)
@@ -154,10 +155,9 @@ class Predictor:
                     f"{_applied_optim_cache_dir!r}, cannot switch to "
                     f"{new_dir!r} in the same process"
                 )
-            os.makedirs(new_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", new_dir)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            # a cache placed from outside (JAX_COMPILATION_CACHE_DIR) wins:
+            # compiles persist there and `new_dir` stays unused
+            compile_cache.enable(new_dir)
             _applied_optim_cache_dir = new_dir
         self._scope = Scope()
         self._exe = Executor()

@@ -475,7 +475,6 @@ def fused_attention_qkv(
     forward re-run; every other path returns (out, None)."""
     from .. import observability as _obs
 
-    _obs.add("kernels.fused_attention_qkv")
     B, S, three_hd = qkv.shape
     D = three_hd // 3 // num_heads
     if scale is None:
@@ -553,6 +552,9 @@ def fused_attention_qkv(
             "interpret mode (interpreter PRNG is a stub)"
         )
     seed = _seed_words(rng_key)
+    # counted past the dispatch decision: traces that took the packed
+    # Pallas kernel, not calls (the tiled path counts kernels.flash_tiled)
+    _obs.add("kernels.fused_attention_qkv")
     out = _flash_qkv(
         qkv, bias, seed, num_heads, D, tuple(statics.items()), interpret
     )
@@ -788,7 +790,6 @@ def fused_attention(
     """
     from .. import observability as _obs
 
-    _obs.add("kernels.fused_attention")
     B, H, S, D = q.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(D))
@@ -825,4 +826,6 @@ def fused_attention(
             "the jnp reference path (force_reference=True)"
         )
     seed = _seed_words(rng_key)
+    # counted past the dispatch decision: kernel traces, not calls
+    _obs.add("kernels.fused_attention")
     return _flash(q, k, v, bias, seed, tuple(statics.items()), interpret)

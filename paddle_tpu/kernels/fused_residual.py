@@ -20,8 +20,8 @@ mean/rstd and z), regenerating the identical dropout mask from the same
 per-row-block PRNG seeding — so no mask and no intermediate tensor ever
 reach HBM.
 
-Shapes: callers flatten to [R, N]; N % 128 == 0, R % 8 == 0 (else the
-jnp reference path runs). Dropout semantics are fluid's dropout_op.cc,
+Shapes: callers flatten to [R, N]; N % 128 == 0, R % 16 == 0 (else the
+jnp reference path runs); the row block shrinks with N (kernels/vmem.py). Dropout semantics are fluid's dropout_op.cc,
 as in flash_attention.py.
 """
 
@@ -36,27 +36,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 256 rows x 8K cols max keeps the bwd kernel (x, y, dout in VMEM + fp32
-# z/zhat temporaries) under the 16 MB scoped-vmem limit even when one
-# operand arrives fp32 (mixed AMP boundaries)
-ROW_BLOCK = 256
+from .vmem import row_block
 
 
 def supports(rows: int, n: int, dtype) -> bool:
+    # rows % 16: the dropout keep-mask draws (blk/2, N) PRNG words, and
+    # Mosaic refuses a draw of fewer than 8 rows — so the smallest block,
+    # and with it every `rows`, is a multiple of 16
     return (
         n % 128 == 0
         and n <= 8192
-        and rows % 8 == 0
+        and rows % 16 == 0
         and jnp.dtype(dtype) in (jnp.dtype(jnp.float32),
                                  jnp.dtype(jnp.bfloat16))
     )
 
 
-def _row_block(rows):
-    blk = min(ROW_BLOCK, rows)
-    while rows % blk:
-        blk //= 2
-    return max(blk, 2)
+def _row_block(rows, n, x_dtype, y_dtype):
+    """One block size for forward AND backward (the per-row-block PRNG
+    seeding must regenerate the identical mask), sized for the backward's
+    five [blk, N] blocks: x, y, dout, dx (x's dtype) and dy (y's). At
+    N = 8192 fp32 that is 32 rows; at BERT's 768 the full 256."""
+    return row_block(rows, n, [x_dtype] * 3 + [y_dtype] * 2)
 
 
 def _seed_block(seed_ref):
@@ -155,7 +156,7 @@ def fused_dropout_add_ln_fwd(x2d, y2d, g, c, seed, rate, is_test, upscale,
         g = jnp.ones((N,), jnp.float32)
     if c is None:
         c = jnp.zeros((N,), jnp.float32)
-    blk = _row_block(R)
+    blk = _row_block(R, N, x2d.dtype, y2d.dtype)
     kern = functools.partial(
         _fwd_kernel, rate=float(rate), is_test=bool(is_test),
         upscale=bool(upscale), eps=float(eps),
@@ -182,7 +183,7 @@ def fused_dropout_add_ln_bwd(x2d, y2d, g, seed, d_out, rate, is_test,
     R, N = x2d.shape
     if g is None:
         g = jnp.ones((N,), jnp.float32)
-    blk = _row_block(R)
+    blk = _row_block(R, N, x2d.dtype, y2d.dtype)
     kern = functools.partial(
         _bwd_kernel, rate=float(rate), is_test=bool(is_test),
         upscale=bool(upscale), eps=float(eps),

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-ROW_BLOCK = 256
+from .vmem import row_block
 
 
 def supports(rows: int, n: int, dtype) -> bool:
@@ -40,11 +40,10 @@ def supports(rows: int, n: int, dtype) -> bool:
     )
 
 
-def _row_block(rows):
-    blk = min(ROW_BLOCK, rows)
-    while rows % blk:
-        blk //= 2
-    return max(blk, 1)
+def _row_block(rows, n, dtype):
+    """Sized for the backward's three [blk, N] blocks (x, dy, dx); the
+    forward (x, y and two [blk, 1] statistics) fits wherever that does."""
+    return row_block(rows, n, [dtype] * 3)
 
 
 def _fwd_kernel(x_ref, scale_ref, bias_ref, y_ref, mean_ref, var_ref, *, eps):
@@ -102,7 +101,7 @@ def layer_norm_fwd(x2d, scale, bias, eps, interpret=False):
         scale = jnp.ones((N,), jnp.float32)
     if bias is None:
         bias = jnp.zeros((N,), jnp.float32)
-    blk = _row_block(R)
+    blk = _row_block(R, N, x2d.dtype)
     y, mean, var = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=float(eps)),
         grid=(R // blk,),
@@ -135,7 +134,7 @@ def layer_norm_bwd(x2d, scale, d_y, eps, interpret=False):
     R, N = x2d.shape
     if scale is None:
         scale = jnp.ones((N,), jnp.float32)
-    blk = _row_block(R)
+    blk = _row_block(R, N, x2d.dtype)
     dx, ds, db = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=float(eps)),
         grid=(R // blk,),

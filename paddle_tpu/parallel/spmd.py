@@ -20,28 +20,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False,
-               axis_names=None):
-    """Version-compat shard_map: new-style jax.shard_map (check_vma /
-    axis_names) when present, else jax.experimental.shard_map.shard_map
-    (check_rep, and `auto` = the COMPLEMENT of axis_names)."""
-    if hasattr(jax, "shard_map"):
-        kw = {"axis_names": axis_names} if axis_names is not None else {}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, **kw,
-    )
-
-
 def spec_for(program, name) -> P:
     s = program._sharding.get(name)
     if not s:
@@ -119,6 +97,29 @@ def _project_spec(spec, manual):
     return P(*out)
 
 
+def _staged_dispatch(jitted, stage, counter, mesh):
+    """fn(feeds, smut, sro, step_key): stage the arguments onto the mesh,
+    then dispatch `jitted`. ``fn.lower`` stages the same way and lowers
+    without dispatching (Executor.lower)."""
+    from .. import observability as _obs
+
+    _obs.set_gauge("collective.mesh_devices", mesh.size)
+    mesh_desc = "x".join(f"{k}{v}" for k, v in mesh.shape.items())
+
+    def fn(feeds, smut, sro, step_key):
+        _obs.add(counter)
+        # a traced child span under executor.step: in a causal trace the
+        # staging+dispatch segment is attributable to the mesh, and the
+        # mesh shape rides on the span for the pod-timeline merge
+        with _obs.span("spmd.dispatch", category="spmd", mesh=mesh_desc):
+            return jitted(*stage(feeds, smut, sro), step_key)
+
+    fn.lower = lambda feeds, smut, sro, step_key: jitted.lower(
+        *stage(feeds, smut, sro), step_key
+    )
+    return fn
+
+
 def wrap_shard_map(
     traced, program, mesh, state_ro, state_mut, write_back, fetch_names,
     manual_axes=None,
@@ -158,61 +159,53 @@ def wrap_shard_map(
             tuple(body_spec(n) for n in fetch_names),
             {n: body_spec(n) for n in write_back},
         )
-        sm = _shard_map(
+        sm = jax.shard_map(
             traced,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
             check_vma=False,
-            axis_names=manual if partial_manual else None,
+            **({"axis_names": manual} if partial_manual else {}),
         )
         return sm(feeds, smut, sro, step_key)
 
     jitted = jax.jit(run, donate_argnums=(1,))
     multiproc = _spans_processes(mesh)
-    from .. import observability as _obs
 
-    _obs.set_gauge("collective.mesh_devices", mesh.size)
-
-    mesh_desc = "x".join(f"{k}{v}" for k, v in mesh.shape.items())
-
-    def fn(feeds, smut, sro, step_key):
-        _obs.add("collective.shard_map_dispatches")
-        # a traced child span under executor.step: in a causal trace the
-        # staging+dispatch segment is attributable to the mesh, and the
-        # mesh shape rides on the span for the pod-timeline merge
-        with _obs.span("spmd.dispatch", category="spmd", mesh=mesh_desc):
-            feeds = {
-                k: stage_global(v, mesh, spec_for(program, k), multiproc)
-                for k, v in feeds.items()
+    def stage(feeds, smut, sro):
+        feeds = {
+            k: stage_global(v, mesh, spec_for(program, k), multiproc)
+            for k, v in feeds.items()
+        }
+        if multiproc or partial_manual:
+            # multi-process: state must be global arrays; each
+            # process's scope holds the FULL value (startup ran
+            # locally), so local_is_full slices out this process's
+            # part.
+            # hybrid: the Auto axes' sharding lives ONLY on the
+            # arrays' committed NamedShardings (the body specs
+            # project them away), so state must be staged with its
+            # full spec or mp-annotated params silently stay
+            # replicated on every device
+            smut = {
+                k: stage_global(
+                    v, mesh, spec_for(program, k), multiproc,
+                    local_is_full=True,
+                )
+                for k, v in smut.items()
             }
-            if multiproc or partial_manual:
-                # multi-process: state must be global arrays; each
-                # process's scope holds the FULL value (startup ran
-                # locally), so local_is_full slices out this process's
-                # part.
-                # hybrid: the Auto axes' sharding lives ONLY on the
-                # arrays' committed NamedShardings (the body specs
-                # project them away), so state must be staged with its
-                # full spec or mp-annotated params silently stay
-                # replicated on every device
-                smut = {
-                    k: stage_global(
-                        v, mesh, spec_for(program, k), multiproc,
-                        local_is_full=True,
-                    )
-                    for k, v in smut.items()
-                }
-                sro = {
-                    k: stage_global(
-                        v, mesh, spec_for(program, k), multiproc,
-                        local_is_full=True,
-                    )
-                    for k, v in sro.items()
-                }
-            return jitted(feeds, smut, sro, step_key)
+            sro = {
+                k: stage_global(
+                    v, mesh, spec_for(program, k), multiproc,
+                    local_is_full=True,
+                )
+                for k, v in sro.items()
+            }
+        return feeds, smut, sro
 
-    return fn
+    return _staged_dispatch(
+        jitted, stage, "collective.shard_map_dispatches", mesh
+    )
 
 
 def wrap_gspmd(
@@ -229,9 +222,6 @@ def wrap_gspmd(
 
     jitted = jax.jit(traced, donate_argnums=(1,))
     multiproc = _spans_processes(mesh)
-    from .. import observability as _obs
-
-    _obs.set_gauge("collective.mesh_devices", mesh.size)
 
     def put(k, v):
         # multi-process gspmd convention: every process holds the FULL
@@ -242,17 +232,12 @@ def wrap_gspmd(
             v, mesh, spec_for(program, k), multiproc, local_is_full=True
         )
 
-    mesh_desc = "x".join(f"{k}{v}" for k, v in mesh.shape.items())
+    def stage(feeds, smut, sro):
+        return tuple(
+            {k: put(k, v) for k, v in d.items()} for d in (feeds, smut, sro)
+        )
 
-    def fn(feeds, smut, sro, step_key):
-        _obs.add("collective.gspmd_dispatches")
-        with _obs.span("spmd.dispatch", category="spmd", mesh=mesh_desc):
-            feeds = {k: put(k, v) for k, v in feeds.items()}
-            smut = {k: put(k, v) for k, v in smut.items()}
-            sro = {k: put(k, v) for k, v in sro.items()}
-            return jitted(feeds, smut, sro, step_key)
-
-    return fn
+    return _staged_dispatch(jitted, stage, "collective.gspmd_dispatches", mesh)
 
 
 def device_put_sharded(x, mesh, pspec):

@@ -478,8 +478,12 @@ def _idle_pulse(state, interval):
 def worker_main(argv=None):
     from ..resilience.health import PREEMPTION_EXIT_CODE
 
+    from ..core import compile_cache
+
     args = parse_args(argv)
     srv, port = bind_serving_socket(args.host, args.port)
+    # a respawned worker re-warms its buckets from the compile cache
+    compile_cache.enable()
     state = _WorkerState(args)
 
     import signal as _signal

@@ -1,0 +1,229 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is described,
+not attached (on-chip-measurement guide, section 2): the chip's own
+compiler is installed here and refuses what the chip would refuse — a
+slice not aligned to the tiling, more VMEM than a kernel may use. Nothing
+runs, so this says nothing about results or times.
+
+Two groups, one parametrised test:
+
+* every kernel chip_smoke.py reaches, at the shapes it reaches it with
+  (chip_smoke.REAL), forward and backward;
+* the corners of ``supports()`` of the two row-wise kernels: whatever
+  ``supports()`` admits must compile (ISSUE 21: at rows=16384 the fixed
+  256-row block ran out of VMEM from n=2048 on).
+
+The dispatch gates read ``jax.default_backend()``, which is ``cpu`` here,
+so the kernel entry points are compiled directly.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import math  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
+from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
+from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
+from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
+from paddle_tpu.kernels import ring_block as rb  # noqa: E402
+
+SZ = chip_smoke.REAL
+BF16, F32 = jnp.bfloat16, jnp.float32
+H, D = 12, 64          # BERT-base / GPT-small heads
+HID = H * D
+ROWS = SZ.bert_batch * SZ.bert_seq  # 16384 rows of the train step
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip; the persistent
+    compile cache is off while the module runs (an entry written for a
+    described chip cannot be read back without one, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu / no such topology in this image
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _statics(rate, is_test=False, causal=False):
+    return dict(scale=1.0 / math.sqrt(D), rate=rate, is_test=is_test,
+                upscale=False, causal=causal)
+
+
+def _packed(batch, seq, dtype, rate, is_test=False):
+    """(fwd, bwd) of the packed-QKV whole-row kernel."""
+    st = _statics(rate, is_test)
+    qkv = ((batch, seq, 3 * HID), dtype)
+    bias, seed = ((batch, seq), F32), ((2,), jnp.uint32)
+    out = ((batch, seq, HID), dtype)
+    fwd = (lambda q, b, s: fa._pallas_fwd_qkv(q, b, s, H, D, st, False),
+           (qkv, bias, seed))
+    bwd = (lambda q, b, s, do: fa._pallas_bwd_qkv(q, b, s, do, H, D, st,
+                                                  False),
+           (qkv, bias, seed, out))
+    return fwd, bwd
+
+
+def _whole_row(batch, seq, dtype):
+    """(fwd, bwd) of the 4-D whole-row kernel at its S cap."""
+    st = _statics(0.1)
+    x = ((batch, H, seq, D), dtype)
+    bias, seed = ((batch, seq), F32), ((2,), jnp.uint32)
+    fwd = (lambda q, k, v, b, s: fa._pallas_fwd(q, k, v, b, s, st, False),
+           (x, x, x, bias, seed))
+    bwd = (lambda q, k, v, b, s, do: fa._pallas_bwd(q, k, v, b, s, do, st,
+                                                    False),
+           (x, x, x, bias, seed, x))
+    return fwd, bwd
+
+
+def _tiled(batch, seq, dtype, rate):
+    """(fwd, bwd) of the KV-tiled kernels, causal."""
+    st = _statics(rate, causal=True)
+    qkv = ((batch, seq, 3 * HID), dtype)
+    bias, seed = ((batch, seq), F32), ((2,), jnp.uint32)
+    out, lse = ((batch, seq, HID), dtype), ((batch, seq, HID), F32)
+    fwd = (lambda q, b, s: ft.flash_tiled_fwd(q, b, s, H, D, st),
+           (qkv, bias, seed))
+    bwd = (lambda q, b, s, do, o, l: ft.flash_tiled_bwd(q, b, s, do, o, l,
+                                                        H, D, st),
+           (qkv, bias, seed, out, out, lse))
+    return fwd, bwd
+
+
+def _ring(batch, s_local, dtype):
+    """(fwd, dq, dkv) shard kernels of ring attention, causal."""
+    scale = 1.0 / math.sqrt(D)
+    x = ((batch, s_local, HID), dtype)
+    f32 = ((batch, s_local, HID), F32)
+    offs = ((2,), jnp.int32)
+    fwd = (lambda q, k, v, o: rb.shard_fwd(q, k, v, o, H, D, True, scale,
+                                           False),
+           (x, x, x, offs))
+    args = (x, x, x, x, f32, f32, offs)
+    dq = (lambda q, k, v, do, l, d, o: rb.shard_dq(
+        q, k, v, do, l, d, o, H, D, True, scale, False), args)
+    dkv = (lambda q, k, v, do, l, d, o: rb.shard_dkv(
+        q, k, v, do, l, d, o, H, D, True, scale, False), args)
+    return fwd, dq, dkv
+
+
+def _fused_residual(rows, n, dtype, rate, is_test=False):
+    x, g, seed = ((rows, n), dtype), ((n,), F32), ((2,), jnp.uint32)
+    fwd = (lambda a, b, g_, c, s: fr.fused_dropout_add_ln_fwd(
+        a, b, g_, c, s, rate, is_test, False, 1e-5), (x, x, g, g, seed))
+    bwd = (lambda a, b, g_, s, do: fr.fused_dropout_add_ln_bwd(
+        a, b, g_, s, do, rate, is_test, False, 1e-5), (x, x, g, seed, x))
+    return fwd, bwd
+
+
+def _layer_norm(rows, n, dtype):
+    x, g = ((rows, n), dtype), ((n,), F32)
+    fwd = (lambda a, g_, c: ln.layer_norm_fwd(a, g_, c, 1e-5), (x, g, g))
+    bwd = (lambda a, g_, dy: ln.layer_norm_bwd(a, g_, dy, 1e-5), (x, g, x))
+    return fwd, bwd
+
+
+def _cases():
+    cases = {}
+
+    def add(name, parts, labels=("fwd", "bwd")):
+        for label, part in zip(labels, parts):
+            cases[f"{name}-{label}"] = part
+
+    b, s = SZ.bert_batch, SZ.bert_seq
+    # train / kernels phases: BERT-base step, AMP bf16, dropout 0.1
+    add(f"packed-b{b}-s{s}-bf16", _packed(b, s, BF16, 0.1))
+    add(f"fused_residual-{ROWS}x{HID}-bf16",
+        _fused_residual(ROWS, HID, BF16, 0.1))
+    add(f"layer_norm-{ROWS}x{HID}-bf16", _layer_norm(ROWS, HID, BF16))
+    # four-chip dp legs: a shard's quarter of the batch
+    add(f"packed-b{b // 4}-s{s}-bf16", _packed(b // 4, s, BF16, 0.0))
+    # longctx phase: GPT-small causal at S=4096, AMP bf16, dropout 0.1
+    add(f"tiled-b{SZ.long_batch}-s{SZ.long_seq}-bf16",
+        _tiled(SZ.long_batch, SZ.long_seq, BF16, 0.1))
+    # serve phase: frozen fp32 graph in test mode, largest and smallest bucket
+    for bucket in (max(SZ.serve_buckets), min(SZ.serve_buckets)):
+        add(f"packed-b{bucket}-s{SZ.serve_seq}-f32-test",
+            _packed(bucket, SZ.serve_seq, F32, 0.1, is_test=True)[:1])
+        add(f"fused_residual-{bucket * SZ.serve_seq}x{HID}-f32-test",
+            _fused_residual(bucket * SZ.serve_seq, HID, F32, 0.1,
+                            is_test=True)[:1])
+    # whole-row kernels at their S cap (the packed layout's fallback)
+    add(f"packed-b2-s{fa.MAX_SEQ}-bf16", _packed(2, fa.MAX_SEQ, BF16, 0.1))
+    add(f"whole_row-b2-s{fa.MAX_SEQ}-f32", _whole_row(2, fa.MAX_SEQ, F32))
+    # --chips 4 ring leg: fp32, S=8192 over sp=4, and its one-device
+    # comparison through the KV-tiled kernels
+    add(f"ring-b{SZ.ring_batch}-s{SZ.ring_seq // 4}-f32",
+        _ring(SZ.ring_batch, SZ.ring_seq // 4, F32), ("fwd", "dq", "dkv"))
+    add(f"tiled-b{SZ.ring_batch}-s{SZ.ring_seq}-f32",
+        _tiled(SZ.ring_batch, SZ.ring_seq, F32, 0.0))
+    # supports() corners of the row-wise kernels
+    for n in (768, 2048, 4096, 8192):
+        for dtype in (BF16, F32):
+            tag = f"{ROWS}x{n}-{jnp.dtype(dtype).name}"
+            if fr.supports(ROWS, n, dtype):
+                add(f"corner-fused_residual-{tag}",
+                    _fused_residual(ROWS, n, dtype, 0.1))
+            if ln.supports(ROWS, n, dtype):
+                add(f"corner-layer_norm-{tag}", _layer_norm(ROWS, n, dtype))
+    return cases
+
+
+_CASES = _cases()
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, specs = _CASES[name]
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in specs
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_supports_refuses_what_the_row_block_cannot_serve():
+    """The other side of the corner contract: beyond n=8192, off the lane
+    width, or (fused_residual) off the 16-row PRNG draw, supports() says
+    False and the jnp path runs."""
+    for mod in (fr, ln):
+        assert not mod.supports(ROWS, 16384, BF16)
+        assert not mod.supports(ROWS, 8192 + 128, F32)
+        assert not mod.supports(ROWS, 100, F32)
+        assert not mod.supports(ROWS, 768, jnp.float16)
+    assert not fr.supports(8, 768, F32)
+    assert fr.supports(16, 768, F32)
+    assert ln.supports(8, 768, F32)
+
+
+@pytest.mark.parametrize("n,dtype,expect", [
+    (768, BF16, 256), (768, F32, 256), (2048, F32, 128),
+    (4096, BF16, 64), (8192, BF16, 32), (8192, F32, 32),
+])
+def test_fused_residual_row_block(n, dtype, expect):
+    """BERT width keeps the 256-row block (no numerics change there: the
+    dropout mask is drawn per row block); wider rows shrink it."""
+    assert fr._row_block(ROWS, n, dtype, dtype) == expect
+    assert ROWS % fr._row_block(ROWS, n, dtype, dtype) == 0
