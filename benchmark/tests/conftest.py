@@ -1,0 +1,12 @@
+"""The harness's own tests: `pytest benchmark/tests -q` (tier-1 collects
+`tests/` only). Everything here runs on the CPU."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
