@@ -58,7 +58,9 @@ def _vjp_emit(ctx, op, ins):
         merged = {s: list(v) for s, v in fwd_ins.items()}
         for (slot, idx), val in zip(want, diff_vals):
             merged[slot][idx] = val
-        return fwd_def.emit(ctx, fwd_op, merged)
+        # the replayed forward keeps its op's name beneath `__vjp__`
+        with jax.named_scope(fwd_op.type):
+            return fwd_def.emit(ctx, fwd_op, merged)
 
     diff_vals = [fwd_ins[slot][idx] for slot, idx in want]
     # structural pre-pass: which outputs are differentiable (inexact dtype).

@@ -180,24 +180,22 @@ class Executor:
         return_numpy=True,
         use_program_cache=True,
     ):
-        import time
-
         from .. import observability as _obs
 
         _obs.add("executor.run_steps")
         self._last_run = None
-        with _obs.span("executor.step"):
-            t0 = time.perf_counter()
-            try:
+        step = _obs.span("executor.step")
+        try:
+            with step:
                 result = self._run_body(
                     program, feed, fetch_list, scope, return_numpy,
                     use_program_cache,
                 )
-            finally:
-                _obs.observe(
-                    "executor.step_latency", time.perf_counter() - t0
-                )
-        self._note_perf(time.perf_counter() - t0)
+        finally:
+            # the span's own stamps: None when monitoring is off
+            if step.seconds is not None:
+                _obs.observe("executor.step_latency", step.seconds)
+        self._note_perf(step.seconds)
         return result
 
     @staticmethod
@@ -219,7 +217,7 @@ class Executor:
 
         noted = self._last_run
         self._last_run = None
-        if noted is None or not _obs.enabled():
+        if noted is None or dt is None or not _obs.enabled():
             return
         compiled, fresh_compile, seg = noted
         est = compiled.est
@@ -312,11 +310,65 @@ class Executor:
         self, program, feed, fetch_list, scope, return_numpy,
         use_program_cache,
     ):
-        import time
-
+        """One step as four child spans of ``executor.step``: prologue
+        (entry to just before the compiled function is called; the PRNG
+        key's derivation is ``executor.rng_key`` inside it), dispatch
+        (the call: staging, enqueue and any back-pressure of the
+        runtime's queue), writeback (new state into the scope) and, for
+        ``return_numpy`` callers, fetch (the wait for the device plus the
+        copy to the host). In a profiler capture an idle device's gap
+        falls under the one the host was in."""
         from .. import observability as _obs
 
-        t_body = time.perf_counter()
+        with _obs.span("executor.prologue") as prologue:
+            compiled, fresh_compile, scope, call_args = self._prologue(
+                program, feed, fetch_list, scope, use_program_cache
+            )
+        with _obs.span("executor.dispatch") as dispatch:
+            fetches, new_state = compiled.fn(*call_args)
+        # write-back FIRST: state_mut buffers were donated, so skipping the
+        # write-back on error would leave the scope holding deleted arrays
+        # (params irretrievably lost right when the user wants to inspect)
+        with _obs.span("executor.writeback") as writeback:
+            for n, v in new_state.items():
+                scope.set_var(n, v)
+        if return_numpy:
+            with _obs.span("executor.fetch") as fetch:
+                fetches = [np.asarray(f) for f in fetches]
+            if fetch.seconds is not None:
+                # host/device split for the per-step attribution
+                # (perf.wait_*), from the spans' own stamps: host =
+                # prologue + write-back; device = the dispatch and the
+                # wait for its outputs. ONLY return_numpy callers wait
+                # inside this call; an async caller's dispatch returns
+                # before the device is done, so such runs publish no
+                # attribution sample.
+                self._last_run = (compiled, fresh_compile, (
+                    prologue.seconds + writeback.seconds,
+                    dispatch.seconds + fetch.seconds,
+                ))
+        if compiled.nan_ops is not None:
+            bad = np.asarray(fetches[-1])
+            fetches = fetches[:-1]
+            if bad.any():
+                idx = int(np.argmax(bad))
+                op = compiled.nan_ops[idx]
+                from ..errors import NonFiniteError
+
+                raise NonFiniteError(
+                    f"NaN/Inf detected in outputs of op #{idx} "
+                    f"{op.type!r} — FLAGS_check_nan_inf mode "
+                    "(reference details/nan_inf_utils_detail.cc)",
+                    op=op,
+                    outputs=op.output_names(),
+                )
+        return list(fetches)
+
+    def _prologue(self, program, feed, fetch_list, scope, use_program_cache):
+        """Everything before the compiled call: (compiled, fresh_compile,
+        the resolved scope, the call's arguments)."""
+        from .. import observability as _obs
+
         # the shared prologue keys the cache on the Program OBJECT
         # (identity hash, strong ref) so a freed Program's recycled id
         # cannot produce a stale hit; _prepared is the single source of
@@ -353,8 +405,8 @@ class Executor:
                     self._est_memo.pop(next(iter(self._est_memo)))
                 self._est_memo[key] = est
             compiled.est = est
-        # seg (host vs device split) is filled in below once the dispatch
-        # completes; a run that raises before then reports no attribution
+        # seg (host vs device split) is filled in by _run_body once the
+        # fetch completes; a run that raises before then reports none
         self._last_run = (compiled, fresh_compile, None)
 
         state_ro = {n: self._from_scope(scope, n, block) for n in compiled.state_ro}
@@ -392,65 +444,17 @@ class Executor:
         program._rng_step += 1
         from ..core.random import prng_impl
 
-        step_key = jax.random.fold_in(
-            jax.random.key(seed, impl=prng_impl()), step
-        )
-
-        # host/device split for the per-step attribution (perf.wait_*):
-        # everything up to the dispatch is host prologue; the dispatch is
-        # bounded with block_until_ready so the device segment is real
-        # device wall time, not async-dispatch return time. ONLY on the
-        # return_numpy path: those callers synchronize inside this very
-        # call anyway (np.asarray below), so the early block changes
-        # nothing — while return_numpy=False callers (bench.py's
-        # pipelined timing loops) rely on async dispatch overlapping
-        # step N's device work with step N+1's host prologue, and an
-        # attribution block there would serialize the accelerator
-        # pipeline. Such runs simply publish no attribution sample.
-        attributing = return_numpy and _obs.enabled()
-        t_dispatch = time.perf_counter()
-        fetches, new_state = compiled.fn(feed_arrays, state_mut, state_ro, step_key)
-        if attributing:
-            # one leaf suffices: XLA materializes every output of the
-            # computation together, and walking the whole state pytree
-            # (hundreds of arrays) would cost more than the span itself
-            leaf = fetches[0] if fetches else next(
-                iter(new_state.values()), None
+        # a span of its own inside the prologue: the key is derived by
+        # tiny device programs, and on a mesh the runtime's wait for room
+        # in its queue lands on them, not on the step's dispatch (PERF.md,
+        # Findings PR 24)
+        with _obs.span("executor.rng_key"):
+            step_key = jax.random.fold_in(
+                jax.random.key(seed, impl=prng_impl()), step
             )
-            if leaf is not None:
-                jax.block_until_ready(leaf)
-        t_device_end = time.perf_counter()
-        # write-back FIRST: state_mut buffers were donated, so skipping the
-        # write-back on error would leave the scope holding deleted arrays
-        # (params irretrievably lost right when the user wants to inspect)
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        if attributing:
-            # host = prologue + write-back epilogue; the trailing numpy
-            # conversion is already-on-host copies, charged to the caller
-            self._last_run = (compiled, fresh_compile, (
-                (t_dispatch - t_body)
-                + (time.perf_counter() - t_device_end),
-                t_device_end - t_dispatch,
-            ))
-        if compiled.nan_ops is not None:
-            bad = np.asarray(fetches[-1])
-            fetches = fetches[:-1]
-            if bad.any():
-                idx = int(np.argmax(bad))
-                op = compiled.nan_ops[idx]
-                from ..errors import NonFiniteError
-
-                raise NonFiniteError(
-                    f"NaN/Inf detected in outputs of op #{idx} "
-                    f"{op.type!r} — FLAGS_check_nan_inf mode "
-                    "(reference details/nan_inf_utils_detail.cc)",
-                    op=op,
-                    outputs=op.output_names(),
-                )
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+        return compiled, fresh_compile, scope, (
+            feed_arrays, state_mut, state_ro, step_key
+        )
 
     # ------------------------------------------------------------------
     def _estimate(self, program, feed_arrays):

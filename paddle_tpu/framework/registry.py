@@ -267,7 +267,10 @@ def run_op(ctx, op, env):
         slot: [env[n] if n else None for n in names]
         for slot, names in op.inputs.items()
     }
-    outs = op_def.emit(ctx, op, ins)
+    # the op's type rides into the compiled step's HLO metadata
+    # (op_name), so a profiler's device events can be read by Fluid op
+    with jax.named_scope(op.type):
+        outs = op_def.emit(ctx, op, ins)
     for slot, names in op.outputs.items():
         vals = outs.get(slot, [])
         for n, v in itertools.zip_longest(names, vals):

@@ -218,6 +218,7 @@ def _pallas_fwd(q, k, v, bias, seed, statics, interpret):
     kern = functools.partial(_fwd_kernel, **statics)
     return pl.pallas_call(
         kern,
+        name="flash_attention_fwd",
         grid=(B, H),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -238,6 +239,7 @@ def _pallas_bwd(q, k, v, bias, seed, do, statics, interpret):
     kern = functools.partial(_bwd_kernel, **statics)
     dq, dk, dv, dbias = pl.pallas_call(
         kern,
+        name="flash_attention_bwd",
         grid=(B, H),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -375,6 +377,7 @@ def _pallas_fwd_qkv(qkv, bias, seed, H, D, statics, interpret):
     kern = functools.partial(_fwd_kernel_qkv, D=D, **statics)
     return pl.pallas_call(
         kern,
+        name="flash_attention_qkv_fwd",
         grid=(B, num_groups),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -396,6 +399,7 @@ def _pallas_bwd_qkv(qkv, bias, seed, do, H, D, statics, interpret):
     kern = functools.partial(_bwd_kernel_qkv, D=D, **statics)
     dq, dk, dv, dbias = pl.pallas_call(
         kern,
+        name="flash_attention_qkv_bwd",
         grid=(B, num_groups),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

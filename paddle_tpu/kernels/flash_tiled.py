@@ -231,6 +231,7 @@ def flash_tiled_fwd(qkv, bias, seed, H, D, statics, interpret=False):
     kern = functools.partial(_fwd_kernel, D=D, BQ=BQ, BK=BK, **statics)
     out, lse = pl.pallas_call(
         kern,
+        name="flash_tiled_fwd",
         grid=(B, G, S // BQ, S // BK),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -410,6 +411,7 @@ def flash_tiled_bwd(qkv, bias, seed, do, out, lse, H, D, statics,
     dkv_kern = functools.partial(_dkv_kernel, D=D, BQ=BQ, BK=BK, **statics)
     dk, dv, dbias_parts = pl.pallas_call(
         dkv_kern,
+        name="flash_tiled_dkv",
         grid=(B, G, S // BK, S // BQ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -457,6 +459,7 @@ def flash_tiled_bwd(qkv, bias, seed, do, out, lse, H, D, statics,
     dq_kern = functools.partial(_dq_kernel, D=D, BQ=BQ, BK=BK, **statics)
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_tiled_dq",
         grid=(B, G, S // BQ, S // BK),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

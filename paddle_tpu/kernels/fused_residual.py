@@ -163,6 +163,7 @@ def fused_dropout_add_ln_fwd(x2d, y2d, g, c, seed, rate, is_test, upscale,
     )
     return pl.pallas_call(
         kern,
+        name="fused_residual_fwd",
         grid=(R // blk,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -190,6 +191,7 @@ def fused_dropout_add_ln_bwd(x2d, y2d, g, seed, d_out, rate, is_test,
     )
     dx, dy, dg, dc = pl.pallas_call(
         kern,
+        name="fused_residual_bwd",
         grid=(R // blk,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -104,6 +104,7 @@ def layer_norm_fwd(x2d, scale, bias, eps, interpret=False):
     blk = _row_block(R, N, x2d.dtype)
     y, mean, var = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=float(eps)),
+        name="layer_norm_fwd",
         grid=(R // blk,),
         in_specs=[
             pl.BlockSpec((blk, N), lambda r: (r, 0),
@@ -137,6 +138,7 @@ def layer_norm_bwd(x2d, scale, d_y, eps, interpret=False):
     blk = _row_block(R, N, x2d.dtype)
     dx, ds, db = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=float(eps)),
+        name="layer_norm_bwd",
         grid=(R // blk,),
         in_specs=[
             pl.BlockSpec((blk, N), lambda r: (r, 0),

@@ -167,6 +167,7 @@ def shard_fwd(q, k, v, offs, H, D, causal, scale, interpret):
                              scale=scale, causal=causal)
     return pl.pallas_call(
         kern,
+        name="ring_block_fwd",
         grid=(B, NG, Sq // BQ, Sk // BK),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -283,6 +284,7 @@ def shard_dq(q, k, v, do, lse, delta, offs, H, D, causal, scale, interpret):
                              scale=scale, causal=causal)
     return pl.pallas_call(
         kern,
+        name="ring_block_dq",
         grid=(B, NG, Sq // BQ, Sk // BK),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -316,6 +318,7 @@ def shard_dkv(q, k, v, do, lse, delta, offs, H, D, causal, scale, interpret):
                          memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kern,
+        name="ring_block_dkv",
         grid=(B, NG, Sk // BK, Sq // BQ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -1,10 +1,17 @@
-"""Host spans: named wall-clock regions in a bounded ring buffer.
+"""Host spans: named regions in a bounded ring buffer AND in the profiler.
 
-`span("name")` is the app-level sibling of profiler.RecordEvent: where
-RecordEvent only annotates an *active* jax.profiler capture, spans record
-always (unless the monitor kill-switch is off) into a deque capped at
-PADDLE_TPU_SPAN_BUFFER entries (default 4096) — old spans fall off, a
-long-running trainer never grows memory.
+`span("name")` records always (unless the monitor kill-switch is off) into
+a deque capped at PADDLE_TPU_SPAN_BUFFER entries (default 4096) — old
+spans fall off, counted as ``trace.spans_dropped``, so a long-running
+trainer never grows memory. The ring's stamps are wall-clock; a
+jax.profiler capture runs on a clock of its own (events start near 0 at
+``start_trace``), so the ring alone cannot be laid over the device's
+lines. Every LIVE span therefore also enters a
+``jax.profiler.TraceAnnotation(name)`` — the mechanism behind
+profiler.RecordEvent: an atomic flag read while no capture runs; under a
+capture the span sits on its thread's line of the host plane, on the
+device lines' clock and nested as the code nests. :func:`record` spans
+are retrospective (their start is already past) and stay ring-only.
 
 Causal tracing (trace.py): when a TraceContext is active on the recording
 thread, the span record additionally carries ``trace_id`` / ``span_id`` /
@@ -34,6 +41,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from . import metrics, trace
 
 try:
@@ -46,15 +55,30 @@ _lock = threading.Lock()
 _spans: collections.deque = collections.deque(maxlen=_MAX_SPANS)
 
 
-class _Span:
-    """Context manager AND decorator recording one ring-buffer span."""
+def _push(rec):
+    """Append to the ring; a span the full ring pushes out is counted."""
+    with _lock:
+        dropped = _spans.maxlen and len(_spans) == _spans.maxlen
+        _spans.append(rec)
+    if dropped:
+        metrics.add("trace.spans_dropped")
 
-    __slots__ = ("name", "category", "args", "_wall_us", "_t0", "_trace")
+
+class _Span:
+    """Context manager AND decorator recording one span: a ring-buffer
+    record plus a profiler annotation while the body runs."""
+
+    __slots__ = ("name", "category", "args", "seconds", "_wall_us", "_t0",
+                 "_trace", "_annotation")
 
     def __init__(self, name, category="host", args=None):
         self.name = name
         self.category = category
         self.args = args or {}
+        # the span's own duration once it has ended (None while it runs,
+        # and when monitoring is off): callers that split a step by its
+        # child spans read this instead of a second clock
+        self.seconds = None
         self._t0 = None
         self._trace = None  # (trace_id, span_id, parent_id) when traced
 
@@ -66,6 +90,7 @@ class _Span:
 
     def __enter__(self):
         self._trace = None
+        self.seconds = None
         if metrics.enabled():
             self._wall_us = time.time_ns() / 1e3
             self._t0 = time.perf_counter_ns()
@@ -74,15 +99,19 @@ class _Span:
                 sid = trace.new_id()
                 self._trace = (ctx.trace_id, sid, ctx.span_id)
                 trace._push(ctx.child(sid))
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
         else:
             self._t0 = None
         return self
 
     def __exit__(self, *exc):
         if self._t0 is not None:
+            self._annotation.__exit__(*exc)
             if self._trace is not None:
                 trace._pop()
             dur_us = (time.perf_counter_ns() - self._t0) / 1e3
+            self.seconds = dur_us / 1e6
             rec = {
                 "name": self.name,
                 "cat": self.category,
@@ -95,8 +124,7 @@ class _Span:
                 rec["trace_id"], rec["span_id"], rec["parent_id"] = \
                     self._trace
                 metrics.add("trace.spans")
-            with _lock:
-                _spans.append(rec)
+            _push(rec)
         return False
 
     def __call__(self, fn):
@@ -119,7 +147,9 @@ def record(name, duration_s, category="host", ctx=None, args=None):
     request's queue wait, a batch slot's dispatch share). ``ctx`` parents
     the span (default: the thread's active context; pass a captured
     context to file it under another thread's trace). Returns the new
-    span_id, or None when monitoring is off."""
+    span_id, or None when monitoring is off. Ring-only: a profiler
+    annotation cannot be entered in the past, so a capture does not show
+    these."""
     if not metrics.enabled():
         return None
     if ctx is None:
@@ -140,8 +170,7 @@ def record(name, duration_s, category="host", ctx=None, args=None):
         rec["span_id"] = sid
         rec["parent_id"] = ctx.span_id
         metrics.add("trace.spans")
-    with _lock:
-        _spans.append(rec)
+    _push(rec)
     return sid
 
 

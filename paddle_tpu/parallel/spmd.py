@@ -110,9 +110,13 @@ def _staged_dispatch(jitted, stage, counter, mesh):
         _obs.add(counter)
         # a traced child span under executor.step: in a causal trace the
         # staging+dispatch segment is attributable to the mesh, and the
-        # mesh shape rides on the span for the pod-timeline merge
+        # mesh shape rides on the span for the pod-timeline merge;
+        # spmd.stage is the host's part of it (placing the arguments),
+        # the rest the enqueue and whatever back-pressure it meets
         with _obs.span("spmd.dispatch", category="spmd", mesh=mesh_desc):
-            return jitted(*stage(feeds, smut, sro), step_key)
+            with _obs.span("spmd.stage", category="spmd"):
+                staged = stage(feeds, smut, sro)
+            return jitted(*staged, step_key)
 
     fn.lower = lambda feeds, smut, sro, step_key: jitted.lower(
         *stage(feeds, smut, sro), step_key

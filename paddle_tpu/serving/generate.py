@@ -150,11 +150,27 @@ class GPTGenerator:
                 f"context {self.context_len} + {max_new_tokens} new tokens "
                 f"exceeds max_len {self.max_len}"
             )
-        self.reset()
         # child spans under the caller's trace (the serving router
         # activates the request's context around runner.run): the
-        # prefill/decode split of a generate request's latency — each
-        # executor.step inside nests one level further
+        # reset / prefill / decode split of a generate request's latency —
+        # each executor.step inside nests one level further, and each
+        # serving.sample is the host's own work between two of them
+        # (argmax over the fetched logits, the next step's feed)
+        with _obs.span("serving.cache_reset", category="serving"):
+            self.reset()
+        out = np.zeros((self.batch, max_new_tokens), np.int64)
+
+        def sample(logits, t):
+            with _obs.span("serving.sample", category="serving"):
+                nxt = np.argmax(np.asarray(logits)[:, -1, :], axis=-1)
+                out[:, t] = nxt
+                # the fed token's position
+                pos = self.context_len + t
+                return {
+                    "token_ids": nxt[:, None].astype(np.int64),
+                    "pos_ids": np.array([[pos]], np.int64),
+                }
+
         with self._scope_guard(self.scope):
             with _obs.span("serving.prefill", category="serving",
                            context_len=self.context_len):
@@ -162,25 +178,15 @@ class GPTGenerator:
                     self.prefill_prog, feed={"context_ids": ids},
                     fetch_list=self._prefill_fetch, scope=self.scope,
                 )
-            _obs.add("serving.prefill_steps")
-            out = np.zeros((self.batch, max_new_tokens), np.int64)
-            nxt = np.argmax(np.asarray(logits)[:, -1, :], axis=-1)
-            out[:, 0] = nxt
+            feed = sample(logits, 0)
             with _obs.span("serving.decode_loop", category="serving",
                            tokens=int(max_new_tokens)):
                 for t in range(1, max_new_tokens):
-                    # position of the fed token
-                    pos = self.context_len + t - 1
                     (logits,) = self.executor.run(
-                        self.decode_prog,
-                        feed={
-                            "token_ids": nxt[:, None].astype(np.int64),
-                            "pos_ids": np.array([[pos]], np.int64),
-                        },
+                        self.decode_prog, feed=feed,
                         fetch_list=self._decode_fetch, scope=self.scope,
                     )
-                    nxt = np.argmax(np.asarray(logits)[:, -1, :], axis=-1)
-                    out[:, t] = nxt
+                    feed = sample(logits, t)
             _obs.add("serving.decode_steps", max(0, max_new_tokens - 1))
         return out
 

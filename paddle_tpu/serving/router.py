@@ -516,7 +516,15 @@ class Endpoint:
         while True:
             expired = []
             batch = None
-            with self._cond:
+            # live on the scheduler thread from the moment it starts
+            # waiting for work to the moment the batch is popped: a device
+            # idle for want of requests has this owner in a profiler
+            # capture (batch_size 0: the wait ended with nothing to run)
+            forming = self._obs.span(
+                "serving.form_batch", category="serving",
+                endpoint=self.name, batch_size=0,
+            )
+            with forming, self._cond:
                 while not self._qsize_locked() and not self._stopped:
                     self._cond.wait(0.05)
                 if self._stopped and not self._qsize_locked():
@@ -552,6 +560,7 @@ class Endpoint:
                     batch = self._pop_batch_locked(
                         min(self._qsize_locked(), max_bucket)
                     )
+                    forming.args["batch_size"] = len(batch)
                     # the bucket is chosen under the SAME lock hold that
                     # formed the batch: a concurrent brownout bucket-cap
                     # change must not shrink the target below the batch
@@ -671,7 +680,6 @@ class Endpoint:
             "serving.padding_waste", (bucket - n) / bucket,
             buckets=_RATIO_BUCKETS,
         )
-        self._obs.add("serving.padded_rows", bucket - n)
         goodput = late = 0
         for i, r in enumerate(batch):
             r.future.set_result([o[i] for o in outs])

@@ -1,0 +1,328 @@
+"""The program's spans in the profiler's trace (ISSUE 24): every live
+span is also a `jax.profiler.TraceAnnotation`, so under a capture it sits
+in the host plane on the device lines' clock, nested as the code nests;
+the ring keeps the same names; `record()` spans stay ring-only; a span
+the full ring pushes out is counted."""
+
+import collections
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import layers, observability as obs
+from paddle_tpu.framework import unique_name
+from paddle_tpu.observability import spans as span_ring
+
+PROGRAM_PREFIXES = ("executor.", "spmd.", "serving.")
+STEP_CHILDREN = ["executor.prologue", "executor.dispatch",
+                 "executor.writeback"]
+
+
+@pytest.fixture(autouse=True)
+def fresh():
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.framework.scope.Scope()
+    obs.set_enabled(True)
+    obs.reset()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            unique_name.guard():
+        yield
+    obs.set_enabled(None)
+    obs.reset()
+
+
+class Capture:
+    """A profiler capture (python tracer off) around the body; afterwards
+    `lines` = {host thread line: [(name, start_ns, end_ns)]} of the
+    program's annotations, by start."""
+
+    def __init__(self, path):
+        self.path = str(path)
+        self.lines = {}
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.path, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        from jax.profiler import ProfileData
+
+        jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(self.path, "**", "*.xplane.pb"),
+                          recursive=True)
+        data = ProfileData.from_file(max(files, key=os.path.getmtime))
+        for plane in data.planes:
+            if plane.name.startswith("/device:"):
+                continue
+            # threads share a line name ("python"): key each by position
+            for k, ln in enumerate(plane.lines):
+                events = sorted(
+                    (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in ln.events
+                    if ev.name.startswith(PROGRAM_PREFIXES)
+                )
+                if events:
+                    self.lines[f"{ln.name}#{k}"] = sorted(
+                        events, key=lambda e: e[1])
+        return False
+
+    def named(self, name):
+        return [(line, e) for line, events in self.lines.items()
+                for e in events if e[0] == name]
+
+    def children(self, line, parent):
+        """Names of the annotations directly inside `parent` on `line`,
+        in order (nested deeper ones left out)."""
+        _n, lo, hi = parent
+        inside = [e for e in self.lines[line]
+                  if e is not parent and lo <= e[1] and e[2] <= hi]
+        return [e[0] for e in inside
+                if not any(o is not e and o[1] <= e[1] and e[2] <= o[2]
+                           for o in inside)]
+
+
+def _fit_a_line():
+    x = fluid.data("x", [-1, 4], "float32")
+    y = fluid.data("y", [-1, 1], "float32")
+    loss = layers.reduce_mean(
+        layers.square_error_cost(layers.fc(x, size=1), y)
+    )
+    fluid.optimizer.SGD(0.01).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    rng = np.random.RandomState(0)
+    feed = {"x": rng.randn(8, 4).astype("float32"),
+            "y": rng.randn(8, 1).astype("float32")}
+    return exe, loss, feed
+
+
+def _ring_names():
+    return [s["name"] for s in obs.get_spans()]
+
+
+@pytest.mark.parametrize("return_numpy", [True, False])
+def test_executor_step_children_in_capture_and_ring(tmp_path, return_numpy):
+    exe, loss, feed = _fit_a_line()
+    exe.run(feed=feed, fetch_list=[loss])  # compile outside the capture
+    obs.reset()
+    with Capture(tmp_path) as cap:
+        exe.run(feed=feed, fetch_list=[loss], return_numpy=return_numpy)
+    want = STEP_CHILDREN + (["executor.fetch"] if return_numpy else [])
+    (line, step), = cap.named("executor.step")
+    # one thread of the host plane holds the step and all its children
+    assert cap.children(line, step) == want
+    (_l, prologue), = cap.named("executor.prologue")
+    assert cap.children(line, prologue) == ["executor.rng_key"]
+    assert sorted(_ring_names()) == sorted(
+        want + ["executor.step", "executor.rng_key"])
+    # the ring's children lie inside the ring's step, too
+    by_name = {s["name"]: s for s in obs.get_spans()}
+    outer = by_name["executor.step"]
+    for name in want:
+        s = by_name[name]
+        assert outer["ts"] <= s["ts"]
+        assert s["ts"] + s["dur"] <= outer["ts"] + outer["dur"] + 1.0
+
+
+def test_compile_is_a_child_of_the_prologue(tmp_path):
+    exe, loss, feed = _fit_a_line()
+    with Capture(tmp_path) as cap:
+        exe.run(feed=feed, fetch_list=[loss])
+    (line, step), = cap.named("executor.step")
+    (_l, prologue), = cap.named("executor.prologue")
+    assert cap.children(line, step)[0] == "executor.prologue"
+    assert cap.children(line, prologue) == ["executor.compile",
+                                            "executor.rng_key"]
+
+
+def test_monitor_off_leaves_neither_ring_nor_annotation(tmp_path):
+    exe, loss, feed = _fit_a_line()
+    exe.run(feed=feed, fetch_list=[loss])
+    obs.reset()
+    obs.set_enabled(False)
+    with Capture(tmp_path) as cap:
+        (lv,) = exe.run(feed=feed, fetch_list=[loss])
+    assert np.isfinite(lv).all()
+    assert cap.lines == {}
+    assert obs.get_spans() == []
+
+
+def test_host_device_split_comes_from_the_spans():
+    """`perf.step_attribution`'s host seconds are the prologue's and the
+    write-back's own durations: no clock beside the spans."""
+    exe, loss, feed = _fit_a_line()
+    exe.run(feed=feed, fetch_list=[loss])
+    obs.reset()
+    exe.run(feed=feed, fetch_list=[loss])
+    dur = {s["name"]: s["dur"] / 1e6 for s in obs.get_spans()}
+    table = obs.get_tables()["perf.step_attribution"]
+    assert table["host_stall_seconds"] == pytest.approx(
+        dur["executor.prologue"] + dur["executor.writeback"], rel=1e-9
+    )
+    hist = obs.get_histograms()
+    assert hist["executor.step_latency"]["sum"] == pytest.approx(
+        dur["executor.step"], rel=1e-9
+    )
+    assert hist["perf.host_stall_seconds"]["count"] == 1
+
+
+def test_spmd_stage_inside_dispatch_on_four_devices(tmp_path):
+    from paddle_tpu.parallel import make_mesh, shard_program
+
+    x = fluid.data("x", [-1, 4], "float32")
+    out = layers.fc(x, size=2)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    shard_program(fluid.default_main_program(), mesh,
+                  {"x": ("dp",), out.name: ("dp",)})
+    feed = {"x": np.ones((8, 4), np.float32)}
+    exe.run(feed=feed, fetch_list=[out])
+    obs.reset()
+    with Capture(tmp_path) as cap:
+        exe.run(feed=feed, fetch_list=[out])
+    (line, dispatch), = cap.named("executor.dispatch")
+    assert cap.children(line, dispatch) == ["spmd.dispatch"]
+    (_l, spmd), = cap.named("spmd.dispatch")
+    assert cap.children(line, spmd) == ["spmd.stage"]
+    ring = {s["name"]: s for s in obs.get_spans()}
+    assert ring["spmd.dispatch"]["args"] == {"mesh": "dp4"}
+    assert ring["spmd.stage"]["dur"] <= ring["spmd.dispatch"]["dur"]
+
+
+def test_generate_spans_sample_per_token_and_cover_the_loop(tmp_path):
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.serving import GPTGenerator
+
+    cfg = GPTConfig.tiny()
+    cfg.use_fused_attention = False
+    gen = GPTGenerator(cfg, batch=2, context_len=12, max_len=24)
+    gen.init_params(seed=11)
+    ctx = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, size=(2, 12)).astype(np.int64)
+    tokens = 8
+    first = gen.generate(ctx, tokens)  # compiles
+    obs.reset()
+    with Capture(tmp_path) as cap:
+        again = gen.generate(ctx, tokens)
+    np.testing.assert_array_equal(first, again)
+    names = collections.Counter(_ring_names())
+    assert names["serving.sample"] == tokens
+    assert names["serving.cache_reset"] == 1
+    assert names["serving.prefill"] == names["serving.decode_loop"] == 1
+    assert names["executor.step"] == tokens  # prefill + tokens - 1
+    (line, loop), = cap.named("serving.decode_loop")
+    kids = cap.children(line, loop)
+    assert kids == ["executor.step", "serving.sample"] * (tokens - 1)
+    inside = [e for e in cap.lines[line]
+              if e[0] in ("executor.step", "serving.sample")
+              and loop[1] <= e[1] and e[2] <= loop[2]]
+    covered = sum(e[2] - e[1] for e in inside)
+    assert covered >= 0.9 * (loop[2] - loop[1])
+    # reset -> prefill -> first sample -> loop, all on the caller's thread
+    order = [e[0] for e in cap.lines[line] if e[0].startswith("serving.")]
+    assert order[:4] == ["serving.cache_reset", "serving.prefill",
+                         "serving.sample", "serving.decode_loop"]
+
+
+class _Doubler:
+    feed_names = ("x",)
+
+    def sample_spec(self, name):
+        return (2,), "float32"
+
+    def run(self, feed):
+        return [feed["x"] * 2.0]
+
+
+def test_form_batch_ends_where_the_batch_begins(tmp_path):
+    from paddle_tpu.serving import Endpoint, EndpointConfig
+
+    with Capture(tmp_path) as cap:
+        ep = Endpoint("stub", _Doubler(),
+                      EndpointConfig(buckets=(4,), max_wait_ms=2000.0))
+        futs = [ep.submit({"x": np.full(2, i, np.float32)})
+                for i in range(4)]
+        for f in futs:
+            f.result(timeout=10)
+        ep.drain(timeout=10)
+    ring = obs.get_spans()
+    formed = [s for s in ring if s["name"] == "serving.form_batch"
+              and s["args"]["batch_size"]]
+    (form,), (batch,) = formed, [s for s in ring
+                                 if s["name"] == "serving.batch"]
+    assert form["args"] == {"endpoint": "stub", "batch_size": 4}
+    assert form["tid"] == batch["tid"]
+    gap_us = batch["ts"] - (form["ts"] + form["dur"])
+    assert -50.0 <= gap_us < 50e3
+    # a wait that ended with nothing to run (the drain) says so
+    idle = [s for s in ring if s["name"] == "serving.form_batch"
+            and not s["args"]["batch_size"]]
+    assert all(s["ts"] >= batch["ts"] for s in idle)
+    # the same pair, in order, on the scheduler thread's line of the trace
+    (line, _e), = cap.named("serving.batch")
+    order = [e[0] for e in cap.lines[line]
+             if e[0] in ("serving.form_batch", "serving.batch")]
+    assert order[:2] == ["serving.form_batch", "serving.batch"]
+
+
+def test_recorded_spans_stay_out_of_the_capture(tmp_path):
+    with Capture(tmp_path) as cap:
+        with obs.span("serving.live"):
+            obs.record("serving.queue_wait", 0.25)
+    assert [n for n, _e in cap.named("serving.queue_wait")] == []
+    assert len(cap.named("serving.live")) == 1
+    assert sorted(_ring_names()) == ["serving.live", "serving.queue_wait"]
+
+
+def test_dropped_spans_are_counted(monkeypatch):
+    monkeypatch.setattr(span_ring, "_spans", collections.deque(maxlen=8))
+    for i in range(8):
+        with obs.span(f"s{i}"):
+            pass
+    assert "trace.spans_dropped" not in obs.get_counters()
+    for i in range(8, 11):
+        with obs.span(f"s{i}"):
+            pass
+    obs.record("late", 0.001)
+    obs.record("later", 0.001)
+    assert obs.get_counters()["trace.spans_dropped"] == 5
+    assert _ring_names() == [f"s{i}" for i in range(5, 11)] + ["late",
+                                                              "later"]
+
+
+def test_span_reports_its_own_seconds():
+    with obs.span("a") as a:
+        assert a.seconds is None
+    assert a.seconds == pytest.approx(obs.get_spans()[-1]["dur"] / 1e6)
+    obs.set_enabled(False)
+    with obs.span("b") as b:
+        pass
+    assert b.seconds is None
+
+    @obs.span("decorated")
+    def f():
+        return 3
+
+    obs.set_enabled(True)
+    assert f() == 3 and _ring_names()[-1] == "decorated"
+
+
+def test_lowered_step_carries_op_type_scopes():
+    exe, loss, feed = _fit_a_line()
+    text = exe.lower(feed=feed, fetch_list=[loss]).as_text(debug_info=True)
+    ops = {op.type for op in fluid.default_main_program().global_block.ops}
+    scoped = {t for t in ops if f'"jit(traced)/{t}/' in text}
+    # forward, backward and optimizer ops alike; an op whose emitter adds
+    # no instruction of its own (assign) leaves no location to carry one
+    assert {"mul", "elementwise_add", "square_error_cost", "reduce_mean",
+            "__vjp__", "sgd"} <= scoped, sorted(ops - scoped)
+    # a generic grad op names the forward op it replays
+    assert '"jit(traced)/__vjp__/transpose(jvp(mul))/dot_general"' in text

@@ -204,6 +204,52 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
+def _named_cases():
+    """One case per `pl.pallas_call` site -> the `name=` it passes (the
+    tiled backward holds two kernels)."""
+    b, s = SZ.bert_batch, SZ.bert_seq
+    packed = f"packed-b{b}-s{s}-bf16"
+    whole = f"whole_row-b2-s{fa.MAX_SEQ}-f32"
+    tiled = f"tiled-b{SZ.long_batch}-s{SZ.long_seq}-bf16"
+    ring = f"ring-b{SZ.ring_batch}-s{SZ.ring_seq // 4}-f32"
+    rows = f"{ROWS}x{HID}-bf16"
+    return {
+        f"{packed}-fwd": ["flash_attention_qkv_fwd"],
+        f"{packed}-bwd": ["flash_attention_qkv_bwd"],
+        f"{whole}-fwd": ["flash_attention_fwd"],
+        f"{whole}-bwd": ["flash_attention_bwd"],
+        f"{tiled}-fwd": ["flash_tiled_fwd"],
+        f"{tiled}-bwd": ["flash_tiled_dkv", "flash_tiled_dq"],
+        f"{ring}-fwd": ["ring_block_fwd"],
+        f"{ring}-dq": ["ring_block_dq"],
+        f"{ring}-dkv": ["ring_block_dkv"],
+        f"fused_residual-{rows}-fwd": ["fused_residual_fwd"],
+        f"fused_residual-{rows}-bwd": ["fused_residual_bwd"],
+        f"layer_norm-{rows}-fwd": ["layer_norm_fwd"],
+        f"layer_norm-{rows}-bwd": ["layer_norm_bwd"],
+    }
+
+
+_NAMED = _named_cases()
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED))
+def test_kernel_name_rides_into_the_lowered_module(chip, name):
+    """Each kernel's `name=` is the custom call's `kernel_name` in the
+    module lowered for the chip: what a device trace's reader tells the
+    families apart by (ISSUE 24). Lowered, not compiled: fast."""
+    fn, specs = _CASES[name]
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in specs
+    ]
+    text = jax.jit(fn).lower(*args).as_text()
+    want = _NAMED[name]
+    assert text.count("tpu_custom_call") >= len(want), name
+    for kernel in want:
+        assert f'kernel_name = "{kernel}"' in text, (name, kernel)
+
+
 def test_supports_refuses_what_the_row_block_cannot_serve():
     """The other side of the corner contract: beyond n=8192, off the lane
     width, or (fused_residual) off the 16-row PRNG draw, supports() says

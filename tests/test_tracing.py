@@ -7,6 +7,7 @@ PADDLE_TPU_MONITOR kill-switch across metrics, spans AND traces."""
 
 import importlib.util
 import os
+import threading
 import time
 
 import numpy as np
@@ -317,14 +318,24 @@ def test_coalesced_publish_parents_to_surviving_save_trace(
     exe, loss = _build_sgd_model()
     fleet = _fleet()
     rng = np.random.RandomState(0)
-    os.environ[HANG_ENV] = "0.4"
     saver = fc.AsyncCheckpointer(fleet, str(tmp_path / "ck"),
                                  executor=exe,
                                  remain_all_checkpoint=True)
+    # the first publish is held until saves 2 and 3 have landed behind
+    # it, so 2 is superseded by 3 — its trace must never own a publish
+    # span. An ordering the test waits on, not a sleep it hopes to beat:
+    # the slowed publish says when it is in flight, the test when to go on
+    in_flight, release = threading.Event(), threading.Event()
+    publish = saver._publish
+
+    def held_publish(job):
+        if not in_flight.is_set():
+            in_flight.set()
+            assert release.wait(timeout=30)
+        return publish(job)
+
+    saver._publish = held_publish
     try:
-        # first publish is slowed; saves 2 and 3 land behind it, so 2 is
-        # superseded by 3 — its trace must never own a publish span
-        faults.inject("checkpoint.publish", "hang", 1.0, 0, 1)
         handles, traces = [], []
         for i in range(3):
             _step(exe, loss, rng)
@@ -334,10 +345,14 @@ def test_coalesced_publish_parents_to_surviving_save_trace(
                 handles.append(
                     saver.save(fc.TrainStatus(i, global_step=i + 1))
                 )
+            if i == 0:
+                assert in_flight.wait(timeout=30)
+        release.set()
         for h in handles:
             h.result(timeout=30)
         saver.wait(timeout=30)
     finally:
+        release.set()
         saver.close()
     assert obs.get_counters().get("checkpoint.coalesced", 0) >= 1
     pub_traces = [s["trace_id"] for s in _by_name("checkpoint.publish")]
