@@ -190,7 +190,10 @@ def gpt_logits(input_ids, cfg, is_test=True):
 # layer's K/V rows in persistable scope vars shared BETWEEN two programs:
 # a prefill program that embeds the full context once and fills the cache,
 # and a single-token decode program that appends one K/V row and attends
-# over the cache — O(1) recompute per token. Parameter names match
+# over the cache — O(1) recompute per token. A cache var is stored in the
+# layout the decode attention reads, [B, nh, dh, max_len]: the shape is
+# ops/kv_cache.py::cache_shape's to say, the layers hand their [B, T, H]
+# rows to kv_cache_write as they are. Parameter names match
 # gpt_decoder/gpt_logits exactly, so a trained checkpoint loads into
 # either graph unchanged (serving/generate.py drives the pair).
 
@@ -203,15 +206,16 @@ def gpt_cache_names(cfg):
     return out
 
 
-def _cache_var(name, batch, max_len, hidden):
+def _cache_var(name, batch, max_len, num_heads, head_dim):
     from ..framework.program import default_main_program
+    from ..ops.kv_cache import cache_shape
 
     blk = default_main_program().global_block
     if blk.has_var(name):
         return blk.var(name)
     return blk.create_var(
-        name=name, shape=(batch, max_len, hidden), dtype="float32",
-        persistable=True,
+        name=name, shape=cache_shape(batch, max_len, num_heads, head_dim),
+        dtype="float32", persistable=True,
     )
 
 
@@ -231,8 +235,8 @@ def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
     q = layers.slice(qkv, [2], [0], [h])
     k = layers.slice(qkv, [2], [h], [2 * h])
     v = layers.slice(qkv, [2], [2 * h], [3 * h])
-    ck = _cache_var(f"{prefix}_cache_k", b, max_len, h)
-    cv = _cache_var(f"{prefix}_cache_v", b, max_len, h)
+    ck = _cache_var(f"{prefix}_cache_k", b, max_len, nh, dh)
+    cv = _cache_var(f"{prefix}_cache_v", b, max_len, nh, dh)
     blk = default_main_program().global_block
     for cache, rows in ((ck, k), (cv, v)):
         blk.append_op(

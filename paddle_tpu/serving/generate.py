@@ -4,12 +4,17 @@
 decoder into and the Scope their cache persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
-  ``gpt_l{i}_cache_{k,v}`` persistable rows 0..S-1, emit the last
+  ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
   position's logits;
 * decode — embed ONE token at a runtime position, append its K/V rows to
   the caches (in-place: the Executor donates mutated persistables, so the
   update is an HBM dynamic-update-slice), attend over the cache, emit
   next-token logits.
+
+The caches are ``[batch, num_heads, head_dim, max_len]`` float32 arrays,
+the layout ``kv_cache_attention`` reads without a copy;
+``ops/kv_cache.py::cache_shape`` owns that shape for the graphs and for
+``reset`` alike.
 
 Generation is O(1) recompute per token instead of O(S): both programs
 compile exactly once (shapes never change across steps), so a T-token
@@ -115,8 +120,12 @@ class GPTGenerator:
         import jax.numpy as jnp
 
         from ..models.gpt import gpt_cache_names
+        from ..ops.kv_cache import cache_shape
 
-        shape = (self.batch, self.max_len, self.cfg.hidden_size)
+        nh = self.cfg.num_heads
+        shape = cache_shape(
+            self.batch, self.max_len, nh, self.cfg.hidden_size // nh
+        )
         for name in gpt_cache_names(self.cfg):
             self.scope.set_var(name, jnp.zeros(shape, jnp.float32))
 

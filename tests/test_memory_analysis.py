@@ -30,6 +30,7 @@ from paddle_tpu.analysis import (
 )
 from paddle_tpu.errors import PreconditionNotMetError, ProgramVerifyError
 from paddle_tpu.framework import unique_name
+from paddle_tpu.ops.kv_cache import cache_shape
 
 
 @pytest.fixture(autouse=True)
@@ -182,20 +183,24 @@ def test_fetches_stay_live_to_the_end(fresh):
 # ---------------------------------------------------------------------------
 
 
+# rows [B=1, T=4, H=8] into a cache of 16 slots, 2 heads of 4
+_KV_CACHE = list(cache_shape(batch=1, max_len=16, num_heads=2, head_dim=4))
+
+
 def _kv_donation_program(main, read_after=True):
     rows = fluid.data("rows", [1, 4, 8])
     pos = fluid.data("pos", [1], dtype="int32")
     blk = main.global_block
-    blk.create_var(name="cache", shape=[16, 4, 8], dtype="float32",
+    blk.create_var(name="cache", shape=_KV_CACHE, dtype="float32",
                    persistable=True)
-    blk.create_var(name="cache_new", shape=[16, 4, 8], dtype="float32",
+    blk.create_var(name="cache_new", shape=_KV_CACHE, dtype="float32",
                    persistable=True)
     blk.append_op(
         "kv_cache_write",
         {"Cache": ["cache"], "X": [rows.name], "Pos": [pos.name]},
         {"Out": ["cache_new"]},
     )
-    blk.create_var(name="reader", shape=[16, 4, 8], dtype="float32")
+    blk.create_var(name="reader", shape=_KV_CACHE, dtype="float32")
     src = "cache" if read_after else "cache_new"
     blk.append_op("scale", {"X": [src]}, {"Out": ["reader"]},
                   {"scale": 2.0})
@@ -232,14 +237,14 @@ def test_same_name_cache_write_is_clean(fresh):
     rows = fluid.data("rows", [1, 4, 8])
     pos = fluid.data("pos", [1], dtype="int32")
     blk = main.global_block
-    blk.create_var(name="cache", shape=[16, 4, 8], dtype="float32",
+    blk.create_var(name="cache", shape=_KV_CACHE, dtype="float32",
                    persistable=True)
     blk.append_op(
         "kv_cache_write",
         {"Cache": ["cache"], "X": [rows.name], "Pos": [pos.name]},
         {"Out": ["cache"]},
     )
-    blk.create_var(name="reader", shape=[16, 4, 8], dtype="float32")
+    blk.create_var(name="reader", shape=_KV_CACHE, dtype="float32")
     blk.append_op("scale", {"X": ["cache"]}, {"Out": ["reader"]},
                   {"scale": 2.0})
     mt = plan_memory(main, feed_names=("rows", "pos"),
@@ -255,9 +260,9 @@ def test_rewritten_donated_name_is_a_fresh_buffer(fresh):
     blk = main.global_block
     blk.append_op(
         "fill_constant", {}, {"Out": ["cache"]},
-        {"shape": [16, 4, 8], "dtype": "float32", "value": 0.0},
+        {"shape": _KV_CACHE, "dtype": "float32", "value": 0.0},
     )
-    blk.create_var(name="reader2", shape=[16, 4, 8], dtype="float32")
+    blk.create_var(name="reader2", shape=_KV_CACHE, dtype="float32")
     blk.append_op("scale", {"X": ["cache"]}, {"Out": ["reader2"]},
                   {"scale": 1.0})
     mt = plan_memory(main, feed_names=feeds, fetch_names=("reader2",))

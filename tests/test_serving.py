@@ -362,6 +362,7 @@ def test_router_rejects_on_full_queue():
 
 
 def _np_ref_cache_attention(q, k, v, pos, nh, scale, prob_scale=1.0):
+    """k, v: the caches in logical form, [B, S, H]."""
     b, t, h = q.shape
     s = k.shape[1]
     dh = h // nh
@@ -377,35 +378,214 @@ def _np_ref_cache_attention(q, k, v, pos, nh, scale, prob_scale=1.0):
     return (probs @ vh).transpose(0, 2, 1, 3).reshape(b, t, h)
 
 
-def test_kv_cache_op_goldens():
+_KV_B, _KV_S, _KV_NH, _KV_DH = 2, 8, 3, 4  # nh * dh = 12 != S
+
+
+def to_logical(cache):
+    """A stored cache (kv_cache.cache_shape: [B, nh, dh, S]) as the
+    [B, S, H] rows the layers wrote."""
+    c = np.asarray(cache)
+    b, nh, dh, s = c.shape
+    return c.transpose(0, 3, 1, 2).reshape(b, s, nh * dh)
+
+
+def _stored(logical):
+    """[B, S, H] rows -> the stored layout (inverse of to_logical)."""
+    b, s, h = logical.shape
+    return np.ascontiguousarray(
+        logical.reshape(b, s, _KV_NH, h // _KV_NH).transpose(0, 2, 3, 1)
+    )
+
+
+@pytest.mark.parametrize("prob_scale", [1.0, 0.9])
+@pytest.mark.parametrize(
+    "t,pos",
+    [(1, 0), (1, 3), (1, _KV_S - 1), (4, 3), (4, 6)],
+    ids=["decode-first", "decode-middle", "decode-last-slot",
+         "prefill-at-0", "prefill-offset"],
+)
+def test_kv_cache_op_goldens(t, pos, prob_scale):
+    """Rows go in as the layer produces them ([B, T, H]) and land at
+    slots Pos-(T-1)..Pos of the stored cache; attention over the stored
+    caches equals the numpy reference over the logical ones."""
     import jax.numpy as jnp
 
     from paddle_tpu.framework.registry import OpView
     from paddle_tpu.ops.kv_cache import (_kv_cache_attention,
-                                         _kv_cache_write)
+                                         _kv_cache_write, cache_shape)
 
-    rng = np.random.RandomState(0)
-    cache = rng.randn(2, 8, 12).astype(np.float32)
-    rows = rng.randn(2, 1, 12).astype(np.float32)
-    out = _kv_cache_write(
-        None, OpView("kv_cache_write", {}),
-        {"Cache": [jnp.asarray(cache)], "X": [jnp.asarray(rows)],
-         "Pos": [jnp.asarray([3])]},
-    )["Out"][0]
-    want = cache.copy()
-    want[:, 3:4, :] = rows
-    np.testing.assert_allclose(np.asarray(out), want)
+    h = _KV_NH * _KV_DH
+    rng = np.random.RandomState(7 * t + pos)
+    k0, v0 = (rng.randn(_KV_B, _KV_S, h).astype(np.float32)
+              for _ in range(2))
+    krows, vrows, q = (rng.randn(_KV_B, t, h).astype(np.float32)
+                       for _ in range(3))
+    first = pos - (t - 1)
 
-    q = rng.randn(2, 1, 12).astype(np.float32)
+    def write(cache, rows):
+        return _kv_cache_write(
+            None, OpView("kv_cache_write", {}),
+            {"Cache": [jnp.asarray(_stored(cache))],
+             "X": [jnp.asarray(rows)], "Pos": [jnp.asarray([first])]},
+        )["Out"][0]
+
+    ck, cv = write(k0, krows), write(v0, vrows)
+    want_k, want_v = k0.copy(), v0.copy()
+    want_k[:, first:pos + 1, :] = krows
+    want_v[:, first:pos + 1, :] = vrows
+    assert ck.shape == cache_shape(_KV_B, _KV_S, _KV_NH, _KV_DH)
+    np.testing.assert_array_equal(to_logical(ck), want_k)
+    np.testing.assert_array_equal(to_logical(cv), want_v)
+
     attn = _kv_cache_attention(
         None,
         OpView("kv_cache_attention",
-               {"num_heads": 3, "scale": 0.5, "prob_scale": 0.9}),
-        {"Q": [jnp.asarray(q)], "CacheK": [jnp.asarray(cache)],
-         "CacheV": [jnp.asarray(cache)], "Pos": [jnp.asarray([5])]},
+               {"num_heads": _KV_NH, "scale": 0.5,
+                "prob_scale": prob_scale}),
+        {"Q": [jnp.asarray(q)], "CacheK": [ck], "CacheV": [cv],
+         "Pos": [jnp.asarray([pos])]},
     )["Out"][0]
-    ref = _np_ref_cache_attention(q, cache, cache, 5, 3, 0.5, 0.9)
+    ref = _np_ref_cache_attention(
+        q, want_k, want_v, pos, _KV_NH, 0.5, prob_scale
+    )
     np.testing.assert_allclose(np.asarray(attn), ref, rtol=1e-5, atol=1e-6)
+
+
+def test_kv_cache_write_then_attend_program_round_trip():
+    """Both ops through a two-op Program, twice: the Executor donates the
+    caches (``mutates`` aliases Out onto Cache) and writes them back, so
+    the second run attends over what the first one stored."""
+    from paddle_tpu.ops.kv_cache import cache_shape
+
+    h = _KV_NH * _KV_DH
+    shape = cache_shape(_KV_B, _KV_S, _KV_NH, _KV_DH)
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        q = fluid.data("q", [_KV_B, 1, h])
+        rows = fluid.data("rows", [_KV_B, 1, h])
+        pos = fluid.data("pos", [1], dtype="int32")
+        blk = main.global_block
+        for name in ("ck", "cv"):
+            blk.create_var(name=name, shape=shape, dtype="float32",
+                           persistable=True)
+            blk.append_op(
+                "kv_cache_write",
+                {"Cache": [name], "X": [rows.name], "Pos": [pos.name]},
+                {"Out": [name]},
+            )
+        out = blk.create_var(name="attn", shape=[_KV_B, 1, h],
+                             dtype="float32")
+        blk.append_op(
+            "kv_cache_attention",
+            {"Q": [q.name], "CacheK": ["ck"], "CacheV": ["cv"],
+             "Pos": [pos.name]},
+            {"Out": [out.name]},
+            {"num_heads": _KV_NH, "scale": 0.5, "prob_scale": 1.0},
+        )
+    rng = np.random.RandomState(3)
+    logical = np.zeros((_KV_B, _KV_S, h), np.float32)
+    scope, exe = Scope(), fluid.Executor()
+    with scope_guard(scope):
+        for name in ("ck", "cv"):
+            scope.set_var(name, np.zeros(shape, np.float32))
+        for step in (2, 3):
+            qv, rv = (rng.randn(_KV_B, 1, h).astype(np.float32)
+                      for _ in range(2))
+            logical[:, step:step + 1, :] = rv
+            (got,) = exe.run(
+                main,
+                feed={"q": qv, "rows": rv,
+                      "pos": np.array([step], np.int32)},
+                fetch_list=[out.name], scope=scope,
+            )
+            ref = _np_ref_cache_attention(
+                qv, logical, logical, step, _KV_NH, 0.5
+            )
+            np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(to_logical(scope.find_var("ck")), logical)
+    np.testing.assert_array_equal(to_logical(scope.find_var("cv")), logical)
+
+
+def _relayouts(jaxpr, min_elems):
+    """(primitive, operand shape) of every transpose / copy / reshape in
+    `jaxpr` (sub-jaxprs included) whose operand has >= min_elems elements."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for sub in eqn.params.values():
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                found += _relayouts(inner, min_elems)
+        if eqn.primitive.name in ("transpose", "copy", "reshape"):
+            shape = eqn.invars[0].aval.shape
+            if int(np.prod(shape)) >= min_elems:
+                found.append((eqn.primitive.name, tuple(shape)))
+    return found
+
+
+def test_kv_cache_emitters_relayout_only_the_new_rows():
+    """The structural half of the layout contract, no chip needed: at a
+    decode shape neither emitter transposes, copies or reshapes anything
+    of a cache's size (a cache is an entry parameter of the step, so any
+    of these would be a whole-cache copy per layer per token); the new
+    rows and Q are."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.framework.registry import OpView
+    from paddle_tpu.ops.kv_cache import (_kv_cache_attention,
+                                         _kv_cache_write, cache_shape)
+
+    b, s, nh, dh = 2, 40, 3, 8
+    h = nh * dh
+    cache = jnp.zeros(cache_shape(b, s, nh, dh), jnp.float32)
+    x = jnp.zeros((b, 1, h), jnp.float32)
+    pos = jnp.asarray([5], jnp.int32)
+    write = jax.make_jaxpr(lambda c, r, p: _kv_cache_write(
+        None, OpView("kv_cache_write", {}),
+        {"Cache": [c], "X": [r], "Pos": [p]})["Out"][0])(cache, x, pos)
+    attend = jax.make_jaxpr(lambda q, k, v, p: _kv_cache_attention(
+        None,
+        OpView("kv_cache_attention",
+               {"num_heads": nh, "scale": 0.5, "prob_scale": 0.9}),
+        {"Q": [q], "CacheK": [k], "CacheV": [v], "Pos": [p]},
+    )["Out"][0])(x, cache, cache, pos)
+    for closed, reader in ((write, "dynamic_update_slice"),
+                           (attend, "dot_general")):
+        assert _relayouts(closed.jaxpr, cache.size) == []
+        small = _relayouts(closed.jaxpr, x.size)
+        assert small and all(int(np.prod(sh)) == x.size for _, sh in small)
+        # a cache goes straight from the step's parameters into the op
+        # that reads it in place
+        caches = [v for v in closed.jaxpr.invars
+                  if v.aval.shape == cache.shape]
+        users = {e.primitive.name for e in closed.jaxpr.eqns
+                 if any(v in caches for v in e.invars)}
+        assert users == {reader}
+
+
+def test_kv_cache_shape_has_one_owner():
+    """models/gpt.py's cache vars, GPTGenerator.reset's arrays and
+    kv_cache.cache_shape agree, for nh * dh != max_len."""
+    from paddle_tpu.models.gpt import GPTConfig, gpt_cache_names
+    from paddle_tpu.ops.kv_cache import cache_shape
+
+    cfg = GPTConfig.tiny()
+    cfg.use_fused_attention = False
+    batch, max_len = 3, 20
+    nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    assert nh * dh != max_len and dh != max_len
+    want = cache_shape(batch, max_len, nh, dh)
+    assert want == (batch, nh, dh, max_len)
+    gen = GPTGenerator(cfg, batch=batch, context_len=6, max_len=max_len)
+    gen.reset()
+    names = gpt_cache_names(cfg)
+    assert len(names) == 2 * cfg.num_layers
+    for prog in (gen.prefill_prog, gen.decode_prog):
+        for name in names:
+            assert tuple(prog.global_block.var(name).shape) == want
+    for name in names:
+        arr = gen.scope.find_var(name)
+        assert tuple(arr.shape) == want and arr.dtype == np.float32
 
 
 def test_kv_decode_parity_with_full_recompute():

@@ -217,15 +217,17 @@ def _broken_donation_fixture():
     read observes the overwritten pages; the donation verifier must
     reject it with ``use-after-donate``."""
     import paddle_tpu as fluid
+    from paddle_tpu.ops.kv_cache import cache_shape
 
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        rows = fluid.data("rows", [1, 4, 8])
+        rows = fluid.data("rows", [1, 4, 8])  # [B, T, H]
         pos = fluid.data("pos", [1], dtype="int32")
     blk = main.global_block
-    blk.create_var(name="cache", shape=[16, 4, 8], dtype="float32",
+    shape = list(cache_shape(batch=1, max_len=16, num_heads=2, head_dim=4))
+    blk.create_var(name="cache", shape=shape, dtype="float32",
                    persistable=True)
-    blk.create_var(name="cache_new", shape=[16, 4, 8], dtype="float32",
+    blk.create_var(name="cache_new", shape=shape, dtype="float32",
                    persistable=True)
     blk.append_op(
         "kv_cache_write",
@@ -233,7 +235,7 @@ def _broken_donation_fixture():
         {"Out": ["cache_new"]},
     )
     # the defect: 'cache' was donated to 'cache_new' one op ago
-    blk.create_var(name="stale", shape=[16, 4, 8], dtype="float32")
+    blk.create_var(name="stale", shape=shape, dtype="float32")
     blk.append_op("scale", {"X": ["cache"]}, {"Out": ["stale"]},
                   {"scale": 2.0})
     return main, ("rows", "pos"), ("stale",)
