@@ -325,6 +325,46 @@ def gpt_decode_step(token_ids, pos_ids, cfg, max_len):
     return _lm_head(x, cfg)
 
 
+class GPTDecoder:
+    """What `serving.GPTGenerator` asks of a decoder: the prefill body,
+    the decode body and the specs of the state the two programs share."""
+
+    prefill_rows = None     # one prefill dispatch takes the whole batch
+    counters_var = None
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def prefill(self, context_ids, batch, max_len, row_ids=None):
+        """(logits, further variables fetched beside them: none)."""
+        return gpt_prefill(context_ids, self.cfg, max_len), []
+
+    def decode_step(self, token_ids, pos_ids, max_len):
+        return gpt_decode_step(token_ids, pos_ids, self.cfg, max_len), []
+
+    def state_specs(self, batch, max_len):
+        from ..ops.kv_cache import cache_shape
+
+        nh = self.cfg.num_heads
+        shape = cache_shape(batch, max_len, nh, self.cfg.hidden_size // nh)
+        return [(name, shape, "float32")
+                for name in gpt_cache_names(self.cfg)]
+
+    def cache_kind(self, name):
+        return "full"
+
+    def logits(self, input_ids):
+        """The full-context graph (`generate_full_recompute`)."""
+        return gpt_logits(input_ids, self.cfg, is_test=True)
+
+    def describe(self):
+        cfg = self.cfg
+        return {"family": "gpt", "hidden_size": cfg.hidden_size,
+                "num_layers": cfg.num_layers, "num_heads": cfg.num_heads,
+                "intermediate_size": cfg.intermediate_size,
+                "vocab_size": cfg.vocab_size}
+
+
 def gpt_tp_shardings(cfg, axis="mp"):
     """Megatron column/row-parallel annotations (see bert_tp_shardings)."""
     sh = {"wte": (axis, None), "lm_head_w": (None, axis)}

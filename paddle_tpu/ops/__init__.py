@@ -18,6 +18,7 @@ from . import (  # noqa: F401
     detection_ext,
     fused,
     kv_cache,
+    llm,
     loss_ext,
     math,
     math_ext,

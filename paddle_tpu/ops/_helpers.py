@@ -12,6 +12,19 @@ import jax.numpy as jnp
 from ..framework.registry import register_op
 
 
+def einsum_f32(spec, x, y):
+    """`jnp.einsum` of two low-precision operands into a float32 result:
+    the products are exact and the accumulator is never rounded to the
+    operands' dtype. XLA's CPU runtime lacks some bfloat16 x bfloat16 =
+    float32 products, so off the TPU the operands are cast up first (the
+    same numbers: every bfloat16 is a float32)."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        x, y = x.astype(jnp.float32), y.astype(jnp.float32)
+    return jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
+
+
 def fluid_broadcast(x, y, axis):
     """fluid elementwise broadcasting: y's shape aligns to x starting at `axis`
     (elementwise_op_function.h in the reference). axis=-1 means numpy rules /

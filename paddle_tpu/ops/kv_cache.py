@@ -37,14 +37,50 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..errors import InvalidArgumentError
 from ..framework.registry import register_op
+from ._helpers import einsum_f32
 
 
-def cache_shape(batch, max_len, num_heads, head_dim):
-    """Stored shape of ONE layer's K (or V) cache: ``[B, nh, dh, S]``.
-    The graph builder (models/gpt.py) and the code that allocates the
-    arrays (serving/generate.py) both ask here."""
-    return (int(batch), int(num_heads), int(head_dim), int(max_len))
+def cache_shape(batch, max_len, num_heads, head_dim, window=0):
+    """Stored shape of ONE layer's K (or V) cache: ``[B, nh, dh, slots]``,
+    `nh` the KV heads the layer stores (its query heads may be a multiple
+    of them). A full-attention layer holds ``max_len`` slots; a layer
+    whose keys are only visible for `window` positions holds a ring of
+    ``min(max_len, window)`` slots, position p in slot ``p % slots``.
+    The graph builders (models/) and the code that allocates the arrays
+    (serving/generate.py) both ask here."""
+    slots = min(int(max_len), int(window)) if window else int(max_len)
+    return (int(batch), int(num_heads), int(head_dim), slots)
+
+
+def attention_mask(qpos, slots, window=0):
+    """[T, slots] bool: may the query at position `qpos[i]` read slot j?
+    Slot j holds position p = qpos - ((qpos - j) mod slots), the newest
+    one written there; it is visible iff it has been written (p >= 0)
+    and, under a `window`, qpos - p < window. With `slots` beyond every
+    position this is the causal mask j <= qpos."""
+    j = jnp.arange(slots, dtype=jnp.int32)[None, :]
+    age = jnp.mod(qpos[:, None] - j, slots)
+    valid = age <= qpos[:, None]
+    if window:
+        valid = valid & (age < window)
+    return valid
+
+
+def grouped_attention(q, k, v, valid, num_kv_heads, scale):
+    """q [B, T, nh * dh] over k, v [B, nkv, dh, S] read in place: query
+    head n reads KV head n // (nh / nkv), so a KV head is never repeated
+    in HBM. `valid` [T, S]. Scores and softmax in float32."""
+    b, t, h = q.shape
+    dh = k.shape[2]
+    g = h // dh // num_kv_heads
+    qh = q.reshape(b, t, num_kv_heads, g, dh)
+    scores = einsum_f32("btkgd,bkds->bkgts", qh, k) * scale
+    scores = jnp.where(valid[None, None, None], scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgts,bkds->btkgd", probs, v)
+    return out.reshape(b, t, h)
 
 
 def _pos_scalar(pos):
@@ -54,19 +90,46 @@ def _pos_scalar(pos):
 
 @register_op(
     "kv_cache_write",
-    inputs=["Cache", "X", "Pos"],
+    inputs=["Cache", "X", "Pos", "Row"],
     outputs=["Out"],
     differentiable=False,
     mutates=(("Out", "Cache"),),
 )
 def _kv_cache_write(ctx, op, ins):
-    cache = ins["Cache"][0]  # [B, nh, dh, S]
+    cache = ins["Cache"][0]  # [B, nh, dh, slots]
     x = ins["X"][0]  # [B, T, H], H = nh * dh
     pos = _pos_scalar(ins["Pos"][0])
-    b, nh, dh, _ = cache.shape
+    b, nh, dh, slots = cache.shape
+    if op.attr("ring", False):
+        return {"Out": [_ring_write(cache, x, pos, ins.get("Row"))]}
+    if ins.get("Row"):
+        raise InvalidArgumentError(
+            "kv_cache_write: `Row` (a block of the batch's rows) needs "
+            "ring=True; the plain write takes the whole batch"
+        )
     rows = x.astype(cache.dtype).reshape(b, -1, nh, dh).transpose(0, 2, 3, 1)
     out = jax.lax.dynamic_update_slice_in_dim(cache, rows, pos, axis=3)
     return {"Out": [out]}
+
+
+def _ring_write(cache, x, pos, row):
+    """Rows for positions pos .. pos + T - 1 into slots ``p % slots`` of
+    batch rows `row` .. (a prefill block of a larger batch). Of more rows
+    than slots only the newest are kept; one row (decode) is an in-place
+    update, several are rotated into place and written at slot 0."""
+    _, nh, dh, slots = cache.shape
+    rb, t = x.shape[0], x.shape[1]
+    rows = x.astype(cache.dtype).reshape(rb, t, nh, dh).transpose(0, 2, 3, 1)
+    r0 = jnp.int32(0) if row is None else _pos_scalar(row[0])
+    if t == 1:
+        return jax.lax.dynamic_update_slice(
+            cache, rows, (r0, 0, 0, jnp.mod(pos, slots)))
+    if t >= slots:
+        rows, pos = rows[..., t - slots:], pos + (t - slots)
+        rows = jnp.roll(rows, jnp.mod(pos, slots), axis=3)
+        return jax.lax.dynamic_update_slice(cache, rows, (r0, 0, 0, 0))
+    # fewer rows than slots: they must not wrap (a prefill from 0)
+    return jax.lax.dynamic_update_slice(cache, rows, (r0, 0, 0, pos))
 
 
 @register_op(
@@ -82,6 +145,12 @@ def _kv_cache_attention(ctx, op, ins):
     pos = _pos_scalar(ins["Pos"][0])
     nh = int(op.attr("num_heads"))
     scale = float(op.attr("scale", 1.0))
+    kvh, window = int(op.attr("num_kv_heads", nh)), int(op.attr("window", 0))
+    if kvh != nh or window:
+        t = q.shape[1]
+        qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
+        valid = attention_mask(qpos, k.shape[3], window)
+        return {"Out": [grouped_attention(q, k, v, valid, kvh, scale)]}
     # inference residue of fluid's downgrade_in_infer attention dropout:
     # probs scale by (1 - dropout_prob) so cached decode matches the
     # training graph's test-mode numerics exactly

@@ -123,7 +123,15 @@ def _mul(ctx, op, ins):
     xs, ys = x.shape, y.shape
     x2 = x.reshape((math.prod(xs[:xnc]), -1))
     y2 = y.reshape((math.prod(ys[:ync]), -1))
-    out = jnp.matmul(x2, y2)
+    out_dtype = op.attr("out_dtype", None)
+    if out_dtype:
+        # the product leaves the accumulator in `out_dtype` (float32
+        # logits of a bfloat16 head), not rounded to the operands' first
+        from ._helpers import einsum_f32
+
+        out = einsum_f32("mk,kn->mn", x2, y2).astype(jnp.dtype(out_dtype))
+    else:
+        out = jnp.matmul(x2, y2)
     return {"Out": [out.reshape(xs[:xnc] + ys[ync:])]}
 
 

@@ -8,9 +8,17 @@ load-balancing auxiliary loss (mean(fraction * prob) * E).
 
 Without a mesh (or no "ep" axis) the same math runs dense on one chip —
 the dispatch einsums are identical, only the all_to_alls drop out.
+
+`moe_local_experts` is the serving-side layer of today's sparse models
+(sigmoid scores, top-k over ALL experts, no capacity, nothing dropped):
+told which experts it holds, it computes the part of the result its own
+experts give. That is what expert parallelism asks of a chip; on one chip
+it runs without the exchange, and the absent experts' part is absent.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +132,159 @@ def _moe_ffn_op(ctx, op, ins):
     else:
         y, aux = moe_ffn(x, gate_w, w1, b1, w2, b2, capacity_factor=cf)
     return {"Out": [y], "AuxLoss": [aux.reshape([1])]}
+
+
+# ---------------------------------------------------------------------------
+# dropless sigmoid-routed experts, one chip's share
+# ---------------------------------------------------------------------------
+
+# counters a step accumulates in place (one int32 vector, the order below)
+MOE_COUNTERS = ("assignments_local", "assignments_total", "experts_hit",
+                "max_expert_load", "max_expert_load_sum", "calls",
+                # the same of one-token (decode) calls alone
+                "decode_assignments_local", "decode_experts_hit",
+                "decode_calls")
+
+
+def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
+                       route_norm=True):
+    """tokens [T, H] -> (selected [T, k] int32 over all experts, weights
+    [T, k] float32). Scores are sigmoids in float32 (a product of
+    bfloat16 operands accumulated in float32 is exact); `expert_bias`
+    moves the selection only; the weights are the selected scores over
+    their sum (over all k, wherever those experts live), times
+    `route_scale`."""
+    from ..ops._helpers import einsum_f32
+
+    logits = einsum_f32("th,he->te", tokens, router_w)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = lax.top_k(scores + expert_bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if route_norm:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * route_scale
+
+
+def _row_tile(assignments):
+    """Rows of one tile of the grouped product: MXU-sized once the
+    experts see hundreds of rows each, the bfloat16 sublane pack for a
+    decode step's handful."""
+    return 256 if assignments >= 8192 else 16
+
+
+def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
+                      top_k, route_scale, expert_offset, route_norm=True,
+                      interpret=False):
+    """The routed part of an expert layer that THIS chip's experts give.
+
+    x [B, T, H]; router_w [H, E] over all E experts; w_gate_up
+    [E_local, H, 2F], w_down [E_local, F, H]: experts `expert_offset` ..
+    `expert_offset + E_local - 1`. Every token is scored against all E,
+    its top-k chosen, and the assignments that fall on a local expert are
+    sorted by expert (each group padded to whole row tiles), pushed
+    through a grouped product (kernels/moe_gmm.py), and summed back into
+    their tokens with their weights. No capacity, nothing dropped: the
+    sorted buffer holds the worst case, every assignment local.
+
+    Returns (y [B, T, H], selected [B, T, k], counts [E_local] int32)."""
+    from ..kernels import moe_gmm
+
+    b, t, h = x.shape
+    n_local = w_gate_up.shape[0]
+    tokens = x.reshape(b * t, h)
+    n_tok = b * t
+    sel, weights = sigmoid_topk_route(
+        tokens, router_w, expert_bias, top_k, route_scale, route_norm
+    )
+    local = sel - expert_offset
+    is_local = (local >= 0) & (local < n_local)
+    expert = jnp.where(is_local, local, n_local).reshape(-1)     # [A]
+    n_assign = n_tok * top_k
+    tm = _row_tile(n_assign)
+    n_tiles = -(-n_assign // tm) + n_local
+
+    # each assignment's rank inside its expert's group, and the groups'
+    # sizes, from one running count per expert
+    onehot = (expert[:, None] == jnp.arange(n_local)[None, :])
+    running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)       # [A, E_l]
+    counts = running[-1]
+    clamped = jnp.minimum(expert, n_local - 1)
+    rank = jnp.take_along_axis(running, clamped[:, None], axis=1)[:, 0] - 1
+    group_tiles = -(-counts // tm)
+    tiles_before = jnp.cumsum(group_tiles) - group_tiles
+    num_active = jnp.sum(group_tiles)
+    slot = jnp.where(
+        expert < n_local,
+        tiles_before[clamped] * tm + rank,
+        n_tiles * tm,                      # out of range: dropped below
+    )
+    token_of = jnp.arange(n_assign, dtype=jnp.int32) // top_k
+    token_of_slot = jnp.zeros((n_tiles * tm,), jnp.int32).at[slot].set(
+        token_of, mode="drop"
+    )
+    # tile i belongs to the expert whose run of tiles covers it; tiles
+    # past the last active one repeat its expert (no new weight fetch)
+    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(num_active - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(group_tiles), tile, side="right"),
+        n_local - 1,
+    ).astype(jnp.int32)
+    active = num_active.reshape(1).astype(jnp.int32)
+
+    # off the TPU the same product in `jnp` (kernel tests: `interpret`)
+    gmm = moe_gmm.gmm_reference
+    if interpret or jax.default_backend() == "tpu":
+        gmm = functools.partial(moe_gmm.gmm, interpret=interpret)
+
+    def product(lhs, rhs):
+        return gmm(lhs, rhs, tile_expert, active, tm)
+
+    from ..ops.llm import swiglu
+
+    with jax.named_scope("moe_dispatch"):
+        x_sorted = tokens[token_of_slot]                        # [M, H]
+    with jax.named_scope("moe_experts"):
+        hidden = swiglu(product(x_sorted, w_gate_up))
+        y_sorted = product(hidden, w_down)                      # [M, H]
+    with jax.named_scope("moe_combine"):
+        picked = y_sorted[jnp.minimum(slot, n_tiles * tm - 1)]  # [A, H]
+        # a slot of a non-local assignment reads a row nobody wrote:
+        # select, never multiply by zero
+        part = jnp.where(
+            is_local.reshape(-1, 1),
+            picked.astype(jnp.float32) * weights.reshape(-1, 1), 0.0,
+        )
+        y = jnp.sum(part.reshape(n_tok, top_k, h), axis=1)
+    return (y.astype(x.dtype).reshape(b, t, h), sel.reshape(b, t, top_k),
+            counts)
+
+
+@register_op(
+    "moe_local_experts",
+    inputs=["X", "RouterW", "ExpertBias", "WGateUp", "WDown", "Counters"],
+    outputs=["Out", "Selected", "CountersOut"],
+    differentiable=False,
+    mutates=(("CountersOut", "Counters"),),
+)
+def _moe_local_experts_op(ctx, op, ins):
+    x, router_w, bias, wgu, wd, counters = (
+        ins[k][0] for k in ("X", "RouterW", "ExpertBias", "WGateUp", "WDown",
+                            "Counters")
+    )
+    top_k = int(op.attr("top_k"))
+    y, sel, counts = local_experts_ffn(
+        x, router_w, bias, wgu, wd, top_k=top_k,
+        route_scale=float(op.attr("route_scale", 1.0)),
+        route_norm=bool(op.attr("route_norm", True)),
+        expert_offset=int(op.attr("expert_offset", 0)),
+    )
+    most = jnp.max(counts)
+    local, hit = jnp.sum(counts), jnp.sum(counts > 0).astype(jnp.int32)
+    decode = jnp.int32(x.shape[1] == 1)
+    step = jnp.stack([
+        local, jnp.int32(x.shape[0] * x.shape[1] * top_k), hit,
+        jnp.int32(0), most, jnp.int32(1),
+        decode * local, decode * hit, decode,
+    ])
+    new = (counters + step).at[3].set(jnp.maximum(counters[3], most))
+    return {"Out": [y], "Selected": [sel], "CountersOut": [new]}

@@ -1,7 +1,8 @@
-"""KV-cache GPT generation: prefill + single-token decode programs.
+"""KV-cache generation: prefill + single-token decode programs.
 
-``GPTGenerator`` owns the two programs ``models/gpt.py`` splits the
-decoder into and the Scope their cache persistables share:
+``GPTGenerator`` owns the two programs a decoder (``models/gpt.py``'s
+``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``) splits itself
+into and the Scope their cache persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
   ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
@@ -11,10 +12,20 @@ decoder into and the Scope their cache persistables share:
   update is an HBM dynamic-update-slice), attend over the cache, emit
   next-token logits.
 
-The caches are ``[batch, num_heads, head_dim, max_len]`` float32 arrays,
-the layout ``kv_cache_attention`` reads without a copy;
-``ops/kv_cache.py::cache_shape`` owns that shape for the graphs and for
-``reset`` alike.
+The caches are ``[batch, kv_heads, head_dim, slots]`` arrays, the layout
+``kv_cache_attention`` reads without a copy; ``ops/kv_cache.py::
+cache_shape`` owns that shape (``slots`` is ``max_len``, or a ring of the
+window's length on a sliding-window layer) for the graphs and, through
+the decoder's ``state_specs``, for ``reset`` alike. A decoder is handed
+in as an object with ``prefill(ids, batch, max_len, row_ids)``,
+``decode_step(token, pos, max_len)``, ``state_specs(batch, max_len)``,
+``prefill_rows`` (rows of the batch one prefill dispatch takes; None for
+all) and ``counters_var`` (a device-side int32 vector the steps update
+in place, read once per batch under ``counter_names``; None for none).
+Both bodies return ``(logits, extras)``: ``extras`` are further
+variables of the step (an expert decoder's selected expert ids) that
+are fetched beside the logits, so that whoever checks a step against a
+reference reads them from the executables that serve.
 
 Generation is O(1) recompute per token instead of O(S): both programs
 compile exactly once (shapes never change across steps), so a T-token
@@ -43,49 +54,80 @@ class GPTGenerator:
     def __init__(self, cfg, batch, context_len, max_len, scope=None,
                  executor=None):
         import paddle_tpu as fluid
+        from .. import observability as _obs
+        from ..core.dtypes import to_numpy_dtype
         from ..framework.scope import Scope, scope_guard
-        from ..models.gpt import gpt_decode_step, gpt_prefill
 
         if context_len >= max_len:
             raise InvalidArgumentError(
                 f"context_len {context_len} must leave room to generate "
                 f"(max_len {max_len})"
             )
-        self.cfg = cfg
+        # `cfg`: a decoder, or a GPTConfig (models/gpt.py's decoder)
+        if hasattr(cfg, "decode_step"):
+            decoder = cfg
+        else:
+            from ..models.gpt import GPTDecoder
+
+            decoder = GPTDecoder(cfg)
+        self.decoder = decoder
+        self.cfg = decoder.cfg
         self.batch = int(batch)
         self.context_len = int(context_len)
         self.max_len = int(max_len)
         self.scope = scope or Scope()
         self.executor = executor or fluid.Executor()
+        rows = decoder.prefill_rows or self.batch
+        if self.batch % rows:
+            raise InvalidArgumentError(
+                f"prefill_rows {rows} must divide the batch {self.batch}"
+            )
+        self.prefill_rows = rows
 
         self.prefill_prog = fluid.Program()
         self.startup_prog = fluid.Program()
         with fluid.program_guard(self.prefill_prog, self.startup_prog):
-            ids = fluid.data("context_ids", [batch, context_len], "int64")
-            logits = gpt_prefill(ids, cfg, max_len)
-        self._prefill_fetch = [logits.name]
+            ids = fluid.data("context_ids", [rows, context_len], "int64")
+            row_ids = None
+            if rows != self.batch:
+                row_ids = fluid.data("row_ids", [1], "int64")
+            logits, extras = decoder.prefill(ids, self.batch, max_len,
+                                             row_ids)
+        self._prefill_fetch = [logits.name] + [v.name for v in extras]
 
         self.decode_prog = fluid.Program()
         decode_startup = fluid.Program()  # same init ops; never run
         with fluid.program_guard(self.decode_prog, decode_startup):
             tok = fluid.data("token_ids", [batch, 1], "int64")
             pos = fluid.data("pos_ids", [1, 1], "int64")
-            dlogits = gpt_decode_step(tok, pos, cfg, max_len)
-        self._decode_fetch = [dlogits.name]
+            dlogits, extras = decoder.decode_step(tok, pos, max_len)
+        self._decode_fetch = [dlogits.name] + [v.name for v in extras]
 
         # both are pure inference graphs: mark them so the Executor traces
         # in test mode and the verifier holds the inference contract
         self.prefill_prog._is_inference = True
         self.decode_prog._is_inference = True
         self._scope_guard = scope_guard
+        self._state_specs = decoder.state_specs(self.batch, self.max_len)
+        by_kind = {}
+        for name, shape, dtype in self._state_specs:
+            kind = decoder.cache_kind(name)
+            if kind:
+                by_kind[kind] = by_kind.get(kind, 0) + int(
+                    np.prod(shape) * np.dtype(to_numpy_dtype(dtype)).itemsize
+                )
+        for kind, nbytes in by_kind.items():
+            _obs.set_gauge(f"kv_cache.bytes.{kind}", nbytes)
+        _obs.set_table("serving.generate.model", {
+            **decoder.describe(), "batch": self.batch,
+            "context_len": self.context_len, "max_len": self.max_len,
+        })
 
     def _param_vars(self):
-        from ..models.gpt import gpt_cache_names
-
-        caches = set(gpt_cache_names(self.cfg))
+        state = {name for name, _shape, _dtype in self._state_specs}
         return [
             v for v in self.prefill_prog.list_vars()
-            if v.persistable and v.name not in caches
+            if v.persistable and v.name not in state
         ]
 
     # -- parameters --------------------------------------------------------
@@ -116,18 +158,55 @@ class GPTGenerator:
             return _io.save(self.prefill_prog, path)
 
     def reset(self):
-        """Zero the KV caches (fresh generation state)."""
+        """Zero the generation state by the decoder's specs: every
+        layer's KV cache and the step counters."""
         import jax.numpy as jnp
 
-        from ..models.gpt import gpt_cache_names
-        from ..ops.kv_cache import cache_shape
+        from ..core.dtypes import to_numpy_dtype
 
-        nh = self.cfg.num_heads
-        shape = cache_shape(
-            self.batch, self.max_len, nh, self.cfg.hidden_size // nh
-        )
-        for name in gpt_cache_names(self.cfg):
-            self.scope.set_var(name, jnp.zeros(shape, jnp.float32))
+        for name, shape, dtype in self._state_specs:
+            self.scope.set_var(name, jnp.zeros(shape, to_numpy_dtype(dtype)))
+
+    def prefill_feeds(self, ids):
+        """The prefill program's feeds for a batch of prompts: one per
+        `prefill_rows` rows, each with the rows' offset in the batch."""
+        rows = self.prefill_rows
+        if rows == self.batch:
+            yield {"context_ids": ids}
+            return
+        for r0 in range(0, self.batch, rows):
+            yield {"context_ids": ids[r0:r0 + rows],
+                   "row_ids": np.array([r0], np.int64)}
+
+    def _prefill(self, ids):
+        """The prompt through the prefill program; last-position logits
+        of the whole batch."""
+        blocks = [
+            self.executor.run(self.prefill_prog, feed=feed,
+                              fetch_list=self._prefill_fetch,
+                              scope=self.scope)[0]
+            for feed in self.prefill_feeds(ids)
+        ]
+        return blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
+
+    def _publish_counters(self):
+        """The decoder's device-side step counters, read ONCE per batch:
+        added to the registry under the decoder's names (a gauge where
+        the decoder says the value is no sum) and left on the span as
+        args (a window's reader sums the spans that began inside it)."""
+        from .. import observability as _obs
+
+        dec = self.decoder
+        if dec.counters_var is None:
+            return
+        with _obs.span("serving.step_counters", category="serving") as sp:
+            values = np.asarray(self.scope.find_var(dec.counters_var))
+            for name, value in zip(dec.counter_names, values.tolist()):
+                sp.args[name] = value
+                if name in dec.counter_gauges:
+                    _obs.set_gauge(name, value)
+                else:
+                    _obs.add(name, value)
 
     # -- generation --------------------------------------------------------
     def generate(self, context_ids, max_new_tokens, greedy=True):
@@ -183,20 +262,18 @@ class GPTGenerator:
         with self._scope_guard(self.scope):
             with _obs.span("serving.prefill", category="serving",
                            context_len=self.context_len):
-                (logits,) = self.executor.run(
-                    self.prefill_prog, feed={"context_ids": ids},
-                    fetch_list=self._prefill_fetch, scope=self.scope,
-                )
+                logits = self._prefill(ids)
             feed = sample(logits, 0)
             with _obs.span("serving.decode_loop", category="serving",
                            tokens=int(max_new_tokens)):
                 for t in range(1, max_new_tokens):
-                    (logits,) = self.executor.run(
-                        self.decode_prog, feed=feed,
-                        fetch_list=self._decode_fetch, scope=self.scope,
-                    )
+                    logits = self.executor.run(
+                        self.decode_prog, feed=feed, scope=self.scope,
+                        fetch_list=self._decode_fetch,
+                    )[0]
                     feed = sample(logits, t)
             _obs.add("serving.decode_steps", max(0, max_new_tokens - 1))
+            self._publish_counters()
         return out
 
     def generate_full_recompute(self, context_ids, max_new_tokens):
@@ -205,8 +282,11 @@ class GPTGenerator:
         shape, so it too compiles once — the comparison isolates
         recompute cost, not compile count)."""
         import paddle_tpu as fluid
-        from ..models.gpt import gpt_logits
 
+        if not hasattr(self.decoder, "logits"):
+            raise InvalidArgumentError(
+                f"{type(self.decoder).__name__} has no full-context graph"
+            )
         ids = np.asarray(context_ids)
         if int(max_new_tokens) < 1:
             raise InvalidArgumentError(
@@ -220,13 +300,12 @@ class GPTGenerator:
             )
         prog = getattr(self, "_recompute_prog", None)
         if prog is None or self._recompute_len != t_total:
-            cfg = self.cfg
             prog = fluid.Program()
             startup = fluid.Program()  # params come from the shared scope
             with fluid.program_guard(prog, startup):
                 full = fluid.data("full_ids", [self.batch, t_total],
                                   "int64")
-                logits = gpt_logits(full, cfg, is_test=True)
+                logits = self.decoder.logits(full)
             prog._is_inference = True
             self._recompute_prog = prog
             self._recompute_len = t_total

@@ -31,6 +31,7 @@ from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
 from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
 from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
 from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
+from paddle_tpu.kernels import moe_gmm  # noqa: E402
 from paddle_tpu.kernels import ring_block as rb  # noqa: E402
 
 SZ = chip_smoke.REAL
@@ -144,6 +145,14 @@ def _layer_norm(rows, n, dtype):
     return fwd, bwd
 
 
+def _gmm(rows, tm, k, n, experts=32):
+    """The routed experts' grouped product at Trinity-Large's widths:
+    `rows` sorted rows in tiles of `tm`, `experts` matrices of [k, n]."""
+    specs = (((rows, k), BF16), ((experts, k, n), BF16),
+             ((rows // tm,), jnp.int32), ((1,), jnp.int32))
+    return (lambda x, w, te, na: moe_gmm.gmm(x, w, te, na, tm), specs),
+
+
 def _cases():
     cases = {}
 
@@ -178,6 +187,13 @@ def _cases():
         _ring(SZ.ring_batch, SZ.ring_seq // 4, F32), ("fwd", "dq", "dkv"))
     add(f"tiled-b{SZ.ring_batch}-s{SZ.ring_seq}-f32",
         _tiled(SZ.ring_batch, SZ.ring_seq, F32, 0.0))
+    # generate phase of trinity_large_ep8: a decode step's 64 x 4
+    # assignments in tiles of 16, a prefill block's 16 x 896 x 4 in tiles
+    # of 256; gate+up (3072 -> 6144) and down (3072 -> 3072)
+    for rows, tm in ((768, 16), (65536, 256)):
+        for n in (6144, 3072):
+            add(f"moe_gmm-{rows}x3072x{n}-tm{tm}-bf16",
+                _gmm(rows, tm, 3072, n), ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
@@ -227,6 +243,7 @@ def _named_cases():
         f"fused_residual-{rows}-bwd": ["fused_residual_bwd"],
         f"layer_norm-{rows}-fwd": ["layer_norm_fwd"],
         f"layer_norm-{rows}-bwd": ["layer_norm_bwd"],
+        "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm"],
     }
 
 
