@@ -38,6 +38,7 @@ import argparse
 import dataclasses
 import gc
 import json
+import os
 import sys
 import threading
 import time
@@ -61,6 +62,12 @@ F32_ATTN_TOL = 2e-3
 SERVE_ATOL = BF16_RTOL
 #: KV-cache decode vs full recompute logits at the first differing token
 GEN_LOGIT_ATOL = 5e-2
+#: the device-side token hand-off vs the host's argmax chain through the
+#: same two executables: a row in which some step's two largest logits
+#: lie closer than this is counted and left out of the comparison (the
+#: two runs repeat each other to the bit unless the chip does not; a row
+#: left out says so)
+GEN_TIE_EPS = 1e-4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +87,11 @@ class Sizes:
     serve_buckets: tuple
     gen_context: int
     gen_new: int
+    moe: str             # "cell": configs/trinity_large_ep8.json | "tiny"
+    moe_batch: int
+    moe_context: int
+    moe_max_len: int
+    moe_new: int
     ring_batch: int
     ring_heads: int
     ring_seq: int
@@ -91,6 +103,8 @@ REAL = Sizes(
     gpt="small", long_batch=2, long_seq=4096,
     serve_seq=128, serve_buckets=(1, 2, 4, 8),
     gen_context=512, gen_new=16,
+    # the Trinity generate cell's shapes: the whole decode batch, its caches
+    moe="cell", moe_batch=64, moe_context=896, moe_max_len=1024, moe_new=9,
     ring_batch=1, ring_heads=12, ring_seq=8192, ring_head_dim=64,
 )
 
@@ -600,9 +614,90 @@ def phase_serve(sz, kernels, shared):
     }
 
 
+def handoff_check(gen, prompts, new):
+    """The ids `gen.generate` returns (the choice made and handed from
+    step to step on the device, read once) against the argmax chain of
+    the logits the SAME two executables fetch when the host feeds each
+    token, as the benchmark's probes drive them: every row, `new`
+    tokens. Rows with a step whose two largest logits lie within
+    GEN_TIE_EPS are counted and left out; any other row must be equal."""
+    from paddle_tpu.framework.scope import scope_guard
+
+    exe, scope = gen.executor, gen.scope
+
+    def last_logits(program, feed, fetch):
+        got = exe.run(program, feed=feed, fetch_list=fetch, scope=scope)
+        return np.asarray(got[0])[:, -1, :]
+
+    gen.reset()
+    with scope_guard(scope):
+        logits = np.concatenate([
+            last_logits(gen.prefill_prog, feed, gen._prefill_fetch)
+            for feed in gen.prefill_feeds(prompts)
+        ])
+        chain, gaps = [], []
+        for t in range(new):
+            top2 = np.partition(logits, -2, axis=-1)[:, -2:]
+            gaps.append(top2[:, 1] - top2[:, 0])
+            chain.append(np.argmax(logits, axis=-1))
+            if t + 1 < new:
+                logits = last_logits(
+                    gen.decode_prog,
+                    {"token_ids": chain[-1][:, None].astype(np.int64),
+                     "pos_ids": np.array([[gen.context_len + t]], np.int64)},
+                    gen._decode_fetch,
+                )
+    chain, gaps = np.stack(chain, axis=1), np.stack(gaps, axis=1)
+    got = gen.generate(prompts, new)
+    tied = (gaps < GEN_TIE_EPS).any(axis=1)
+    differ = (got != chain).any(axis=1)
+    _check(not (differ & ~tied).any(),
+           f"rows {np.nonzero(differ & ~tied)[0].tolist()}: generate's ids "
+           "differ from the host argmax chain of the same executables' "
+           f"logits with no two logits within {GEN_TIE_EPS}")
+    return {
+        "rows": int(got.shape[0]), "tokens": int(new),
+        "rows_equal": int((~differ).sum()),
+        "rows_left_out_near_tie": int(tied.sum()),
+        "near_tie_rows_that_differ": int((differ & tied).sum()),
+        "tie_eps": GEN_TIE_EPS, "smallest_top2_gap": float(gaps.min()),
+        "distinct_rows": len({tuple(r) for r in got.tolist()}),
+    }
+
+
+def afmoe_generator(sz):
+    """`serving.GPTGenerator` handed the afmoe decoder at the benchmark
+    configuration's widths (bfloat16, 4.32B parameters) or its tiny cut."""
+    from benchmark.builders.afmoe import model_config
+    from paddle_tpu.models.afmoe import AfmoeDecoder
+    from paddle_tpu.serving import GPTGenerator
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "configs", "trinity_large_ep8.json")
+    with open(path) as f:
+        cfg = model_config(json.load(f), tiny=sz.moe == "tiny")
+    gen = GPTGenerator(AfmoeDecoder(cfg), batch=sz.moe_batch,
+                       context_len=sz.moe_context, max_len=sz.moe_max_len)
+    gen.init_params(seed=SEED)
+    return gen
+
+
 def phase_generate(sz, kernels, shared):
     """GPTGenerator behind Server: prefill + per-token KV-cache decode,
-    against generate_full_recompute."""
+    against generate_full_recompute; then the token hand-off of both
+    decoders against the host's argmax chain (`handoff_check`), the
+    afmoe decoder at the whole decode batch."""
+    out = _gpt_generate(sz)
+    gc.collect()    # the GPT generator's arrays, before 8.64 GB of weights
+    rng = np.random.RandomState(SEED + 1)
+    gen = afmoe_generator(sz)
+    prompts = rng.randint(0, gen.cfg.vocab_size,
+                          (sz.moe_batch, sz.moe_context)).astype(np.int64)
+    out["afmoe_handoff"] = handoff_check(gen, prompts, sz.moe_new)
+    return out
+
+
+def _gpt_generate(sz):
     import paddle_tpu as fluid
     from paddle_tpu.models.gpt import gpt_logits
     from paddle_tpu.serving import EndpointConfig, GPTGenerator, Server

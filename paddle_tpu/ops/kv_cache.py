@@ -27,8 +27,13 @@ minor dimension is padded to 128 and doubles the cache.
   Q for the current token against the full cache, positions beyond ``Pos``
   masked out. XLA sees one [B, nh, T, S] score tensor per layer instead of
   a chain of mask/where/softmax ops (the PR-6 "one wide op" argument).
+* ``greedy_token`` — the step's greedy choice, kept on the device: the
+  argmax of the last position's logits goes into a ``[B, 1]`` persistable
+  (the next step's token feed, handed over as a device array) and into
+  the step's column of a ``[B, new_tokens]`` persistable the host reads
+  once per batch.
 
-Neither op is differentiable: they exist only in frozen inference graphs
+No op here is differentiable: they exist only in frozen inference graphs
 (serving/freeze.py verifies no training op survives next to them).
 """
 
@@ -175,3 +180,39 @@ def _kv_cache_attention(ctx, op, ins):
     # contracts S, the minor dimension of both operands: no V^T is built
     out = jnp.einsum("bnts,bnds->bntd", probs, v)  # [B, nh, T, dh]
     return {"Out": [out.transpose(0, 2, 1, 3).reshape(b, t, h)]}
+
+
+@register_op(
+    "greedy_token",
+    inputs=["Logits", "Tokens", "Next", "Pos", "Row"],
+    outputs=["TokensOut", "NextOut"],
+    differentiable=False,
+    mutates=(("TokensOut", "Tokens"), ("NextOut", "Next")),
+)
+def _greedy_token(ctx, op, ins):
+    """argmax over the vocabulary of `Logits` [R, T, V] at the last
+    position (first index on a tie, as ``np.argmax``), written to column
+    ``Pos + column`` (`column` alone without `Pos`) of `Tokens` [B, N]
+    and handed out as `NextOut` [B, 1] in `Tokens`' dtype. R = B rows,
+    or a block of them starting at row `Row`, which then also needs
+    `Next`, the [B, 1] array the block's rows are written into."""
+    tokens = ins["Tokens"][0]
+    picked = jnp.argmax(ins["Logits"][0][:, -1, :], axis=-1)
+    picked = picked.astype(tokens.dtype)[:, None]
+    col = jnp.int32(int(op.attr("column", 0)))
+    if ins.get("Pos"):
+        col = col + _pos_scalar(ins["Pos"][0])
+    row = jnp.int32(0)
+    if ins.get("Row"):
+        if not ins.get("Next"):
+            raise InvalidArgumentError(
+                "greedy_token: a block of rows (`Row`) is written into "
+                "`Next`, which must then be an input"
+            )
+        row = _pos_scalar(ins["Row"][0])
+        picked_all = jax.lax.dynamic_update_slice(
+            ins["Next"][0], picked, (row, jnp.int32(0)))
+    else:
+        picked_all = picked
+    out = jax.lax.dynamic_update_slice(tokens, picked, (row, col))
+    return {"TokensOut": [out], "NextOut": [picked_all]}

@@ -27,6 +27,21 @@ variables of the step (an expert decoder's selected expert ids) that
 are fetched beside the logits, so that whoever checks a step against a
 reference reads them from the executables that serve.
 
+After a decoder's body the generator appends the greedy choice to BOTH
+programs (``greedy_token``, ops/kv_cache.py): the argmax of the logits
+is written to ``NEXT_TOKEN_VAR`` ([batch, 1]) and to the step's column
+of ``TOKENS_VAR`` ([batch, max_len - context_len]), two persistables of
+the generator's own that ``reset()`` zeroes with the caches. A step's
+fetch list is still the logits (and the extras), and ``token_ids`` is
+still a feed: ``generate`` feeds the previous step's ``NEXT_TOKEN_VAR``
+as the device array it is, dispatches every step with
+``return_numpy=False`` (the fetched logits stay on the device and cost
+no transfer) and reads ``TOKENS_VAR`` once, after the last step. So the
+host never waits on the device inside a batch, and whoever drives the
+two programs with host feeds and ``_prefill_fetch`` / ``_decode_fetch``
+(a check against a reference, another sampling policy reading the
+logits) runs the very executables a request's batch runs.
+
 Generation is O(1) recompute per token instead of O(S): both programs
 compile exactly once (shapes never change across steps), so a T-token
 generation is 1 prefill dispatch + T-1 decode dispatches against warm
@@ -40,6 +55,12 @@ from __future__ import annotations
 import numpy as np
 
 from ..errors import InvalidArgumentError
+
+# the generator's own state beside the decoder's: the token every row
+# chose last ([batch, 1], the next step's `token_ids` feed) and all of a
+# batch's choices ([batch, max_len - context_len], column t = token t)
+NEXT_TOKEN_VAR = "serving_next_token"
+TOKENS_VAR = "serving_tokens"
 
 
 class GPTGenerator:
@@ -93,6 +114,7 @@ class GPTGenerator:
                 row_ids = fluid.data("row_ids", [1], "int64")
             logits, extras = decoder.prefill(ids, self.batch, max_len,
                                              row_ids)
+            self._append_greedy(logits, row=row_ids)
         self._prefill_fetch = [logits.name] + [v.name for v in extras]
 
         self.decode_prog = fluid.Program()
@@ -101,6 +123,9 @@ class GPTGenerator:
             tok = fluid.data("token_ids", [batch, 1], "int64")
             pos = fluid.data("pos_ids", [1, 1], "int64")
             dlogits, extras = decoder.decode_step(tok, pos, max_len)
+            # the fed token sits at `pos`: the step chooses token
+            # pos - context_len + 1 of the batch
+            self._append_greedy(dlogits, pos=pos)
         self._decode_fetch = [dlogits.name] + [v.name for v in extras]
 
         # both are pure inference graphs: mark them so the Executor traces
@@ -108,9 +133,12 @@ class GPTGenerator:
         self.prefill_prog._is_inference = True
         self.decode_prog._is_inference = True
         self._scope_guard = scope_guard
-        self._state_specs = decoder.state_specs(self.batch, self.max_len)
+        specs = decoder.state_specs(self.batch, self.max_len)
+        self._state_specs = specs + [
+            (name, shape, "int64") for name, shape in self._token_vars()
+        ]
         by_kind = {}
-        for name, shape, dtype in self._state_specs:
+        for name, shape, dtype in specs:
             kind = decoder.cache_kind(name)
             if kind:
                 by_kind[kind] = by_kind.get(kind, 0) + int(
@@ -122,6 +150,36 @@ class GPTGenerator:
             **decoder.describe(), "batch": self.batch,
             "context_len": self.context_len, "max_len": self.max_len,
         })
+
+    def _token_vars(self):
+        return ((NEXT_TOKEN_VAR, (self.batch, 1)),
+                (TOKENS_VAR, (self.batch, self.max_len - self.context_len)))
+
+    def _append_greedy(self, logits, pos=None, row=None):
+        """The greedy choice of the step being built, left on the device
+        in the generator's two token persistables. `pos`: the fed
+        token's position (decode); `row`: first row of a prefill block."""
+        from ..framework.program import default_main_program
+
+        blk = default_main_program().global_block
+        nxt, tokens = (
+            blk.create_var(name=name, shape=shape, dtype="int64",
+                           persistable=True)
+            for name, shape in self._token_vars()
+        )
+        ins = {"Logits": [logits.name], "Tokens": [tokens.name]}
+        if pos is not None:
+            ins["Pos"] = [pos.name]
+        if row is not None:
+            # a block's rows go into the batch's array; a whole batch
+            # replaces it, so the array a step is fed is never one the
+            # step also takes (and donates) as state
+            ins.update(Row=[row.name], Next=[nxt.name])
+        blk.append_op(
+            "greedy_token", ins,
+            {"TokensOut": [tokens.name], "NextOut": [nxt.name]},
+            {"column": 0 if pos is None else 1 - self.context_len},
+        )
 
     def _param_vars(self):
         state = {name for name, _shape, _dtype in self._state_specs}
@@ -158,14 +216,18 @@ class GPTGenerator:
             return _io.save(self.prefill_prog, path)
 
     def reset(self):
-        """Zero the generation state by the decoder's specs: every
-        layer's KV cache and the step counters."""
+        """Zero the generation state by its specs: every layer's KV
+        cache, the step counters and the two token arrays, each in the
+        dtype a host feed of the declared one becomes on the device
+        (int64 is int32 unless x64 is on)."""
+        import jax
         import jax.numpy as jnp
 
         from ..core.dtypes import to_numpy_dtype
 
         for name, shape, dtype in self._state_specs:
-            self.scope.set_var(name, jnp.zeros(shape, to_numpy_dtype(dtype)))
+            dtype = jax.dtypes.canonicalize_dtype(to_numpy_dtype(dtype))
+            self.scope.set_var(name, jnp.zeros(shape, dtype))
 
     def prefill_feeds(self, ids):
         """The prefill program's feeds for a batch of prompts: one per
@@ -177,17 +239,6 @@ class GPTGenerator:
         for r0 in range(0, self.batch, rows):
             yield {"context_ids": ids[r0:r0 + rows],
                    "row_ids": np.array([r0], np.int64)}
-
-    def _prefill(self, ids):
-        """The prompt through the prefill program; last-position logits
-        of the whole batch."""
-        blocks = [
-            self.executor.run(self.prefill_prog, feed=feed,
-                              fetch_list=self._prefill_fetch,
-                              scope=self.scope)[0]
-            for feed in self.prefill_feeds(ids)
-        ]
-        return blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
 
     def _publish_counters(self):
         """The decoder's device-side step counters, read ONCE per batch:
@@ -212,9 +263,18 @@ class GPTGenerator:
     def generate(self, context_ids, max_new_tokens, greedy=True):
         """Generate `max_new_tokens` per sequence; returns [B, T] int64.
 
-        Greedy decoding (argmax) — the deterministic contract the parity
-        tests rely on; sampling policies plug in at the caller by reading
-        logits instead."""
+        Greedy decoding, chosen on the device: each step's program ends
+        in the argmax of its logits (first index on a tie, as numpy's
+        on the host), the chosen token is the next step's feed as the
+        device array the step left in the scope, no step waits for its
+        fetch, and the host reads the batch's [B, T] ids once, after the
+        last step. A step still fetches its logits (as device arrays
+        here): a caller that wants another sampling policy drives
+        ``prefill_prog`` / ``decode_prog`` itself with ``_prefill_fetch``
+        / ``_decode_fetch`` and its own ``token_ids`` feed, and reads
+        the logits."""
+        import jax
+
         from .. import observability as _obs
 
         if not greedy:
@@ -222,7 +282,8 @@ class GPTGenerator:
                 "only greedy decoding is implemented; sample from the "
                 "logits fetch at the caller for other policies"
             )
-        if int(max_new_tokens) < 1:
+        new = int(max_new_tokens)
+        if new < 1:
             raise InvalidArgumentError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}"
             )
@@ -232,8 +293,7 @@ class GPTGenerator:
                 f"context_ids must be [{self.batch}, {self.context_len}], "
                 f"got {ids.shape}"
             )
-        t_total = self.context_len + int(max_new_tokens)
-        if t_total > self.max_len:
+        if self.context_len + new > self.max_len:
             raise InvalidArgumentError(
                 f"context {self.context_len} + {max_new_tokens} new tokens "
                 f"exceeds max_len {self.max_len}"
@@ -242,39 +302,42 @@ class GPTGenerator:
         # activates the request's context around runner.run): the
         # reset / prefill / decode split of a generate request's latency —
         # each executor.step inside nests one level further, and each
-        # serving.sample is the host's own work between two of them
-        # (argmax over the fetched logits, the next step's feed)
+        # serving.sample is the host's own work between two of them (the
+        # next step's feed; the choice itself is the device's)
         with _obs.span("serving.cache_reset", category="serving"):
             self.reset()
-        out = np.zeros((self.batch, max_new_tokens), np.int64)
 
-        def sample(logits, t):
+        def run(program, feed, fetch):
+            self.executor.run(program, feed=feed, fetch_list=fetch,
+                              scope=self.scope, return_numpy=False)
+
+        def next_feed(t):
+            """The feed of the step that chooses token t + 1."""
             with _obs.span("serving.sample", category="serving"):
-                nxt = np.argmax(np.asarray(logits)[:, -1, :], axis=-1)
-                out[:, t] = nxt
-                # the fed token's position
-                pos = self.context_len + t
                 return {
-                    "token_ids": nxt[:, None].astype(np.int64),
-                    "pos_ids": np.array([[pos]], np.int64),
+                    "token_ids": self.scope.find_var(NEXT_TOKEN_VAR),
+                    "pos_ids": np.array([[self.context_len + t]], np.int64),
                 }
 
         with self._scope_guard(self.scope):
             with _obs.span("serving.prefill", category="serving",
                            context_len=self.context_len):
-                logits = self._prefill(ids)
-            feed = sample(logits, 0)
+                for feed in self.prefill_feeds(ids):
+                    run(self.prefill_prog, feed, self._prefill_fetch)
+                # the span is the prefill's device time: wait for it
+                jax.block_until_ready(self.scope.find_var(NEXT_TOKEN_VAR))
+            feed = next_feed(0)
             with _obs.span("serving.decode_loop", category="serving",
-                           tokens=int(max_new_tokens)):
-                for t in range(1, max_new_tokens):
-                    logits = self.executor.run(
-                        self.decode_prog, feed=feed, scope=self.scope,
-                        fetch_list=self._decode_fetch,
-                    )[0]
-                    feed = sample(logits, t)
-            _obs.add("serving.decode_steps", max(0, max_new_tokens - 1))
+                           tokens=new):
+                for t in range(1, new):
+                    run(self.decode_prog, feed, self._decode_fetch)
+                    feed = next_feed(t)
+                # the batch's one read: returns when the last step has
+                # run, so the span holds all of the loop's device work
+                out = np.asarray(self.scope.find_var(TOKENS_VAR))
+            _obs.add("serving.decode_steps", new - 1)
             self._publish_counters()
-        return out
+        return out[:, :new].astype(np.int64)
 
     def generate_full_recompute(self, context_ids, max_new_tokens):
         """The naive baseline: re-run the FULL context through a plain

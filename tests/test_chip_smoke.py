@@ -173,6 +173,7 @@ TINY = cs.Sizes(
     gpt="tiny", long_batch=2, long_seq=128,
     serve_seq=16, serve_buckets=(1, 2, 4, 8),
     gen_context=32, gen_new=6,
+    moe="tiny", moe_batch=4, moe_context=24, moe_max_len=40, moe_new=9,
     ring_batch=1, ring_heads=8, ring_seq=64, ring_head_dim=16,
 )
 phases = cs.FOUR_CHIP_PHASES if sys.argv[1] == "4" else cs.ONE_CHIP_PHASES

@@ -197,7 +197,7 @@ def test_spmd_stage_inside_dispatch_on_four_devices(tmp_path):
     assert ring["spmd.stage"]["dur"] <= ring["spmd.dispatch"]["dur"]
 
 
-def test_generate_spans_sample_per_token_and_cover_the_loop(tmp_path):
+def _gpt_generator():
     from paddle_tpu.models.gpt import GPTConfig
     from paddle_tpu.serving import GPTGenerator
 
@@ -205,8 +205,30 @@ def test_generate_spans_sample_per_token_and_cover_the_loop(tmp_path):
     cfg.use_fused_attention = False
     gen = GPTGenerator(cfg, batch=2, context_len=12, max_len=24)
     gen.init_params(seed=11)
+    return gen, 1
+
+
+def _afmoe_generator():
+    from paddle_tpu.models.afmoe import AfmoeConfig, AfmoeDecoder
+    from paddle_tpu.serving import GPTGenerator
+
+    gen = GPTGenerator(AfmoeDecoder(AfmoeConfig.tiny(prefill_rows=1)),
+                       batch=2, context_len=12, max_len=24)
+    gen.init_params(seed=11)
+    return gen, 2   # prefill dispatches a batch
+
+
+@pytest.mark.parametrize("make", [_gpt_generator, _afmoe_generator],
+                         ids=["gpt", "afmoe"])
+def test_generate_spans_no_fetch_in_the_loop_and_one_sample_a_token(
+        tmp_path, make):
+    """The decode loop's tree (ISSUE 28): one `executor.step` and one
+    `serving.sample` a token, no step waits for its fetch (no
+    `executor.fetch` anywhere under `serving.batch`'s children), the
+    loop ends with the batch's one read, after its last child."""
+    gen, prefills = make()
     ctx = np.random.RandomState(0).randint(
-        0, cfg.vocab_size, size=(2, 12)).astype(np.int64)
+        0, gen.cfg.vocab_size, size=(2, 12)).astype(np.int64)
     tokens = 8
     first = gen.generate(ctx, tokens)  # compiles
     obs.reset()
@@ -217,19 +239,28 @@ def test_generate_spans_sample_per_token_and_cover_the_loop(tmp_path):
     assert names["serving.sample"] == tokens
     assert names["serving.cache_reset"] == 1
     assert names["serving.prefill"] == names["serving.decode_loop"] == 1
-    assert names["executor.step"] == tokens  # prefill + tokens - 1
+    assert names["executor.step"] == prefills + tokens - 1
+    assert names["executor.fetch"] == 0
+    assert names["serving.step_counters"] == (gen.decoder.counters_var
+                                              is not None)
     (line, loop), = cap.named("serving.decode_loop")
     kids = cap.children(line, loop)
     assert kids == ["executor.step", "serving.sample"] * (tokens - 1)
-    inside = [e for e in cap.lines[line]
-              if e[0] in ("executor.step", "serving.sample")
-              and loop[1] <= e[1] and e[2] <= loop[2]]
-    covered = sum(e[2] - e[1] for e in inside)
-    assert covered >= 0.9 * (loop[2] - loop[1])
+    (_l, prefill), = cap.named("serving.prefill")
+    assert cap.children(line, prefill) == ["executor.step"] * prefills
+    steps = [e for e in cap.lines[line] if e[0] == "executor.step"
+             and loop[1] <= e[1] and e[2] <= loop[2]]
+    for step in steps:
+        assert cap.children(line, step) == STEP_CHILDREN
+    # the loop's last child ends before the loop does: the one read
+    last = max(e[2] for e in cap.lines[line]
+               if e[0] == "serving.sample" and e[2] <= loop[2])
+    assert last < loop[2]
     # reset -> prefill -> first sample -> loop, all on the caller's thread
     order = [e[0] for e in cap.lines[line] if e[0].startswith("serving.")]
     assert order[:4] == ["serving.cache_reset", "serving.prefill",
                          "serving.sample", "serving.decode_loop"]
+    assert prefill[2] <= loop[1]
 
 
 class _Doubler:

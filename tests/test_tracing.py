@@ -252,6 +252,9 @@ def test_gpt_generator_decode_spans_under_request_trace():
     steps = [s for s in _by_name("executor.step")
              if s["parent_id"] == decode["span_id"]]
     assert len(steps) == 2  # 3 tokens -> 2 decode dispatches
+    # no step of a batch waits for its fetch: the host reads the ids once
+    assert not [s for s in _by_name("executor.fetch")
+                if s.get("trace_id") == tr.trace_id]
 
 
 # -- async checkpointer: publish parents to the SURVIVING save ---------------
