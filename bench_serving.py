@@ -1,7 +1,7 @@
 """Serving load generator: checkpoint -> frozen graph -> QPS.
 
 Drives the paddle_tpu.serving router with traffic mixes and prints ONE
-JSON line per mix (bench.py convention):
+JSON line per mix:
 
   * ``bert_classify``  — tiny-BERT sequence classifier, closed-loop
     concurrent clients over buckets (1, 2, 4, 8);
@@ -452,7 +452,7 @@ def bench_gpt_generate(smoke, results):
     rng = np.random.RandomState(0)
     ctx = rng.randint(0, cfg.vocab_size, (1, context)).astype(np.int64)
 
-    # decode vs full-recompute, best-of-3 (the bench.py convention)
+    # decode vs full-recompute, best-of-3
     best_kv = best_full = float("inf")
     gen.generate(ctx, new_tokens)
     gen.generate_full_recompute(ctx, new_tokens)

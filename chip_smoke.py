@@ -205,7 +205,7 @@ def run_phase(name, fn, meter, *args):
 
 
 def _amp(opt):
-    """bench.py's AMP recipe: bf16, static loss scale 1."""
+    """The AMP recipe of every training cell: bf16, static loss scale 1."""
     from paddle_tpu.contrib import mixed_precision as mp
 
     return mp.decorate(
@@ -239,7 +239,7 @@ def gpt_config(sz, max_position=None):
 
 
 def build_bert_train(cfg, b, s, n_pred, minimize):
-    """bench.py::bench_bert's build: masked-position MLM head, `minimize`
+    """The BERT pre-training build: masked-position MLM head, `minimize`
     applied to the loss (plain AMP Adam, or a fleet optimizer)."""
     import paddle_tpu as fluid
     from paddle_tpu.models import bert_pretrain

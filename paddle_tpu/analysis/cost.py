@@ -1,9 +1,9 @@
 """Per-op cost attribution: analytic FLOPs / bytes-moved / roofline latency
 over the Program IR — the fourth ``analysis/`` family (ROADMAP item 3).
 
-Every perf win through r6 came from hand-probing: bench.py hard-coded a
-per-model FLOPs closed form, MFU was computed offline per leg, and "which
-ops eat the step" meant reading XLA dumps. Learned TPU cost models
+Every perf win through r6 came from hand-probing: a per-model FLOPs
+closed form hard-coded beside each timing loop, MFU computed offline per
+leg, and "which ops eat the step" meant reading XLA dumps. Learned TPU cost models
 (arXiv:2008.01040) and TVM's cost-model-driven search (arXiv:1802.04799)
 both start from exactly the feature this pass extracts: per-op compute and
 traffic at concrete shapes. The model here is analytic (closed forms per
@@ -34,10 +34,11 @@ Walk model (mirrors the collective-schedule walker, collectives.py):
 
 Roofline: ``latency = max(flops/peak_flops, bytes/peak_bandwidth)`` with
 peaks from ``PADDLE_TPU_PEAK_TFLOPS`` / ``PADDLE_TPU_PEAK_GBPS``
-(defaults: TPU v5e bf16 197 TFLOP/s, 819 GB/s HBM). The same peak feeds
-the executor's live ``perf.mfu`` gauge, so offline and live MFU agree by
-construction. README §Cost attribution & perf telemetry documents the
-contract.
+(defaults: TPU v5e bf16 197 TFLOP/s, 819 GB/s HBM). This is an OFFLINE
+estimator: ``tools/perf_report.py`` and ``Endpoint.plan_memory()`` call
+it; the executor does not, and nothing it computes is published as a
+measurement (a speed comes from ``benchmark/run.py`` on the chip). README
+§The offline estimator documents the contract.
 """
 
 from __future__ import annotations

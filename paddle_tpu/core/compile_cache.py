@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache, placed from outside.
 
 One rule for every entry point that compiles at real sizes (chip_smoke.py,
-bench.py, bench_serving.py, the serving worker, the inference Predictor):
+benchmark/run.py, bench_serving.py, the serving worker, the inference
+Predictor):
 where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself reads it and no code
 sets another directory; where it is not, the cache lives at ONE fixed path
 inside the checkout. The path is part of how a later process finds an

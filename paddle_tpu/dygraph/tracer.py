@@ -11,7 +11,7 @@ Reference parity: imperative/tracer.cc:45 (TraceOp), basic_engine.cc:159
   reverse sweep accumulating into VarBase._grad by addition.
 * Per-op jit caching (r4 — measured, not just claimed: the uncached
   tracer paid a fresh jax.vjp trace + op-by-op eager dispatch per op,
-  22x the static executor on small shapes, tools/bench_dygraph.py): the
+  22x the static executor on small shapes): the
   fused forward+vjp of each op is jax.jit-compiled once per (op_type,
   attrs, input avals) — jax.vjp's closure is a PYTREE (residual arrays
   as leaves), so it crosses the jit boundary as residual outputs. backward()

@@ -237,9 +237,9 @@ class CostAnalysisUnavailableWarning(UserWarning):
     """The compiled executable's ``cost_analysis()`` returned no data
     (``Executor.flops``): the backend genuinely reports nothing, which is
     NOT the same as a zero-FLOP program. Callers deriving MFU from
-    ``Executor.flops`` should fall back to ``Program.estimate()`` — the
-    executor's live ``perf.mfu`` gauge already does. Each occurrence also
-    bumps the ``perf.cost_analysis_unavailable`` counter."""
+    ``Executor.flops`` should fall back to ``Program.estimate()``. Each
+    occurrence also bumps the ``perf.cost_analysis_unavailable``
+    counter."""
 
 
 class TrainingDivergedError(EnforceNotMet, RuntimeError):

@@ -37,11 +37,9 @@ from .scope import global_scope
 
 
 class _Compiled:
-    __slots__ = ("fn", "state_ro", "state_mut", "fetch_names", "nan_ops",
-                 "est", "recent_dts", "recent_segs", "wire_stats")
+    __slots__ = ("fn", "state_ro", "state_mut", "fetch_names", "nan_ops")
 
-    def __init__(self, fn, state_ro, state_mut, fetch_names, nan_ops=None,
-                 wire_stats=None):
+    def __init__(self, fn, state_ro, state_mut, fetch_names, nan_ops=None):
         self.fn = fn
         self.state_ro = state_ro
         self.state_mut = state_mut
@@ -49,62 +47,6 @@ class _Compiled:
         # ops list compiled with per-op NaN/Inf checks (FLAGS_check_nan_inf);
         # the extra trailing fetch indexes into this to name the offender
         self.nan_ops = nan_ops
-        # analytic cost estimate for this executable (perf.* telemetry):
-        # None = not yet computed, False = estimation failed (never retried)
-        self.est = None
-        # steady-state step latencies (compile-carrying runs excluded) —
-        # the window behind the live perf.mfu gauge
-        self.recent_dts = None
-        # steady-state (host_seconds, device_seconds) pairs — the window
-        # behind the perf.wait_fraction.* attribution gauges
-        self.recent_segs = None
-        # mutable {"bytes": float} the collective emitters fill at trace
-        # time (ops/collective.py): this executable's per-step estimated
-        # wire payload, an estimate-independent attribution cross-check
-        self.wire_stats = wire_stats
-
-
-class _PerfEstimate:
-    """Digest of a CostTable cached per executable for the per-run
-    perf.* updates (the full table is published once, to the
-    "perf.cost_table" observability table)."""
-
-    __slots__ = ("flops", "bytes", "peak", "family_shares",
-                 "wire_latency", "compute_latency", "wire_total_latency",
-                 "overlap_ratio", "step_latency")
-
-    def __init__(self, table):
-        self.flops = float(table.total_flops)
-        self.bytes = float(table.total_bytes)
-        self.peak = float(table.peak_flops)
-        total_lat = table.total_latency
-        fams = table.by_family()
-        self.family_shares = {
-            fam: (agg["latency"] / total_lat if total_lat else 0.0)
-            for fam, agg in fams.items()
-        }
-        # the wire split (ROADMAP item 4's denominator, now
-        # OVERLAP-AWARE): `wire_total_latency` is the serialized wire —
-        # the collective family's roofline, closed forms carrying the
-        # ring (n-1)/n factors and quantized element sizes;
-        # `wire_latency` is the EXPOSED wire — the part the program's
-        # actual collective schedule cannot hide behind compute
-        # (CostTable.wire_exposed_latency; == total for a serialized
-        # schedule, so pre-overlap programs attribute exactly as before).
-        self.wire_total_latency = float(table.wire_latency)
-        self.wire_latency = float(table.wire_exposed_latency)
-        self.compute_latency = max(
-            0.0, float(total_lat) - self.wire_total_latency
-        )
-        self.step_latency = float(table.step_latency)
-        # wire seconds hidden / total wire seconds under the schedule
-        self.overlap_ratio = float(table.overlap_ratio)
-
-    @property
-    def wire_fraction(self):
-        """Share of the estimated step the EXPOSED wire serializes."""
-        denom = self.wire_latency + self.compute_latency
-        return self.wire_latency / denom if denom > 0 else 0.0
 
 
 def _analyze_block(block, feed_names, fetch_names):
@@ -161,14 +103,9 @@ class Executor:
         # not have fails here (UnavailableError), never at a CPU's pace
         self.place.jax_device()
         self._cache = OrderedDict()
-        # (compiled, fresh_compile, (host_s, device_s) | None) of the
-        # last run — consumed once by _note_perf
-        self._last_run = None
-        self._est_memo = {}  # cache key -> _PerfEstimate | False
 
     def close(self):
         self._cache.clear()
-        self._est_memo.clear()
 
     # ------------------------------------------------------------------
     def run(
@@ -183,7 +120,6 @@ class Executor:
         from .. import observability as _obs
 
         _obs.add("executor.run_steps")
-        self._last_run = None
         step = _obs.span("executor.step")
         try:
             with step:
@@ -195,116 +131,7 @@ class Executor:
             # the span's own stamps: None when monitoring is off
             if step.seconds is not None:
                 _obs.observe("executor.step_latency", step.seconds)
-        self._note_perf(step.seconds)
         return result
-
-    @staticmethod
-    def _drop_perf_gauges(_obs):
-        for prefix in ("perf.mfu", "perf.step_seconds",
-                       "perf.family_time.", "perf.wait_fraction.",
-                       "collective.overlap_ratio"):
-            _obs.drop_gauges(prefix)
-        # the attribution table describes ONE executable, same as the
-        # gauges: a snapshot taken right after an executable switch must
-        # not pair the old split with the new program
-        _obs.drop_tables("perf.step_attribution")
-
-    def _note_perf(self, dt):
-        """Per-run perf.* telemetry from the analytic cost estimate: step
-        FLOP/byte counters always; the live MFU gauge only from
-        steady-state runs (a compile-carrying run would crater it)."""
-        from .. import observability as _obs
-
-        noted = self._last_run
-        self._last_run = None
-        if noted is None or dt is None or not _obs.enabled():
-            return
-        compiled, fresh_compile, seg = noted
-        est = compiled.est
-        if not est:
-            # this executable has no estimate: a previous executable's
-            # gauges must not read as live for it
-            self._drop_perf_gauges(_obs)
-            return
-        _obs.add("perf.step_flops", int(est.flops))
-        _obs.add("perf.step_bytes", int(est.bytes))
-        if fresh_compile or dt <= 0:
-            # compile-carrying run: no steady-state value for THIS
-            # executable yet, and the old gauges describe another one
-            self._drop_perf_gauges(_obs)
-            return
-        if compiled.recent_dts is None:
-            from collections import deque
-
-            compiled.recent_dts = deque(maxlen=32)
-            compiled.recent_segs = deque(maxlen=32)
-        compiled.recent_dts.append(dt)
-        mean_dt = sum(compiled.recent_dts) / len(compiled.recent_dts)
-        _obs.set_gauge("perf.step_seconds", mean_dt)
-        if est.peak > 0 and est.flops > 0:
-            _obs.set_gauge("perf.mfu", est.flops / mean_dt / est.peak)
-        # attribute the MEASURED step time across op families by each
-        # family's share of the estimated roofline; drop first so families
-        # only present in a PREVIOUS executable don't survive as stale
-        _obs.drop_gauges("perf.family_time.")
-        for fam, share in est.family_shares.items():
-            _obs.set_gauge(f"perf.family_time.{fam}", share * mean_dt)
-        if seg is not None:
-            self._note_attribution(_obs, compiled, est, mean_dt, seg)
-
-    @staticmethod
-    def _note_attribution(_obs, compiled, est, mean_dt, seg):
-        """Per-step compute / collective-wait / host-stall attribution
-        (the serialized-wire denominator ROADMAP item 4 measures against):
-        the measured step splits into host time (feed/state assembly +
-        write-back, directly measured) and device time (dispatch +
-        block-until-ready); device time splits into compute vs
-        collective-wait by the cost model's wire share — under serialized
-        collectives the wire's roofline share of the device step IS the
-        time the math waits on the wire."""
-        host, device = seg
-        compiled.recent_segs.append((host, device))
-        n = len(compiled.recent_segs)
-        mean_host = sum(s[0] for s in compiled.recent_segs) / n
-        mean_device = sum(s[1] for s in compiled.recent_segs) / n
-        wire_share = est.wire_fraction
-        coll_wait = mean_device * wire_share
-        compute = mean_device - coll_wait
-        denom = mean_host + mean_device
-        if denom <= 0:
-            return
-        # the wait_fraction gauge SET is fixed (3 names), so unlike the
-        # per-family gauges there is nothing stale to drop per step —
-        # _drop_perf_gauges clears them on executable switch
-        _obs.set_gauge("perf.wait_fraction.collective", coll_wait / denom)
-        _obs.set_gauge("perf.wait_fraction.host", mean_host / denom)
-        _obs.set_gauge("perf.wait_fraction.compute", compute / denom)
-        # wire seconds hidden / total wire seconds under the executable's
-        # collective schedule (0 = serialized) — the overlap consumer of
-        # the PR-13 attribution split
-        _obs.set_gauge("collective.overlap_ratio", est.overlap_ratio)
-        _obs.observe("perf.compute_seconds", device * (1.0 - wire_share))
-        _obs.observe("perf.collective_wait_seconds", device * wire_share)
-        _obs.observe("perf.host_stall_seconds", host)
-        wire_stats = compiled.wire_stats or {}
-        _obs.set_table("perf.step_attribution", {
-            "step_seconds": mean_dt,
-            "compute_seconds": compute,
-            "collective_wait_seconds": coll_wait,
-            "host_stall_seconds": mean_host,
-            "wait_fraction_collective": coll_wait / denom,
-            "wait_fraction_host": mean_host / denom,
-            "est_compute_seconds": est.compute_latency,
-            "est_wire_seconds": est.wire_latency,
-            "est_wire_total_seconds": est.wire_total_latency,
-            "est_wire_hidden_seconds": max(
-                0.0, est.wire_total_latency - est.wire_latency
-            ),
-            "est_overlap_ratio": est.overlap_ratio,
-            "est_wait_fraction": wire_share,
-            "traced_wire_bytes": float(wire_stats.get("bytes", 0.0)),
-            "window_steps": n,
-        })
 
     def _run_body(
         self, program, feed, fetch_list, scope, return_numpy,
@@ -320,33 +147,21 @@ class Executor:
         falls under the one the host was in."""
         from .. import observability as _obs
 
-        with _obs.span("executor.prologue") as prologue:
-            compiled, fresh_compile, scope, call_args = self._prologue(
+        with _obs.span("executor.prologue"):
+            compiled, scope, call_args = self._prologue(
                 program, feed, fetch_list, scope, use_program_cache
             )
-        with _obs.span("executor.dispatch") as dispatch:
+        with _obs.span("executor.dispatch"):
             fetches, new_state = compiled.fn(*call_args)
         # write-back FIRST: state_mut buffers were donated, so skipping the
         # write-back on error would leave the scope holding deleted arrays
         # (params irretrievably lost right when the user wants to inspect)
-        with _obs.span("executor.writeback") as writeback:
+        with _obs.span("executor.writeback"):
             for n, v in new_state.items():
                 scope.set_var(n, v)
         if return_numpy:
-            with _obs.span("executor.fetch") as fetch:
+            with _obs.span("executor.fetch"):
                 fetches = [np.asarray(f) for f in fetches]
-            if fetch.seconds is not None:
-                # host/device split for the per-step attribution
-                # (perf.wait_*), from the spans' own stamps: host =
-                # prologue + write-back; device = the dispatch and the
-                # wait for its outputs. ONLY return_numpy callers wait
-                # inside this call; an async caller's dispatch returns
-                # before the device is done, so such runs publish no
-                # attribution sample.
-                self._last_run = (compiled, fresh_compile, (
-                    prologue.seconds + writeback.seconds,
-                    dispatch.seconds + fetch.seconds,
-                ))
         if compiled.nan_ops is not None:
             bad = np.asarray(fetches[-1])
             fetches = fetches[:-1]
@@ -365,8 +180,8 @@ class Executor:
         return list(fetches)
 
     def _prologue(self, program, feed, fetch_list, scope, use_program_cache):
-        """Everything before the compiled call: (compiled, fresh_compile,
-        the resolved scope, the call's arguments)."""
+        """Everything before the compiled call: (compiled, the resolved
+        scope, the call's arguments)."""
         from .. import observability as _obs
 
         # the shared prologue keys the cache on the Program OBJECT
@@ -376,7 +191,6 @@ class Executor:
         (program, scope, block, feed_arrays, _feed_sig, fetch_names,
          key) = self._prepared(program, feed, fetch_list, scope)
         compiled = self._cache.get(key) if use_program_cache else None
-        fresh_compile = compiled is None
         if compiled is None:
             if use_program_cache:
                 _obs.add("executor.cache_misses")
@@ -394,20 +208,6 @@ class Executor:
         else:
             _obs.add("executor.cache_hits")
             self._cache.move_to_end(key)
-
-        if compiled.est is None and _obs.enabled():
-            # memo per cache key so use_program_cache=False callers don't
-            # re-walk the graph every step (fresh _Compiled each run)
-            est = self._est_memo.get(key)
-            if est is None:
-                est = self._estimate(program, feed_arrays)
-                if len(self._est_memo) >= 64:
-                    self._est_memo.pop(next(iter(self._est_memo)))
-                self._est_memo[key] = est
-            compiled.est = est
-        # seg (host vs device split) is filled in by _run_body once the
-        # fetch completes; a run that raises before then reports none
-        self._last_run = (compiled, fresh_compile, None)
 
         state_ro = {n: self._from_scope(scope, n, block) for n in compiled.state_ro}
         state_mut = {n: self._from_scope(scope, n, block) for n in compiled.state_mut}
@@ -452,34 +252,9 @@ class Executor:
             step_key = jax.random.fold_in(
                 jax.random.key(seed, impl=prng_impl()), step
             )
-        return compiled, fresh_compile, scope, (
+        return compiled, scope, (
             feed_arrays, state_mut, state_ro, step_key
         )
-
-    # ------------------------------------------------------------------
-    def _estimate(self, program, feed_arrays):
-        """Analytic cost digest for the executable about to run, pinned at
-        the actual feed shapes; publishes the full per-op table as the
-        "perf.cost_table" observability table. Returns False on failure so
-        the estimate is attempted once per executable, never per step."""
-        from .. import observability as _obs
-
-        try:
-            table = program.estimate(feed_shapes={
-                k: tuple(a.shape) for k, a in feed_arrays.items()
-            })
-            _obs.set_table("perf.cost_table", table.to_dict(top=50))
-            if table.peak_bytes is not None:
-                _obs.set_gauge(
-                    "perf.peak_bytes_est", float(table.peak_bytes)
-                )
-                _obs.set_gauge(
-                    "perf.resident_bytes_est", float(table.resident_bytes)
-                )
-            return _PerfEstimate(table)
-        except Exception:
-            _obs.add("perf.estimate_failures")
-            return False
 
     # ------------------------------------------------------------------
     def lower(self, program=None, feed=None, fetch_list=None, scope=None):
@@ -516,8 +291,8 @@ class Executor:
         the per-op cost tooling of operators/benchmark/op_tester.cc).
         Reuses the executor's compile cache; run the same (program, feed)
         once first for a warm lookup. Pallas custom-call FLOPs are NOT
-        visible to XLA — callers benchmarking hand kernels must add that
-        term analytically (bench.py does for the attention kernels)."""
+        visible to XLA — callers must add that term analytically
+        (benchmark/harness/flops.py is the closed form the cells use)."""
         ca = self.lower(
             program, feed, fetch_list, scope
         ).compile().cost_analysis()
@@ -833,23 +608,16 @@ class Executor:
         # while serving requests
         is_test = bool(getattr(program, "_is_inference", False))
 
-        # filled by the collective emitters at trace time with this
-        # executable's estimated per-step wire bytes (ops/collective.py);
-        # reset at each (re)trace so retraces never double-count
-        wire_stats = {"bytes": 0.0}
-
         def traced(feeds, smut, sro, step_key):
             env = {}
             env.update(sro)
             env.update(smut)
             env.update(feeds)
             axis_sizes = dict(mesh.shape) if mesh is not None else {}
-            wire_stats["bytes"] = 0.0
             ctx = EmitContext(
                 step_key=step_key, is_test=is_test, mesh_axes=mesh_axes,
                 axis_sizes=axis_sizes, program=program,
             )
-            ctx.wire_stats = wire_stats
             nan_flags = []
             for i, op in enumerate(ops):
                 try:
@@ -915,7 +683,6 @@ class Executor:
         return _Compiled(
             fn, state_ro, state_mut, fetch_names,
             nan_ops=ops if (check_nan and ops) else None,
-            wire_stats=wire_stats,
         )
 
 

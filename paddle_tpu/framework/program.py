@@ -458,10 +458,10 @@ class Program:
 
     def estimate(self, feed_shapes=None, peak_tflops=None, peak_gbps=None):
         """Analytic per-op FLOPs / bytes / roofline-latency table for ONE
-        step of this program (analysis/cost.py): the observability half of
-        the IR cost model — ``bench.py`` derives MFU from it, the executor
-        feeds the live ``perf.*`` gauges with it, and the planned autotuner
-        consumes it as its objective. `feed_shapes` ({var: shape}) pins -1
+        step of this program (analysis/cost.py): the offline estimator —
+        ``tools/perf_report.py`` renders and cross-checks it against XLA,
+        and the planned autotuner consumes it as its objective; nothing
+        calls it while a step runs. `feed_shapes` ({var: shape}) pins -1
         batch dims; peaks default from ``PADDLE_TPU_PEAK_TFLOPS`` /
         ``PADDLE_TPU_PEAK_GBPS`` (TPU v5e bf16). The table also carries
         the static HBM plan (``peak_bytes`` / ``resident_bytes`` /

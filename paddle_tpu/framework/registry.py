@@ -66,11 +66,6 @@ class EmitContext:
         # batches are 1/divisor of graph-build shapes (microbatching), and
         # batch-shape-baking ops (reshape2) may re-derive their leading dim
         self.batch_divisor = 1
-        # mutable {"bytes": float} the Executor attaches so the collective
-        # emitters (ops/collective.py) can accumulate the executable's
-        # estimated per-step wire payload for the perf.* attribution;
-        # None outside an Executor trace (infer_shapes replay etc.)
-        self.wire_stats = None
 
     def with_batch_divisor(self, divisor):
         c = EmitContext.__new__(EmitContext)

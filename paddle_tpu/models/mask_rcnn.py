@@ -26,29 +26,14 @@ Two train paths:
   are normalized per image then averaged, so B=1 reproduces the legacy
   losses exactly and the batched loss equals the mean of per-image
   losses up to sampling jitter (fp-order tolerance when caps saturate).
-
-``batched_detection_enabled()`` reads the PADDLE_TPU_BATCHED_DETECTION
-env knob (default on) — bench.py and builders use it to pick the path.
 """
 
 from __future__ import annotations
-
-import os
 
 from .. import layers
 from ..initializer import Normal
 from ..layers import detection as det
 from ..param_attr import ParamAttr
-
-
-def batched_detection_enabled():
-    """Env/config knob for the batched vs legacy per-image detection path
-    (PADDLE_TPU_BATCHED_DETECTION, default on). The ops themselves
-    dispatch on input rank; this only selects which graph builders and
-    bench legs construct."""
-    return os.environ.get(
-        "PADDLE_TPU_BATCHED_DETECTION", "1"
-    ).lower() not in ("0", "false", "off")
 
 
 def _head_attr(std=0.01):

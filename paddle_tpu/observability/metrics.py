@@ -41,8 +41,8 @@ _counters: dict[str, int] = {}
 _gauges: dict[str, float] = {}
 _histograms: dict[str, "_Histogram"] = {}
 # structured tables (plain-JSON dicts, last value wins): richer artifacts a
-# scalar cannot carry — e.g. the executor publishes the latest per-op cost
-# attribution as "perf.cost_table" (tools/stats_report.py --top-ops)
+# scalar cannot carry — e.g. the generator publishes its model's shape as
+# "serving.generate.model", the watcher its "watch.findings"
 _tables: dict[str, dict] = {}
 
 
@@ -122,9 +122,9 @@ def observe(name: str, value: float, buckets=None) -> None:
 
 def drop_gauges(prefix: str) -> None:
     """Remove every gauge whose name starts with `prefix`. For publishers
-    whose gauge SET varies with the source (e.g. the executor's
-    per-op-family ``perf.family_time.*``): dropping before re-publishing
-    keeps gauges from a previous executable from surviving as stale."""
+    whose gauge SET varies with the source (one gauge per family, per
+    replica, ...): dropping before re-publishing keeps gauges of a
+    previous source from surviving as stale."""
     with _lock:
         for k in [k for k in _gauges if k.startswith(prefix)]:
             del _gauges[k]
@@ -142,9 +142,8 @@ def set_table(name: str, table: dict) -> None:
 def drop_tables(prefix: str) -> None:
     """Remove every table whose name starts with `prefix` — the table
     analogue of :func:`drop_gauges`, for publishers whose table describes
-    ONE source (e.g. the executor's per-executable
-    ``perf.step_attribution``): dropping on source switch keeps a stale
-    table from being read as live for the new source."""
+    ONE source: dropping on source switch keeps a stale table from being
+    read as live for the new source."""
     with _lock:
         for k in [k for k in _tables if k.startswith(prefix)]:
             del _tables[k]

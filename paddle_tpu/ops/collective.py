@@ -30,19 +30,13 @@ def _axis(ctx, op):
     return name if name in ctx.mesh_axes else None
 
 
-def _record(ctx, kind, x, ax):
+def _record(kind, x, ax):
     """Count the collective and its per-shard payload bytes by kind.
 
     Emitters run at TRACE time, so these counters advance once per program
     compile (per collective op in the block), not once per device step —
     the right granularity for "how much ICI traffic does one step issue",
-    since the compiled step replays the same collectives every run.
-
-    When the Executor attached a ``ctx.wire_stats`` holder, the site also
-    accumulates its single-traversal ring wire estimate (payload x
-    (n-1)/n) there — the per-executable wire total behind the
-    ``perf.step_attribution`` cross-check, available even when the full
-    cost model declines the program."""
+    since the compiled step replays the same collectives every run."""
     if ax is None:
         return
     from .. import observability as _obs
@@ -58,10 +52,6 @@ def _record(ctx, kind, x, ax):
     except (AttributeError, TypeError):
         return
     _obs.add(f"collective.{kind}.bytes", nbytes)
-    if ctx is not None and getattr(ctx, "wire_stats", None) is not None:
-        n = int(ctx.axis_sizes.get(ax, 1))
-        if n > 1:
-            ctx.wire_stats["bytes"] += nbytes * (n - 1) / n
 
 
 def _register_allreduce(op_type, reducer):
@@ -69,7 +59,7 @@ def _register_allreduce(op_type, reducer):
     def emit(ctx, op, ins):
         x = ins["X"][0]
         ax = _axis(ctx, op)
-        _record(ctx, op_type, x, ax)
+        _record(op_type, x, ax)
         return {"Out": [x if ax is None else reducer(x, ax)]}
 
     return emit
@@ -95,7 +85,7 @@ def _mp_allreduce_sum(ctx, op, ins):
     while scaling the cotangent down (same trick as pipeline.py:196)."""
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "mp_allreduce_sum", x, ax)
+    _record("mp_allreduce_sum", x, ax)
     if ax is None:
         return {"Out": [x]}
     n = ctx.axis_sizes[ax]
@@ -107,7 +97,7 @@ def _mp_allreduce_sum(ctx, op, ins):
 def _c_broadcast(ctx, op, ins):
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "c_broadcast", x, ax)
+    _record("c_broadcast", x, ax)
     if ax is None:
         return {"Out": [x]}
     root = op.attr("root", 0)
@@ -120,7 +110,7 @@ def _c_broadcast(ctx, op, ins):
 def _c_allgather(ctx, op, ins):
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "c_allgather", x, ax)
+    _record("c_allgather", x, ax)
     if ax is None:
         return {"Out": [x]}
     out = lax.all_gather(x, ax)  # [nranks, ...]
@@ -133,7 +123,7 @@ def _c_allgather(ctx, op, ins):
 def _c_reducescatter(ctx, op, ins):
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "c_reducescatter", x, ax)
+    _record("c_reducescatter", x, ax)
     if ax is None:
         return {"Out": [x]}
     return {"Out": [lax.psum_scatter(x, ax, scatter_dimension=0, tiled=True)]}
@@ -143,7 +133,7 @@ def _c_reducescatter(ctx, op, ins):
 def _alltoall(ctx, op, ins):
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "alltoall", x, ax)
+    _record("alltoall", x, ax)
     if ax is None:
         return {"Out": [x]}
     n = lax.axis_size(ax)
@@ -158,7 +148,7 @@ def _alltoall(ctx, op, ins):
 def _collective_permute(ctx, op, ins):
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "collective_permute", x, ax)
+    _record("collective_permute", x, ax)
     if ax is None:
         return {"Out": [x]}
     n = lax.axis_size(ax)
@@ -179,7 +169,7 @@ def _c_allreduce_any(ctx, op, ins):
     on found_inf)."""
     x = ins["X"][0]
     ax = _axis(ctx, op)
-    _record(ctx, "c_allreduce_any", x, ax)
+    _record("c_allreduce_any", x, ax)
     if ax is None:
         return {"Out": [x]}
     return {"Out": [lax.pmax(x.astype(jnp.int32), ax).astype(x.dtype)]}
@@ -206,13 +196,11 @@ def _quant_precision(quant, dtype):
             "float64": "fp64"}.get(str(jnp.dtype(dtype)), str(dtype))
 
 
-def _record_zero(ctx, kind, op, payload_elems, dtype, ax, n):
+def _record_zero(kind, op, payload_elems, dtype, ax, n):
     """Count a sharded-update collective and its estimated ring WIRE bytes
     (payload x (n-1)/n, plus per-block scale overhead when quantized) by
     kind and precision: collective.bytes.reduce_scatter_int8 etc. Trace-
-    time granularity, like _record (once per compiled collective site);
-    the exact wire estimate also lands in ``ctx.wire_stats`` when the
-    Executor attached the per-executable attribution holder."""
+    time granularity, like _record (once per compiled collective site)."""
     if ax is None:
         return
     from .. import observability as _obs
@@ -230,8 +218,6 @@ def _record_zero(ctx, kind, op, payload_elems, dtype, ax, n):
     wire = int(payload * (n - 1) / n) if n > 1 else 0
     _obs.add(f"collective.{kind}")
     _obs.add(f"collective.bytes.{kind}_{precision}", wire)
-    if ctx is not None and getattr(ctx, "wire_stats", None) is not None:
-        ctx.wire_stats["bytes"] += wire
 
 
 def _block_quantize(x, block):
@@ -288,7 +274,7 @@ def _c_bucket_allreduce_sum(ctx, op, ins):
         return {"Out": list(xs)}
     sizes = [int(x.size) for x in xs]
     flat = jnp.concatenate([x.reshape(-1) for x in xs])
-    _record(ctx, "c_bucket_allreduce_sum", flat, ax)
+    _record("c_bucket_allreduce_sum", flat, ax)
     _record_bucket(len(xs), int(flat.size) * flat.dtype.itemsize)
     total = lax.psum(flat, ax)
     out, off = [], 0
@@ -320,7 +306,7 @@ def _zero_reduce_scatter(ctx, op, ins):
     if pad_len > flat.shape[0]:
         flat = jnp.pad(flat, (0, pad_len - flat.shape[0]))
     n = int(ctx.axis_sizes.get(ax, 1)) if ax is not None else 1
-    _record_zero(ctx, "reduce_scatter", op, pad_len, flat.dtype, ax, n)
+    _record_zero("reduce_scatter", op, pad_len, flat.dtype, ax, n)
     if ax is None:
         return {"Out": [flat]}
     if quant == "none":
@@ -386,7 +372,7 @@ def _zero_bucket_reduce_scatter(ctx, op, ins):
     total = sum(pad_lens)
     n = int(ctx.axis_sizes.get(ax, 1)) if ax is not None else 1
     dtype = flats[0].dtype if flats else jnp.float32
-    _record_zero(ctx, "bucket_reduce_scatter", op, total, dtype, ax, n)
+    _record_zero("bucket_reduce_scatter", op, total, dtype, ax, n)
     if ax is not None:
         _record_bucket(len(xs), total * jnp.dtype(dtype).itemsize)
     if ax is None:
@@ -443,7 +429,7 @@ def _zero_all_gather(ctx, op, ins):
     quant = op.attr("quant", "none") or "none"
     block = int(op.attr("quant_block", 256) or 256)
     n = int(ctx.axis_sizes.get(ax, 1)) if ax is not None else 1
-    _record_zero(ctx, "all_gather", op, pad_len, x.dtype, ax, n)
+    _record_zero("all_gather", op, pad_len, x.dtype, ax, n)
     if ax is None:
         full = x
     elif quant == "none":
@@ -500,7 +486,7 @@ def _c_comm_init_all(ctx, op, ins):
 def _barrier(ctx, op, ins):
     x = ins["X"][0] if ins.get("X") and ins["X"][0] is not None else jnp.zeros([1])
     ax = _axis(ctx, op)
-    _record(ctx, "barrier", None, ax)  # zero-payload sync: count the op, no bytes
+    _record("barrier", None, ax)  # zero-payload sync: count the op, no bytes
     if ax is None:
         return {"Out": [x]}
     return {"Out": [x + 0 * lax.psum(jnp.zeros([1], x.dtype), ax)]}

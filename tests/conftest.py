@@ -16,15 +16,36 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-# Set AFTER this process imported jax, so it reaches only the children
-# tests start (serving workers, launcher ranks, bench drivers): those call
-# core.compile_cache.enable(), and a tier-1 run must neither write a
-# persistent compile cache into the checkout nor read a stale one.
+# Set AFTER this process imported jax, so it reaches the children tests
+# start (serving workers, launcher ranks, bench drivers: those call
+# core.compile_cache.enable()) and, under xdist, the workers themselves,
+# which import jax after the controller ran this line: a tier-1 run must
+# neither write a persistent compile cache into the checkout nor read a
+# stale one. A test of the cache turns it on for itself.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import copy  # noqa: E402
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture
+def compile_cache_settings():
+    """Snapshot/restore the process-global persistent-compile-cache
+    settings and JAX's handle on the directory, for a test that places or
+    enables the cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        yield saved
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
 
 
 def pytest_configure(config):

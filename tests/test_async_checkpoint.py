@@ -12,6 +12,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -382,24 +383,45 @@ def test_compressed_payload_roundtrip_and_smaller(tmp_path, fresh_programs):
 
 
 # -- lifecycle: rollback race + drain ----------------------------------------
-def test_rollback_cancels_pending_awaits_inflight(tmp_path, fresh_programs):
+def test_rollback_cancels_pending_awaits_inflight(tmp_path, fresh_programs,
+                                                  monkeypatch):
     exe, loss = _build_model()
     fleet = _fleet()
     rng = np.random.RandomState(0)
     path = str(tmp_path / "ck")
-    os.environ[HANG_ENV] = "0.6"
     saver = fc.AsyncCheckpointer(fleet, path, executor=exe,
                                  remain_all_checkpoint=True)
+    # the in-flight publish stays at its fault seam until the rollback
+    # has cancelled the snapshot queued behind it, and that snapshot is
+    # taken only once the publish is at the seam (earlier it would
+    # coalesce with it): the order the test is about, held by the
+    # pipeline's own state and not by two sleeps
+    queued = []
+    at_seam = threading.Event()
+    real_fault_point = faults.fault_point
+
+    def wedged_until_cancel(site, *args, **kwargs):
+        if site == "checkpoint.publish":
+            at_seam.set()
+            deadline = time.monotonic() + 20
+            while not (queued and queued[0].cancelled):
+                if time.monotonic() > deadline:
+                    raise TimeoutError("the queued snapshot was never "
+                                       "cancelled")
+                time.sleep(0.002)
+        return real_fault_point(site, *args, **kwargs)
+
     try:
         _step(exe, loss, rng)
         saver.save(fc.TrainStatus(0, global_step=1)).result(30)
-        # in-flight publish is slowed; a second snapshot queues behind it
-        faults.inject("checkpoint.publish", "hang", 1.0, 0, 1)
+        monkeypatch.setattr(faults, "fault_point", wedged_until_cancel)
         _step(exe, loss, rng)
         inflight_state = _persistable_state()
         inflight = saver.save(fc.TrainStatus(1, global_step=2))
+        assert at_seam.wait(30)
         _step(exe, loss, rng)
         pending = saver.save(fc.TrainStatus(2, global_step=3))
+        queued.append(pending)
         with TrainGuard(exe, checkpointer=saver, max_bad_steps=1,
                         snapshot=False) as g:
             bad = np.full((8, 4), np.nan, np.float32)

@@ -6,8 +6,8 @@ without the chip, and nothing hides a failed leg.
   ``default_place()`` still serves tests;
 * the compile-cache helper honours ``JAX_COMPILATION_CACHE_DIR`` and
   otherwise names one fixed path inside the checkout;
-* ``bench.py`` / ``bench_serving.py`` exit non-zero when a leg raises and
-  still print the others;
+* ``bench_serving.py`` exits non-zero when a mix raises and still prints
+  the others;
 * (slow) every chip_smoke phase runs at a tiny size on the CPU — the
   rehearsal to make before spending chip time.
 """
@@ -21,7 +21,7 @@ import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:  # bench.py / bench_serving.py live at the root
+if REPO not in sys.path:  # bench_serving.py lives at the root
     sys.path.insert(0, REPO)
 
 
@@ -61,25 +61,8 @@ def test_tpu_place_never_resolves_to_a_cpu_device():
     fluid.Executor()  # the default place resolves
 
 
-@pytest.fixture
-def cache_config():
-    """Snapshot/restore the process-global compile-cache settings."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    names = ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_entry_size_bytes",
-             "jax_persistent_cache_min_compile_time_secs")
-    saved = {n: getattr(jax.config, n) for n in names}
-    try:
-        yield saved
-    finally:
-        for n, v in saved.items():
-            jax.config.update(n, v)
-        cc.reset_cache()
-
-
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
-                                              cache_config):
+                                              compile_cache_settings):
     from paddle_tpu.core import compile_cache
 
     # the default: one fixed git-ignored path inside the checkout
@@ -104,41 +87,6 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
     assert compile_cache.enable(str(tmp_path / "other")) == outside
     assert jax.config.jax_compilation_cache_dir == user  # untouched
     assert not os.path.exists(tmp_path / "other")
-
-
-_BENCH_LEGS = (
-    "bench_bert", "bench_resnet", "bench_yolov3", "bench_gpt_longctx",
-    "bench_deepfm", "bench_deepfm_fused", "bench_mask_rcnn",
-    "bench_dp_sharding", "bench_dp_overlap",
-)
-
-
-def test_bench_main_exits_nonzero_when_a_leg_raises(monkeypatch, capsys):
-    import bench
-    from paddle_tpu.core import compile_cache
-
-    monkeypatch.setattr(compile_cache, "enable", lambda *a: "")
-
-    def leg(name):
-        return lambda *a, **k: {"metric": name, "value": 1.0, "unit": "x/s"}
-
-    for name in _BENCH_LEGS:
-        monkeypatch.setattr(bench, name, leg(name))
-    assert bench.main() == 0
-    capsys.readouterr()
-
-    def boom(*a, **k):
-        raise RuntimeError("leg blew up")
-
-    monkeypatch.setattr(bench, "bench_yolov3", boom)
-    assert bench.main() == 1
-    captured = capsys.readouterr()
-    compact = _last_json(captured.out)
-    assert "leg blew up" in compact["legs"]["yolov3"]["error"]
-    # every other leg was still run and printed
-    assert compact["legs"]["bert"]["value"] == 1.0
-    assert compact["legs"]["mask_rcnn"]["value"] == 1.0
-    assert "yolov3" in captured.err
 
 
 def test_bench_serving_main_exits_nonzero_when_a_mix_raises(monkeypatch,

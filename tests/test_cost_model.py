@@ -514,62 +514,17 @@ def test_zoo_estimate_vs_xla(name):
 
 
 # ---------------------------------------------------------------------------
-# live perf.* telemetry
+# the tables kind, and the two "XLA returned nothing" counters
 # ---------------------------------------------------------------------------
 
 
 class TestPerfTelemetry:
-    def test_executor_publishes_perf_metrics(self, fresh):
-        main, startup, scope = fresh
-        loss = _fc_train(main, startup)
-        exe = fluid.Executor()
-        exe.run(startup, scope=scope)
-        observability.reset()  # drop the startup program's own estimate
-        feed = {"x": np.ones((8, 16), "float32")}
-        for _ in range(3):
-            exe.run(main, feed=feed, fetch_list=[loss.name], scope=scope)
-        snap = observability.snapshot()
-        est = main.estimate(feed_shapes={"x": (8, 16)})
-        # counters tick every run, compile-carrying or not
-        assert snap["counters"]["perf.step_flops"] == 3 * int(
-            est.total_flops
-        )
-        assert snap["counters"]["perf.step_bytes"] == 3 * int(
-            est.total_bytes
-        )
-        gauges = snap["gauges"]
-        # the MFU gauge is exactly est-flops over the steady-state mean
-        # step, against the configured peak
-        assert gauges["perf.mfu"] == pytest.approx(
-            est.total_flops / gauges["perf.step_seconds"] / est.peak_flops
-        )
-        for fam in est.by_family():
-            assert f"perf.family_time.{fam}" in gauges
-        table = snap["tables"]["perf.cost_table"]
-        assert table["total_flops"] == pytest.approx(est.total_flops)
-        assert table["ops"]
-
-    def test_mfu_gauge_excludes_compile_runs(self, fresh):
-        main, startup, scope = fresh
-        loss = _fc_train(main, startup)
-        exe = fluid.Executor()
-        exe.run(startup, scope=scope)
-        feed = {"x": np.ones((8, 16), "float32")}
-        observability.reset()
-        exe.run(main, feed=feed, fetch_list=[loss.name], scope=scope)
-        snap = observability.snapshot()
-        # first run carries the compile: counters tick, no MFU yet
-        assert "perf.step_flops" in snap["counters"]
-        assert "perf.mfu" not in snap["gauges"]
-        exe.run(main, feed=feed, fetch_list=[loss.name], scope=scope)
-        assert "perf.mfu" in observability.snapshot()["gauges"]
-
     def test_tables_reset_and_snapshot_backcompat(self):
         observability.reset()
         assert "tables" not in observability.snapshot()  # nothing published
-        observability.set_table("perf.cost_table", {"total_flops": 1.0})
+        observability.set_table("some.table", {"total_flops": 1.0})
         assert observability.get_tables() == {
-            "perf.cost_table": {"total_flops": 1.0}
+            "some.table": {"total_flops": 1.0}
         }
         observability.reset()
         assert observability.get_tables() == {}
@@ -739,30 +694,3 @@ class TestTimelineMerge:
         out.write_text(json.dumps(trace))
         reloaded = json.loads(out.read_text())
         assert {e.get("pid") for e in reloaded["traceEvents"]} == {0, 1}
-
-
-# ---------------------------------------------------------------------------
-# stats_report rendering of the published cost table
-# ---------------------------------------------------------------------------
-
-
-def test_stats_report_top_ops_and_require(tmp_path, fresh):
-    main, startup, scope = fresh
-    loss = _fc_train(main, startup)
-    exe = fluid.Executor()
-    exe.run(startup, scope=scope)
-    feed = {"x": np.ones((8, 16), "float32")}
-    for _ in range(2):
-        exe.run(main, feed=feed, fetch_list=[loss.name], scope=scope)
-    snap_path = tmp_path / "snap.json"
-    observability.dump(str(snap_path))
-    stats_report = _load_tool("stats_report")
-    out = stats_report.render(
-        json.load(open(snap_path)), top_ops=3
-    )
-    assert "perf.cost_table" in out
-    assert "top 3 op sites" in out
-    # --require perf. is satisfied by the table name alone
-    assert stats_report.main([str(snap_path), "--require", "perf."]) in (
-        0, None,
-    )

@@ -78,7 +78,24 @@ def test_batch_bucketing_pads_and_slices(tmp_path):
         pred.run([PaddleTensor(rng.randn(32, 8).astype(np.float32), "x")])
 
 
-def test_optim_cache_dir_persists_compiles(tmp_path):
+@pytest.fixture
+def compile_cache_on(monkeypatch, compile_cache_settings):
+    """The persistent compile cache on for one test: a tier-1 worker
+    starts with it off (the export in tests/conftest.py reaches xdist's
+    workers too), and the predictor's note of the directory is
+    process-global like JAX's own settings."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu import inference
+
+    monkeypatch.setattr(inference, "_applied_optim_cache_dir", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def test_optim_cache_dir_persists_compiles(tmp_path, compile_cache_on):
     model_dir, feed, ref = _save_model(tmp_path)
     cache = tmp_path / "xla_cache"
     cfg = AnalysisConfig(model_dir)

@@ -330,11 +330,16 @@ def test_staleness_grows_between_applies_and_snaps_down(
     assert sub.staleness_s(now=t0 + 5.0) == pytest.approx(s0 + 5.0)
     assert sub.staleness_s(now=t0 + 9.0) > sub.staleness_s(now=t0 + 5.0)
     assert "serving.model_staleness_seconds" in obs.get_gauges()
-    # ...and snaps down when a fresher bundle applies
+    # ...and snaps down when a fresher bundle applies: at one and the
+    # same instant it reads less than the replaced bundle would have
+    # (no margin between two wall-clock intervals)
+    replaced = sub.commit_time
     trainer.step()
     p.publish(step=2)
     sub.poll()
-    assert sub.staleness_s(now=time.time() + 5.0) < s0 + 5.0
+    assert sub.commit_time > replaced
+    now = time.time() + 5.0
+    assert sub.staleness_s(now=now) < now - replaced
 
 
 def test_apply_stamps_heartbeat_with_model_version(trainer, tmp_path):

@@ -489,20 +489,6 @@ def test_estimate_carries_the_memory_plan(fresh):
     assert d["memory"]["watermark"] is not None
 
 
-def test_executor_publishes_peak_gauges(fresh):
-    from paddle_tpu import observability as obs
-
-    main, _, _ = fresh
-    x = fluid.data("x", [4, 8])
-    y = layers.relu(x)
-    exe = fluid.Executor()
-    exe.run(main, feed={"x": np.ones((4, 8), "float32")},
-            fetch_list=[y])
-    snap = obs.snapshot()
-    assert snap["gauges"].get("perf.peak_bytes_est", 0) > 0
-    assert "perf.resident_bytes_est" in snap["gauges"]
-
-
 def _frozen_classifier(main, startup, scope):
     from paddle_tpu.serving import freeze_program
 

@@ -518,16 +518,22 @@ def test_tdm_and_instag(run, rng):
 
 
 def test_coverage_target_reached():
-    """The checker itself is the acceptance test for VERDICT r2 item 1."""
+    """The checker itself is the acceptance test for VERDICT r2 item 1.
+    Without the reference tree there is nothing to count: it skips, with
+    the checker's own message."""
     import subprocess
     import sys
     import os
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
+    proc = subprocess.run(
         [sys.executable, os.path.join(repo, "tools", "check_op_surface.py")],
         capture_output=True, text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    ).stdout
+    )
+    if proc.returncode == 3:  # check_op_surface.NO_REFERENCE
+        pytest.skip(proc.stderr.strip().splitlines()[-1])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = proc.stdout
     import re
 
     # r4 headline splits real emitters from documented subsumptions; the

@@ -440,7 +440,10 @@ def test_program_estimate_overlap_aware():
     assert est_o.scheduled_latency is not None
     assert est_o.step_latency <= est_o.total_latency
     assert 0.0 < est_o.wire_exposed_latency <= est_o.wire_latency
-    assert 0.0 <= est_o.overlap_ratio <= 1.0
+    # the schedule hides some wire, and exposes less of it than the
+    # serialized build does
+    assert 0.0 < est_o.overlap_ratio <= 1.0
+    assert est_o.wire_exposed_latency < est_s.wire_exposed_latency
     d = est_o.to_dict()
     for key in ("scheduled_latency", "wire_latency",
                 "wire_exposed_latency", "overlap_ratio"):
@@ -448,26 +451,14 @@ def test_program_estimate_overlap_aware():
     assert any("overlap schedule" in a for a in d["assumptions"])
 
 
-def test_executor_publishes_overlap_attribution():
-    """The live attribution split on an overlapped dp=8 run: wait
-    fractions sum to ~1, the est wire term is nonzero, and the
-    collective.overlap_ratio gauge + est_wire_hidden_seconds land."""
+def test_overlapped_zero_dp8_bitwise_and_bucket_counters():
+    """At dp=8 too the overlapped schedule reproduces the serialized
+    ZeRO trajectory BITWISE, and the trace leaves the bucket counters."""
+    l0, _ = _train("sharded", nranks=8, steps=3)
     observability.reset()
-    _train("sharded", nranks=8, bucket=1 << 20, prefetch=True, steps=3,
-           return_numpy=True)
-    snap = observability.snapshot()
-    gauges = snap["gauges"]
-    attr = snap["tables"].get("perf.step_attribution")
-    assert attr is not None
-    assert attr["est_wire_seconds"] > 0
-    assert attr["est_wire_total_seconds"] >= attr["est_wire_seconds"]
-    assert attr["est_wire_hidden_seconds"] >= 0
-    assert 0.0 <= attr["est_overlap_ratio"] <= 1.0
-    assert "collective.overlap_ratio" in gauges
-    total = (gauges["perf.wait_fraction.collective"]
-             + gauges["perf.wait_fraction.host"]
-             + gauges["perf.wait_fraction.compute"])
-    assert total == pytest.approx(1.0, abs=1e-6)
-    counters = snap["counters"]
+    l1, _ = _train("sharded", nranks=8, bucket=1 << 20, prefetch=True,
+                   steps=3)
+    np.testing.assert_array_equal(l0, l1)
+    counters = observability.snapshot()["counters"]
     assert counters.get("collective.buckets", 0) > 0
     assert counters.get("collective.bucket_bytes", 0) > 0

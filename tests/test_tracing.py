@@ -1,9 +1,9 @@
 """End-to-end causal tracing (ISSUE 13): TraceContext propagation across
 threads (serving scheduler, AsyncCheckpointer publisher, embedding
-Prefetcher worker) and ranks (heartbeat stamps), per-step
-compute-vs-wait attribution, the live watcher's structured findings, and
-the trace_report reconstruction tooling — plus the unified
-PADDLE_TPU_MONITOR kill-switch across metrics, spans AND traces."""
+Prefetcher worker) and ranks (heartbeat stamps), the live watcher's
+structured findings, and the trace_report reconstruction tooling — plus
+the unified PADDLE_TPU_MONITOR kill-switch across metrics, spans AND
+traces."""
 
 import importlib.util
 import os
@@ -467,102 +467,6 @@ def test_heartbeat_stamps_active_trace(tmp_path):
     # outside any trace the stamp is absent (no stale ids)
     hb.beat(step=8)
     assert "trace_id" not in read_beat(hb.path)
-
-
-# -- per-step attribution ----------------------------------------------------
-
-
-def test_step_attribution_on_dp_mesh(fresh_programs):
-    from paddle_tpu.parallel import make_mesh, shard_program
-
-    main, startup, scope = fresh_programs
-    fluid.data("x", [8, 4], "float32")
-    blk = main.global_block
-    blk.create_var(name="out", shape=(8, 4), dtype="float32")
-    blk.append_op("c_allreduce_sum", inputs={"X": ["x"]},
-                  outputs={"Out": ["out"]}, attrs={"axis_name": "dp"})
-    shard_program(main, make_mesh({"dp": 8}),
-                  {"x": ("dp",), "out": ("dp",)})
-    exe = fluid.Executor()
-    data = np.arange(32, dtype="float32").reshape(8, 4)
-    for _ in range(3):
-        exe.run(main, feed={"x": data}, fetch_list=["out"], scope=scope)
-    snap = obs.snapshot()
-    g = snap["gauges"]
-    fracs = {k: g[k] for k in ("perf.wait_fraction.collective",
-                               "perf.wait_fraction.host",
-                               "perf.wait_fraction.compute")}
-    assert all(0.0 <= v <= 1.0 for v in fracs.values()), fracs
-    assert sum(fracs.values()) == pytest.approx(1.0, abs=1e-6)
-    table = snap["tables"]["perf.step_attribution"]
-    # collective-only program: the cost model attributes ALL device
-    # roofline to the wire, and the emitters recorded wire bytes
-    assert table["est_wait_fraction"] == pytest.approx(1.0)
-    assert table["est_wire_seconds"] > 0
-    assert table["collective_wait_seconds"] > 0
-    assert table["traced_wire_bytes"] > 0
-    assert snap["histograms"]["perf.collective_wait_seconds"]["count"] >= 1
-    assert snap["histograms"]["perf.host_stall_seconds"]["count"] >= 1
-
-
-def test_attribution_without_collectives_reports_zero_wait(fresh_programs):
-    main, startup, scope = fresh_programs
-    x = fluid.data("x", [4, 4])
-    y = layers.fc(x, 4)
-    exe = fluid.Executor()
-    exe.run(startup, scope=scope)
-    for _ in range(3):
-        exe.run(main, feed={"x": np.ones((4, 4), "float32")},
-                fetch_list=[y], scope=scope)
-    snap = obs.snapshot()
-    assert snap["gauges"]["perf.wait_fraction.collective"] == 0.0
-    table = snap["tables"]["perf.step_attribution"]
-    assert table["est_wire_seconds"] == 0.0
-    assert table["compute_seconds"] > 0
-
-
-def test_attribution_table_dropped_on_executable_switch(fresh_programs):
-    """A snapshot right after an executable switch must not pair the OLD
-    executable's attribution split with the new program (same staleness
-    contract as the perf.* gauges)."""
-    main, startup, scope = fresh_programs
-    x = fluid.data("x", [4, 4])
-    y = layers.fc(x, 4)
-    exe = fluid.Executor()
-    exe.run(startup, scope=scope)
-    for _ in range(3):
-        exe.run(main, feed={"x": np.ones((4, 4), "float32")},
-                fetch_list=[y], scope=scope)
-    assert "perf.step_attribution" in obs.snapshot()["tables"]
-    other = fluid.Program()
-    with fluid.program_guard(other, fluid.Program()):
-        z = fluid.data("z", [2, 2])
-        w = layers.scale(z, scale=2.0)
-    # compile-carrying run of ANOTHER executable: gauges AND table drop
-    exe.run(other, feed={"z": np.ones((2, 2), "float32")},
-            fetch_list=[w], scope=scope)
-    snap = obs.snapshot()
-    assert "perf.step_attribution" not in snap.get("tables", {})
-    assert "perf.wait_fraction.collective" not in snap["gauges"]
-
-
-def test_attribution_skipped_on_pipelined_no_numpy_path(fresh_programs):
-    """return_numpy=False callers (bench.py's pipelined timing loops)
-    rely on async dispatch — those runs must neither block on the device
-    nor publish an attribution sample."""
-    main, startup, scope = fresh_programs
-    x = fluid.data("x", [4, 4])
-    y = layers.fc(x, 4)
-    exe = fluid.Executor()
-    exe.run(startup, scope=scope)
-    for _ in range(3):
-        exe.run(main, feed={"x": np.ones((4, 4), "float32")},
-                fetch_list=[y], scope=scope, return_numpy=False)
-    snap = obs.snapshot()
-    assert "perf.step_attribution" not in snap.get("tables", {})
-    assert "perf.wait_fraction.collective" not in snap["gauges"]
-    # the rest of the perf surface still publishes
-    assert "perf.mfu" in snap["gauges"]
 
 
 # -- live watcher ------------------------------------------------------------

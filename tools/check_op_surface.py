@@ -177,6 +177,14 @@ SUBSUMED_DIRS = {
 }
 
 
+#: exit code when there is no reference tree to compare against
+NO_REFERENCE = 3
+
+
+def _operators_dir(ref_root):
+    return os.path.join(ref_root, "paddle", "fluid", "operators")
+
+
 def reference_ops(ref_root):
     """op name -> first file registering it, from REGISTER_* macros."""
     pat = re.compile(
@@ -184,7 +192,7 @@ def reference_ops(ref_root):
         r"\(\s*([a-z0-9_]+)"
     )
     ops = {}
-    base = os.path.join(ref_root, "paddle", "fluid", "operators")
+    base = _operators_dir(ref_root)
     for dirpath, _, files in os.walk(base):
         for fn in files:
             if not fn.endswith((".cc", ".cu")):
@@ -205,6 +213,13 @@ def main():
     ap.add_argument("--missing", action="store_true",
                     help="list every uncovered op")
     args = ap.parse_args()
+
+    base = _operators_dir(args.reference)
+    if not os.path.isdir(base):
+        print(f"check_op_surface: no reference operator library at {base}; "
+              "nothing to compare the registry against "
+              "(pass --reference)", file=sys.stderr)
+        return NO_REFERENCE
 
     import paddle_tpu  # noqa: F401  (registers all emitters)
     from paddle_tpu.framework.registry import registered_ops

@@ -311,107 +311,6 @@ def _print_merge_stats(stats):
 
 
 # ---------------------------------------------------------------------------
-# per-step attribution: estimate vs measured compute / wait split
-# ---------------------------------------------------------------------------
-
-
-def report_attribution(snapshot_path, require_wait=False):
-    """Render the executor's ``perf.step_attribution`` table (measured
-    compute / collective-wait / host-stall split vs the cost model's
-    wire-time estimate) from an observability snapshot. This is the
-    serialized-wire denominator ROADMAP item 4 measures overlap against:
-    ``wait_fraction_collective`` of a serialized step is the share an
-    overlapped schedule can hide.
-
-    ``require_wait=True`` additionally fails unless the leg actually
-    exercised the wire (est_wire_seconds > 0) — the dp-sharded CI leg's
-    guard that the split did not silently degrade to compute-only."""
-    with open(snapshot_path) as f:
-        snap = json.load(f)
-    table = (snap.get("tables") or {}).get("perf.step_attribution")
-    if not table:
-        print(
-            "no perf.step_attribution table in the snapshot — run at "
-            "least 2 steps of one executable (the first carries the "
-            "compile) with monitoring on",
-            file=sys.stderr,
-        )
-        return 2
-    ms = 1e3
-    print("==== per-step attribution (steady-state window mean) ====")
-    print(
-        f"  measured step      {table['step_seconds'] * ms:9.3f} ms over "
-        f"{table.get('window_steps', 0)} step(s)"
-    )
-    denom = table["step_seconds"] or 1.0
-    for key, label in (
-        ("compute_seconds", "compute"),
-        ("collective_wait_seconds", "collective wait"),
-        ("host_stall_seconds", "host stall"),
-    ):
-        v = table.get(key, 0.0)
-        print(f"  {label:<18} {v * ms:9.3f} ms  ({v / denom:6.1%})")
-    est_wire = table.get("est_wire_seconds", 0.0)
-    est_comp = table.get("est_compute_seconds", 0.0)
-    print(
-        f"  cost-model roofline: compute {est_comp * ms:.3f} ms, wire "
-        f"{est_wire * ms:.3f} ms -> est wait fraction "
-        f"{table.get('est_wait_fraction', 0.0):.1%} "
-        f"(measured {table.get('wait_fraction_collective', 0.0):.1%} of "
-        "the step)"
-    )
-    if table.get("est_wire_total_seconds"):
-        # overlap-aware split (PR 14): est_wire_seconds above is the
-        # EXPOSED wire; the hidden share rides behind compute
-        hidden = table.get("est_wire_hidden_seconds", 0.0)
-        print(
-            f"  overlap schedule: serialized wire "
-            f"{table['est_wire_total_seconds'] * ms:.3f} ms, hidden "
-            f"{hidden * ms:.3f} ms "
-            f"({table.get('est_overlap_ratio', 0.0):.0%} of the wire "
-            "behind the math)"
-        )
-    if table.get("traced_wire_bytes"):
-        print(
-            f"  traced collective sites move ~"
-            f"{table['traced_wire_bytes'] / 1e6:.3f} MB wire/step "
-            "(emitter-side cross-check)"
-        )
-    gauges = snap.get("gauges", {})
-    waits = {k: v for k, v in gauges.items()
-             if k.startswith("perf.wait_fraction.")}
-    if waits:
-        print("  live gauges: " + "  ".join(
-            f"{k.split('.')[-1]}={v:.1%}" for k, v in sorted(waits.items())
-        ))
-    bad = []
-    for key in ("wait_fraction_collective", "wait_fraction_host",
-                "est_wait_fraction"):
-        v = table.get(key)
-        if v is None or not (0.0 <= v <= 1.0):
-            bad.append(f"{key}={v!r}")
-    # "the leg touched the wire" means the SERIALIZED wire roofline is
-    # nonzero — a perfectly overlapped schedule may legitimately expose
-    # zero wire (est_wire_seconds == 0 with overlap_ratio == 1), and that
-    # must not read as a dead leg. Older snapshots without the overlap
-    # fields fall back to the exposed term (there the two are equal).
-    est_wire_total = table.get("est_wire_total_seconds", est_wire)
-    if require_wait and est_wire_total <= 0:
-        bad.append("est_wire_total_seconds=0 (leg never touched the wire)")
-    if require_wait and est_wire > 0 \
-            and table.get("collective_wait_seconds", 0) <= 0:
-        # measured wait must exist whenever the estimate says wire is
-        # still exposed; a fully hidden wire (est_wire == 0) makes a
-        # zero measured wait the CORRECT answer, not a degraded split
-        bad.append("collective_wait_seconds=0")
-    if bad:
-        print(f"attribution check FAILED: {bad}", file=sys.stderr)
-        return 2
-    print(json.dumps({"attribution": table}))
-    return 0
-
-
-# ---------------------------------------------------------------------------
 
 
 def main(argv=None):
@@ -442,23 +341,12 @@ def main(argv=None):
                          "XLA's scheduling freedom)")
     ap.add_argument("--merge", nargs="+", metavar="TRACE.json",
                     help="merge per-rank chrome span exports")
-    ap.add_argument("--attribution", metavar="SNAPSHOT.json",
-                    help="render the perf.step_attribution table "
-                         "(measured compute/wait/host split vs the cost "
-                         "model's wire estimate) from a snapshot")
-    ap.add_argument("--require-wait", action="store_true",
-                    help="with --attribution: fail unless the leg "
-                         "exercised the wire (est_wire_seconds > 0)")
     ap.add_argument("--heartbeat-dir", metavar="DIR",
                     help="fold hb_rank* beats into the merged trace")
     ap.add_argument("-o", "--out", metavar="PATH",
                     help="write the merged trace JSON here")
     args = ap.parse_args(argv)
 
-    if args.attribution:
-        return report_attribution(
-            args.attribution, require_wait=args.require_wait
-        )
     if args.merge:
         trace, stats = merge_traces(args.merge, args.heartbeat_dir)
         _print_merge_stats(stats)

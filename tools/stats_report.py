@@ -3,15 +3,12 @@
 
 Usage:
     python tools/stats_report.py SNAPSHOT.json [--require PREFIX ...]
-    python tools/stats_report.py SNAPSHOT.json --top-ops 15
 
 SNAPSHOT.json is the file written by `paddle_tpu.observability.dump(path)`
 (counters / gauges / histograms / span_count / tables). `--require PREFIX`
 (repeatable) exits nonzero unless at least one metric name starts with
 PREFIX — the CI guard that instrumentation did not silently go dead.
-`--top-ops N` renders the top-N op sites of the "perf.cost_table" table
-the executor publishes (per-op FLOPs/bytes/roofline from
-`Program.estimate`); the default dump shows the table's totals.
+Per-op cost tables are the offline estimator's: `tools/perf_report.py`.
 """
 
 from __future__ import annotations
@@ -31,35 +28,7 @@ def _sparkline(hist):
     return "".join(_BARS[round(c / peak * (len(_BARS) - 1))] for c in per)
 
 
-def _render_cost_table(table, top_ops, lines):
-    lines.append(
-        f"-- perf.cost_table: {table.get('total_flops', 0) / 1e9:.3f} "
-        f"GFLOP/step, {table.get('total_bytes', 0) / 1e6:.3f} MB moved, "
-        f"roofline >= {table.get('total_latency', 0) * 1e3:.3f} ms --"
-    )
-    fams = sorted(
-        (table.get("by_family") or {}).items(),
-        key=lambda kv: -kv[1].get("latency", 0),
-    )
-    for fam, agg in fams:
-        lines.append(
-            f"  {fam:<14} {agg.get('flops', 0) / 1e9:>10.3f} GFLOP "
-            f"{agg.get('bytes', 0) / 1e6:>10.3f} MB  ({agg.get('ops', 0)} "
-            "ops)"
-        )
-    if top_ops:
-        lines.append(f"-- top {top_ops} op sites by roofline latency --")
-        for e in (table.get("ops") or [])[:top_ops]:
-            lines.append(
-                f"  {e.get('op_type', '?'):<28} "
-                f"{e.get('flops', 0) / 1e9:>10.3f} GFLOP "
-                f"{e.get('bytes', 0) / 1e6:>9.3f} MB "
-                f"{e.get('latency', 0) * 1e6:>9.1f} us"
-                f"  b{e.get('block_idx', 0)}#{e.get('op_index', 0)}"
-            )
-
-
-def render(snap, top_ops=0):
+def render(snap):
     lines = []
     counters = snap.get("counters", {})
     gauges = snap.get("gauges", {})
@@ -107,25 +76,16 @@ def render(snap, top_ops=0):
             lines.append(
                 f"  {name:<{width}}  {payload[name] / 1e6:>10.3f} MB"
             )
-    # collective overlap digest (PR 14): bucketed grad collectives + the
-    # cost model's hidden-wire estimate — the numbers bench_overlap gates
     n_buckets = counters.get("collective.buckets", 0)
-    overlap_ratio = gauges.get("collective.overlap_ratio")
-    if n_buckets or overlap_ratio is not None:
-        lines.append("-- collective overlap --")
-        if n_buckets:
-            members = counters.get("collective.bucket_members", 0)
-            lines.append(
-                f"  {n_buckets} bucket(s), "
-                f"{counters.get('collective.bucket_bytes', 0) / 1e6:.3f} "
-                f"MB bucketed payload"
-                + (f", {members} member grads" if members else "")
-            )
-        if overlap_ratio is not None:
-            lines.append(
-                f"  est overlap ratio {overlap_ratio:.1%} of wire "
-                "seconds hidden behind compute"
-            )
+    if n_buckets:
+        members = counters.get("collective.bucket_members", 0)
+        lines.append("-- collective buckets --")
+        lines.append(
+            f"  {n_buckets} bucket(s), "
+            f"{counters.get('collective.bucket_bytes', 0) / 1e6:.3f} "
+            f"MB bucketed payload"
+            + (f", {members} member grads" if members else "")
+        )
     # checkpoint pipeline digest: the stage split (snapshot = the step
     # loop's only cost; publish = background), bandwidth, and the tiered
     # save mix — the numbers the async-checkpoint bench gates on
@@ -163,31 +123,6 @@ def render(snap, top_ops=0):
             + (f" delta_bytes_dropped={dropped / 1e6:.2f}MB"
                if dropped else "")
         )
-    if "perf.cost_table" in tables:
-        _render_cost_table(tables["perf.cost_table"], top_ops, lines)
-    # per-step attribution digest: the compute/collective-wait/host-stall
-    # split the executor publishes (the serialized-wire denominator)
-    attr = tables.get("perf.step_attribution")
-    if attr:
-        lines.append("-- step attribution --")
-        lines.append(
-            f"  step {attr.get('step_seconds', 0) * 1e3:.3f} ms = compute "
-            f"{attr.get('compute_seconds', 0) * 1e3:.3f} + collective-wait "
-            f"{attr.get('collective_wait_seconds', 0) * 1e3:.3f} + "
-            f"host-stall {attr.get('host_stall_seconds', 0) * 1e3:.3f} ms"
-        )
-        lines.append(
-            f"  wait fraction {attr.get('wait_fraction_collective', 0):.1%}"
-            f" (cost-model wire estimate "
-            f"{attr.get('est_wait_fraction', 0):.1%} of roofline)"
-        )
-        if attr.get("est_wire_hidden_seconds"):
-            lines.append(
-                f"  overlap: {attr['est_wire_hidden_seconds'] * 1e3:.3f} "
-                f"ms wire hidden "
-                f"({attr.get('est_overlap_ratio', 0):.0%} of the "
-                "serialized wire)"
-            )
     # serving fault-domain digest (r15): goodput vs shed/expired, the
     # brownout rung, and per-replica breaker states — the overload/
     # failover picture at a glance
@@ -286,14 +221,10 @@ def main(argv=None):
         "--require", action="append", default=[], metavar="PREFIX",
         help="fail unless some metric name starts with PREFIX (repeatable)",
     )
-    ap.add_argument(
-        "--top-ops", type=int, default=0, metavar="N",
-        help="show the top-N op sites of the published perf.cost_table",
-    )
     args = ap.parse_args(argv)
     with open(args.snapshot) as f:
         snap = json.load(f)
-    print(render(snap, top_ops=args.top_ops))
+    print(render(snap))
     names = (
         list(snap.get("counters", {}))
         + list(snap.get("gauges", {}))
