@@ -9,23 +9,38 @@ Tile sizes adapt to S: the largest of 512/256/128 that divides S, so any
 S % 128 == 0 works (the r3 kernel hard-required S % 512 == 0 — VERDICT r3
 weak item 3).
 
-Forward: grid (B, groups, S//BQ, S//BK), kv innermost. Scratch carries the
-online-softmax state (running max m, running sum l, unnormalized
-accumulator acc) across kv steps; the output block (indexed by q) is
-written on the LAST kv step. The row logsumexp L = m + log(l) is saved for
-the backward. Under causal masking, tiles strictly above the diagonal are
-SKIPPED (pl.when over the whole head loop) — at S >> BQ that is ~half the
-grid's MXU/VPU work; the block DMAs still run (static grid), which is why
-the win tops out near 2x.
+The tiles are walked INSIDE the kernel, not by the grid (a grid axis over
+tiles spends a step and its block DMAs on every dead tile of a causal
+call, whatever `pl.when` skips of the arithmetic). A grid step owns one
+block of one axis and keeps the other axis's operands of the lane group
+resident in VMEM as one super-block of R rows: R = S wherever they
+fit (`vmem.resident_rows`, against the `vmem_limit_bytes` the calls state;
+S=4096 bf16: 2 MB of K and V, 6 MB of Q, dO, lse, delta), else the last grid
+axis walks S // R super-blocks, and the block index of a super-block wholly
+on the dead side is held at the last live one, so it is not fetched. Under
+causal masking the kernel runs `fori_loop` over exactly the blocks strictly
+below the diagonal, with no mask code in the loop body, and then the
+diagonal block once under the static triangle col <= row (BQ == BK): the
+trip counts carry the causality (`_walk`), nothing decides per tile. The
+gauges `kernels.flash_tiled.tiles_visited` / `.tiles_computed` read 36 / 36
+at eight blocks (a grid over tiles would visit 64); without causality
+64 / 64.
+
+Forward: grid (B, groups, S//BQ, S//R). Scratch carries the online-softmax
+state (running max m, running sum l, unnormalized accumulator acc) over the
+kv blocks; the output block (indexed by q) is written on the last
+super-block. The row logsumexp L = m + log(l) is saved for the backward.
 
 Backward: flash attention's standard two-kernel split (dq needs a sum over
-kv, dk/dv over q — one grid cannot accumulate both):
-  * dkv kernel: grid (..., KB, QB), q innermost; p recomputed per tile
-    from the saved L (no renormalization pass), dk/dv accumulate in
-    scratch, written on the last q step. Per-q-block partial dbias rows
-    emit to a [QB, S] buffer summed outside.
-  * dq kernel: grid (..., QB, KB), kv innermost; dq accumulates in
-    scratch. Needs delta = rowsum(do * o), precomputed outside (cheap
+kv, dk/dv over q: one walk cannot accumulate both):
+  * dkv kernel: grid (..., S//BK, S//R), Q, dO, lse, delta resident; the
+    diagonal q block first, then the q blocks after it in ascending
+    order; p recomputed per tile from the saved L
+    (no renormalization pass), dk/dv accumulate in scratch, the bias
+    gradient of the lane group in its [1, BK] output block, summed over
+    the lane groups outside.
+  * dq kernel: grid (..., S//BQ, S//R), K and V resident; dq accumulates
+    in scratch. Needs delta = rowsum(do * o), precomputed outside (cheap
     elementwise XLA pass, the FlashAttention-2 formulation).
 
 Dropout regenerates per-tile masks from a seed mixed with
@@ -42,6 +57,7 @@ is whole-row too; the tiled form is what long-context needs
 from __future__ import annotations
 
 import functools
+import types
 
 import numpy as np
 
@@ -89,22 +105,25 @@ def _seed_tile(seed_ref, head, qb, kb):
 # all three kernels (fwd, dkv, dq) draw the identical (BQ/BK-shaped) tile
 # mask after the identical per-tile reseed, so masks agree regardless of
 # loop order
+from . import vmem as _vmem
 from .prng_mask import keep_mask as _keep
 
 
-def _tile_scores(q, k, bias_tile, scale, causal, qb, kb, BQ, BK):
-    """[BQ, BK] fp32 scores for one head; causal mask in global coords.
+def _tile_scores(q, k, bias_tile, scale, diag):
+    """[BQ, BK] fp32 scores for one head. `diag` is a Python bool: the
+    block on the diagonal gets the static triangle col <= row (BQ == BK,
+    so no block offset enters it); every other block the loops reach lies
+    wholly below the diagonal and carries no mask code at all.
 
-    The mask applies unconditionally on live tiles: gating it on
-    diagonal-straddling tiles via lax.cond was MEASURED SLOWER on chip
-    (S=8192 GPT leg 44.9k -> 34.4k tok/s — the in-kernel cond defeats
-    Mosaic's cross-iteration pipelining), so three flat VPU passes beat
-    one branch."""
+    Deciding per tile at run time whether to mask (lax.cond around the
+    mask) was MEASURED SLOWER on chip (S=8192 GPT leg 44.9k -> 34.4k
+    tok/s: the in-kernel cond defeats Mosaic's pipelining). Nothing
+    decides here: the loops' trip counts carry the causality."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     s = s + bias_tile
-    if causal:
-        row = qb * BQ + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = kb * BK + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if diag:
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(col <= row, s, NEG_INF)
     return s
 
@@ -119,6 +138,68 @@ def _dropout_tile(e, rate, is_test, upscale, seed_ref, head, qb, kb):
     return jnp.where(keep, e / (1.0 - rate) if upscale else e, 0.0)
 
 
+def _walk(x, first, nbr, causal, before):
+    """Which blocks of the super-block [first, first + nbr) of the looped
+    axis are live against block `x` of the grid's axis, as local indices
+    (lo, hi, d): the kernel loops over [lo, hi) unmasked and computes
+    block d masked where 0 <= d < nbr (d is None without causality).
+    `before`: the unmasked blocks precede the diagonal (kv blocks under a
+    q block: fwd, dq) or follow it (q blocks over a kv block: dkv). A
+    super-block wholly on the dead side gives an empty range and a d
+    outside it, so its grid step computes nothing. Works on Python ints
+    too: `_tile_counts` reads the gauges off the same arithmetic."""
+    if not causal:
+        return 0, nbr, None
+
+    def clip(v):
+        return min(max(v, 0), nbr) if isinstance(v, int) else jnp.clip(
+            v, 0, nbr)
+
+    d = x - first
+    if before:
+        return 0, clip(d), d
+    return clip(d + 1), nbr, d
+
+
+def _tile_counts(n, nbr, causal):
+    """(visited, computed) tiles of one (batch, lane group) of the forward,
+    `n` q blocks against super-blocks of `nbr` kv blocks: a loop trip or a
+    diagonal part is one tile visited and computed, a grid step on a dead
+    super-block one visit that computes nothing."""
+    visited = computed = 0
+    for x in range(n):
+        for first in range(0, n, nbr):
+            lo, hi, d = _walk(x, first, nbr, causal, before=True)
+            live = hi - lo + (d is not None and 0 <= d < nbr)
+            computed += live
+            visited += max(live, 1)
+    return visited, computed
+
+
+def _rows(j, blk):
+    return pl.ds(pl.multiple_of(j * blk, blk), blk)
+
+
+def _resident_rows(S, blk, dtypes):
+    """Rows R of the super-block a kernel keeps in VMEM beside its grid
+    axis's own block (`dtypes`: one per resident [R, 128] operand): S
+    wherever that fits."""
+    return _vmem.resident_rows(
+        S, blk, 128 * sum(jnp.dtype(d).itemsize for d in dtypes))
+
+
+def _frozen(statics):
+    return tuple(sorted(statics.items()))
+
+
+def _params(interpret):
+    return dict(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem.RESIDENT_VMEM_LIMIT_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -128,25 +209,27 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
                 *, D, BQ, BK, scale, rate, is_test, upscale, causal):
     qb = pl.program_id(2)
-    kb = pl.program_id(3)
-    nk = pl.num_programs(3)
+    sb = pl.program_id(3)
+    nbr = k_ref.shape[1] // BK
+    first = sb * nbr
     G = 128 // D
 
-    @pl.when(kb == 0)
+    @pl.when(sb == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        bias_tile = bias_ref[0]  # [1, BK]
+    def tile(j, diag):
+        rows = _rows(j, BK)
+        bias_tile = bias_ref[0, j]  # [1, BK]
         for i in range(G):
             sl = slice(i * D, (i + 1) * D)
             q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
+            k = k_ref[0, rows, sl]
+            v = v_ref[0, rows, sl]
             head = (pl.program_id(1) * G + i)
-            s = _tile_scores(q, k, bias_tile, scale, causal, qb, kb, BQ, BK)
+            s = _tile_scores(q, k, bias_tile, scale, diag)
             m_prev = m_scr[:, sl][:, :1]  # [BQ, 1] (per-head col block)
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
@@ -160,7 +243,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                                              dtype=jnp.float32)
             ed = _dropout_tile(
                 e, rate, is_test, upscale, seed_ref, head.astype(jnp.uint32),
-                qb, kb,
+                qb, first + j,
             )
             pv = jnp.dot(ed.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
@@ -168,16 +251,14 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             m_scr[:, sl] = jnp.broadcast_to(m_new, (m_new.shape[0], D))
             l_scr[:, sl] = jnp.broadcast_to(l_new, (l_new.shape[0], D))
 
+    lo, hi, d = _walk(qb, first, nbr, causal, before=True)
+    jax.lax.fori_loop(lo, hi, lambda j, _: tile(j, False), None)
     if causal:
-        # tiles strictly above the diagonal are all-masked: skip the MXU/
-        # VPU work entirely (the scratch state is unchanged by a dead tile)
-        @pl.when(kb * BK <= qb * BQ + (BQ - 1))
-        def _live():
-            _compute()
-    else:
-        _compute()
+        @pl.when((d >= 0) & (d < nbr))
+        def _diagonal():
+            tile(d, True)
 
-    @pl.when(kb == nk - 1)
+    @pl.when(sb == pl.num_programs(3) - 1)
     def _finalize():
         for i in range(G):
             sl = slice(i * D, (i + 1) * D)
@@ -192,55 +273,93 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             )
 
 
-def _q_spec(section, num_groups, BQ):
-    return pl.BlockSpec(
-        (1, BQ, 128),
-        lambda b, g, qb, kb: (b, qb, section * num_groups + g),
-        memory_space=pltpu.VMEM,
-    )
+def _specs(S, blk, R, G, causal, before):
+    """BlockSpecs of a kernel whose grid is (B, G, S // blk, S // R).
+    `own(sec)` / `resident(sec)`: the third axis's own [blk, 128] block,
+    resp. the last axis's resident [R, 128] super-block, of qkv section
+    `sec` or of a [B, S, H*D] array (sec None). `own_bias` /
+    `resident_bias`: the same two of the key bias, held per block as
+    [B, S // blk, 1, blk] so that the kernel indexes a block on an untiled
+    axis. Under causality the super-block index is held at the one with
+    the diagonal on every dead step, so a dead super-block is not fetched
+    either."""
+    nbr = R // blk
 
+    def lane(sec, g):
+        return g if sec is None else sec * G + g
 
-def _kv_spec(section, num_groups, BK):
-    return pl.BlockSpec(
-        (1, BK, 128),
-        lambda b, g, qb, kb: (b, kb, section * num_groups + g),
-        memory_space=pltpu.VMEM,
-    )
+    def sup(x, sb):
+        if not causal:
+            return sb
+        live = x // nbr
+        return jnp.minimum(sb, live) if before else jnp.maximum(sb, live)
 
+    def own(sec=None):
+        return pl.BlockSpec(
+            (1, blk, 128), lambda b, g, x, sb: (b, x, lane(sec, g)),
+            memory_space=pltpu.VMEM)
 
-def _bias_spec(BK):
-    return pl.BlockSpec(
-        (1, 1, BK), lambda b, g, qb, kb: (b, 0, kb),
-        memory_space=pltpu.VMEM,
-    )
+    def resident(sec=None):
+        return pl.BlockSpec(
+            (1, R, 128), lambda b, g, x, sb: (b, sup(x, sb), lane(sec, g)),
+            memory_space=pltpu.VMEM)
 
-
-def _out_spec(BQ):
-    return pl.BlockSpec(
-        (1, BQ, 128), lambda b, g, qb, kb: (b, qb, g),
-        memory_space=pltpu.VMEM,
+    return types.SimpleNamespace(
+        own=own, resident=resident,
+        own_bias=pl.BlockSpec(
+            (1, 1, 1, blk), lambda b, g, x, sb: (b, x, 0, 0),
+            memory_space=pltpu.VMEM),
+        resident_bias=pl.BlockSpec(
+            (1, nbr, 1, blk), lambda b, g, x, sb: (b, sup(x, sb), 0, 0),
+            memory_space=pltpu.VMEM),
     )
 
 
 def flash_tiled_fwd(qkv, bias, seed, H, D, statics, interpret=False):
     """qkv [B, S, 3*H*D]; bias [B, S] -> (out [B, S, H*D], lse [B, S, H*D])."""
+    from .. import observability as _obs
+
+    S = qkv.shape[1]
+    blk = _tile(S)
+    R = _resident_rows(S, blk, [qkv.dtype] * 2)
+    visited, computed = _tile_counts(S // blk, R // blk, statics["causal"])
+    _obs.set_gauge("kernels.flash_tiled.tiles_visited", visited)
+    _obs.set_gauge("kernels.flash_tiled.tiles_computed", computed)
+    return _fwd_call(qkv, bias, seed, H=H, D=D, statics=_frozen(statics),
+                     R=R, interpret=interpret)
+
+
+# Each pallas_call sits in a jit of its own: a model's layers share shapes
+# and statics, so a program traces and lowers each kernel once, not once a
+# layer (a kernel holds every tile's code twice, loop body and diagonal:
+# 12 layers of the three cost 9 s of a 32 s warm start otherwise). Only
+# the pallas_call: with the XLA ops around it inside, XLA scheduled the
+# step into 119 MB more of temporaries
+_kernel_jit = functools.partial(
+    jax.jit, static_argnames=("H", "D", "statics", "R", "interpret"))
+
+
+@_kernel_jit
+def _fwd_call(qkv, bias, seed, *, H, D, statics, R, interpret):
+    """One q block a grid step, K and V resident."""
+    statics = dict(statics)
     B, S, _ = qkv.shape
     G = H * D // 128
     BQ = BK = _tile(S)
-    bias3 = bias.reshape(B, 1, S)
+    sp = _specs(S, BQ, R, G, statics["causal"], before=True)
     kern = functools.partial(_fwd_kernel, D=D, BQ=BQ, BK=BK, **statics)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         name="flash_tiled_fwd",
-        grid=(B, G, S // BQ, S // BK),
+        grid=(B, G, S // BQ, S // R),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            _q_spec(0, G, BQ),
-            _kv_spec(1, G, BK),
-            _kv_spec(2, G, BK),
-            _bias_spec(BK),
+            sp.own(0),
+            sp.resident(1),
+            sp.resident(2),
+            sp.resident_bias,
         ],
-        out_specs=[_out_spec(BQ), _out_spec(BQ)],
+        out_specs=[sp.own(), sp.own()],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, H * D), qkv.dtype),
             jax.ShapeDtypeStruct((B, S, H * D), jnp.float32),
@@ -250,9 +369,8 @@ def flash_tiled_fwd(qkv, bias, seed, H, D, statics, interpret=False):
             pltpu.VMEM((BQ, 128), jnp.float32),
             pltpu.VMEM((BQ, 128), jnp.float32),
         ],
-        interpret=pltpu.InterpretParams() if interpret else False,
-    )(seed, qkv, qkv, qkv, bias3)
-    return out, lse
+        **_params(interpret),
+    )(seed, qkv, qkv, qkv, bias.reshape(B, S // BK, 1, BK))
 
 
 # ---------------------------------------------------------------------------
@@ -260,81 +378,84 @@ def flash_tiled_fwd(qkv, bias, seed, H, D, statics, interpret=False):
 # ---------------------------------------------------------------------------
 
 
-def _tile_probs_from_lse(q, k, bias_tile, lse_col, scale, causal, qb, kb,
-                         BQ, BK):
-    s = _tile_scores(q, k, bias_tile, scale, causal, qb, kb, BQ, BK)
+def _tile_grads(q, k, v, do, bias_tile, lse_col, delta_col, seed_ref, head,
+                qb, kb, diag, *, scale, rate, is_test, upscale):
+    """One head's tile of the backward, p recomputed from the saved row
+    logsumexp (no renormalisation pass): (p, drop, ds), where `drop`
+    applies the tile's dropout mask and scale to a [BQ, BK] array."""
+    s = _tile_scores(q, k, bias_tile, scale, diag)
     edt = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
-    return jnp.exp((s - lse_col).astype(edt))  # [BQ, BK] normalized probs
+    p = jnp.exp((s - lse_col).astype(edt))  # [BQ, BK] normalized probs
+    if rate > 0.0 and not is_test:
+        _seed_tile(seed_ref, head.astype(jnp.uint32), qb, kb)
+        keep = _keep(p.shape, rate)
+        inv = 1.0 / (1.0 - rate) if upscale else 1.0
+
+        def drop(t):
+            return jnp.where(keep, t * inv, 0.0)
+    else:
+        ts = 1.0 if (rate == 0.0 or upscale) else 1.0 - rate
+
+        def drop(t):
+            return t * ts
+    dp = drop(jnp.dot(do.astype(v.dtype), v.T,
+                      preferred_element_type=jnp.float32))
+    return p, drop, p * (dp - delta_col)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dbias_ref, dk_scr, dv_scr,
                 *, D, BQ, BK, scale, rate, is_test, upscale, causal):
     kb = pl.program_id(2)
-    qb = pl.program_id(3)
-    nq = pl.num_programs(3)
+    sb = pl.program_id(3)
+    nbr = q_ref.shape[1] // BQ
+    first = sb * nbr
     G = 128 // D
 
-    @pl.when(qb == 0)
+    @pl.when(sb == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
-    def _compute():
-        bias_tile = bias_ref[0]
+    def tile(j, diag):
+        rows = _rows(j, BQ)
+        bias_tile = bias_ref[0, 0]  # [1, BK]
         db_rows = jnp.zeros((1, BK), jnp.float32)
         for i in range(G):
             sl = slice(i * D, (i + 1) * D)
-            q = q_ref[0, :, sl]
+            q = q_ref[0, rows, sl]
             k = k_ref[0, :, sl]
             v = v_ref[0, :, sl]
-            do = do_ref[0, :, sl]
-            lse_col = lse_ref[0, :, sl][:, :1]
-            delta_col = delta_ref[0, :, sl][:, :1]
-            head = pl.program_id(1) * G + i
-            p = _tile_probs_from_lse(q, k, bias_tile, lse_col, scale,
-                                     causal, qb, kb, BQ, BK)
-            if rate > 0.0 and not is_test:
-                _seed_tile(seed_ref, head.astype(jnp.uint32), qb, kb)
-                keep = _keep(p.shape, rate)
-                inv = 1.0 / (1.0 - rate) if upscale else 1.0
-                pm = jnp.where(keep, p * inv, 0.0)
-                dpm = jnp.dot(do.astype(v.dtype), v.T,
-                              preferred_element_type=jnp.float32)
-                dp = jnp.where(keep, dpm * inv, 0.0)
-            else:
-                ts = 1.0 if (rate == 0.0 or upscale) else 1.0 - rate
-                pm = p * ts
-                dp = jnp.dot(do.astype(v.dtype), v.T,
-                             preferred_element_type=jnp.float32) * ts
+            do = do_ref[0, rows, sl]
+            p, drop, ds = _tile_grads(
+                q, k, v, do, bias_tile, lse_ref[0, rows, sl][:, :1],
+                delta_ref[0, rows, sl][:, :1], seed_ref,
+                pl.program_id(1) * G + i, first + j, kb, diag,
+                scale=scale, rate=rate, is_test=is_test, upscale=upscale)
             dv_scr[:, sl] += jnp.dot(
-                pm.astype(v.dtype).T, do.astype(v.dtype),
+                drop(p).astype(v.dtype).T, do.astype(v.dtype),
                 preferred_element_type=jnp.float32,
             )
-            ds = p * (dp - delta_col)
-            dsb = ds.astype(v.dtype)
             dk_scr[:, sl] += jnp.dot(
-                dsb.T, q, preferred_element_type=jnp.float32
+                ds.astype(v.dtype).T, q, preferred_element_type=jnp.float32
             ) * scale
             db_rows = db_rows + jnp.sum(ds, axis=0, keepdims=True)
-        dbias_ref[0, 0] = db_rows
+        return db_rows
 
+    # the diagonal block first, then the q blocks after it: dk / dv
+    # accumulate over the q blocks in ascending order
+    lo, hi, d = _walk(kb, first, nbr, causal, before=False)
     if causal:
-        live = qb * BQ + (BQ - 1) >= kb * BK
+        @pl.when((d >= 0) & (d < nbr))
+        def _diagonal():
+            dbias_ref[0, 0] += tile(d, True)
 
-        @pl.when(live)
-        def _live():
-            _compute()
+    dbias_ref[0, 0] += jax.lax.fori_loop(
+        lo, hi, lambda j, db: db + tile(j, False),
+        jnp.zeros((1, BK), jnp.float32))
 
-        @pl.when(jnp.logical_not(live))
-        def _dead():
-            # this (g, kb, qb) partial-dbias block is written exactly once;
-            # a dead tile must still zero it
-            dbias_ref[0, 0] = jnp.zeros((1, BK), jnp.float32)
-    else:
-        _compute()
-
-    @pl.when(qb == nq - 1)
+    @pl.when(sb == pl.num_programs(3) - 1)
     def _write():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -344,51 +465,39 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                delta_ref, dq_ref, dq_scr,
                *, D, BQ, BK, scale, rate, is_test, upscale, causal):
     qb = pl.program_id(2)
-    kb = pl.program_id(3)
-    nk = pl.num_programs(3)
+    sb = pl.program_id(3)
+    nbr = k_ref.shape[1] // BK
+    first = sb * nbr
     G = 128 // D
 
-    @pl.when(kb == 0)
+    @pl.when(sb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        bias_tile = bias_ref[0]
+    def tile(j, diag):
+        rows = _rows(j, BK)
+        bias_tile = bias_ref[0, j]
         for i in range(G):
             sl = slice(i * D, (i + 1) * D)
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            do = do_ref[0, :, sl]
-            lse_col = lse_ref[0, :, sl][:, :1]
-            delta_col = delta_ref[0, :, sl][:, :1]
-            head = pl.program_id(1) * G + i
-            p = _tile_probs_from_lse(q, k, bias_tile, lse_col, scale,
-                                     causal, qb, kb, BQ, BK)
-            if rate > 0.0 and not is_test:
-                _seed_tile(seed_ref, head.astype(jnp.uint32), qb, kb)
-                keep = _keep(p.shape, rate)
-                inv = 1.0 / (1.0 - rate) if upscale else 1.0
-                dpm = jnp.dot(do.astype(v.dtype), v.T,
-                              preferred_element_type=jnp.float32)
-                dp = jnp.where(keep, dpm * inv, 0.0)
-            else:
-                ts = 1.0 if (rate == 0.0 or upscale) else 1.0 - rate
-                dp = jnp.dot(do.astype(v.dtype), v.T,
-                             preferred_element_type=jnp.float32) * ts
-            ds = p * (dp - delta_col)
+            k = k_ref[0, rows, sl]
+            _, _, ds = _tile_grads(
+                q_ref[0, :, sl], k, v_ref[0, rows, sl], do_ref[0, :, sl],
+                bias_tile, lse_ref[0, :, sl][:, :1],
+                delta_ref[0, :, sl][:, :1], seed_ref,
+                pl.program_id(1) * G + i, qb, first + j, diag,
+                scale=scale, rate=rate, is_test=is_test, upscale=upscale)
             dq_scr[:, sl] += jnp.dot(
-                ds.astype(v.dtype), k, preferred_element_type=jnp.float32
+                ds.astype(k.dtype), k, preferred_element_type=jnp.float32
             ) * scale
 
+    lo, hi, d = _walk(qb, first, nbr, causal, before=True)
+    jax.lax.fori_loop(lo, hi, lambda j, _: tile(j, False), None)
     if causal:
-        @pl.when(kb * BK <= qb * BQ + (BQ - 1))
-        def _live():
-            _compute()
-    else:
-        _compute()
+        @pl.when((d >= 0) & (d < nbr))
+        def _diagonal():
+            tile(d, True)
 
-    @pl.when(kb == nk - 1)
+    @pl.when(sb == pl.num_programs(3) - 1)
     def _write():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -397,9 +506,7 @@ def flash_tiled_bwd(qkv, bias, seed, do, out, lse, H, D, statics,
                     interpret=False):
     """-> (dqkv [B, S, 3HD], dbias [B, S])."""
     B, S, _ = qkv.shape
-    G = H * D // 128
-    BQ = BK = _tile(S)
-    bias3 = bias.reshape(B, 1, S)
+    blk = _tile(S)
     # delta = rowsum(do * o) per head, broadcast to the lane layout
     do3 = do.reshape(B, S, H, D)
     o3 = out.reshape(B, S, H, D)
@@ -407,79 +514,90 @@ def flash_tiled_bwd(qkv, bias, seed, do, out, lse, H, D, statics,
         do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
     )  # [B, S, H]
     delta = jnp.repeat(delta, D, axis=-1)  # [B, S, H*D] column-replicated
+    args = (qkv, bias, seed, do, lse, delta)
+    static = dict(H=H, D=D, statics=_frozen(statics), interpret=interpret)
+    dk, dv, dbias_parts = _dkv_call(*args, **static, R=_resident_rows(
+        S, blk, [qkv.dtype, do.dtype, lse.dtype, delta.dtype]))
+    dq = _dq_call(*args, **static,
+                  R=_resident_rows(S, blk, [qkv.dtype] * 2))
+    dbias = jnp.sum(dbias_parts, axis=1).reshape(B, S)
+    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)
+    return dqkv, dbias
 
+
+@_kernel_jit
+def _dkv_call(qkv, bias, seed, do, lse, delta, *, H, D, statics, R,
+              interpret):
+    """One kv block a grid step, the q rows' operands resident."""
+    statics = dict(statics)
+    B, S, _ = qkv.shape
+    G = H * D // 128
+    BQ = BK = _tile(S)
+    sp = _specs(S, BK, R, G, statics["causal"], before=False)
     dkv_kern = functools.partial(_dkv_kernel, D=D, BQ=BQ, BK=BK, **statics)
-    dk, dv, dbias_parts = pl.pallas_call(
+    return pl.pallas_call(
         dkv_kern,
         name="flash_tiled_dkv",
-        grid=(B, G, S // BK, S // BQ),
+        grid=(B, G, S // BK, S // R),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            # q-indexed operands use the INNER axis (qb = program_id(3))
-            pl.BlockSpec((1, BQ, 128),
-                         lambda b, g, kb, qb: (b, qb, 0 * G + g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BK, 128),
-                         lambda b, g, kb, qb: (b, kb, 1 * G + g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BK, 128),
-                         lambda b, g, kb, qb: (b, kb, 2 * G + g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, BK), lambda b, g, kb, qb: (b, 0, kb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BQ, 128), lambda b, g, kb, qb: (b, qb, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BQ, 128), lambda b, g, kb, qb: (b, qb, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BQ, 128), lambda b, g, kb, qb: (b, qb, g),
-                         memory_space=pltpu.VMEM),
+            sp.resident(0),
+            sp.own(1),
+            sp.own(2),
+            sp.own_bias,
+            sp.resident(),
+            sp.resident(),
+            sp.resident(),
         ],
         out_specs=[
-            pl.BlockSpec((1, BK, 128), lambda b, g, kb, qb: (b, kb, g),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BK, 128), lambda b, g, kb, qb: (b, kb, g),
-                         memory_space=pltpu.VMEM),
-            # per-(g, kb, qb) partial bias rows; summed below
-            pl.BlockSpec((1, 1, 1, BK),
-                         lambda b, g, kb, qb: (b, g * (S // BQ) + qb, 0, kb),
+            sp.own(),
+            sp.own(),
+            # per-lane-group partial bias rows; summed below
+            pl.BlockSpec((1, 1, 1, BK), lambda b, g, kb, sb: (b, g, 0, kb),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, H * D), qkv.dtype),
             jax.ShapeDtypeStruct((B, S, H * D), qkv.dtype),
-            jax.ShapeDtypeStruct((B, G * (S // BQ), 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, G, 1, S), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((BK, 128), jnp.float32),
             pltpu.VMEM((BK, 128), jnp.float32),
         ],
-        interpret=pltpu.InterpretParams() if interpret else False,
-    )(seed, qkv, qkv, qkv, bias3, do, lse, delta)
+        **_params(interpret),
+    )(seed, qkv, qkv, qkv, bias.reshape(B, S // BK, 1, BK), do, lse, delta)
 
+
+@_kernel_jit
+def _dq_call(qkv, bias, seed, do, lse, delta, *, H, D, statics, R,
+             interpret):
+    """One q block a grid step, K and V resident."""
+    statics = dict(statics)
+    B, S, _ = qkv.shape
+    G = H * D // 128
+    BQ = BK = _tile(S)
+    sp = _specs(S, BQ, R, G, statics["causal"], before=True)
     dq_kern = functools.partial(_dq_kernel, D=D, BQ=BQ, BK=BK, **statics)
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         dq_kern,
         name="flash_tiled_dq",
-        grid=(B, G, S // BQ, S // BK),
+        grid=(B, G, S // BQ, S // R),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            _q_spec(0, G, BQ),
-            _kv_spec(1, G, BK),
-            _kv_spec(2, G, BK),
-            _bias_spec(BK),
-            _out_spec(BQ),
-            _out_spec(BQ),
-            _out_spec(BQ),
+            sp.own(0),
+            sp.resident(1),
+            sp.resident(2),
+            sp.resident_bias,
+            sp.own(),
+            sp.own(),
+            sp.own(),
         ],
-        out_specs=_out_spec(BQ),
+        out_specs=sp.own(),
         out_shape=jax.ShapeDtypeStruct((B, S, H * D), qkv.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, 128), jnp.float32)],
-        interpret=pltpu.InterpretParams() if interpret else False,
-    )(seed, qkv, qkv, qkv, bias3, do, lse, delta)
-
-    dbias = jnp.sum(dbias_parts, axis=1).reshape(B, S)
-    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)
-    return dqkv, dbias
+        **_params(interpret),
+    )(seed, qkv, qkv, qkv, bias.reshape(B, S // BK, 1, BK), do, lse, delta)
 
 
 # ---------------------------------------------------------------------------

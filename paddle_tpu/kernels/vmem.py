@@ -42,3 +42,31 @@ def row_block(rows: int, n: int, block_dtypes) -> int:
     while rows % blk:
         blk //= 2
     return blk
+
+
+# -- the resident super-block of the KV-tiled flash kernels (flash_tiled) --
+# What those kernels state as `vmem_limit_bytes`: they keep a whole lane
+# group's K and V (fwd, dq) or Q, dO, lse, delta (dkv) in VMEM and loop
+# over it, which Mosaic's 16 MiB default cannot hold at S=4096. Half of a
+# v5e core's 128 MiB
+RESIDENT_VMEM_LIMIT_BYTES = 64 * 2**20
+# of it, left to the kernel body: the [tile, tile] fp32 temporaries of a
+# tile's element-wise chain (tile <= 512: 1 MiB each, 8-10 live in dkv),
+# the grid axis's own blocks and the scratch accumulators
+_BODY_BYTES = 16 * 2**20
+
+
+def resident_rows(rows: int, blk: int, row_bytes: int) -> int:
+    """Rows R of the super-block a kernel may keep resident: the largest
+    whole share of `rows`, in blocks of `blk`, whose operands
+    (`row_bytes` a row, all resident operands together, double-buffered
+    by the pipeline) fit beside the body. R == rows wherever that fits;
+    beyond it the kernel's last grid axis walks rows // R super-blocks."""
+    n = rows // blk
+    for parts in range(1, n + 1):
+        if n % parts == 0 and (
+            2 * (rows // parts) * row_bytes
+            <= RESIDENT_VMEM_LIMIT_BYTES - _BODY_BYTES
+        ):
+            return rows // parts
+    return blk

@@ -98,9 +98,9 @@ def _whole_row(batch, seq, dtype):
     return fwd, bwd
 
 
-def _tiled(batch, seq, dtype, rate):
-    """(fwd, bwd) of the KV-tiled kernels, causal."""
-    st = _statics(rate, causal=True)
+def _tiled(batch, seq, dtype, rate, causal=True):
+    """(fwd, bwd) of the KV-tiled kernels."""
+    st = _statics(rate, causal=causal)
     qkv = ((batch, seq, 3 * HID), dtype)
     bias, seed = ((batch, seq), F32), ((2,), jnp.uint32)
     out, lse = ((batch, seq, HID), dtype), ((batch, seq, HID), F32)
@@ -187,6 +187,16 @@ def _cases():
         _ring(SZ.ring_batch, SZ.ring_seq // 4, F32), ("fwd", "dq", "dkv"))
     add(f"tiled-b{SZ.ring_batch}-s{SZ.ring_seq}-f32",
         _tiled(SZ.ring_batch, SZ.ring_seq, F32, 0.0))
+    # the tiled kernels' resident super-block (vmem.resident_rows) at the
+    # sizes that press on it: K, V (fwd, dq) and Q, dO, lse, delta (dkv) of
+    # a lane group held whole at S=8192 and bf16 S=16384 (48 MiB of dkv
+    # operands, double-buffered), two super-blocks beyond; and the loops
+    # without causality
+    add("corner-tiled-b1-s8192-bf16", _tiled(1, 8192, BF16, 0.1))
+    add("corner-tiled-b1-s16384-bf16", _tiled(1, 16384, BF16, 0.1))
+    add("corner-tiled-b1-s16384-f32", _tiled(1, 16384, F32, 0.1))
+    add("corner-tiled-b1-s4096-bf16-noncausal",
+        _tiled(1, 4096, BF16, 0.1, causal=False))
     # generate phase of trinity_large_ep8: a decode step's 64 x 4
     # assignments in tiles of 16, a prefill block's 16 x 896 x 4 in tiles
     # of 256; gate+up (3072 -> 6144) and down (3072 -> 3072)
