@@ -127,10 +127,10 @@ def _param(name, shape, cfg, init, dtype=None):
     )
 
 
-def _proj(x, size, name, cfg):
+def _proj(x, size, name, cfg, init=None):
     return layers.fc(
         x, size=size, num_flatten_dims=2, bias_attr=False,
-        param_attr=ParamAttr(name=name, initializer=_normal(cfg)),
+        param_attr=ParamAttr(name=name, initializer=init or _normal(cfg)),
     )
 
 
@@ -264,11 +264,11 @@ def _embed(ids, cfg, seq):
     return x
 
 
-def _head(x, cfg):
+def _head(x, cfg, family="afmoe"):
     """Final norm, then the untied head over the vocabulary held here;
     float32 out of the product (not a rounded bfloat16 cast up)."""
-    x = _rms(x, "afmoe_norm_f", cfg)
-    w = _param("afmoe_head_w", [cfg.hidden_size, cfg.vocab_size], cfg,
+    x = _rms(x, f"{family}_norm_f", cfg)
+    w = _param(f"{family}_head_w", [cfg.hidden_size, cfg.vocab_size], cfg,
                _normal(cfg))
     return _simple("mul", {"X": [x], "Y": [w]},
                    {"x_num_col_dims": 2, "y_num_col_dims": 1,
@@ -357,7 +357,22 @@ def afmoe_decode_step(token_ids, pos_ids, cfg, max_len):
     return _head(x, cfg), _side_by_side(selected)
 
 
-class AfmoeDecoder:
+class MoeCounters:
+    """How `serving.GPTGenerator` reads an expert decoder's device-side
+    step counters (`parallel/moe.py::MOE_COUNTERS`, one int32 vector
+    named by the decoder's `counters_var`)."""
+
+    @property
+    def counter_names(self):
+        from ..parallel.moe import MOE_COUNTERS
+
+        return tuple(f"moe.{name}" for name in MOE_COUNTERS)
+
+    # the fullest expert's rows in one call: a maximum, not a sum
+    counter_gauges = frozenset({"moe.max_expert_load"})
+
+
+class AfmoeDecoder(MoeCounters):
     """What `serving.GPTGenerator` asks of a decoder: the two bodies, the
     state they share and how to read its counters."""
 
@@ -406,15 +421,6 @@ class AfmoeDecoder:
         return "window" if self.cfg.window_of(layer) else "full"
 
     counters_var = COUNTERS_VAR
-
-    @property
-    def counter_names(self):
-        from ..parallel.moe import MOE_COUNTERS
-
-        return tuple(f"moe.{name}" for name in MOE_COUNTERS)
-
-    # the fullest expert's rows in one call: a maximum, not a sum
-    counter_gauges = frozenset({"moe.max_expert_load"})
 
     def describe(self):
         """The sizes a cost model needs (benchmark/harness/moe_cost.py)."""

@@ -30,6 +30,7 @@ from . import (  # noqa: F401
     random,
     rnn,
     sparse,
+    ssm,
     tensor_ext,
     tensor_ops,
 )

@@ -59,6 +59,29 @@ def cache_shape(batch, max_len, num_heads, head_dim, window=0):
     return (int(batch), int(num_heads), int(head_dim), slots)
 
 
+def ssm_state_shape(batch, num_heads, head_dim, state_size, num_groups):
+    """Stored shape of ONE state-space layer's recurrent state:
+    ``[B, H / pack, N, pack * P]`` float32, whatever `max_len` is. The
+    state dimension N lies on the sublanes and `pack` heads' P channels
+    side by side on the lanes (a full 128-lane row where P divides 128),
+    the layout the decode update (kernels/ssm_update.py) reads and
+    writes in place with no transposition; the heads of a pack share a
+    B / C group. `ops/ssm.py::pack_state` converts from [B, H, P, N]."""
+    per_group = int(num_heads) // int(num_groups)
+    pack = max(1, min(128 // int(head_dim), per_group))
+    while per_group % pack:
+        pack -= 1
+    return (int(batch), int(num_heads) // pack, int(state_size),
+            pack * int(head_dim))
+
+
+def conv_tail_shape(batch, channels, kernel):
+    """Stored shape of ONE causal convolution's tail: the last
+    `kernel - 1` un-convolved rows of every sequence, ``[B, kernel - 1,
+    C]``, whatever `max_len` is."""
+    return (int(batch), int(kernel) - 1, int(channels))
+
+
 def attention_mask(qpos, slots, window=0):
     """[T, slots] bool: may the query at position `qpos[i]` read slot j?
     Slot j holds position p = qpos - ((qpos - j) mod slots), the newest

@@ -172,29 +172,47 @@ def _row_tile(assignments):
     return 256 if assignments >= 8192 else 16
 
 
+def _expert_activation(name):
+    """The experts' form, by the op's `activation`: gated `swiglu` over
+    a fused [.., 2F] first product, or non-gated `relu2` over [.., F]."""
+    if name == "swiglu":
+        from ..ops.llm import swiglu
+
+        return swiglu
+    if name == "relu2":
+        from ..ops.ssm import relu2
+
+        return relu2
+    raise ValueError(f"moe_local_experts: no expert activation {name!r}")
+
+
 def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
                       top_k, route_scale, expert_offset, route_norm=True,
-                      interpret=False):
+                      activation="swiglu", router_x=None, interpret=False):
     """The routed part of an expert layer that THIS chip's experts give.
 
-    x [B, T, H]; router_w [H, E] over all E experts; w_gate_up
-    [E_local, H, 2F], w_down [E_local, F, H]: experts `expert_offset` ..
-    `expert_offset + E_local - 1`. Every token is scored against all E,
-    its top-k chosen, and the assignments that fall on a local expert are
-    sorted by expert (each group padded to whole row tiles), pushed
-    through a grouped product (kernels/moe_gmm.py), and summed back into
-    their tokens with their weights. No capacity, nothing dropped: the
-    sorted buffer holds the worst case, every assignment local.
+    x [B, T, K], K the width the experts work at; router_w [H, E] over
+    all E experts, scored on `router_x` [B, T, H] (x itself where the
+    experts work at the hidden width); w_gate_up [E_local, K, 2F]
+    (`swiglu`) or [E_local, K, F] (`relu2`), w_down [E_local, F, K]:
+    experts `expert_offset` .. `expert_offset + E_local - 1`. Every
+    token is scored against all E, its top-k chosen, and the assignments
+    that fall on a local expert are sorted by expert (each group padded
+    to whole row tiles), pushed through a grouped product
+    (kernels/moe_gmm.py), and summed back into their tokens with their
+    weights. No capacity, nothing dropped: the sorted buffer holds the
+    worst case, every assignment local.
 
-    Returns (y [B, T, H], selected [B, T, k], counts [E_local] int32)."""
+    Returns (y [B, T, K], selected [B, T, k], counts [E_local] int32)."""
     from ..kernels import moe_gmm
 
     b, t, h = x.shape
     n_local = w_gate_up.shape[0]
     tokens = x.reshape(b * t, h)
     n_tok = b * t
+    scored = tokens if router_x is None else router_x.reshape(n_tok, -1)
     sel, weights = sigmoid_topk_route(
-        tokens, router_w, expert_bias, top_k, route_scale, route_norm
+        scored, router_w, expert_bias, top_k, route_scale, route_norm
     )
     local = sel - expert_offset
     is_local = (local >= 0) & (local < n_local)
@@ -239,12 +257,11 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     def product(lhs, rhs):
         return gmm(lhs, rhs, tile_expert, active, tm)
 
-    from ..ops.llm import swiglu
-
+    act = _expert_activation(activation)
     with jax.named_scope("moe_dispatch"):
         x_sorted = tokens[token_of_slot]                        # [M, H]
     with jax.named_scope("moe_experts"):
-        hidden = swiglu(product(x_sorted, w_gate_up))
+        hidden = act(product(x_sorted, w_gate_up))
         y_sorted = product(hidden, w_down)                      # [M, H]
     with jax.named_scope("moe_combine"):
         picked = y_sorted[jnp.minimum(slot, n_tiles * tm - 1)]  # [A, H]
@@ -261,12 +278,16 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
 
 @register_op(
     "moe_local_experts",
-    inputs=["X", "RouterW", "ExpertBias", "WGateUp", "WDown", "Counters"],
+    inputs=["X", "RouterW", "ExpertBias", "WGateUp", "WDown", "Counters",
+            "RouterX"],
     outputs=["Out", "Selected", "CountersOut"],
     differentiable=False,
     mutates=(("CountersOut", "Counters"),),
 )
 def _moe_local_experts_op(ctx, op, ins):
+    """`activation` ("swiglu" by default) names the experts' form;
+    `RouterX`, where given, is what the router scores (experts that work
+    in a latent read `X`, the router the hidden state)."""
     x, router_w, bias, wgu, wd, counters = (
         ins[k][0] for k in ("X", "RouterW", "ExpertBias", "WGateUp", "WDown",
                             "Counters")
@@ -277,6 +298,8 @@ def _moe_local_experts_op(ctx, op, ins):
         route_scale=float(op.attr("route_scale", 1.0)),
         route_norm=bool(op.attr("route_norm", True)),
         expert_offset=int(op.attr("expert_offset", 0)),
+        activation=op.attr("activation", "swiglu"),
+        router_x=(ins.get("RouterX") or [None])[0],
     )
     most = jnp.max(counts)
     local, hit = jnp.sum(counts), jnp.sum(counts > 0).astype(jnp.int32)

@@ -1,8 +1,9 @@
 """KV-cache generation: prefill + single-token decode programs.
 
 ``GPTGenerator`` owns the two programs a decoder (``models/gpt.py``'s
-``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``) splits itself
-into and the Scope their cache persistables share:
+``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``,
+``models/nemotron_h.py``'s ``NemotronHDecoder``) splits itself into and
+the Scope their state persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
   ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
@@ -16,7 +17,14 @@ The caches are ``[batch, kv_heads, head_dim, slots]`` arrays, the layout
 ``kv_cache_attention`` reads without a copy; ``ops/kv_cache.py::
 cache_shape`` owns that shape (``slots`` is ``max_len``, or a ring of the
 window's length on a sliding-window layer) for the graphs and, through
-the decoder's ``state_specs``, for ``reset`` alike. A decoder is handed
+the decoder's ``state_specs``, for ``reset`` alike. Per-sequence state
+need not be a KV cache: a state-space block carries a recurrent state
+and a convolution tail whose shapes (``ssm_state_shape``,
+``conv_tail_shape``, the same owner) do not depend on ``max_len``; the
+decoder's ``cache_kind`` names each piece's kind for the
+``kv_cache.bytes.<kind>`` gauges, and a prefill that takes a block of
+the batch's rows writes those rows' FINAL state into the batch's arrays
+as it writes their keys and values. A decoder is handed
 in as an object with ``prefill(ids, batch, max_len, row_ids)``,
 ``decode_step(token, pos, max_len)``, ``state_specs(batch, max_len)``,
 ``prefill_rows`` (rows of the batch one prefill dispatch takes; None for
@@ -217,7 +225,8 @@ class GPTGenerator:
 
     def reset(self):
         """Zero the generation state by its specs: every layer's KV
-        cache, the step counters and the two token arrays, each in the
+        cache or recurrent state, the step counters and the two token
+        arrays, each in the
         dtype a host feed of the declared one becomes on the device
         (int64 is int32 unless x64 is on)."""
         import jax

@@ -33,6 +33,7 @@ from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
 from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
 from paddle_tpu.kernels import moe_gmm  # noqa: E402
 from paddle_tpu.kernels import ring_block as rb  # noqa: E402
+from paddle_tpu.kernels import ssm_update  # noqa: E402
 
 SZ = chip_smoke.REAL
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -153,6 +154,17 @@ def _gmm(rows, tm, k, n, experts=32):
     return (lambda x, w, te, na: moe_gmm.gmm(x, w, te, na, tm), specs),
 
 
+def _ssm_update(batch, heads=128, head_dim=64, state=128, groups=8):
+    """The decode step's in-place state update at Nemotron-3-Super's
+    sizes: the stored state [B, H / 2, N, 2 P] float32, aliased."""
+    from paddle_tpu.ops.kv_cache import ssm_state_shape
+
+    shape = ssm_state_shape(batch, heads, head_dim, state, groups)
+    row, col = shape[:2] + shape[3:], (batch, state, groups)
+    specs = tuple((s, F32) for s in (shape, row, row, col, col))
+    return (ssm_update.update, specs),
+
+
 def _cases():
     cases = {}
 
@@ -204,6 +216,15 @@ def _cases():
         for n in (6144, 3072):
             add(f"moe_gmm-{rows}x3072x{n}-tm{tm}-bf16",
                 _gmm(rows, tm, 3072, n), ("fwd",))
+    # generate phase of nemotron3_super_ep4: a decode step's 64 x 22
+    # assignments in tiles of 16, a prefill block's 8 x 896 x 22 in tiles
+    # of 256, up (1024 -> 2688) and down (2688 -> 1024) over 128 experts;
+    # and the recurrent state's update, 64 sequences of 4 MB
+    for rows, tm in ((3456, 16), (190464, 256)):
+        for k, n in ((1024, 2688), (2688, 1024)):
+            add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
+                _gmm(rows, tm, k, n, experts=128), ("fwd",))
+    add("ssm_state_update-b64-h128x64-n128-f32", _ssm_update(64), ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
@@ -254,6 +275,7 @@ def _named_cases():
         f"layer_norm-{rows}-fwd": ["layer_norm_fwd"],
         f"layer_norm-{rows}-bwd": ["layer_norm_bwd"],
         "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm"],
+        "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
     }
 
 
