@@ -190,8 +190,8 @@ def gpt_logits(input_ids, cfg, is_test=True):
 # layer's K/V rows in persistable scope vars shared BETWEEN two programs:
 # a prefill program that embeds the full context once and fills the cache,
 # and a single-token decode program that appends one K/V row and attends
-# over the cache — O(1) recompute per token. A cache var is stored in the
-# layout the decode attention reads, [B, nh, dh, max_len]: the shape is
+# over the cache — O(1) recompute per token. A cache var is stored as the
+# layers produce their rows, [B, max_len, H]: the shape is
 # ops/kv_cache.py::cache_shape's to say, the layers hand their [B, T, H]
 # rows to kv_cache_write as they are. Parameter names match
 # gpt_decoder/gpt_logits exactly, so a trained checkpoint loads into
@@ -221,10 +221,12 @@ def _cache_var(name, batch, max_len, num_heads, head_dim):
 
 def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
     """Pre-LN decoder layer routed through the layer's KV cache: write this
-    call's K/V rows at `write_pos`, attend Q over the cache up to
-    `attend_pos` (inclusive). Dropout sites keep their test-mode
-    ``downgrade_in_infer`` (1 - p) scaling so outputs match the training
-    graph's ``is_test`` numerics (the freeze-parity contract)."""
+    call's K/V rows at `write_pos`, then attend Q over the cache up to
+    `attend_pos` (inclusive), or, with `attend_pos` None (a prefill from
+    position 0), over the call's own rows, which are all the cache holds.
+    Dropout sites keep their test-mode ``downgrade_in_infer`` (1 - p)
+    scaling so outputs match the training graph's ``is_test`` numerics
+    (the freeze-parity contract)."""
     from ..framework.program import default_main_program
     from ..layers.tensor import _simple
 
@@ -245,12 +247,17 @@ def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
              "Pos": [write_pos.name]},
             {"Out": [cache.name]},
         )
-    ctxv = _simple(
-        "kv_cache_attention",
-        {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [attend_pos]},
-        {"num_heads": nh, "scale": 1.0 / math.sqrt(dh),
-         "prob_scale": 1.0 - cfg.attention_dropout},
-    )
+    attrs = {"num_heads": nh, "num_kv_heads": nh,
+             "scale": 1.0 / math.sqrt(dh),
+             "prob_scale": 1.0 - cfg.attention_dropout}
+    if attend_pos is None:
+        ctxv = _simple("causal_gqa_attention",
+                       {"Q": [q], "K": [k], "V": [v]}, attrs)
+    else:
+        ctxv = _simple(
+            "kv_cache_attention",
+            {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [attend_pos]},
+            attrs)
     attn = _dense(ctxv, h, f"{prefix}_attn_out", cfg)
     x = x + layers.dropout(attn, cfg.hidden_dropout, is_test=True)
     m = _ln(x, f"{prefix}_ln2")
@@ -283,10 +290,9 @@ def gpt_prefill(context_ids, cfg, max_len):
     )
     x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=True)
     write_pos = layers.fill_constant([1], "int32", 0)
-    attend_pos = layers.fill_constant([1], "int32", s - 1)
     for i in range(cfg.num_layers):
         x = _cached_decoder_layer(
-            x, cfg, f"gpt_l{i}", write_pos, attend_pos, max_len
+            x, cfg, f"gpt_l{i}", write_pos, None, max_len
         )
     x = _ln(x, "gpt_lnf")
     last_h = layers.slice(x, [1], [s - 1], [s])

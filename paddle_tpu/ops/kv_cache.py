@@ -7,26 +7,32 @@ donation/write-back aliasing the optimizer uses for parameters, so the
 cache update is an in-place HBM dynamic-update-slice) and runs a
 single-token program per step.
 
-A cache is stored head-major with the sequence as the minor dimension,
-``[B, nh, dh, S]`` (``cache_shape`` is the one place that says so), which
-is the layout the decode attention consumes: the score product contracts
-K's ``dh`` axis and the value product contracts ``S`` on both operands,
-so a cache is only ever read in place and updated in place. Only the new
-rows (``[B, T, H]`` as the layer produces them) are transposed, on the
-way in. A cache is an entry parameter of the step with a fixed layout,
-so a stored layout the two products cannot consume as it is (``[B, S, H]``)
-costs a copy of the whole cache per layer per token. The minor dimension
-is S and not ``dh`` because the chip tiles fp32 as (8, 128): a 64-wide
-minor dimension is padded to 128 and doubles the cache.
+A cache is stored as the layers produce their rows, ``[B, slots,
+nkv * dh]`` (``cache_shape`` is the one place that says so): a position
+is one contiguous row, every KV head's `dh` lanes side by side. So a
+decode step WRITES its token as a row, a few tiles a sequence, with no
+transposition on the way in. (PR 26 stored ``[B, nh, dh, S]``, sequence
+minor, which XLA's two products read in place; its price was the write,
+a COLUMN at a runtime lane offset that touched every tile of the cache:
+38% of GPT-2's decode step and 15% of Trinity's, ledger PR 31.) XLA's
+products cannot read the row layout without copying the whole cache a
+layer and token, so a decode step reads it through ONE Pallas kernel,
+``kernels/decode_attention.py``, which contracts the whole lane width
+with the query laid block-diagonally and never takes a head's lanes
+apart. The minor dimension is a whole number of 128-lane tiles at the
+served widths (768 / 1024 / 256), so nothing is padded.
 
 * ``kv_cache_write`` — write the current step's K/V rows into the cache at
-  a runtime position (``jax.lax.dynamic_update_slice_in_dim`` along the
-  sequence axis; the output aliases the cache input, which the Executor
-  donates).
-* ``kv_cache_attention`` — one fused emitter for masked decode attention:
-  Q for the current token against the full cache, positions beyond ``Pos``
-  masked out. XLA sees one [B, nh, T, S] score tensor per layer instead of
-  a chain of mask/where/softmax ops (the PR-6 "one wide op" argument).
+  a runtime position (``jax.lax.dynamic_update_slice`` along the slot
+  axis; the output aliases the cache input, which the Executor donates).
+* ``kv_cache_attention`` — masked attention of the step's queries over
+  the cache, one path for every decoder: grouped query heads (``g = 1``
+  is GPT-2), causal, ring and window validity from ``attention_mask``.
+  One token a sequence on the TPU is the kernel; several (rows appended
+  to a cache that holds earlier ones) and the CPU take the same attention
+  in `jnp` (``grouped_attention``). The gauge ``kernels.decode_attention.calls``
+  is the count of kernel calls in the decode step lowered last (0: the
+  `jnp` path ran).
 * ``greedy_token`` — the step's greedy choice, kept on the device: the
   argmax of the last position's logits goes into a ``[B, 1]`` persistable
   (the next step's token feed, handed over as a device array) and into
@@ -48,7 +54,7 @@ from ._helpers import einsum_f32
 
 
 def cache_shape(batch, max_len, num_heads, head_dim, window=0):
-    """Stored shape of ONE layer's K (or V) cache: ``[B, nh, dh, slots]``,
+    """Stored shape of ONE layer's K (or V) cache: ``[B, slots, nh * dh]``,
     `nh` the KV heads the layer stores (its query heads may be a multiple
     of them). A full-attention layer holds ``max_len`` slots; a layer
     whose keys are only visible for `window` positions holds a ring of
@@ -56,7 +62,7 @@ def cache_shape(batch, max_len, num_heads, head_dim, window=0):
     The graph builders (models/) and the code that allocates the arrays
     (serving/generate.py) both ask here."""
     slots = min(int(max_len), int(window)) if window else int(max_len)
-    return (int(batch), int(num_heads), int(head_dim), slots)
+    return (int(batch), slots, int(num_heads) * int(head_dim))
 
 
 def ssm_state_shape(batch, num_heads, head_dim, state_size, num_groups):
@@ -96,18 +102,26 @@ def attention_mask(qpos, slots, window=0):
     return valid
 
 
-def grouped_attention(q, k, v, valid, num_kv_heads, scale):
-    """q [B, T, nh * dh] over k, v [B, nkv, dh, S] read in place: query
-    head n reads KV head n // (nh / nkv), so a KV head is never repeated
-    in HBM. `valid` [T, S]. Scores and softmax in float32."""
+def grouped_attention(q, k, v, valid, num_kv_heads, scale, prob_scale=1.0):
+    """q [B, T, nh * dh] over k, v [B, S, nkv * dh] (a cache as stored,
+    or a call's own rows): query head n reads KV head n // (nh / nkv),
+    so a KV head is never repeated in HBM. `valid` [T, S]. Scores and
+    softmax in float32; `prob_scale` is the inference residue of fluid's
+    downgrade_in_infer attention dropout (probabilities scale by
+    1 - dropout_prob, so that cached decode matches the training graph's
+    test-mode numerics)."""
     b, t, h = q.shape
-    dh = k.shape[2]
-    g = h // dh // num_kv_heads
-    qh = q.reshape(b, t, num_kv_heads, g, dh)
-    scores = einsum_f32("btkgd,bkds->bkgts", qh, k) * scale
+    s, hk = k.shape[1:]
+    dh = hk // num_kv_heads
+    qh = q.reshape(b, t, num_kv_heads, h // hk, dh)
+    kh = k.reshape(b, s, num_kv_heads, dh)
+    vh = v.reshape(b, s, num_kv_heads, dh)
+    scores = einsum_f32("btkgd,bskd->bkgts", qh, kh) * scale
     scores = jnp.where(valid[None, None, None], scores, jnp.float32(-1e9))
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgts,bkds->btkgd", probs, v)
+    if prob_scale != 1.0:
+        probs = probs * jnp.asarray(prob_scale, q.dtype)
+    out = jnp.einsum("bkgts,bskd->btkgd", probs, vh)
     return out.reshape(b, t, h)
 
 
@@ -124,10 +138,9 @@ def _pos_scalar(pos):
     mutates=(("Out", "Cache"),),
 )
 def _kv_cache_write(ctx, op, ins):
-    cache = ins["Cache"][0]  # [B, nh, dh, slots]
-    x = ins["X"][0]  # [B, T, H], H = nh * dh
+    cache = ins["Cache"][0]  # [B, slots, H], H = nh * dh
+    x = ins["X"][0].astype(cache.dtype)  # [rows, T, H]
     pos = _pos_scalar(ins["Pos"][0])
-    b, nh, dh, slots = cache.shape
     if op.attr("ring", False):
         return {"Out": [_ring_write(cache, x, pos, ins.get("Row"))]}
     if ins.get("Row"):
@@ -135,8 +148,7 @@ def _kv_cache_write(ctx, op, ins):
             "kv_cache_write: `Row` (a block of the batch's rows) needs "
             "ring=True; the plain write takes the whole batch"
         )
-    rows = x.astype(cache.dtype).reshape(b, -1, nh, dh).transpose(0, 2, 3, 1)
-    out = jax.lax.dynamic_update_slice_in_dim(cache, rows, pos, axis=3)
+    out = jax.lax.dynamic_update_slice_in_dim(cache, x, pos, axis=1)
     return {"Out": [out]}
 
 
@@ -145,19 +157,18 @@ def _ring_write(cache, x, pos, row):
     batch rows `row` .. (a prefill block of a larger batch). Of more rows
     than slots only the newest are kept; one row (decode) is an in-place
     update, several are rotated into place and written at slot 0."""
-    _, nh, dh, slots = cache.shape
-    rb, t = x.shape[0], x.shape[1]
-    rows = x.astype(cache.dtype).reshape(rb, t, nh, dh).transpose(0, 2, 3, 1)
+    slots = cache.shape[1]
+    t = x.shape[1]
     r0 = jnp.int32(0) if row is None else _pos_scalar(row[0])
     if t == 1:
         return jax.lax.dynamic_update_slice(
-            cache, rows, (r0, 0, 0, jnp.mod(pos, slots)))
+            cache, x, (r0, jnp.mod(pos, slots), 0))
     if t >= slots:
-        rows, pos = rows[..., t - slots:], pos + (t - slots)
-        rows = jnp.roll(rows, jnp.mod(pos, slots), axis=3)
-        return jax.lax.dynamic_update_slice(cache, rows, (r0, 0, 0, 0))
+        x, pos = x[:, t - slots:], pos + (t - slots)
+        x = jnp.roll(x, jnp.mod(pos, slots), axis=1)
+        return jax.lax.dynamic_update_slice(cache, x, (r0, 0, 0))
     # fewer rows than slots: they must not wrap (a prefill from 0)
-    return jax.lax.dynamic_update_slice(cache, rows, (r0, 0, 0, pos))
+    return jax.lax.dynamic_update_slice(cache, x, (r0, pos, 0))
 
 
 @register_op(
@@ -167,42 +178,54 @@ def _ring_write(cache, x, pos, row):
     differentiable=False,
 )
 def _kv_cache_attention(ctx, op, ins):
-    q = ins["Q"][0]  # [B, T, H]
-    k = ins["CacheK"][0]  # [B, nh, dh, S]
-    v = ins["CacheV"][0]  # [B, nh, dh, S]
+    """`Pos` is the cache position of the LAST query row; query row i
+    sits at position Pos - (T - 1) + i and may read what `attention_mask`
+    says (causal within a prefill, the slots written so far in decode;
+    later slots hold garbage or future rows)."""
+    from .. import observability as _obs
+
+    q = ins["Q"][0]  # [B, T, nh * dh]
+    k = ins["CacheK"][0]  # [B, slots, nkv * dh]
+    v = ins["CacheV"][0]
     pos = _pos_scalar(ins["Pos"][0])
     nh = int(op.attr("num_heads"))
-    scale = float(op.attr("scale", 1.0))
     kvh, window = int(op.attr("num_kv_heads", nh)), int(op.attr("window", 0))
-    if kvh != nh or window:
-        t = q.shape[1]
-        qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
-        valid = attention_mask(qpos, k.shape[3], window)
-        return {"Out": [grouped_attention(q, k, v, valid, kvh, scale)]}
-    # inference residue of fluid's downgrade_in_infer attention dropout:
-    # probs scale by (1 - dropout_prob) so cached decode matches the
-    # training graph's test-mode numerics exactly
+    scale = float(op.attr("scale", 1.0))
     prob_scale = float(op.attr("prob_scale", 1.0))
-    b, t, h = q.shape
-    s = k.shape[3]
-    qh = q.reshape(b, t, nh, h // nh).transpose(0, 2, 1, 3)  # [B, nh, T, dh]
-    scores = jnp.matmul(qh, k).astype(jnp.float32) * scale  # [B, nh, T, S]
-    # Pos is the cache position of the LAST query row; query row i sits at
-    # position Pos - (T-1) + i and may attend keys 0..that position
-    # (causal within a prefill window, the single current slot in decode;
-    # later cache slots hold garbage or future rows)
-    qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
-    valid = (
-        jnp.arange(s, dtype=jnp.int32)[None, None, None, :]
-        <= qpos[None, None, :, None]
-    )
-    scores = jnp.where(valid, scores, jnp.float32(-1e9))
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    if prob_scale != 1.0:
-        probs = probs * jnp.asarray(prob_scale, q.dtype)
-    # contracts S, the minor dimension of both operands: no V^T is built
-    out = jnp.einsum("bnts,bnds->bntd", probs, v)  # [B, nh, T, dh]
-    return {"Out": [out.transpose(0, 2, 1, 3).reshape(b, t, h)]}
+    t = q.shape[1]
+    if t > 1:
+        qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
+        valid = attention_mask(qpos, k.shape[1], window)
+        return {"Out": [grouped_attention(q, k, v, valid, kvh, scale,
+                                          prob_scale)]}
+    out, kernel = decode_attention(q[:, 0], k, v, pos, kvh, scale, window,
+                                   prob_scale)
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered step: the last call leaves the count
+        ctx.decode_attention_calls = kernel + getattr(
+            ctx, "decode_attention_calls", 0)
+        _obs.set_gauge("kernels.decode_attention.calls",
+                       ctx.decode_attention_calls)
+    return {"Out": [out[:, None]]}
+
+
+def decode_attention(q, k, v, pos, num_kv_heads, scale, window=0,
+                     prob_scale=1.0, interpret=False):
+    """One token a sequence, q [B, nh * dh] at position `pos`, over the
+    caches as stored: on the TPU (and with `interpret`) the Pallas kernel
+    (kernels/decode_attention.py), elsewhere the same in `jnp`. Returns
+    (out [B, nh * dh], whether the kernel ran)."""
+    kernel = interpret or jax.default_backend() == "tpu"
+    if kernel:
+        from ..kernels import decode_attention as _kernel
+
+        return _kernel.attend(
+            q, k, v, pos, num_kv_heads=num_kv_heads, scale=scale,
+            window=window, prob_scale=prob_scale, interpret=interpret,
+        ), True
+    valid = attention_mask(pos[None], k.shape[1], window)
+    return grouped_attention(q[:, None], k, v, valid, num_kv_heads, scale,
+                             prob_scale)[:, 0], False
 
 
 @register_op(

@@ -99,26 +99,25 @@ def _causal_gqa_attention(ctx, op, ins):
     """Prefill attention over the call's own rows: Q [B, S, nh * dh]
     against K, V [B, S, nkv * dh], query head n on KV head
     n // (nh / nkv), causal, and with `window` > 0 only keys closer than
-    it. Plain products in blocks of (rows, queries); softmax in float32."""
+    it. Plain products in blocks of (rows, queries); softmax in float32;
+    `prob_scale` as `kv_cache_attention` has it."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     nh, kvh = int(op.attr("num_heads")), int(op.attr("num_kv_heads"))
     window = int(op.attr("window", 0))
     scale = float(op.attr("scale", 1.0))
+    prob_scale = float(op.attr("prob_scale", 1.0))
     b, s, h = q.shape
-    dh = h // nh
     rows, queries = _query_block(b, nh, s)
-    kt = k.reshape(b, s, kvh, dh).transpose(0, 2, 3, 1)   # [B, kvh, dh, S]
-    vt = v.reshape(b, s, kvh, dh).transpose(0, 2, 3, 1)
     nb, nq = b // rows, s // queries
 
     def block(i):
         r0, q0 = (i // nq) * rows, (i % nq) * queries
         qb = jax.lax.dynamic_slice(q, (r0, q0, 0), (rows, queries, h))
-        kb = jax.lax.dynamic_slice_in_dim(kt, r0, rows, axis=0)
-        vb = jax.lax.dynamic_slice_in_dim(vt, r0, rows, axis=0)
+        kb = jax.lax.dynamic_slice_in_dim(k, r0, rows, axis=0)
+        vb = jax.lax.dynamic_slice_in_dim(v, r0, rows, axis=0)
         qpos = q0 + jnp.arange(queries, dtype=jnp.int32)
         valid = attention_mask(qpos, s, window)
-        return grouped_attention(qb, kb, vb, valid, kvh, scale)
+        return grouped_attention(qb, kb, vb, valid, kvh, scale, prob_scale)
 
     if nb * nq == 1:
         out = block(jnp.int32(0))

@@ -13,11 +13,16 @@ the Scope their state persistables share:
   update is an HBM dynamic-update-slice), attend over the cache, emit
   next-token logits.
 
-The caches are ``[batch, kv_heads, head_dim, slots]`` arrays, the layout
-``kv_cache_attention`` reads without a copy; ``ops/kv_cache.py::
-cache_shape`` owns that shape (``slots`` is ``max_len``, or a ring of the
-window's length on a sliding-window layer) for the graphs and, through
-the decoder's ``state_specs``, for ``reset`` alike. Per-sequence state
+The caches are ``[batch, slots, kv_heads * head_dim]`` arrays, a position
+a row, the layout a decode step writes with no transposition and
+``kv_cache_attention`` reads without a copy (one Pallas kernel, kernels/
+decode_attention.py); ``ops/kv_cache.py::cache_shape`` owns that shape
+(``slots`` is ``max_len``, or a ring of the window's length on a
+sliding-window layer) for the graphs and, through the decoder's
+``state_specs``, for ``reset`` alike. What a batch's decode steps NEED
+to read of them (the slots a query may see, once) is counted on the host
+from the positions fed: ``kv_cache.decode_bytes_needed`` over
+``kv_cache.decode_steps``. Per-sequence state
 need not be a KV cache: a state-space block carries a recurrent state
 and a convolution tail whose shapes (``ssm_state_shape``,
 ``conv_tail_shape``, the same owner) do not depend on ``max_len``; the
@@ -146,12 +151,18 @@ class GPTGenerator:
             (name, shape, "int64") for name, shape in self._token_vars()
         ]
         by_kind = {}
+        # (slots, bytes a slot) of every K and V cache: what a decode
+        # step at a position needs to read (`_decode_bytes_needed`)
+        self._kv_slots = []
         for name, shape, dtype in specs:
             kind = decoder.cache_kind(name)
             if kind:
-                by_kind[kind] = by_kind.get(kind, 0) + int(
+                nbytes = int(
                     np.prod(shape) * np.dtype(to_numpy_dtype(dtype)).itemsize
                 )
+                by_kind[kind] = by_kind.get(kind, 0) + nbytes
+                if kind in ("full", "window"):
+                    self._kv_slots.append((shape[1], nbytes // shape[1]))
         for kind, nbytes in by_kind.items():
             _obs.set_gauge(f"kv_cache.bytes.{kind}", nbytes)
         _obs.set_table("serving.generate.model", {
@@ -268,6 +279,16 @@ class GPTGenerator:
                 else:
                     _obs.add(name, value)
 
+    def _decode_bytes_needed(self, steps):
+        """Bytes of K and V the first `steps` decode steps of a batch need
+        to read: at position p a query may see ``min(p + 1, slots)`` slots
+        of a cache (a ring's window is its slots), each once."""
+        if not self._kv_slots:
+            return 0
+        slots, slot_bytes = np.array(self._kv_slots).T
+        seen = np.arange(1, steps + 1)[:, None] + self.context_len
+        return int((np.minimum(seen, slots) * slot_bytes).sum())
+
     # -- generation --------------------------------------------------------
     def generate(self, context_ids, max_new_tokens, greedy=True):
         """Generate `max_new_tokens` per sequence; returns [B, T] int64.
@@ -345,6 +366,9 @@ class GPTGenerator:
                 # run, so the span holds all of the loop's device work
                 out = np.asarray(self.scope.find_var(TOKENS_VAR))
             _obs.add("serving.decode_steps", new - 1)
+            _obs.add("kv_cache.decode_steps", new - 1)
+            _obs.add("kv_cache.decode_bytes_needed",
+                     self._decode_bytes_needed(new - 1))
             self._publish_counters()
         return out[:, :new].astype(np.int64)
 

@@ -127,9 +127,9 @@ def test_causal_gqa_attention_grouped_heads_window_and_blocks(
 # -- caches by layer kind ------------------------------------------------------
 
 def test_cache_shape_owns_the_kind():
-    assert kv_cache.cache_shape(2, 32, 4, 16) == (2, 4, 16, 32)
-    assert kv_cache.cache_shape(2, 32, 2, 16, window=8) == (2, 2, 16, 8)
-    assert kv_cache.cache_shape(2, 6, 2, 16, window=8) == (2, 2, 16, 6)
+    assert kv_cache.cache_shape(2, 32, 4, 16) == (2, 32, 4 * 16)
+    assert kv_cache.cache_shape(2, 32, 2, 16, window=8) == (2, 8, 2 * 16)
+    assert kv_cache.cache_shape(2, 6, 2, 16, window=8) == (2, 6, 2 * 16)
 
 
 @pytest.mark.parametrize("slots,window", [(32, 0), (8, 8), (32, 8)])
@@ -151,7 +151,8 @@ def test_ring_cache_prefill_longer_than_the_ring_then_decode_wraps():
     slot holds the newest position congruent to it; a block of rows of a
     larger batch lands at its row offset."""
     nh, dh, slots = 2, 4, 8
-    cache = np.zeros((3, nh, dh, slots), np.float32)
+    cache = np.zeros(kv_cache.cache_shape(3, 64, nh, dh, window=slots),
+                     np.float32)
     rows = rand(9, 2, 20, nh * dh)
     got = run_op("kv_cache_write",
                  {"c": cache, "x": rows, "p": np.array([0], np.int32),
@@ -159,7 +160,7 @@ def test_ring_cache_prefill_longer_than_the_ring_then_decode_wraps():
                  {"Cache": "c", "X": "x", "Pos": "p", "Row": "r"})
     want = cache.copy()
     for p in range(20):
-        want[1:3, :, :, p % slots] = rows[:, p].reshape(2, nh, dh)
+        want[1:3, p % slots] = rows[:, p]
     np.testing.assert_array_equal(got, want)
     cache = got
     for p in range(20, 25):
@@ -167,7 +168,7 @@ def test_ring_cache_prefill_longer_than_the_ring_then_decode_wraps():
         cache = run_op("kv_cache_write",
                        {"c": cache, "x": row, "p": np.array([[p]], np.int64)},
                        {"ring": True}, {"Cache": "c", "X": "x", "Pos": "p"})
-        want[:, :, :, p % slots] = row[:, 0].reshape(3, nh, dh)
+        want[:, p % slots] = row[:, 0]
     np.testing.assert_array_equal(cache, want)
 
 
@@ -175,14 +176,14 @@ def test_cached_decode_attention_reads_grouped_heads_in_place():
     """48-over-8 in miniature: 4 query heads on 2 KV heads of a cache
     that holds 2, against attention with the KV heads repeated."""
     q = rand(10, 2, 1, 64)
-    ck, cv = rand(11, 2, 2, 16, 12), rand(12, 2, 2, 16, 12)
+    shape = kv_cache.cache_shape(2, 12, 2, 16)
+    ck, cv = rand(11, *shape), rand(12, *shape)
     got = run_op(
         "kv_cache_attention",
         {"q": q, "k": ck, "v": cv, "p": np.array([[9]], np.int64)},
         {"num_heads": 4, "num_kv_heads": 2, "scale": 0.25},
         {"Q": "q", "CacheK": "k", "CacheV": "v", "Pos": "p"})
-    k = ck.transpose(0, 3, 1, 2).reshape(2, 12, 32)[:, :10]
-    v = cv.transpose(0, 3, 1, 2).reshape(2, 12, 32)[:, :10]
+    k, v = ck[:, :10], cv[:, :10]       # stored as the rows went in
     qfull = np.concatenate([np.zeros((2, 9, 64), np.float32), q], 1)
     want = dense_attention(qfull, k, v, 4, 2, 0, 0.25)[:, -1:]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -315,8 +316,10 @@ def test_layer_kinds_and_state_specs():
     assert kinds[0] == (SLIDING, DENSE) and kinds[-1] == (FULL, EXPERTS)
     assert sum(k == (SLIDING, EXPERTS) for k in kinds) == 3
     specs = {n: (s, d) for n, s, d in gen._state_specs}
-    assert specs["afmoe_l0_cache_k"] == ((2, 2, 16, 8), "bfloat16")   # ring
-    assert specs["afmoe_l4_cache_v"] == ((2, 2, 16, 32), "bfloat16")  # full
+    assert specs["afmoe_l0_cache_k"] == (
+        kv_cache.cache_shape(2, 32, 2, 16, window=8), "bfloat16")   # ring
+    assert specs["afmoe_l4_cache_v"] == (
+        kv_cache.cache_shape(2, 32, 2, 16), "bfloat16")             # full
     assert specs["afmoe_moe_counters"][1] == "int32"
     gen.reset()
     for name, (shape, _d) in specs.items():
@@ -527,7 +530,7 @@ def test_the_router_orders_scores_a_bfloat16_router_would_tie():
 
 
 def test_cache_write_takes_a_row_block_only_into_a_ring_layout():
-    cache = np.zeros((4, 2, 16, 8), np.float32)
+    cache = np.zeros(kv_cache.cache_shape(4, 8, 2, 16), np.float32)
     rows = rand(12, 2, 8, 32)
     feeds = {"c": cache, "x": rows, "p": np.zeros(1, np.int32),
              "r": np.array([2], np.int64)}

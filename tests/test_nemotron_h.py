@@ -468,7 +468,8 @@ def test_block_kinds_state_specs_and_gauges():
     specs = {n: (s, d) for n, s, d in gen._state_specs}
     assert specs["nemotron_l0_ssm_state"] == ((2, 2, 16, 64), "float32")
     assert specs["nemotron_l0_conv_tail"] == ((2, 3, CONV), "bfloat16")
-    assert specs["nemotron_l9_cache_k"] == ((2, 2, 16, 19), "bfloat16")
+    assert specs["nemotron_l9_cache_k"] == (
+        kv_cache.cache_shape(2, 19, 2, 16), "bfloat16")
     assert specs["nemotron_moe_counters"][1] == "int32"
     assert sum(n.endswith("_ssm_state") for n in specs) == 5
     assert sum("_cache_" in n for n in specs) == 2

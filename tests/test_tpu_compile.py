@@ -27,6 +27,7 @@ import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 import chip_smoke  # noqa: E402
+from paddle_tpu.kernels import decode_attention  # noqa: E402
 from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
 from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
 from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
@@ -165,6 +166,20 @@ def _ssm_update(batch, heads=128, head_dim=64, state=128, groups=8):
     return (ssm_update.update, specs),
 
 
+def _decode_attention(dtype, heads, kv_heads, head_dim, window=0, batch=64,
+                      max_len=1024):
+    """A decode step's attention over one layer's caches as stored
+    (`cache_shape`: a position a row), one query token a sequence."""
+    from paddle_tpu.ops.kv_cache import cache_shape
+
+    cache = (cache_shape(batch, max_len, kv_heads, head_dim, window), dtype)
+    specs = (((batch, heads * head_dim), dtype), cache, cache,
+             ((), jnp.int32))
+    return (lambda q, k, v, pos: decode_attention.attend(
+        q, k, v, pos, num_kv_heads=kv_heads, scale=head_dim ** -0.5,
+        window=window, prob_scale=0.9), specs),
+
+
 def _cases():
     cases = {}
 
@@ -225,6 +240,20 @@ def _cases():
             add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
                 _gmm(rows, tm, k, n, experts=128), ("fwd",))
     add("ssm_state_update-b64-h128x64-n128-f32", _ssm_update(64), ("fwd",))
+    # a decode step's attention over the caches of the three generate
+    # cells (batch 64, 1024 slots): GPT-2's float32 12 x 64, Trinity's
+    # 48 over 8 x 128 (full, and a ring layer whose window never binds),
+    # Nemotron's 32 over 2 x 128; and a context that takes several blocks
+    add("decode_attention-gpt2-12x64-f32", _decode_attention(F32, 12, 12, 64),
+        ("fwd",))
+    for window in (0, 4096):
+        add(f"decode_attention-trinity-48over8x128-w{window}-bf16",
+            _decode_attention(BF16, 48, 8, 128, window), ("fwd",))
+    add("decode_attention-nemotron-32over2x128-bf16",
+        _decode_attention(BF16, 32, 2, 128), ("fwd",))
+    add("corner-decode_attention-trinity-s16384-bf16",
+        _decode_attention(BF16, 48, 8, 128, batch=4, max_len=16384),
+        ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
@@ -276,6 +305,7 @@ def _named_cases():
         f"layer_norm-{rows}-bwd": ["layer_norm_bwd"],
         "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm"],
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
+        "decode_attention-gpt2-12x64-f32-fwd": ["decode_attention"],
     }
 
 
