@@ -87,7 +87,7 @@ class Sizes:
     serve_buckets: tuple
     gen_context: int
     gen_new: int
-    moe: str             # "cell": configs/trinity_large_ep8.json | "tiny"
+    moe: str             # "cell": the expert configurations' files | "tiny"
     moe_batch: int
     moe_context: int
     moe_max_len: int
@@ -665,35 +665,42 @@ def handoff_check(gen, prompts, new):
     }
 
 
-def afmoe_generator(sz):
-    """`serving.GPTGenerator` handed the afmoe decoder at the benchmark
-    configuration's widths (bfloat16, 4.32B parameters) or its tiny cut."""
-    from benchmark.builders.afmoe import model_config
-    from paddle_tpu.models.afmoe import AfmoeDecoder
-    from paddle_tpu.serving import GPTGenerator
+def cell_generator(sz, config):
+    """`serving.GPTGenerator` handed the decoder of a benchmark
+    configuration by its own builder, at the published widths
+    (bfloat16: 4.32B parameters for trinity_large_ep8, 4.57B for
+    dots_vlm1_ep16) or its tiny cut."""
+    import importlib
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "benchmark", "configs", "trinity_large_ep8.json")
+                        "benchmark", "configs", f"{config}.json")
     with open(path) as f:
-        cfg = model_config(json.load(f), tiny=sz.moe == "tiny")
-    gen = GPTGenerator(AfmoeDecoder(cfg), batch=sz.moe_batch,
-                       context_len=sz.moe_context, max_len=sz.moe_max_len)
-    gen.init_params(seed=SEED)
-    return gen
+        cfg_json = json.load(f)
+    builder = importlib.import_module(
+        f"benchmark.builders.{cfg_json['builder']}")
+    traffic = {"batch": sz.moe_batch, "prompt_len": sz.moe_context,
+               "new_tokens": sz.moe_max_len - sz.moe_context}
+    return builder.build_generate(cfg_json, traffic, sz.moe == "tiny",
+                                  SEED).generator
 
 
 def phase_generate(sz, kernels, shared):
     """GPTGenerator behind Server: prefill + per-token KV-cache decode,
-    against generate_full_recompute; then the token hand-off of both
-    decoders against the host's argmax chain (`handoff_check`), the
-    afmoe decoder at the whole decode batch."""
+    against generate_full_recompute; then the token hand-off of the
+    decoders against the host's argmax chain (`handoff_check`): the
+    afmoe decoder and the latent-attention decoder (prefill expanded,
+    cached steps absorbed) at the whole decode batch, one after the
+    other (each holds 9 GB of weights)."""
     out = _gpt_generate(sz)
-    gc.collect()    # the GPT generator's arrays, before 8.64 GB of weights
-    rng = np.random.RandomState(SEED + 1)
-    gen = afmoe_generator(sz)
-    prompts = rng.randint(0, gen.cfg.vocab_size,
-                          (sz.moe_batch, sz.moe_context)).astype(np.int64)
-    out["afmoe_handoff"] = handoff_check(gen, prompts, sz.moe_new)
+    for name, config in (("afmoe", "trinity_large_ep8"),
+                         ("dots_vlm", "dots_vlm1_ep16")):
+        gc.collect()    # the generator before, ahead of 9 GB of weights
+        rng = np.random.RandomState(SEED + 1)
+        gen = cell_generator(sz, config)
+        prompts = rng.randint(0, gen.cfg.vocab_size,
+                              (sz.moe_batch, sz.moe_context)).astype(np.int64)
+        out[f"{name}_handoff"] = handoff_check(gen, prompts, sz.moe_new)
+        del gen
     return out
 
 

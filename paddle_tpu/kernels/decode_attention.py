@@ -22,6 +22,19 @@ with the same diagonal and summed over the `kp` sublanes they land as
 row as it is and for grouped heads one small transposition of the
 result away from it.
 
+A layer may have ONE cache whose rows hold the values too (latent
+attention in its absorbed form: a row is the key/value latent beside the
+shared rotary key part, and the values are the latent, the row's leading
+`value_width` lanes). Then there is one cache operand, a block is
+fetched ONCE and feeds both products (two operands on one array would
+move every byte twice), and with one KV head nothing is laid
+block-diagonally and no query row is padding: `kp` is 1 and the
+`g` = 128 query heads are the product's 128 rows (padded to a sublane
+tile of KV heads they would be 1,024, 896 of them zero, on a call that
+sits at the chip's ridge: 128 heads x (640 + 512) lanes x 2 operations
+over 1,280 bytes a slot). Several KV heads in one shared array multiply
+the whole row and keep each head's leading lanes afterwards.
+
 The grid is (sequence, block of slots). Scores, the running maximum and
 sum and the accumulator are float32 (the online softmax of the flash
 kernels); probabilities are cast to the cache's dtype before the value
@@ -74,15 +87,19 @@ def _product(a, b, dims):
         preferred_element_type=jnp.float32)
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            nkv, kp, blk, slots, window, scale, prob_scale):
+def _kernel(pos_ref, q_ref, k_ref, *refs, nkv, kp, blk, slots, window, scale,
+            prob_scale):
+    # one cache operand: the values are lanes of the key rows
+    v_ref = refs[0] if len(refs) == 5 else None
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     j = pl.program_id(1)
     pos = pos_ref[0]
     g, hk = q_ref.shape[1:]
     dh = hk // nkv
-    head = jax.lax.broadcasted_iota(jnp.int32, (kp, hk), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (kp, hk), 1)
-    own = (lane >= head * dh) & (lane < (head + 1) * dh)
+    if kp > 1:
+        head = jax.lax.broadcasted_iota(jnp.int32, (kp, hk), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (kp, hk), 1)
+        own = (lane >= head * dh) & (lane < (head + 1) * dh)
 
     @pl.when(j == 0)
     def _():
@@ -92,10 +109,13 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j <= _last_live(pos, slots, blk))
     def _():
-        q = q_ref[0].astype(jnp.float32)
-        qbd = jnp.concatenate(
-            [jnp.where(own, q[i:i + 1, :], 0.0) for i in range(g)], axis=0)
-        s = _product(qbd, k_ref[0], ((1,), (1,))) * scale   # [R, blk]
+        qbd = q_ref[0].astype(jnp.float32)
+        if kp > 1:
+            qbd = jnp.concatenate(
+                [jnp.where(own, qbd[i:i + 1, :], 0.0) for i in range(g)],
+                axis=0)
+        kb = k_ref[0]
+        s = _product(qbd, kb, ((1,), (1,))) * scale         # [R, blk]
         # slot c holds position pos - age, age = (pos - c) mod slots
         col = j * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         at = jax.lax.rem(pos, slots)
@@ -109,17 +129,21 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         alpha = jnp.exp(m_old - m_new)
         p = jnp.exp(s - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + _product(
-            p, v_ref[0], ((1,), (0,)))
+        acc = alpha * acc_ref[...]
+        vb = kb[:, :acc.shape[1]] if v_ref is None else v_ref[0]
+        acc_ref[...] = acc + _product(p, vb, ((1,), (0,)))
         m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
         out = acc_ref[...] * (prob_scale / l_ref[...])
-        for i in range(g):
-            mine = jnp.where(own, out[i * kp:(i + 1) * kp], 0.0)
-            o_ref[0, i:i + 1, :] = jnp.sum(
-                mine, axis=0, keepdims=True).astype(o_ref.dtype)
+        if kp == 1:
+            o_ref[0] = out.astype(o_ref.dtype)
+        else:
+            for i in range(g):
+                mine = jnp.where(own, out[i * kp:(i + 1) * kp], 0.0)
+                o_ref[0, i:i + 1, :] = jnp.sum(
+                    mine, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def _last_live(pos, slots, blk):
@@ -129,39 +153,55 @@ def _last_live(pos, slots, blk):
 
 
 def attend(q, k, v, pos, *, num_kv_heads, scale, window=0, prob_scale=1.0,
-           interpret=False):
+           value_width=None, interpret=False):
     """q [B, nh * dh] (one token a sequence, at position `pos`, an int32
     scalar) over k, v [B, slots, nkv * dh] as stored -> [B, nh * dh] in
-    q's dtype. Query head n reads KV head n // (nh / nkv)."""
+    q's dtype. Query head n reads KV head n // (nh / nkv). Without `v`
+    the values are the leading `value_width` lanes of each KV head's key
+    row (a latent cache): one cache operand, each block fetched once for
+    both products, and the result is [B, nh * value_width]."""
     b, slots, hk = k.shape
     nkv = int(num_kv_heads)
     dh = hk // nkv
     g = q.shape[1] // hk
     blk = slot_block(slots, hk * k.dtype.itemsize, 32 // k.dtype.itemsize)
+    # lanes of the value product: one KV head's values are a prefix of
+    # the row; several heads' lie apart, so the whole row is multiplied
+    # and each head's leading lanes are kept below
+    wv = int(value_width) if v is None and nkv == 1 else hk
     # query head k * g + j -> row j, KV head k's lanes
     qj = q.reshape(b, nkv, g, dh).transpose(0, 2, 1, 3).reshape(b, g, hk)
     out = _call(
         jnp.reshape(pos, (1,)).astype(jnp.int32), qj, k, v, nkv=nkv,
-        blk=blk, window=int(window), scale=float(scale),
+        blk=blk, wv=wv, window=int(window), scale=float(scale),
         prob_scale=float(prob_scale), interpret=interpret)
-    return out.reshape(b, g, nkv, dh).transpose(0, 2, 1, 3).reshape(b, -1)
+    out = out.reshape(b, g, nkv, wv // nkv)
+    if v is None:
+        out = out[..., :int(value_width)]
+    return out.transpose(0, 2, 1, 3).reshape(b, -1)
 
 
 # The pallas_call sits in a jit of its own: a model's layers share shapes
 # and statics, so a decode step traces and lowers the kernel once for each
 # kind of layer, not once a layer (kernels/flash_tiled.py, PR 30)
 @functools.partial(jax.jit, static_argnames=(
-    "nkv", "blk", "window", "scale", "prob_scale", "interpret"))
-def _call(pos, qj, k, v, *, nkv, blk, window, scale, prob_scale, interpret):
+    "nkv", "blk", "wv", "window", "scale", "prob_scale", "interpret"))
+def _call(pos, qj, k, v, *, nkv, blk, wv, window, scale, prob_scale,
+          interpret):
     b, slots, hk = k.shape
     g = qj.shape[1]
-    kp = -(-nkv // 8) * 8
+    # one KV head: every query row reads the whole row, nothing to lay
+    # block-diagonally and no rows to pad
+    kp = 1 if nkv == 1 else -(-nkv // 8) * 8
 
     def cache_block(i, j, pos_ref):
         return i, jnp.minimum(j, _last_live(pos_ref[0], slots, blk)), 0
 
-    rows = pl.BlockSpec((1, g, hk), lambda i, j, pos_ref: (i, 0, 0))
+    def rows(width):
+        return pl.BlockSpec((1, g, width), lambda i, j, pos_ref: (i, 0, 0))
+
     cache = pl.BlockSpec((1, blk, hk), cache_block)
+    caches = (k,) if v is None else (k, v)
     return pl.pallas_call(
         functools.partial(
             _kernel, nkv=nkv, kp=kp, blk=blk, slots=slots, window=window,
@@ -170,16 +210,16 @@ def _call(pos, qj, k, v, *, nkv, blk, window, scale, prob_scale, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, slots // blk),
-            in_specs=[rows, cache, cache],
-            out_specs=rows,
+            in_specs=[rows(hk)] + [cache] * len(caches),
+            out_specs=rows(wv),
             scratch_shapes=[pltpu.VMEM((g * kp, 1), jnp.float32),
                             pltpu.VMEM((g * kp, 1), jnp.float32),
-                            pltpu.VMEM((g * kp, hk), jnp.float32)],
+                            pltpu.VMEM((g * kp, wv), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, g, hk), qj.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, wv), qj.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
-    )(pos, qj, k, v)
+    )(pos, qj, *caches)

@@ -161,9 +161,11 @@ def _state_var(name, shape, dtype):
                           persistable=True)
 
 
-def _expert_ffn(x, prefix, cfg):
+def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
     """Shared expert (every chip computes it) + this chip's routed part.
-    Returns (output, the op's `Selected` ids [B, T, k])."""
+    `route_attrs`: further attributes of `moe_local_experts` (a family's
+    group-limited selection). Returns (output, the op's `Selected` ids
+    [B, T, k])."""
     from ..framework import unique_name
     from ..framework.program import default_main_program
     from ..parallel.moe import MOE_COUNTERS
@@ -180,7 +182,7 @@ def _expert_ffn(x, prefix, cfg):
                        cfg, _normal(cfg))
     w_down = _param(f"{prefix}_experts_down_w", [e_local, f, h], cfg,
                     _normal(cfg))
-    counters = _state_var(COUNTERS_VAR, (len(MOE_COUNTERS),), "int32")
+    counters = _state_var(counters_var, (len(MOE_COUNTERS),), "int32")
     blk = default_main_program().global_block
     routed = blk.create_var(name=unique_name.generate(f"{prefix}_routed"),
                             shape=x.shape, dtype=x.dtype)
@@ -196,7 +198,8 @@ def _expert_ffn(x, prefix, cfg):
         {"Out": [routed.name], "Selected": [selected.name],
          "CountersOut": [counters.name]},
         {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
-         "route_norm": cfg.route_norm, "expert_offset": cfg.expert_offset},
+         "route_norm": cfg.route_norm, "expert_offset": cfg.expert_offset,
+         **route_attrs},
     )
     if cfg.num_shared_experts:
         routed = routed + _swiglu_ffn(

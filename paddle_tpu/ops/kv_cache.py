@@ -19,15 +19,23 @@ products cannot read the row layout without copying the whole cache a
 layer and token, so a decode step reads it through ONE Pallas kernel,
 ``kernels/decode_attention.py``, which contracts the whole lane width
 with the query laid block-diagonally and never takes a head's lanes
-apart. The minor dimension is a whole number of 128-lane tiles at the
-served widths (768 / 1024 / 256), so nothing is padded.
+apart. The minor dimension is a whole number of 128-lane tiles: the K
+and V widths served (768 / 1024 / 256) are, and a latent-attention
+layer's row (576 lanes of data: ``latent_cache_shape``) is padded to 640,
+because a minor dimension that is not whole tiles is not kept minor at
+all by the TPU's default layout. A layer has two cache operands (K and
+V) or ONE whose rows hold the values too (the latent kind: the values
+are the rows' leading lanes, ``value_lanes``).
 
 * ``kv_cache_write`` — write the current step's K/V rows into the cache at
   a runtime position (``jax.lax.dynamic_update_slice`` along the slot
   axis; the output aliases the cache input, which the Executor donates).
 * ``kv_cache_attention`` — masked attention of the step's queries over
   the cache, one path for every decoder: grouped query heads (``g = 1``
-  is GPT-2), causal, ring and window validity from ``attention_mask``.
+  is GPT-2, ONE KV head under 128 query heads the absorbed form of
+  latent attention), causal, ring and window validity from
+  ``attention_mask``; with no ``CacheV`` the values are lanes of the key
+  rows.
   One token a sequence on the TPU is the kernel; several (rows appended
   to a cache that holds earlier ones) and the CPU take the same attention
   in `jnp` (``grouped_attention``). The gauge ``kernels.decode_attention.calls``
@@ -63,6 +71,21 @@ def cache_shape(batch, max_len, num_heads, head_dim, window=0):
     (serving/generate.py) both ask here."""
     slots = min(int(max_len), int(window)) if window else int(max_len)
     return (int(batch), slots, int(num_heads) * int(head_dim))
+
+
+LANES = 128
+
+
+def latent_cache_shape(batch, max_len, width):
+    """Stored shape of ONE latent-attention layer's only cache:
+    ``[B, max_len, lanes]``, a position's `width` lanes (the normed
+    latent, then the rotated shared key part) in a row padded with
+    zeros to whole 128-lane tiles. A row that is not whole tiles is not
+    stored as a row at all: compiled for a v5e, a ``[64, 1024, 576]``
+    array gets the SLOTS as its minor dimension and every kernel call a
+    cache-sized transposing copy (PERF.md, Findings PR 33); 640 lanes
+    are read in place."""
+    return (int(batch), int(max_len), -(-int(width) // LANES) * LANES)
 
 
 def ssm_state_shape(batch, num_heads, head_dim, state_size, num_groups):
@@ -103,9 +126,11 @@ def attention_mask(qpos, slots, window=0):
 
 
 def grouped_attention(q, k, v, valid, num_kv_heads, scale, prob_scale=1.0):
-    """q [B, T, nh * dh] over k, v [B, S, nkv * dh] (a cache as stored,
-    or a call's own rows): query head n reads KV head n // (nh / nkv),
-    so a KV head is never repeated in HBM. `valid` [T, S]. Scores and
+    """q [B, T, nh * dh] over k [B, S, nkv * dh] and v [B, S, nkv * dv]
+    (a cache as stored, or a call's own rows; `dv` read from `v`, the
+    result is [B, T, nh * dv]): query head n reads KV head
+    n // (nh / nkv), so a KV head is never repeated in HBM. `valid`
+    [T, S]. Scores and
     softmax in float32; `prob_scale` is the inference residue of fluid's
     downgrade_in_infer attention dropout (probabilities scale by
     1 - dropout_prob, so that cached decode matches the training graph's
@@ -115,14 +140,22 @@ def grouped_attention(q, k, v, valid, num_kv_heads, scale, prob_scale=1.0):
     dh = hk // num_kv_heads
     qh = q.reshape(b, t, num_kv_heads, h // hk, dh)
     kh = k.reshape(b, s, num_kv_heads, dh)
-    vh = v.reshape(b, s, num_kv_heads, dh)
+    vh = v.reshape(b, s, num_kv_heads, -1)
     scores = einsum_f32("btkgd,bskd->bkgts", qh, kh) * scale
     scores = jnp.where(valid[None, None, None], scores, jnp.float32(-1e9))
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     if prob_scale != 1.0:
         probs = probs * jnp.asarray(prob_scale, q.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, vh)
-    return out.reshape(b, t, h)
+    return out.reshape(b, t, -1)
+
+
+def value_lanes(k, num_kv_heads, value_width):
+    """The values of a cache that keeps them inside its key rows (a
+    latent cache): the leading `value_width` lanes of each KV head."""
+    b, s, _hk = k.shape
+    return k.reshape(b, s, num_kv_heads, -1)[..., :value_width].reshape(
+        b, s, num_kv_heads * value_width)
 
 
 def _pos_scalar(pos):
@@ -181,12 +214,16 @@ def _kv_cache_attention(ctx, op, ins):
     """`Pos` is the cache position of the LAST query row; query row i
     sits at position Pos - (T - 1) + i and may read what `attention_mask`
     says (causal within a prefill, the slots written so far in decode;
-    later slots hold garbage or future rows)."""
+    later slots hold garbage or future rows). A layer with no `CacheV`
+    keeps its values inside `CacheK`, the leading `value_width` lanes of
+    each KV head's row (`value_lanes`); Out is then
+    [B, T, nh * value_width]."""
     from .. import observability as _obs
 
     q = ins["Q"][0]  # [B, T, nh * dh]
     k = ins["CacheK"][0]  # [B, slots, nkv * dh]
-    v = ins["CacheV"][0]
+    v = (ins.get("CacheV") or [None])[0]
+    width = op.attr("value_width", None)
     pos = _pos_scalar(ins["Pos"][0])
     nh = int(op.attr("num_heads"))
     kvh, window = int(op.attr("num_kv_heads", nh)), int(op.attr("window", 0))
@@ -196,10 +233,12 @@ def _kv_cache_attention(ctx, op, ins):
     if t > 1:
         qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
         valid = attention_mask(qpos, k.shape[1], window)
+        if v is None:
+            v = value_lanes(k, kvh, width)
         return {"Out": [grouped_attention(q, k, v, valid, kvh, scale,
                                           prob_scale)]}
     out, kernel = decode_attention(q[:, 0], k, v, pos, kvh, scale, window,
-                                   prob_scale)
+                                   prob_scale, width)
     if ctx is not None and not ctx.abstract:
         # one EmitContext a lowered step: the last call leaves the count
         ctx.decode_attention_calls = kernel + getattr(
@@ -210,19 +249,23 @@ def _kv_cache_attention(ctx, op, ins):
 
 
 def decode_attention(q, k, v, pos, num_kv_heads, scale, window=0,
-                     prob_scale=1.0, interpret=False):
+                     prob_scale=1.0, value_width=None, interpret=False):
     """One token a sequence, q [B, nh * dh] at position `pos`, over the
-    caches as stored: on the TPU (and with `interpret`) the Pallas kernel
-    (kernels/decode_attention.py), elsewhere the same in `jnp`. Returns
-    (out [B, nh * dh], whether the kernel ran)."""
+    caches as stored (`v` None: the values are `value_lanes` of `k`, and
+    the kernel takes the one array): on the TPU (and with `interpret`)
+    the Pallas kernel (kernels/decode_attention.py), elsewhere the same
+    in `jnp`. Returns (out [B, nh * dv], whether the kernel ran)."""
     kernel = interpret or jax.default_backend() == "tpu"
     if kernel:
         from ..kernels import decode_attention as _kernel
 
         return _kernel.attend(
             q, k, v, pos, num_kv_heads=num_kv_heads, scale=scale,
-            window=window, prob_scale=prob_scale, interpret=interpret,
+            window=window, prob_scale=prob_scale, value_width=value_width,
+            interpret=interpret,
         ), True
+    if v is None:
+        v = value_lanes(k, num_kv_heads, value_width)
     valid = attention_mask(pos[None], k.shape[1], window)
     return grouped_attention(q[:, None], k, v, valid, num_kv_heads, scale,
                              prob_scale)[:, 0], False

@@ -1,5 +1,8 @@
-"""Ops of today's decoder blocks: RMSNorm, rotary positions, SwiGLU and
-causal grouped-query attention over one call's own keys.
+"""Ops of today's decoder blocks: RMSNorm, rotary positions (whole or
+part of a head, plain or YaRN frequencies), SwiGLU, causal grouped-query
+attention over one call's own keys, and the two forms of multi-head
+latent attention's up-projection: expanded to keys and values for a
+prefill, absorbed into the query and the output for a decode step.
 
 Each is one emitter (so one `jax.named_scope` in the compiled step) and
 keeps its statistics in float32 whatever the activations' dtype: a
@@ -13,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
+from ._helpers import einsum_f32
 from .kv_cache import _pos_scalar, attention_mask, grouped_attention
 
 
@@ -31,20 +35,69 @@ def _rms_norm(ctx, op, ins):
     return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
 
 
-def rotary(x, first_pos, head_dim, theta):
-    """Rotate-half rotary positions over each head's whole width:
-    x [B, T, heads * head_dim], row i at position `first_pos + i`."""
+def yarn_ramp(rotary_dim, theta, yarn):
+    """YaRN's blend of each rotary frequency, [rotary_dim / 2] in [0, 1]
+    (0: the frequency as it is, 1: divided by `factor`), and where it
+    turns: pair i makes ``d^-1(i)`` rotations over the original context,
+    d(r) = dim ln(original / (2 pi r)) / (2 ln theta); pairs below
+    ``low = floor(d(beta_fast))`` keep their frequency, pairs above
+    ``high = ceil(d(beta_slow))`` are interpolated, between them a
+    linear ramp. Returns (ramp, low, high)."""
+    import math
+
+    import numpy as np
+
+    def pair_of(rotations):
+        return rotary_dim * math.log(
+            yarn["original_max_position_embeddings"]
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(yarn["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(yarn["beta_slow"])), rotary_dim - 1)
+    span = max(high - low, 0.001)
+    i = np.arange(rotary_dim // 2, dtype=np.float32)
+    return np.clip((i - low) / span, 0.0, 1.0), low, high
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature for a context stretched `factor`
+    times: 0.1 mscale ln(factor) + 1 (1 where nothing is stretched)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None):
+    """Rotate-half rotary positions: x [B, T, heads * head_dim], row i
+    at position `first_pos + i`. The LAST `rotary_dim` lanes of each
+    head are rotated (all of them by default), the lanes before pass.
+    `yarn` (factor, original_max_position_embeddings, beta_fast,
+    beta_slow, mscale, mscale_all_dim) blends the frequencies as
+    `yarn_ramp` says and scales cos and sin by the ratio of the two
+    temperatures."""
     b, t, h = x.shape
-    half = head_dim // 2
+    rot = head_dim if rotary_dim is None else int(rotary_dim)
+    half = rot // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
-                         / head_dim)
+                         / rot)
+    scale = 1.0
+    if yarn:
+        ramp = jnp.asarray(yarn_ramp(rot, theta, yarn)[0])
+        inv_freq = inv_freq * (1.0 - ramp) + inv_freq / yarn["factor"] * ramp
+        scale = yarn_mscale(yarn["factor"], yarn.get("mscale", 1.0)) \
+            / yarn_mscale(yarn["factor"], yarn.get("mscale_all_dim", 0.0))
     pos = (first_pos + jnp.arange(t, dtype=jnp.int32)).astype(jnp.float32)
     angle = pos[:, None] * inv_freq[None, :]          # [T, half]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32).reshape(b, t, h // head_dim, head_dim)
-    x1, x2 = xf[..., :half], xf[..., half:]
+    turned = xf if rot == head_dim else xf[..., head_dim - rot:]
+    x1, x2 = turned[..., :half], turned[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    if rot != head_dim:
+        out = jnp.concatenate([xf[..., :head_dim - rot], out], -1)
     return out.reshape(b, t, h).astype(x.dtype)
 
 
@@ -52,12 +105,15 @@ def rotary(x, first_pos, head_dim, theta):
              differentiable=False)
 def _rotary_embedding(ctx, op, ins):
     """`Pos` is the position of the LAST row (as `kv_cache_attention`
-    has it), a runtime value."""
+    has it), a runtime value. `rotary_dim` and `yarn` as `rotary` takes
+    them; absent, the whole head turns at the plain frequencies."""
     x = ins["X"][0]
     last = _pos_scalar(ins["Pos"][0])
     return {"Out": [rotary(x, last - (x.shape[1] - 1),
                            int(op.attr("head_dim")),
-                           float(op.attr("theta", 10000.0)))]}
+                           float(op.attr("theta", 10000.0)),
+                           op.attr("rotary_dim", None),
+                           op.attr("yarn", None))]}
 
 
 @register_op("swiglu", inputs=["X"], outputs=["Out"], differentiable=False)
@@ -97,16 +153,18 @@ def _query_block(batch, heads, seq):
              differentiable=False)
 def _causal_gqa_attention(ctx, op, ins):
     """Prefill attention over the call's own rows: Q [B, S, nh * dh]
-    against K, V [B, S, nkv * dh], query head n on KV head
-    n // (nh / nkv), causal, and with `window` > 0 only keys closer than
-    it. Plain products in blocks of (rows, queries); softmax in float32;
-    `prob_scale` as `kv_cache_attention` has it."""
+    against K [B, S, nkv * dh] and V [B, S, nkv * dv] (a value head may
+    be narrower than a key head; Out is [B, S, nh * dv]), query head n
+    on KV head n // (nh / nkv), causal, and with `window` > 0 only keys
+    closer than it. Plain products in blocks of (rows, queries); softmax
+    in float32; `prob_scale` as `kv_cache_attention` has it."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     nh, kvh = int(op.attr("num_heads")), int(op.attr("num_kv_heads"))
     window = int(op.attr("window", 0))
     scale = float(op.attr("scale", 1.0))
     prob_scale = float(op.attr("prob_scale", 1.0))
     b, s, h = q.shape
+    hv = v.shape[-1] // kvh * nh
     rows, queries = _query_block(b, nh, s)
     nb, nq = b // rows, s // queries
 
@@ -123,5 +181,58 @@ def _causal_gqa_attention(ctx, op, ins):
         out = block(jnp.int32(0))
     else:
         out = jax.lax.map(block, jnp.arange(nb * nq, dtype=jnp.int32))
-        out = out.reshape(nb, nq, rows, queries, h).transpose(0, 2, 1, 3, 4)
-    return {"Out": [out.reshape(b, s, h)]}
+        out = out.reshape(nb, nq, rows, queries, hv).transpose(0, 2, 1, 3, 4)
+    return {"Out": [out.reshape(b, s, hv)]}
+
+
+@register_op("mla_expand", inputs=["Latent", "KPe", "WKVB"],
+             outputs=["K", "V"], differentiable=False)
+def _mla_expand(ctx, op, ins):
+    """Latent attention's prefill form. `WKVB` [nh, r, dn + dv] is the
+    stored up-projection, a head's key part W_UK [r, dn] beside its
+    value part W_UV [r, dv] (head-major, so that the decode form's two
+    products below read their halves in place). `Latent` [B, S, r]
+    (normed) times the whole of it gives every head its non-rotary key
+    part and its values; `KPe` [B, S, dr] (rotated) is the ONE rotary
+    key part all heads share. K [B, S, nh * (dn + dr)], a head
+    [k_nope | k_pe], and V [B, S, nh * dv], for `causal_gqa_attention`."""
+    c, k_pe, w = ins["Latent"][0], ins["KPe"][0], ins["WKVB"][0]
+    nh, dn = w.shape[0], int(op.attr("nope_dim"))
+    b, s, _r = c.shape
+    kv = einsum_f32("bsr,hrd->bshd", c, w).astype(c.dtype)
+    shared = jnp.broadcast_to(k_pe[:, :, None, :],
+                              (b, s, nh, k_pe.shape[-1]))
+    k = jnp.concatenate([kv[..., :dn], shared], -1)
+    return {"K": [k.reshape(b, s, -1)],
+            "V": [kv[..., dn:].reshape(b, s, -1)]}
+
+
+@register_op("mla_absorb_query", inputs=["Q", "WKVB"], outputs=["Out"],
+             differentiable=False)
+def _mla_absorb_query(ctx, op, ins):
+    """The decode form's query: Q [B, T, nh * (dn + dr)], a head
+    [q_nope | q_pe], becomes [q_nope W_UK^T | q_pe | 0] of `row_width`
+    lanes a head, which scores against a latent cache's rows
+    [c_kv | k_pe | 0] as q_nope . (c_kv W_UK) + q_pe . k_pe does."""
+    q, w = ins["Q"][0], ins["WKVB"][0]
+    nh, dn = w.shape[0], int(op.attr("nope_dim"))
+    b, t, _h = q.shape
+    qh = q.reshape(b, t, nh, -1)
+    q_lat = einsum_f32("bthd,hrd->bthr", qh[..., :dn], w[..., :dn])
+    parts = [q_lat.astype(q.dtype), qh[..., dn:]]
+    pad = int(op.attr("row_width")) - sum(p.shape[-1] for p in parts)
+    if pad:
+        parts.append(jnp.zeros((b, t, nh, pad), q.dtype))
+    return {"Out": [jnp.concatenate(parts, -1).reshape(b, t, -1)]}
+
+
+@register_op("mla_absorb_output", inputs=["X", "WKVB"], outputs=["Out"],
+             differentiable=False)
+def _mla_absorb_output(ctx, op, ins):
+    """The decode form's output: X [B, T, nh * r], a head's
+    probability-weighted sum of latents, times W_UV -> [B, T, nh * dv]."""
+    x, w = ins["X"][0], ins["WKVB"][0]
+    nh, dn = w.shape[0], int(op.attr("nope_dim"))
+    b, t, _h = x.shape
+    out = einsum_f32("bthr,hrd->bthd", x.reshape(b, t, nh, -1), w[..., dn:])
+    return {"Out": [out.astype(x.dtype).reshape(b, t, -1)]}

@@ -147,18 +147,29 @@ MOE_COUNTERS = ("assignments_local", "assignments_total", "experts_hit",
 
 
 def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
-                       route_norm=True):
+                       route_norm=True, n_group=1, topk_group=1):
     """tokens [T, H] -> (selected [T, k] int32 over all experts, weights
     [T, k] float32). Scores are sigmoids in float32 (a product of
     bfloat16 operands accumulated in float32 is exact); `expert_bias`
     moves the selection only; the weights are the selected scores over
     their sum (over all k, wherever those experts live), times
-    `route_scale`."""
+    `route_scale`. With `n_group` > 1 the selection is group-limited:
+    the experts lie in `n_group` runs of consecutive ids, a run scores
+    the sum of its two largest biased scores, the `topk_group` best runs
+    are kept and every other expert's biased score is set to 0 before
+    the top-k."""
     from ..ops._helpers import einsum_f32
 
     logits = einsum_f32("th,he->te", tokens, router_w)
     scores = jax.nn.sigmoid(logits)
-    _, sel = lax.top_k(scores + expert_bias.astype(jnp.float32), top_k)
+    biased = scores + expert_bias.astype(jnp.float32)
+    if n_group > 1:
+        runs = biased.reshape(biased.shape[0], n_group, -1)
+        run_score = jnp.sum(lax.top_k(runs, 2)[0], axis=-1)     # [T, G]
+        _, kept = lax.top_k(run_score, topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+        biased = jnp.where(keep[:, :, None], runs, 0.0).reshape(biased.shape)
+    _, sel = lax.top_k(biased, top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if route_norm:
         w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -188,12 +199,14 @@ def _expert_activation(name):
 
 def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
                       top_k, route_scale, expert_offset, route_norm=True,
-                      activation="swiglu", router_x=None, interpret=False):
+                      activation="swiglu", router_x=None, n_group=1,
+                      topk_group=1, interpret=False):
     """The routed part of an expert layer that THIS chip's experts give.
 
     x [B, T, K], K the width the experts work at; router_w [H, E] over
     all E experts, scored on `router_x` [B, T, H] (x itself where the
-    experts work at the hidden width); w_gate_up [E_local, K, 2F]
+    experts work at the hidden width), `n_group` / `topk_group` as
+    `sigmoid_topk_route` takes them; w_gate_up [E_local, K, 2F]
     (`swiglu`) or [E_local, K, F] (`relu2`), w_down [E_local, F, K]:
     experts `expert_offset` .. `expert_offset + E_local - 1`. Every
     token is scored against all E, its top-k chosen, and the assignments
@@ -212,7 +225,8 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     n_tok = b * t
     scored = tokens if router_x is None else router_x.reshape(n_tok, -1)
     sel, weights = sigmoid_topk_route(
-        scored, router_w, expert_bias, top_k, route_scale, route_norm
+        scored, router_w, expert_bias, top_k, route_scale, route_norm,
+        n_group, topk_group,
     )
     local = sel - expert_offset
     is_local = (local >= 0) & (local < n_local)
@@ -287,7 +301,8 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
 def _moe_local_experts_op(ctx, op, ins):
     """`activation` ("swiglu" by default) names the experts' form;
     `RouterX`, where given, is what the router scores (experts that work
-    in a latent read `X`, the router the hidden state)."""
+    in a latent read `X`, the router the hidden state); `n_group` and
+    `topk_group` (1 by default) limit a token to its best groups."""
     x, router_w, bias, wgu, wd, counters = (
         ins[k][0] for k in ("X", "RouterW", "ExpertBias", "WGateUp", "WDown",
                             "Counters")
@@ -300,6 +315,8 @@ def _moe_local_experts_op(ctx, op, ins):
         expert_offset=int(op.attr("expert_offset", 0)),
         activation=op.attr("activation", "swiglu"),
         router_x=(ins.get("RouterX") or [None])[0],
+        n_group=int(op.attr("n_group", 1)),
+        topk_group=int(op.attr("topk_group", 1)),
     )
     most = jnp.max(counts)
     local, hit = jnp.sum(counts), jnp.sum(counts > 0).astype(jnp.int32)

@@ -2,8 +2,9 @@
 
 ``GPTGenerator`` owns the two programs a decoder (``models/gpt.py``'s
 ``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``,
-``models/nemotron_h.py``'s ``NemotronHDecoder``) splits itself into and
-the Scope their state persistables share:
+``models/nemotron_h.py``'s ``NemotronHDecoder``, ``models/dots_vlm.py``'s
+``DotsVlmDecoder``) splits itself into and the Scope their state
+persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
   ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
@@ -23,10 +24,15 @@ sliding-window layer) for the graphs and, through the decoder's
 to read of them (the slots a query may see, once) is counted on the host
 from the positions fed: ``kv_cache.decode_bytes_needed`` over
 ``kv_cache.decode_steps``. Per-sequence state
-need not be a KV cache: a state-space block carries a recurrent state
+need not be a K and a V cache: a latent-attention layer keeps ONE cache
+whose rows are neither (``latent_cache_shape``: the key/value latent
+beside the shared rotary key part, padded to whole lane tiles; counted
+once a layer, by the lanes that carry data, which the decoder's
+``cache_lanes`` gives); a state-space block carries a recurrent state
 and a convolution tail whose shapes (``ssm_state_shape``,
-``conv_tail_shape``, the same owner) do not depend on ``max_len``; the
-decoder's ``cache_kind`` names each piece's kind for the
+``conv_tail_shape``, the same owner) do not depend on ``max_len``. The
+decoder's ``cache_kind`` names each piece's kind (``full``, ``window``,
+``latent``, ``ssm``, ``conv``) for the
 ``kv_cache.bytes.<kind>`` gauges, and a prefill that takes a block of
 the batch's rows writes those rows' FINAL state into the batch's arrays
 as it writes their keys and values. A decoder is handed
@@ -151,18 +157,23 @@ class GPTGenerator:
             (name, shape, "int64") for name, shape in self._token_vars()
         ]
         by_kind = {}
-        # (slots, bytes a slot) of every K and V cache: what a decode
-        # step at a position needs to read (`_decode_bytes_needed`)
+        # (slots, bytes a slot) of every cache a decode step attends
+        # over, kinds "full", "window" (a K and a V array each) and
+        # "latent" (one array a layer): what a step at a position needs
+        # to read (`_decode_bytes_needed`). A decoder whose stored rows
+        # are padded says how many lanes carry data (`cache_lanes`).
         self._kv_slots = []
+        lanes_of = getattr(decoder, "cache_lanes", lambda name: None)
         for name, shape, dtype in specs:
             kind = decoder.cache_kind(name)
             if kind:
-                nbytes = int(
-                    np.prod(shape) * np.dtype(to_numpy_dtype(dtype)).itemsize
-                )
+                itemsize = np.dtype(to_numpy_dtype(dtype)).itemsize
+                nbytes = int(np.prod(shape) * itemsize)
                 by_kind[kind] = by_kind.get(kind, 0) + nbytes
-                if kind in ("full", "window"):
-                    self._kv_slots.append((shape[1], nbytes // shape[1]))
+                if kind in ("full", "window", "latent"):
+                    lanes = lanes_of(name) or shape[2]
+                    self._kv_slots.append(
+                        (shape[1], shape[0] * lanes * itemsize))
         for kind, nbytes in by_kind.items():
             _obs.set_gauge(f"kv_cache.bytes.{kind}", nbytes)
         _obs.set_table("serving.generate.model", {
@@ -280,9 +291,10 @@ class GPTGenerator:
                     _obs.add(name, value)
 
     def _decode_bytes_needed(self, steps):
-        """Bytes of K and V the first `steps` decode steps of a batch need
-        to read: at position p a query may see ``min(p + 1, slots)`` slots
-        of a cache (a ring's window is its slots), each once."""
+        """Bytes of the attention caches (K and V, or a latent layer's
+        one) the first `steps` decode steps of a batch need to read: at
+        position p a query may see ``min(p + 1, slots)`` slots of a cache
+        (a ring's window is its slots), each once."""
         if not self._kv_slots:
             return 0
         slots, slot_bytes = np.array(self._kv_slots).T

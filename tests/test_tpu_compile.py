@@ -180,6 +180,21 @@ def _decode_attention(dtype, heads, kv_heads, head_dim, window=0, batch=64,
         window=window, prob_scale=0.9), specs),
 
 
+def _latent_attention(heads=128, width=576, value_width=512, batch=64,
+                      max_len=1024):
+    """A decode step's absorbed attention over one layer's latent cache
+    as stored (`latent_cache_shape`: whole lane tiles), the values the
+    rows' leading lanes: one cache operand."""
+    from paddle_tpu.ops.kv_cache import latent_cache_shape
+
+    cache = latent_cache_shape(batch, max_len, width)
+    specs = (((batch, heads * cache[2]), BF16), (cache, BF16),
+             ((), jnp.int32))
+    return (lambda q, k, pos: decode_attention.attend(
+        q, k, None, pos, num_kv_heads=1, scale=0.1,
+        value_width=value_width), specs),
+
+
 def _cases():
     cases = {}
 
@@ -254,6 +269,20 @@ def _cases():
     add("corner-decode_attention-trinity-s16384-bf16",
         _decode_attention(BF16, 48, 8, 128, batch=4, max_len=16384),
         ("fwd",))
+    # generate phase of dots_vlm1_ep16: the absorbed decode step's
+    # attention, 128 heads over ONE latent cache whose rows hold the
+    # values (512 of 640 stored lanes), at the cell's 1,024 slots and at
+    # a context of several blocks; and its expert products at K = 7168
+    # over 16 experts (a step's 64 x 8 assignments in tiles of 16, a
+    # prefill block's 8 x 896 x 8 in tiles of 256)
+    add("decode_attention-latent-128over1x640-bf16", _latent_attention(),
+        ("fwd",))
+    add("corner-decode_attention-latent-s16384-bf16",
+        _latent_attention(batch=4, max_len=16384), ("fwd",))
+    for rows, tm in ((768, 16), (61440, 256)):
+        for k, n in ((7168, 4096), (2048, 7168)):
+            add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
+                _gmm(rows, tm, k, n, experts=16), ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
