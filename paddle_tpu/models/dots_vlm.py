@@ -12,9 +12,11 @@ lane tiles (`ops/kv_cache.py::latent_cache_shape`). The up-projection
 stored parameter, `[heads, r, dn + dv]` (a head's W_UK beside its W_UV),
 used in two forms (`ops/llm.py`):
 
-* the prefill EXPANDS: `[k_nope | v] = c_kv W_kvb` for all heads, a head's
-  key is `[k_nope | k_pe]` (192 lanes) and its value 128, and the block
-  attends over its own rows (`causal_gqa_attention`);
+* the prefill EXPANDS: `k_nope = c_kv W_UK`, `v = c_kv W_UV` for all
+  heads (`mla_expand`), a head's key is `[k_nope | k_pe]` (192 lanes,
+  the last 64 the ONE rotary part all heads share, handed over apart as
+  `KShared` and never copied into the heads) and its value 128, and the
+  block attends over its own rows (`causal_gqa_attention`);
 * the decode step ABSORBS: `q_lat[h] = q_nope[h] W_UK[h]^T` scores
   against the cached latents themselves, the probabilities sum the
   latents, and `o[h] = o_lat[h] W_UV[h]`: all heads read the one cached
@@ -200,11 +202,12 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
         _write_cache(cache, row, first, row_ids, ring=True)
         k, v = _simple(
             "mla_expand",
-            {"Latent": [c_kv], "KPe": [k_pe], "WKVB": [w_kvb]}, split,
+            {"Latent": [c_kv], "WKVB": [w_kvb]}, split,
             out_slots=("K", "V"),
         )
         out = _simple(
-            "causal_gqa_attention", {"Q": [q], "K": [k], "V": [v]},
+            "causal_gqa_attention",
+            {"Q": [q], "K": [k], "V": [v], "KShared": [k_pe]},
             {"num_heads": nh, "num_kv_heads": nh, "window": 0,
              "scale": cfg.softmax_scale},
         )

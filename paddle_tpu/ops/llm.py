@@ -1,8 +1,9 @@
 """Ops of today's decoder blocks: RMSNorm, rotary positions (whole or
 part of a head, plain or YaRN frequencies), SwiGLU, causal grouped-query
-attention over one call's own keys, and the two forms of multi-head
-latent attention's up-projection: expanded to keys and values for a
-prefill, absorbed into the query and the output for a decode step.
+attention over one call's own keys (on the TPU one Pallas kernel,
+kernels/prefill_attention.py), and the two forms of multi-head latent
+attention's up-projection: expanded to keys and values for a prefill,
+absorbed into the query and the output for a decode step.
 
 Each is one emitter (so one `jax.named_scope` in the compiled step) and
 keeps its statistics in float32 whatever the activations' dtype: a
@@ -149,20 +150,69 @@ def _query_block(batch, heads, seq):
     return 1, queries
 
 
-@register_op("causal_gqa_attention", inputs=["Q", "K", "V"], outputs=["Out"],
-             differentiable=False)
+@register_op("causal_gqa_attention", inputs=["Q", "K", "V", "KShared"],
+             outputs=["Out"], differentiable=False)
 def _causal_gqa_attention(ctx, op, ins):
     """Prefill attention over the call's own rows: Q [B, S, nh * dh]
     against K [B, S, nkv * dh] and V [B, S, nkv * dv] (a value head may
     be narrower than a key head; Out is [B, S, nh * dv]), query head n
     on KV head n // (nh / nkv), causal, and with `window` > 0 only keys
-    closer than it. Plain products in blocks of (rows, queries); softmax
-    in float32; `prob_scale` as `kv_cache_attention` has it."""
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    nh, kvh = int(op.attr("num_heads")), int(op.attr("num_kv_heads"))
-    window = int(op.attr("window", 0))
-    scale = float(op.attr("scale", 1.0))
-    prob_scale = float(op.attr("prob_scale", 1.0))
+    closer than it; softmax in float32; `prob_scale` as
+    `kv_cache_attention` has it. With `KShared` [B, S, ds], K holds a key
+    head's own lanes only and every head's key is [its own | KShared]
+    (`mla_expand`). The gauge `kernels.prefill_attention.calls` is the
+    count of kernel calls in the prefill lowered last (0: the `jnp` path
+    ran)."""
+    from .. import observability as _obs
+
+    out, kernel = prefill_attention(
+        ins["Q"][0], ins["K"][0], ins["V"][0], int(op.attr("num_heads")),
+        int(op.attr("num_kv_heads")), float(op.attr("scale", 1.0)),
+        int(op.attr("window", 0)), float(op.attr("prob_scale", 1.0)),
+        (ins.get("KShared") or [None])[0])
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered prefill: the last call leaves the count
+        ctx.prefill_attention_calls = kernel + getattr(
+            ctx, "prefill_attention_calls", 0)
+        _obs.set_gauge("kernels.prefill_attention.calls",
+                       ctx.prefill_attention_calls)
+    return {"Out": [out]}
+
+
+def prefill_attention(q, k, v, num_heads, num_kv_heads, scale, window=0,
+                      prob_scale=1.0, k_shared=None, interpret=False):
+    """Causal attention of a call's own rows: on the TPU (and with
+    `interpret`) the Pallas kernel (kernels/prefill_attention.py) for the
+    calls it takes (`supports`: no window that binds, S in blocks of 128,
+    heads of whole half lane tiles, float32 or bfloat16), else the same
+    in plain products over blocks of (rows, queries), the shared key
+    part copied into every head first. Returns
+    (out [B, S, nh * dv], whether the kernel ran)."""
+    from ..kernels import prefill_attention as _kernel
+
+    b, s, _ = q.shape
+    shared = 0 if k_shared is None else k_shared.shape[2]
+    if (interpret or jax.default_backend() == "tpu") and _kernel.supports(
+            s, num_heads, num_kv_heads, q.shape[2] // num_heads,
+            v.shape[2] // num_kv_heads, q.dtype, window, shared):
+        return _kernel.attend(
+            q, k, v, num_heads=num_heads, num_kv_heads=num_kv_heads,
+            scale=scale, prob_scale=prob_scale, k_shared=k_shared,
+            interpret=interpret), True
+    if shared:
+        k = jnp.concatenate([
+            k.reshape(b, s, num_kv_heads, -1),
+            jnp.broadcast_to(k_shared[:, :, None, :],
+                             (b, s, num_kv_heads, shared))], -1)
+        k = k.reshape(b, s, -1)
+    return _blocked_attention(q, k, v, num_heads, num_kv_heads, scale,
+                              window, prob_scale), False
+
+
+def _blocked_attention(q, k, v, nh, kvh, scale, window, prob_scale):
+    """`grouped_attention` in blocks of (rows, queries) walked by
+    `jax.lax.map`, so that no block holds more than `SCORE_BLOCK_BYTES`
+    of float32 scores."""
     b, s, h = q.shape
     hv = v.shape[-1] // kvh * nh
     rows, queries = _query_block(b, nh, s)
@@ -182,29 +232,33 @@ def _causal_gqa_attention(ctx, op, ins):
     else:
         out = jax.lax.map(block, jnp.arange(nb * nq, dtype=jnp.int32))
         out = out.reshape(nb, nq, rows, queries, hv).transpose(0, 2, 1, 3, 4)
-    return {"Out": [out.reshape(b, s, hv)]}
+    return out.reshape(b, s, hv)
 
 
-@register_op("mla_expand", inputs=["Latent", "KPe", "WKVB"],
-             outputs=["K", "V"], differentiable=False)
+@register_op("mla_expand", inputs=["Latent", "WKVB"], outputs=["K", "V"],
+             differentiable=False)
 def _mla_expand(ctx, op, ins):
     """Latent attention's prefill form. `WKVB` [nh, r, dn + dv] is the
     stored up-projection, a head's key part W_UK [r, dn] beside its
     value part W_UV [r, dv] (head-major, so that the decode form's two
     products below read their halves in place). `Latent` [B, S, r]
-    (normed) times the whole of it gives every head its non-rotary key
-    part and its values; `KPe` [B, S, dr] (rotated) is the ONE rotary
-    key part all heads share. K [B, S, nh * (dn + dr)], a head
-    [k_nope | k_pe], and V [B, S, nh * dv], for `causal_gqa_attention`."""
-    c, k_pe, w = ins["Latent"][0], ins["KPe"][0], ins["WKVB"][0]
-    nh, dn = w.shape[0], int(op.attr("nope_dim"))
-    b, s, _r = c.shape
-    kv = einsum_f32("bsr,hrd->bshd", c, w).astype(c.dtype)
-    shared = jnp.broadcast_to(k_pe[:, :, None, :],
-                              (b, s, nh, k_pe.shape[-1]))
-    k = jnp.concatenate([kv[..., :dn], shared], -1)
-    return {"K": [k.reshape(b, s, -1)],
-            "V": [kv[..., dn:].reshape(b, s, -1)]}
+    (normed) times each part, laid [r, nh * d] (a copy of the weight, not
+    of an activation), gives every head its non-rotary key part,
+    K [B, S, nh * dn], and its values, V [B, S, nh * dv], as two plain
+    products whose results are rows as `causal_gqa_attention`'s kernel
+    reads them. The ONE rotary key part all heads share goes to that op
+    beside them (`KShared`), never copied into the heads: one product
+    into [B, S, nh, dn + dv], cut and joined with it, came out
+    sequence-minor on the TPU and cost the kernel three operand-sized
+    copies a layer (PERF.md, Findings PR 34)."""
+    c, w = ins["Latent"][0], ins["WKVB"][0]
+    r, dn = w.shape[1], int(op.attr("nope_dim"))
+
+    def expand(part):
+        flat = part.transpose(1, 0, 2).reshape(r, -1)
+        return einsum_f32("bsr,rn->bsn", c, flat).astype(c.dtype)
+
+    return {"K": [expand(w[..., :dn])], "V": [expand(w[..., dn:])]}
 
 
 @register_op("mla_absorb_query", inputs=["Q", "WKVB"], outputs=["Out"],

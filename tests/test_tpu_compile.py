@@ -33,6 +33,7 @@ from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
 from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
 from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
 from paddle_tpu.kernels import moe_gmm  # noqa: E402
+from paddle_tpu.kernels import prefill_attention  # noqa: E402
 from paddle_tpu.kernels import ring_block as rb  # noqa: E402
 from paddle_tpu.kernels import ssm_update  # noqa: E402
 
@@ -195,6 +196,27 @@ def _latent_attention(heads=128, width=576, value_width=512, batch=64,
         value_width=value_width), specs),
 
 
+def _prefill_attention(dtype, heads, kv_heads, key_dim, value_dim, rows,
+                       seq=896, shared=0):
+    """A prefill dispatch's attention over its own rows as the op hands
+    them over: `key_dim` lanes a query head, of which the last `shared`
+    are scored against one key part every head shares."""
+    assert prefill_attention.supports(seq, heads, kv_heads, key_dim,
+                                      value_dim, dtype, 0, shared)
+    specs = [((rows, seq, heads * key_dim), dtype),
+             ((rows, seq, kv_heads * (key_dim - shared)), dtype),
+             ((rows, seq, kv_heads * value_dim), dtype)]
+    if shared:
+        specs.append(((rows, seq, shared), dtype))
+
+    def attend(q, k, v, k_shared=None):
+        return prefill_attention.attend(
+            q, k, v, num_heads=heads, num_kv_heads=kv_heads, scale=0.1,
+            k_shared=k_shared)
+
+    return (attend, tuple(specs)),
+
+
 def _cases():
     cases = {}
 
@@ -283,6 +305,28 @@ def _cases():
         for k, n in ((7168, 4096), (2048, 7168)):
             add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
                 _gmm(rows, tm, k, n, experts=16), ("fwd",))
+    # a prefill dispatch's attention in the four generate cells (896
+    # tokens = one super-block of seven blocks): GPT-2's float32 12 x 64
+    # over 64 rows, Trinity's 48 over 8 x 128 over 16, Nemotron's 32 over
+    # 2 x 128 over 8, dots_vlm's 128 heads of 192 key lanes (64 of them
+    # the shared rotary part) and 128 value lanes over 8, and the same
+    # with the shared part inside K; a sequence of four super-blocks
+    # (the online softmax's state in scratch) and the longest K and V a
+    # float32 lane block may keep resident
+    add("prefill_attention-gpt2-12x64-f32",
+        _prefill_attention(F32, 12, 12, 64, 64, 64), ("fwd",))
+    add("prefill_attention-trinity-48over8x128-bf16",
+        _prefill_attention(BF16, 48, 8, 128, 128, 16), ("fwd",))
+    add("prefill_attention-nemotron-32over2x128-bf16",
+        _prefill_attention(BF16, 32, 2, 128, 128, 8), ("fwd",))
+    add("prefill_attention-dots_vlm-128x192-128-shared64-bf16",
+        _prefill_attention(BF16, 128, 128, 192, 128, 8, shared=64), ("fwd",))
+    add("prefill_attention-dots_vlm-128x192-128-bf16",
+        _prefill_attention(BF16, 128, 128, 192, 128, 8), ("fwd",))
+    add("corner-prefill_attention-trinity-s4096-bf16",
+        _prefill_attention(BF16, 48, 8, 128, 128, 2, seq=4096), ("fwd",))
+    add("corner-prefill_attention-gpt2-s16384-f32",
+        _prefill_attention(F32, 12, 12, 64, 64, 1, seq=16384), ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
@@ -335,6 +379,8 @@ def _named_cases():
         "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm"],
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
         "decode_attention-gpt2-12x64-f32-fwd": ["decode_attention"],
+        "prefill_attention-trinity-48over8x128-bf16-fwd":
+            ["prefill_attention"],
     }
 
 
