@@ -1,0 +1,10 @@
+"""Device time of the dense feed-forward and its norm in one batch's
+prefill: self time of the `jit_<family>_prefill` module's events whose
+scope begins `mlp`, inside the window's whole `serving.prefill` spans, a
+span (`harness/sections.py`)."""
+
+from benchmark.harness import sections
+
+
+def read(run):
+    return sections.section_ms(run, "prefill", "mlp")

@@ -30,6 +30,7 @@ from .framework import (  # noqa: F401
     in_dygraph_mode,
     program_guard,
     device_guard,
+    name_scope,
     scope_guard,
 )
 from . import ops  # noqa: F401  (registers all op emitters)

@@ -10,6 +10,7 @@ from .program import (  # noqa: F401
     default_main_program,
     default_startup_program,
     device_guard,
+    name_scope,
     grad_var_name,
     in_dygraph_mode,
     program_guard,

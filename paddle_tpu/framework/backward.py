@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from ..core.dtypes import is_float
 from ..framework import unique_name
-from .program import grad_var_name
+from .program import grad_var_name, scope_of
 from .registry import OpView, get_op_def, register_op
 
 import jax
@@ -59,6 +59,7 @@ def _vjp_emit(ctx, op, ins):
         for (slot, idx), val in zip(want, diff_vals):
             merged[slot][idx] = val
         # the replayed forward keeps its op's name beneath `__vjp__`
+        # (whose own scope is the forward op's: `run_op`)
         with jax.named_scope(fwd_op.type):
             return fwd_def.emit(ctx, fwd_op, merged)
 
@@ -112,6 +113,64 @@ def _ensure_var(block, name, like_name):
         src = block.var(like_name)
         block.create_var(name=name, shape=src.shape, dtype=src.dtype)
     return block.var(name)
+
+
+def _append_grad_ops(op, op_def, block, contribs, finalize, needs_grad):
+    """The grad ops of one forward op of the reverse walk."""
+    out_has_grad = any(n in contribs for n in op.output_names())
+    if not out_has_grad:
+        return
+    diff_inputs = [
+        (slot, i, n)
+        for slot, names in op.inputs.items()
+        for i, n in enumerate(names)
+        if n and n in needs_grad
+    ]
+    if not diff_inputs:
+        return
+
+    if op_def.grad_maker is not None:
+        # a maker may decline (return False) to fall back to the
+        # generic __vjp__ path, e.g. when a rarely-differentiated
+        # auxiliary output turns out to carry gradients
+        if op_def.grad_maker(op, block, contribs, finalize,
+                             needs_grad=needs_grad) is not False:
+            return
+
+    # finalize the grads of this op's outputs
+    grad_ins = {}
+    for slot, names in op.outputs.items():
+        grad_ins["OutGrad:" + slot] = [
+            (finalize(n) or "") if n in contribs else "" for n in names
+        ]
+    fwd_in_slots = {"FwdIn:" + s: list(v) for s, v in op.inputs.items()}
+
+    diff_set = set(diff_inputs)
+    grad_outs = {}
+    new_contribs = []
+    for slot, names in op.inputs.items():
+        outs = []
+        for i, n in enumerate(names):
+            if (slot, i, n) in diff_set:
+                gname = unique_name.generate(grad_var_name(n) + "@RENAME")
+                _ensure_var(block, gname, n)
+                outs.append(gname)
+                new_contribs.append((n, gname))
+            else:
+                outs.append("")
+        grad_outs["InGrad:" + slot] = outs
+
+    block.append_op(
+        "__vjp__",
+        {**fwd_in_slots, **grad_ins},
+        grad_outs,
+        {
+            "fwd_type": op.type,
+            "fwd_attrs": dict(op.attrs),
+        },
+    )
+    for n, gname in new_contribs:
+        contribs.setdefault(n, []).append(gname)
 
 
 def append_backward(loss, parameter_list=None, no_grad_set=None, callbacks=None):
@@ -181,60 +240,11 @@ def append_backward(loss, parameter_list=None, no_grad_set=None, callbacks=None)
         op_def = get_op_def(op.type)
         if not op_def.differentiable:
             continue
-        out_has_grad = any(n in contribs for n in op.output_names())
-        if not out_has_grad:
-            continue
-        diff_inputs = [
-            (slot, i, n)
-            for slot, names in op.inputs.items()
-            for i, n in enumerate(names)
-            if n and n in needs_grad
-        ]
-        if not diff_inputs:
-            continue
-
-        if op_def.grad_maker is not None:
-            # a maker may decline (return False) to fall back to the
-            # generic __vjp__ path, e.g. when a rarely-differentiated
-            # auxiliary output turns out to carry gradients
-            if op_def.grad_maker(op, block, contribs, finalize,
-                                 needs_grad=needs_grad) is not False:
-                continue
-
-        # finalize the grads of this op's outputs
-        grad_ins = {}
-        for slot, names in op.outputs.items():
-            grad_ins["OutGrad:" + slot] = [
-                (finalize(n) or "") if n in contribs else "" for n in names
-            ]
-        fwd_in_slots = {"FwdIn:" + s: list(v) for s, v in op.inputs.items()}
-
-        diff_set = set(diff_inputs)
-        grad_outs = {}
-        new_contribs = []
-        for slot, names in op.inputs.items():
-            outs = []
-            for i, n in enumerate(names):
-                if (slot, i, n) in diff_set:
-                    gname = unique_name.generate(grad_var_name(n) + "@RENAME")
-                    _ensure_var(block, gname, n)
-                    outs.append(gname)
-                    new_contribs.append((n, gname))
-                else:
-                    outs.append("")
-            grad_outs["InGrad:" + slot] = outs
-
-        block.append_op(
-            "__vjp__",
-            {**fwd_in_slots, **grad_ins},
-            grad_outs,
-            {
-                "fwd_type": op.type,
-                "fwd_attrs": dict(op.attrs),
-            },
-        )
-        for n, gname in new_contribs:
-            contribs.setdefault(n, []).append(gname)
+        # a grad op (and the sums that finalize its outputs' grads)
+        # carries its forward op's name scope
+        with scope_of(op):
+            _append_grad_ops(op, op_def, block, contribs, finalize,
+                             needs_grad)
 
     # 4. finalize parameter grads
     if parameter_list is not None:

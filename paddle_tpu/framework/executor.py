@@ -37,10 +37,15 @@ from .scope import global_scope
 
 
 class _Compiled:
-    __slots__ = ("fn", "state_ro", "state_mut", "fetch_names", "nan_ops")
+    __slots__ = ("fn", "state_ro", "state_mut", "fetch_names", "nan_ops",
+                 "name")
 
-    def __init__(self, fn, state_ro, state_mut, fetch_names, nan_ops=None):
+    def __init__(self, fn, state_ro, state_mut, fetch_names, nan_ops=None,
+                 name=None):
         self.fn = fn
+        # the jitted function's name: the executable is the XLA module
+        # `jit_<name>` in a profiler capture (None: loaded, not compiled)
+        self.name = name
         self.state_ro = state_ro
         self.state_mut = state_mut
         self.fetch_names = fetch_names
@@ -198,7 +203,7 @@ class Executor:
             with _obs.timed("executor.compile_time"), \
                     _obs.span("executor.compile"):
                 compiled = self._compile(
-                    program, block, set(feed_arrays), fetch_names, scope
+                    program, block, set(feed_arrays), fetch_names, scope, key
                 )
             if use_program_cache:
                 self._cache[key] = compiled
@@ -271,7 +276,7 @@ class Executor:
         compiled = self._cache.get(key)
         if compiled is None:
             compiled = self._compile(
-                program, block, set(feed_arrays), fetch_names, scope
+                program, block, set(feed_arrays), fetch_names, scope, key
             )
             self._cache[key] = compiled
         state_ro = {
@@ -418,7 +423,7 @@ class Executor:
             jax.config.update("jax_enable_compilation_cache", False)
             _cc.reset_cache()
             compiled = self._compile(
-                program, block, set(feed_arrays), fetch_names, scope
+                program, block, set(feed_arrays), fetch_names, scope, key
             )
             state_ro = {
                 n: self._from_scope(scope, n, block)
@@ -570,7 +575,23 @@ class Executor:
             )
         return v
 
-    def _compile(self, program, block, feed_names, fetch_names, scope):
+    def _module_name(self, program, key):
+        """What the jitted step is called, hence its XLA module
+        (`jit_<name>` on a capture's "XLA Modules" line and in its
+        events' `hlo_module`): the label the program's owner gave it
+        (`<family>_prefill`, `train_step`, `startup`), else `program<n>`
+        by the order of creation. Two executables this executor holds
+        never share a name: a further compile of one program (another
+        feed shape, another fetch set) appends a digest of what differs."""
+        import zlib
+
+        name = program._label or f"program{program._creation_ordinal}"
+        if any(c.name == name for c in self._cache.values()):
+            name += f"_{zlib.crc32(repr(key[1:]).encode()):08x}"
+        return name
+
+    def _compile(self, program, block, feed_names, fetch_names, scope,
+                 key=()):
         from ..flags import flag
 
         # pre-trace static verification (PADDLE_TPU_VERIFY=strict|warn|0):
@@ -661,6 +682,7 @@ class Executor:
             new_state = {n: env[n] for n in write_back if n in env}
             return fetches, new_state
 
+        traced.__name__ = name = self._module_name(program, key)
         if mesh is not None:
             from ..parallel.spmd import wrap_gspmd, wrap_shard_map
 
@@ -682,7 +704,7 @@ class Executor:
             fn = jax.jit(traced, donate_argnums=(1,))
         return _Compiled(
             fn, state_ro, state_mut, fetch_names,
-            nan_ops=ops if (check_nan and ops) else None,
+            nan_ops=ops if (check_nan and ops) else None, name=name,
         )
 
 

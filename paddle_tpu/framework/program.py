@@ -190,6 +190,10 @@ class Operator:
         # PipelineOptimizer's stage slicing)
         if _current_device is not None and "op_device" not in self.attrs:
             self.attrs["op_device"] = _current_device
+        # what the region of the model this op belongs to is for
+        # (fluid.name_scope); rides into the compiled step's metadata
+        if _name_scopes and "op_namescope" not in self.attrs:
+            self.attrs["op_namescope"] = "/".join(_name_scopes)
         # creation provenance: the user frame that built this op, attached
         # to trace/runtime errors (reference framework/op_call_stack.cc)
         if "__loc__" not in self.attrs:
@@ -385,6 +389,10 @@ class Program:
         # inserts collectives by propagation (the TP/auto path).
         self._spmd_mode = "shard_map"
         self._pipeline = None  # set by PipelineOptimizer
+        # what the program's owner calls it (`gpt_decode`, `train_step`,
+        # `startup`): the Executor names the compiled step after it, so
+        # its XLA module is `jit_<label>` in a profiler capture
+        self._label = None
         self._op_uid = 0
         # per-program run counter folded into the step RNG key; advances on
         # every Executor.run so seeded programs still vary dropout per step
@@ -525,6 +533,7 @@ class Program:
             self._creation_ordinal = _program_counter
         self.__dict__.setdefault("_spmd_mode", "shard_map")
         self.__dict__.setdefault("_pipeline", None)
+        self.__dict__.setdefault("_label", None)
 
     def clone(self, for_test=False):
         """Deep copy. for_test=True flips is_test on ops that honor it
@@ -549,6 +558,7 @@ class Program:
 
 _main_program = Program()
 _startup_program = Program()
+_startup_program._label = "startup"
 
 
 def default_main_program() -> Program:
@@ -566,6 +576,8 @@ def program_guard(main_program, startup_program=None):
     _main_program = main_program
     if startup_program is not None:
         _startup_program = startup_program
+        if startup_program._label is None:
+            startup_program._label = "startup"
     try:
         yield
     finally:
@@ -588,6 +600,40 @@ def device_guard(device=None):
         yield
     finally:
         _current_device = old
+
+
+# --- name_scope (reference fluid.name_scope, framework.py:441) ---
+_name_scopes = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix):
+    """Say what a region of ops is for: an op appended inside carries the
+    attribute ``op_namescope`` (scopes nest with "/"), and the Executor
+    traces it under ``jax.named_scope("<scope>/<op type>")``, so the
+    scope is in the ``op_name`` of every instruction the op compiles to
+    (``profiler.summary(by="scope")`` reads a capture by it). Build-time
+    only: nothing runs per step. The models mark their sections with one
+    vocabulary: ``embed``, ``attn``, ``mlp``, ``moe``, ``ssm``, ``head``
+    (README section Observability)."""
+    _name_scopes.append(str(prefix))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
+
+
+@contextlib.contextmanager
+def scope_of(op):
+    """The name scope `op` was built in, around ops appended on its
+    behalf (its grad ops)."""
+    global _name_scopes
+    scope = op.attr("op_namescope")
+    old, _name_scopes = _name_scopes, [scope] if scope else []
+    try:
+        yield
+    finally:
+        _name_scopes = old
 
 
 # --- dygraph mode switch (framework.py:180 in the reference) ---

@@ -255,6 +255,13 @@ def flatten_outs(op):
     return out
 
 
+def scoped_type(op):
+    """"<fluid.name_scope the op was built in>/<op type>", the type alone
+    where there is no scope: the name an op's emitter is traced under."""
+    scope = op.attr("op_namescope")
+    return f"{scope}/{op.type}" if scope else op.type
+
+
 def run_op(ctx, op, env):
     """Execute one op's emitter against an env (name -> jax value)."""
     op_def = get_op_def(op.type)
@@ -262,9 +269,10 @@ def run_op(ctx, op, env):
         slot: [env[n] if n else None for n in names]
         for slot, names in op.inputs.items()
     }
-    # the op's type rides into the compiled step's HLO metadata
-    # (op_name), so a profiler's device events can be read by Fluid op
-    with jax.named_scope(op.type):
+    # the op's name scope and type ride into the compiled step's HLO
+    # metadata (op_name), so a profiler's device events can be read by
+    # the model's section and by Fluid op (profiler.op_scopes)
+    with jax.named_scope(scoped_type(op)):
         outs = op_def.emit(ctx, op, ins)
     for slot, names in op.outputs.items():
         vals = outs.get(slot, [])

@@ -27,6 +27,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
+from ..framework.program import name_scope
 from ..layers.helper import LayerHelper
 from ..layers.tensor import _simple
 from ..param_attr import ParamAttr
@@ -190,21 +191,26 @@ def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
         name=f"{prefix}_selected", shape=tuple(x.shape[:2]) + (cfg.top_k,),
         dtype="int32",
     )
-    blk.append_op(
-        "moe_local_experts",
-        {"X": [x.name], "RouterW": [router_w.name],
-         "ExpertBias": [bias.name], "WGateUp": [w_gate_up.name],
-         "WDown": [w_down.name], "Counters": [counters.name]},
-        {"Out": [routed.name], "Selected": [selected.name],
-         "CountersOut": [counters.name]},
-        {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
-         "route_norm": cfg.route_norm, "expert_offset": cfg.expert_offset,
-         **route_attrs},
-    )
-    if cfg.num_shared_experts:
-        routed = routed + _swiglu_ffn(
-            x, f * cfg.num_shared_experts, f"{prefix}_shared", cfg
+    # router, top-k, dispatch, the grouped products and the combine are
+    # ONE op: the emitter's own scopes (`moe_router`, `moe_dispatch`,
+    # `moe_experts`, `moe_combine`) tell them apart beneath this one
+    with name_scope("experts"):
+        blk.append_op(
+            "moe_local_experts",
+            {"X": [x.name], "RouterW": [router_w.name],
+             "ExpertBias": [bias.name], "WGateUp": [w_gate_up.name],
+             "WDown": [w_down.name], "Counters": [counters.name]},
+            {"Out": [routed.name], "Selected": [selected.name],
+             "CountersOut": [counters.name]},
+            {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
+             "route_norm": cfg.route_norm,
+             "expert_offset": cfg.expert_offset, **route_attrs},
         )
+    if cfg.num_shared_experts:
+        with name_scope("shared"):
+            routed = routed + _swiglu_ffn(
+                x, f * cfg.num_shared_experts, f"{prefix}_shared", cfg
+            )
     return routed, selected
 
 
@@ -215,25 +221,32 @@ def _layer(x, cfg, i, attend):
     prefix = f"afmoe_l{i}"
     attn_kind, ffn_kind = cfg.layer_kinds[i]
     nh, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    a = _rms(x, f"{prefix}_n1", cfg)
-    q = _rms(_proj(a, nh * dh, f"{prefix}_attn_q_w", cfg),
-             f"{prefix}_attn_qn", cfg, dh)
-    k = _rms(_proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
-             f"{prefix}_attn_kn", cfg, dh)
-    v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
-    gate = _proj(a, nh * dh, f"{prefix}_attn_g_w", cfg)
-    out = attend(prefix, q, k, v, attn_kind == SLIDING)
-    out = _proj(out * layers.sigmoid(gate), cfg.hidden_size,
-                f"{prefix}_attn_o_w", cfg)
-    h = x + _rms(out, f"{prefix}_n2", cfg, seeded=cfg.norm_out_gain)
-    m = _rms(h, f"{prefix}_n3", cfg)
+    with name_scope("attn"):
+        a = _rms(x, f"{prefix}_n1", cfg)
+        with name_scope("proj"):
+            q = _rms(_proj(a, nh * dh, f"{prefix}_attn_q_w", cfg),
+                     f"{prefix}_attn_qn", cfg, dh)
+            k = _rms(_proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
+                     f"{prefix}_attn_kn", cfg, dh)
+            v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
+            gate = _proj(a, nh * dh, f"{prefix}_attn_g_w", cfg)
+        out = attend(prefix, q, k, v, attn_kind == SLIDING)
+        with name_scope("proj"):
+            out = _proj(out * layers.sigmoid(gate), cfg.hidden_size,
+                        f"{prefix}_attn_o_w", cfg)
+        h = x + _rms(out, f"{prefix}_n2", cfg, seeded=cfg.norm_out_gain)
     selected = None
     if ffn_kind == DENSE:
-        m = _swiglu_ffn(m, cfg.intermediate_size, f"{prefix}_mlp", cfg)
-    else:
+        with name_scope("mlp"):
+            m = _rms(h, f"{prefix}_n3", cfg)
+            m = _swiglu_ffn(m, cfg.intermediate_size, f"{prefix}_mlp", cfg)
+            return h + _rms(m, f"{prefix}_n4", cfg,
+                            seeded=cfg.norm_out_gain), selected
+    with name_scope("moe"):
+        m = _rms(h, f"{prefix}_n3", cfg)
         m, selected = _expert_ffn(m, prefix, cfg)
-    return h + _rms(m, f"{prefix}_n4", cfg, seeded=cfg.norm_out_gain), \
-        selected
+        return h + _rms(m, f"{prefix}_n4", cfg,
+                        seeded=cfg.norm_out_gain), selected
 
 
 def _cache_vars(prefix, cfg, batch, max_len, window):
@@ -257,25 +270,28 @@ def _write_cache(cache, rows, pos, row, ring):
 
 
 def _embed(ids, cfg, seq):
-    x = layers.embedding(
-        ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-        param_attr=ParamAttr(name="afmoe_embed", initializer=_normal(cfg)),
-    )
-    x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
-    if cfg.mup_enabled:
-        x = layers.scale(x, scale=math.sqrt(cfg.hidden_size))
-    return x
+    with name_scope("embed"):
+        x = layers.embedding(
+            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
+            param_attr=ParamAttr(name="afmoe_embed",
+                                 initializer=_normal(cfg)),
+        )
+        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
+        if cfg.mup_enabled:
+            x = layers.scale(x, scale=math.sqrt(cfg.hidden_size))
+        return x
 
 
 def _head(x, cfg, family="afmoe"):
     """Final norm, then the untied head over the vocabulary held here;
     float32 out of the product (not a rounded bfloat16 cast up)."""
-    x = _rms(x, f"{family}_norm_f", cfg)
-    w = _param(f"{family}_head_w", [cfg.hidden_size, cfg.vocab_size], cfg,
-               _normal(cfg))
-    return _simple("mul", {"X": [x], "Y": [w]},
-                   {"x_num_col_dims": 2, "y_num_col_dims": 1,
-                    "out_dtype": "float32"})
+    with name_scope("head"):
+        x = _rms(x, f"{family}_norm_f", cfg)
+        w = _param(f"{family}_head_w", [cfg.hidden_size, cfg.vocab_size],
+                   cfg, _normal(cfg))
+        return _simple("mul", {"X": [x], "Y": [w]},
+                       {"x_num_col_dims": 2, "y_num_col_dims": 1,
+                        "out_dtype": "float32"})
 
 
 def _rotary(x, pos, cfg):
@@ -288,8 +304,9 @@ def _side_by_side(selected):
     step beside the logits), or None where no layer routes."""
     if not selected:
         return None
-    return selected[0] if len(selected) == 1 \
-        else layers.concat(selected, axis=-1)
+    with name_scope("head"):
+        return selected[0] if len(selected) == 1 \
+            else layers.concat(selected, axis=-1)
 
 
 def afmoe_prefill(context_ids, cfg, batch, max_len, row_ids=None):
@@ -302,29 +319,33 @@ def afmoe_prefill(context_ids, cfg, batch, max_len, row_ids=None):
     int32)."""
     s = context_ids.shape[1]
     x = _embed(context_ids, cfg, s)
-    first = layers.fill_constant([1], "int32", 0)
-    last = layers.fill_constant([1], "int32", s - 1)
+    with name_scope("attn"):
+        first = layers.fill_constant([1], "int32", 0)
+        last = layers.fill_constant([1], "int32", s - 1)
     scale = 1.0 / math.sqrt(cfg.head_dim)
 
     def attend(prefix, q, k, v, sliding):
         window = cfg.sliding_window if sliding else 0
         if sliding:
             q, k = _rotary(q, last, cfg), _rotary(k, last, cfg)
-        ck, cv = _cache_vars(prefix, cfg, batch, max_len, window)
-        _write_cache(ck, k, first, row_ids, ring=True)
-        _write_cache(cv, v, first, row_ids, ring=True)
-        return _simple(
-            "causal_gqa_attention", {"Q": [q], "K": [k], "V": [v]},
-            {"num_heads": cfg.num_heads, "num_kv_heads": cfg.num_kv_heads,
-             "window": window, "scale": scale},
-        )
+        with name_scope("core"):
+            ck, cv = _cache_vars(prefix, cfg, batch, max_len, window)
+            _write_cache(ck, k, first, row_ids, ring=True)
+            _write_cache(cv, v, first, row_ids, ring=True)
+            return _simple(
+                "causal_gqa_attention", {"Q": [q], "K": [k], "V": [v]},
+                {"num_heads": cfg.num_heads,
+                 "num_kv_heads": cfg.num_kv_heads,
+                 "window": window, "scale": scale},
+            )
 
     selected = []
     for i in range(cfg.num_layers):
         x, sel = _layer(x, cfg, i, attend)
         if sel is not None:
             selected.append(sel)
-    last_h = layers.slice(x, [1], [s - 1], [s])
+    with name_scope("head"):
+        last_h = layers.slice(x, [1], [s - 1], [s])
     return _head(last_h, cfg), _side_by_side(selected)
 
 
@@ -342,15 +363,18 @@ def afmoe_decode_step(token_ids, pos_ids, cfg, max_len):
         window = cfg.sliding_window if sliding else 0
         if sliding:
             q, k = _rotary(q, pos_ids, cfg), _rotary(k, pos_ids, cfg)
-        ck, cv = _cache_vars(prefix, cfg, b, max_len, window)
-        _write_cache(ck, k, pos_ids, None, ring=True)
-        _write_cache(cv, v, pos_ids, None, ring=True)
-        return _simple(
-            "kv_cache_attention",
-            {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [pos_ids]},
-            {"num_heads": cfg.num_heads, "num_kv_heads": cfg.num_kv_heads,
-             "window": window, "scale": scale},
-        )
+        with name_scope("core"):
+            ck, cv = _cache_vars(prefix, cfg, b, max_len, window)
+            _write_cache(ck, k, pos_ids, None, ring=True)
+            _write_cache(cv, v, pos_ids, None, ring=True)
+            return _simple(
+                "kv_cache_attention",
+                {"Q": [q], "CacheK": [ck], "CacheV": [cv],
+                 "Pos": [pos_ids]},
+                {"num_heads": cfg.num_heads,
+                 "num_kv_heads": cfg.num_kv_heads,
+                 "window": window, "scale": scale},
+            )
 
     selected = []
     for i in range(cfg.num_layers):

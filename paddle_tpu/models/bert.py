@@ -17,6 +17,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
+from ..framework.program import name_scope
 from ..param_attr import ParamAttr
 
 
@@ -87,20 +88,30 @@ def _dense(x, size, name, cfg, act=None):
 
 
 def _attention(x, attn_bias, cfg, prefix, is_test):
-    b, s, h = x.shape
+    h = cfg.hidden_size
+    with name_scope("proj"):
+        # [B,S,3H] one fused matmul
+        qkv = _dense(x, 3 * h, f"{prefix}_qkv", cfg)
+    with name_scope("core"):
+        ctxv = _attention_core(qkv, attn_bias, cfg, is_test)
+    with name_scope("proj"):
+        return _dense(ctxv, h, f"{prefix}_out", cfg)
+
+
+def _attention_core(qkv, attn_bias, cfg, is_test):
+    b, s, _ = qkv.shape
+    h = cfg.hidden_size
     nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    qkv = _dense(x, 3 * h, f"{prefix}_qkv", cfg)  # [B,S,3H] one fused matmul
     if cfg.use_fused_attention:
         # one op straight off the qkv matmul: the Pallas flash kernel
         # indexes the packed [B,S,3H] projection in place (no head-split
         # transposes, no [B,nh,S,S] probs in HBM); attn_bias is the [B,S]
         # key mask (0 keep / -1e4 pad)
-        ctxv = layers.fused_qkv_attention(
+        return layers.fused_qkv_attention(
             qkv, nh, key_bias=attn_bias,
             scale=1.0 / math.sqrt(dh),
             dropout_prob=cfg.attention_dropout, is_test=is_test,
         )
-        return _dense(ctxv, h, f"{prefix}_out", cfg)
 
     # dense path: slice along the feature dim + per-tensor [B,nh,S,dh]
     # transposes (XLA folds the slices into the producing matmul and fuses
@@ -123,8 +134,7 @@ def _attention(x, attn_bias, cfg, prefix, is_test):
     )
     ctxv = layers.matmul(probs, v)  # [B,nh,S,dh]
     ctxv = layers.transpose(ctxv, [0, 2, 1, 3])
-    ctxv = layers.reshape(ctxv, [b, s, h])
-    return _dense(ctxv, h, f"{prefix}_out", cfg)
+    return layers.reshape(ctxv, [b, s, h])
 
 
 def _residual_ln(x, branch, cfg, ln_name, is_test):
@@ -147,16 +157,18 @@ def _residual_ln(x, branch, cfg, ln_name, is_test):
 
 
 def _encoder_layer(x, attn_bias, cfg, prefix, is_test):
-    attn = _attention(x, attn_bias, cfg, f"{prefix}_attn", is_test)
-    x = _residual_ln(x, attn, cfg, f"{prefix}_ln1", is_test)
+    with name_scope("attn"):
+        attn = _attention(x, attn_bias, cfg, f"{prefix}_attn", is_test)
+        x = _residual_ln(x, attn, cfg, f"{prefix}_ln1", is_test)
     # tanh-approximate GELU (the original BERT implementation's formula).
     # On TPU the exact erf lowers to a long VPU polynomial — profiled at
     # ~0.77 ms/layer fwd on [32,512,3072] (BASELINE.md round 4); tanh is
     # the canonical-and-cheaper form.
-    ffn = _dense(x, cfg.intermediate_size, f"{prefix}_ffn_in", cfg)
-    ffn = layers.gelu(ffn, approximate=True)
-    ffn = _dense(ffn, cfg.hidden_size, f"{prefix}_ffn_out", cfg)
-    return _residual_ln(x, ffn, cfg, f"{prefix}_ln2", is_test)
+    with name_scope("mlp"):
+        ffn = _dense(x, cfg.intermediate_size, f"{prefix}_ffn_in", cfg)
+        ffn = layers.gelu(ffn, approximate=True)
+        ffn = _dense(ffn, cfg.hidden_size, f"{prefix}_ffn_out", cfg)
+        return _residual_ln(x, ffn, cfg, f"{prefix}_ln2", is_test)
 
 
 def _attn_bias(input_mask):
@@ -172,7 +184,8 @@ def bert_encoder_layers(x, input_mask, cfg, start=0, end=None, is_test=False,
     pipeline-stage splitting (device_guard slices the layer stack).
     `checkpoints`: optional list collecting per-layer outputs for
     RecomputeOptimizer segment boundaries."""
-    attn_bias = _attn_bias(input_mask)
+    with name_scope("attn"):
+        attn_bias = _attn_bias(input_mask)
     end = cfg.num_layers if end is None else end
     for i in range(start, end):
         x = _encoder_layer(x, attn_bias, cfg, f"bert_l{i}", is_test)
@@ -187,32 +200,35 @@ def bert_encoder(input_ids, token_type_ids, input_mask, cfg, is_test=False,
     Returns sequence output [B,S,H]. num_layers limits the stack (pipeline
     stage 0 = embeddings + first half; see bert_encoder_layers)."""
     b, s = input_ids.shape
-    word_emb = layers.embedding(
-        input_ids,
-        size=[cfg.vocab_size, cfg.hidden_size],
-        param_attr=ParamAttr(name="word_embedding", initializer=_init(cfg)),
-    )
-    pos_ids = layers.reshape(
-        layers.range(0, s, 1, "int64"), [1, s]
-    )
-    pos_emb = layers.embedding(
-        pos_ids,
-        size=[cfg.max_position, cfg.hidden_size],
-        param_attr=ParamAttr(name="pos_embedding", initializer=_init(cfg)),
-    )
-    type_emb = layers.embedding(
-        token_type_ids,
-        size=[cfg.type_vocab_size, cfg.hidden_size],
-        param_attr=ParamAttr(name="type_embedding", initializer=_init(cfg)),
-    )
-    emb = word_emb + pos_emb + type_emb
-    emb = layers.layer_norm(
-        emb,
-        begin_norm_axis=2,
-        param_attr=ParamAttr(name="emb_ln_scale"),
-        bias_attr=ParamAttr(name="emb_ln_bias"),
-    )
-    emb = layers.dropout(emb, cfg.hidden_dropout, is_test=is_test)
+    with name_scope("embed"):
+        word_emb = layers.embedding(
+            input_ids,
+            size=[cfg.vocab_size, cfg.hidden_size],
+            param_attr=ParamAttr(name="word_embedding",
+                                 initializer=_init(cfg)),
+        )
+        pos_ids = layers.reshape(
+            layers.range(0, s, 1, "int64"), [1, s]
+        )
+        pos_emb = layers.embedding(
+            pos_ids,
+            size=[cfg.max_position, cfg.hidden_size],
+            param_attr=ParamAttr(name="pos_embedding", initializer=_init(cfg)),
+        )
+        type_emb = layers.embedding(
+            token_type_ids,
+            size=[cfg.type_vocab_size, cfg.hidden_size],
+            param_attr=ParamAttr(name="type_embedding",
+                                 initializer=_init(cfg)),
+        )
+        emb = word_emb + pos_emb + type_emb
+        emb = layers.layer_norm(
+            emb,
+            begin_norm_axis=2,
+            param_attr=ParamAttr(name="emb_ln_scale"),
+            bias_attr=ParamAttr(name="emb_ln_bias"),
+        )
+        emb = layers.dropout(emb, cfg.hidden_dropout, is_test=is_test)
     n = cfg.num_layers if num_layers is None else num_layers
     return bert_encoder_layers(
         emb, input_mask, cfg, 0, n, is_test, checkpoints=checkpoints
@@ -222,26 +238,29 @@ def bert_encoder(input_ids, token_type_ids, input_mask, cfg, is_test=False,
 def bert_mlm_head(seq, mlm_labels, cfg):
     """Masked-LM loss head over [B,S,H] sequence output; mlm_labels [B,S]
     int64 with ignore_index -100 on unmasked positions."""
-    b, s, h = seq.shape
-    seq2 = layers.reshape(seq, [b * s, h])
-    logits = layers.fc(
-        seq2,
-        size=cfg.vocab_size,
-        param_attr=ParamAttr(name="mlm_out_w", initializer=_init(cfg)),
-        bias_attr=ParamAttr(name="mlm_out_b"),
-    )
-    labels = layers.reshape(mlm_labels, [b * s, 1])
-    loss = layers.softmax_with_cross_entropy(logits, labels, ignore_index=-100)
-    # average over the *masked* positions only: ignored positions contribute
-    # zero loss, so a plain mean would scale loss/grads by the masking ratio.
-    # [1]-shaped constant broadcasts, so the head stays batch-size agnostic
-    # (pipeline microbatching shrinks the runtime batch)
-    ignore = layers.fill_constant([1], "int64", -100)
-    valid = layers.cast(layers.not_equal(labels, ignore), "float32")
-    denom = layers.elementwise_max(
-        layers.reduce_sum(valid), layers.fill_constant([1], "float32", 1.0)
-    )
-    return layers.elementwise_div(layers.reduce_sum(loss), denom)
+    with name_scope("head"):
+        b, s, h = seq.shape
+        seq2 = layers.reshape(seq, [b * s, h])
+        logits = layers.fc(
+            seq2,
+            size=cfg.vocab_size,
+            param_attr=ParamAttr(name="mlm_out_w", initializer=_init(cfg)),
+            bias_attr=ParamAttr(name="mlm_out_b"),
+        )
+        labels = layers.reshape(mlm_labels, [b * s, 1])
+        loss = layers.softmax_with_cross_entropy(logits, labels,
+                                                 ignore_index=-100)
+        # average over the *masked* positions only: ignored positions
+        # contribute zero loss, so a plain mean would scale loss/grads by
+        # the masking ratio. [1]-shaped constant broadcasts, so the head
+        # stays batch-size agnostic (pipeline microbatching shrinks the
+        # runtime batch)
+        ignore = layers.fill_constant([1], "int64", -100)
+        valid = layers.cast(layers.not_equal(labels, ignore), "float32")
+        denom = layers.elementwise_max(
+            layers.reduce_sum(valid), layers.fill_constant([1], "float32", 1.0)
+        )
+        return layers.elementwise_div(layers.reduce_sum(loss), denom)
 
 
 def bert_mlm_head_gather(seq, mask_pos, mask_labels, cfg):
@@ -251,23 +270,25 @@ def bert_mlm_head_gather(seq, mask_pos, mask_labels, cfg):
     head FLOPs). mask_pos: [P] int32 indices into the flattened [B*S]
     sequence (padded entries point at any row with label -100);
     mask_labels: [P] vocab ids with -100 padding."""
-    b, s, h = seq.shape
-    seq2 = layers.reshape(seq, [b * s, h])
-    picked = layers.gather(seq2, mask_pos)  # [P, h]
-    logits = layers.fc(
-        picked,
-        size=cfg.vocab_size,
-        param_attr=ParamAttr(name="mlm_out_w", initializer=_init(cfg)),
-        bias_attr=ParamAttr(name="mlm_out_b"),
-    )
-    labels = layers.reshape(mask_labels, [-1, 1])
-    loss = layers.softmax_with_cross_entropy(logits, labels, ignore_index=-100)
-    ignore = layers.fill_constant([1], "int64", -100)
-    valid = layers.cast(layers.not_equal(labels, ignore), "float32")
-    denom = layers.elementwise_max(
-        layers.reduce_sum(valid), layers.fill_constant([1], "float32", 1.0)
-    )
-    return layers.elementwise_div(layers.reduce_sum(loss), denom)
+    with name_scope("head"):
+        b, s, h = seq.shape
+        seq2 = layers.reshape(seq, [b * s, h])
+        picked = layers.gather(seq2, mask_pos)  # [P, h]
+        logits = layers.fc(
+            picked,
+            size=cfg.vocab_size,
+            param_attr=ParamAttr(name="mlm_out_w", initializer=_init(cfg)),
+            bias_attr=ParamAttr(name="mlm_out_b"),
+        )
+        labels = layers.reshape(mask_labels, [-1, 1])
+        loss = layers.softmax_with_cross_entropy(logits, labels,
+                                                 ignore_index=-100)
+        ignore = layers.fill_constant([1], "int64", -100)
+        valid = layers.cast(layers.not_equal(labels, ignore), "float32")
+        denom = layers.elementwise_max(
+            layers.reduce_sum(valid), layers.fill_constant([1], "float32", 1.0)
+        )
+        return layers.elementwise_div(layers.reduce_sum(loss), denom)
 
 
 def bert_pretrain(input_ids, token_type_ids, input_mask, mlm_labels, cfg,

@@ -42,6 +42,7 @@ statistics inside their ops, and the logits leave the head in float32.
 from __future__ import annotations
 
 from .. import layers
+from ..framework.program import name_scope
 from ..layers.tensor import _simple
 from ..param_attr import ParamAttr
 from .afmoe import (
@@ -181,11 +182,12 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
 
     nh, r, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-    c_q = _rms(_proj(a, cfg.q_lora_rank, f"{prefix}_attn_q_a_w", cfg),
-               f"{prefix}_attn_q_a_n", cfg)
-    q = _proj(c_q, nh * (dn + dr), f"{prefix}_attn_q_b_w", cfg)
-    kv_a = _proj(a, r + dr, f"{prefix}_attn_kv_a_w", cfg)
-    c_kv = _rms(_slice_last(kv_a, 0, r), f"{prefix}_attn_kv_a_n", cfg)
+    with name_scope("proj"):
+        c_q = _rms(_proj(a, cfg.q_lora_rank, f"{prefix}_attn_q_a_w", cfg),
+                   f"{prefix}_attn_q_a_n", cfg)
+        q = _proj(c_q, nh * (dn + dr), f"{prefix}_attn_q_b_w", cfg)
+        kv_a = _proj(a, r + dr, f"{prefix}_attn_kv_a_w", cfg)
+        c_kv = _rms(_slice_last(kv_a, 0, r), f"{prefix}_attn_kv_a_n", cfg)
     last = pos_ids
     if last is None:
         last = layers.fill_constant([1], "int32", a.shape[1] - 1)
@@ -195,35 +197,43 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
                    _normal(cfg))
     shape = latent_cache_shape(batch, max_len, cfg.cache_width)
     cache = _state_var(f"{prefix}_cache_kv", shape, cfg.dtype)
-    row = layers.concat([c_kv, k_pe], axis=-1)
     split = {"nope_dim": dn}
+    # the latent's own products (`mla_expand`, `mla_absorb_*`) sit in
+    # `attn` under their op types; `core` is the cache write and the call
     if pos_ids is None:
-        first = layers.fill_constant([1], "int32", 0)
-        _write_cache(cache, row, first, row_ids, ring=True)
+        with name_scope("core"):
+            row = layers.concat([c_kv, k_pe], axis=-1)
+            first = layers.fill_constant([1], "int32", 0)
+            _write_cache(cache, row, first, row_ids, ring=True)
         k, v = _simple(
             "mla_expand",
             {"Latent": [c_kv], "WKVB": [w_kvb]}, split,
             out_slots=("K", "V"),
         )
-        out = _simple(
-            "causal_gqa_attention",
-            {"Q": [q], "K": [k], "V": [v], "KShared": [k_pe]},
-            {"num_heads": nh, "num_kv_heads": nh, "window": 0,
-             "scale": cfg.softmax_scale},
-        )
+        with name_scope("core"):
+            out = _simple(
+                "causal_gqa_attention",
+                {"Q": [q], "K": [k], "V": [v], "KShared": [k_pe]},
+                {"num_heads": nh, "num_kv_heads": nh, "window": 0,
+                 "scale": cfg.softmax_scale},
+            )
     else:
-        _write_cache(cache, row, pos_ids, None, ring=True)
+        with name_scope("core"):
+            row = layers.concat([c_kv, k_pe], axis=-1)
+            _write_cache(cache, row, pos_ids, None, ring=True)
         q_abs = _simple("mla_absorb_query", {"Q": [q], "WKVB": [w_kvb]},
                         {**split, "row_width": shape[2]})
-        o_lat = _simple(
-            "kv_cache_attention",
-            {"Q": [q_abs], "CacheK": [cache], "Pos": [pos_ids]},
-            {"num_heads": nh, "num_kv_heads": 1, "value_width": r,
-             "window": 0, "scale": cfg.softmax_scale},
-        )
+        with name_scope("core"):
+            o_lat = _simple(
+                "kv_cache_attention",
+                {"Q": [q_abs], "CacheK": [cache], "Pos": [pos_ids]},
+                {"num_heads": nh, "num_kv_heads": 1, "value_width": r,
+                 "window": 0, "scale": cfg.softmax_scale},
+            )
         out = _simple("mla_absorb_output", {"X": [o_lat], "WKVB": [w_kvb]},
                       split)
-    return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
+    with name_scope("proj"):
+        return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
 
 
 def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
@@ -231,25 +241,31 @@ def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
     the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
     with. Returns (hidden [.., H], [the expert layers' Selected ids])."""
     seq = ids.shape[1]
-    x = layers.embedding(
-        ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-        param_attr=ParamAttr(name="dots_embed", initializer=_normal(cfg)),
-    )
-    x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
+    with name_scope("embed"):
+        x = layers.embedding(
+            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
+            param_attr=ParamAttr(name="dots_embed",
+                                 initializer=_normal(cfg)),
+        )
+        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
     selected = []
     for i, (_attn, ffn_kind) in enumerate(cfg.layer_kinds):
         prefix = f"dots_l{i}"
-        h = x + _latent_attention(_rms(x, f"{prefix}_n1", cfg), cfg, prefix,
-                                  batch, max_len, row_ids, pos_ids)
-        m = _rms(h, f"{prefix}_n2", cfg)
-        if ffn_kind == DENSE:
-            m = _swiglu_ffn(m, cfg.intermediate_size, f"{prefix}_mlp", cfg)
-        else:
-            m, sel = _expert_ffn(m, prefix, cfg, COUNTERS_VAR,
-                                 n_group=cfg.n_group,
-                                 topk_group=cfg.topk_group)
-            selected.append(sel)
-        x = h + m
+        with name_scope("attn"):
+            h = x + _latent_attention(
+                _rms(x, f"{prefix}_n1", cfg), cfg, prefix, batch, max_len,
+                row_ids, pos_ids)
+        with name_scope("mlp" if ffn_kind == DENSE else "moe"):
+            m = _rms(h, f"{prefix}_n2", cfg)
+            if ffn_kind == DENSE:
+                m = _swiglu_ffn(m, cfg.intermediate_size, f"{prefix}_mlp",
+                                cfg)
+            else:
+                m, sel = _expert_ffn(m, prefix, cfg, COUNTERS_VAR,
+                                     n_group=cfg.n_group,
+                                     topk_group=cfg.topk_group)
+                selected.append(sel)
+            x = h + m
     return x, selected
 
 
@@ -271,7 +287,8 @@ class DotsVlmDecoder(MoeCounters):
         layers' `Selected` ids side by side, [rows, S, layers * k]])."""
         x, selected = _body(context_ids, self.cfg, batch, max_len, row_ids)
         s = context_ids.shape[1]
-        last = layers.slice(x, [1], [s - 1], [s])
+        with name_scope("head"):
+            last = layers.slice(x, [1], [s - 1], [s])
         return _head(last, self.cfg, "dots"), _extras(selected)
 
     def decode_step(self, token_ids, pos_ids, max_len):

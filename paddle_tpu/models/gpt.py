@@ -13,6 +13,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
+from ..framework.program import name_scope
 from ..param_attr import ParamAttr
 
 
@@ -76,73 +77,87 @@ def _ln(x, name):
 
 
 def _decoder_layer(x, cfg, prefix, is_test):
-    b, s, h = x.shape
-    nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    h = cfg.hidden_size
     # pre-LN attention block
-    a = _ln(x, f"{prefix}_ln1")
-    qkv = _dense(a, 3 * h, f"{prefix}_attn_qkv", cfg)
+    with name_scope("attn"):
+        a = _ln(x, f"{prefix}_ln1")
+        with name_scope("proj"):
+            qkv = _dense(a, 3 * h, f"{prefix}_attn_qkv", cfg)
+        with name_scope("core"):
+            ctxv = _attention(qkv, cfg, is_test)
+        with name_scope("proj"):
+            attn = _dense(ctxv, h, f"{prefix}_attn_out", cfg)
+        x = x + layers.dropout(attn, cfg.hidden_dropout, is_test=is_test)
+    # pre-LN MLP block
+    with name_scope("mlp"):
+        m = _ln(x, f"{prefix}_ln2")
+        # tanh-approximate GELU — GPT-2's canonical formula, and ~2x
+        # cheaper than exact erf on the TPU VPU (see models/bert.py)
+        m = _dense(m, cfg.intermediate_size, f"{prefix}_mlp_in", cfg)
+        m = layers.gelu(m, approximate=True)
+        m = _dense(m, cfg.hidden_size, f"{prefix}_mlp_out", cfg)
+        return x + layers.dropout(m, cfg.hidden_dropout, is_test=is_test)
+
+
+def _attention(qkv, cfg, is_test):
+    """Causal self-attention over the packed [B, S, 3H] projections."""
+    b, s, _ = qkv.shape
+    h = cfg.hidden_size
+    nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     if cfg.use_fused_attention:
-        ctxv = layers.fused_qkv_attention(
+        return layers.fused_qkv_attention(
             qkv, nh, causal=True, scale=1.0 / math.sqrt(dh),
             dropout_prob=cfg.attention_dropout, is_test=is_test,
         )
-    else:
-        def head(t):
-            return layers.transpose(
-                layers.reshape(t, [b, s, nh, dh]), [0, 2, 1, 3]
-            )
 
-        q = head(layers.slice(qkv, [2], [0], [h]))
-        k = head(layers.slice(qkv, [2], [h], [2 * h]))
-        v = head(layers.slice(qkv, [2], [2 * h], [3 * h]))
-        scores = layers.matmul(
-            q, k, transpose_y=True, alpha=1.0 / math.sqrt(dh)
+    def head(t):
+        return layers.transpose(
+            layers.reshape(t, [b, s, nh, dh]), [0, 2, 1, 3]
         )
-        # causal additive mask: 0 on/below the diagonal, -1e4 above
-        mask = layers.reshape(
-            layers.scale(
-                layers.tril(layers.fill_constant([s, s], "float32", 1.0)),
-                scale=1e4, bias=-1e4,
-            ),
-            [1, 1, s, s],
-        )
-        scores = scores + mask
-        probs = layers.softmax(scores, axis=-1)
-        probs = layers.dropout(
-            probs, cfg.attention_dropout, is_test=is_test
-        )
-        ctxv = layers.reshape(
-            layers.transpose(layers.matmul(probs, v), [0, 2, 1, 3]),
-            [b, s, h],
-        )
-    attn = _dense(ctxv, h, f"{prefix}_attn_out", cfg)
-    x = x + layers.dropout(attn, cfg.hidden_dropout, is_test=is_test)
-    # pre-LN MLP block
-    m = _ln(x, f"{prefix}_ln2")
-    # tanh-approximate GELU — GPT-2's canonical formula, and ~2x cheaper
-    # than exact erf on the TPU VPU (see models/bert.py)
-    m = _dense(m, cfg.intermediate_size, f"{prefix}_mlp_in", cfg)
-    m = layers.gelu(m, approximate=True)
-    m = _dense(m, cfg.hidden_size, f"{prefix}_mlp_out", cfg)
-    return x + layers.dropout(m, cfg.hidden_dropout, is_test=is_test)
+
+    q = head(layers.slice(qkv, [2], [0], [h]))
+    k = head(layers.slice(qkv, [2], [h], [2 * h]))
+    v = head(layers.slice(qkv, [2], [2 * h], [3 * h]))
+    scores = layers.matmul(
+        q, k, transpose_y=True, alpha=1.0 / math.sqrt(dh)
+    )
+    # causal additive mask: 0 on/below the diagonal, -1e4 above
+    mask = layers.reshape(
+        layers.scale(
+            layers.tril(layers.fill_constant([s, s], "float32", 1.0)),
+            scale=1e4, bias=-1e4,
+        ),
+        [1, 1, s, s],
+    )
+    scores = scores + mask
+    probs = layers.softmax(scores, axis=-1)
+    probs = layers.dropout(
+        probs, cfg.attention_dropout, is_test=is_test
+    )
+    return layers.reshape(
+        layers.transpose(layers.matmul(probs, v), [0, 2, 1, 3]),
+        [b, s, h],
+    )
 
 
 def gpt_decoder(input_ids, cfg, is_test=False):
     """input_ids [B, S] int64 -> final hidden states [B, S, H]."""
     b, s = input_ids.shape
-    tok = layers.embedding(
-        input_ids, size=[cfg.vocab_size, cfg.hidden_size],
-        param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
-    )
-    pos_ids = layers.reshape(layers.range(0, s, 1, "int64"), [1, s])
-    pos = layers.embedding(
-        pos_ids, size=[cfg.max_position, cfg.hidden_size],
-        param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
-    )
-    x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=is_test)
+    with name_scope("embed"):
+        tok = layers.embedding(
+            input_ids, size=[cfg.vocab_size, cfg.hidden_size],
+            param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
+        )
+        pos_ids = layers.reshape(layers.range(0, s, 1, "int64"), [1, s])
+        pos = layers.embedding(
+            pos_ids, size=[cfg.max_position, cfg.hidden_size],
+            param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
+        )
+        x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=is_test)
     for i in range(cfg.num_layers):
         x = _decoder_layer(x, cfg, f"gpt_l{i}", is_test)
-    return _ln(x, "gpt_lnf")
+    with name_scope("head"):
+        return _ln(x, "gpt_lnf")
 
 
 def _lm_head(hidden, cfg):
@@ -163,24 +178,26 @@ def gpt_lm_loss(input_ids, cfg, is_test=False, labels=None):
     # projection copies a [B, S, V] tensor (~0.5 GB at S=2048/V=32k);
     # slicing before it is a [B, S, H] copy and the head matmul computes
     # only the s-1 predicted positions
-    pred_h = layers.slice(hidden, [1], [0], [s - 1])
-    pred = _lm_head(pred_h, cfg)
-    if labels is None:
-        tgt = layers.slice(input_ids, [1], [1], [s])
-    else:
-        tgt = layers.slice(labels, [1], [1], [s])
-    loss = layers.softmax_with_cross_entropy(
-        layers.reshape(pred, [b * (s - 1), cfg.vocab_size]),
-        layers.reshape(tgt, [b * (s - 1), 1]),
-    )
-    return layers.mean(loss)
+    with name_scope("head"):
+        pred_h = layers.slice(hidden, [1], [0], [s - 1])
+        pred = _lm_head(pred_h, cfg)
+        if labels is None:
+            tgt = layers.slice(input_ids, [1], [1], [s])
+        else:
+            tgt = layers.slice(labels, [1], [1], [s])
+        loss = layers.softmax_with_cross_entropy(
+            layers.reshape(pred, [b * (s - 1), cfg.vocab_size]),
+            layers.reshape(tgt, [b * (s - 1), 1]),
+        )
+        return layers.mean(loss)
 
 
 def gpt_logits(input_ids, cfg, is_test=True):
     """Full-context logits [B, S, V] — the serving/full-recompute head
     (no label shift, no loss): every position's next-token distribution."""
     hidden = gpt_decoder(input_ids, cfg, is_test=is_test)
-    return _lm_head(hidden, cfg)
+    with name_scope("head"):
+        return _lm_head(hidden, cfg)
 
 
 # --- KV-cache serving graphs (prefill + single-token decode) ---------------
@@ -232,39 +249,45 @@ def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
 
     b, t, h = x.shape
     nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    a = _ln(x, f"{prefix}_ln1")
-    qkv = _dense(a, 3 * h, f"{prefix}_attn_qkv", cfg)
-    q = layers.slice(qkv, [2], [0], [h])
-    k = layers.slice(qkv, [2], [h], [2 * h])
-    v = layers.slice(qkv, [2], [2 * h], [3 * h])
-    ck = _cache_var(f"{prefix}_cache_k", b, max_len, nh, dh)
-    cv = _cache_var(f"{prefix}_cache_v", b, max_len, nh, dh)
-    blk = default_main_program().global_block
-    for cache, rows in ((ck, k), (cv, v)):
-        blk.append_op(
-            "kv_cache_write",
-            {"Cache": [cache.name], "X": [rows.name],
-             "Pos": [write_pos.name]},
-            {"Out": [cache.name]},
-        )
-    attrs = {"num_heads": nh, "num_kv_heads": nh,
-             "scale": 1.0 / math.sqrt(dh),
-             "prob_scale": 1.0 - cfg.attention_dropout}
-    if attend_pos is None:
-        ctxv = _simple("causal_gqa_attention",
-                       {"Q": [q], "K": [k], "V": [v]}, attrs)
-    else:
-        ctxv = _simple(
-            "kv_cache_attention",
-            {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [attend_pos]},
-            attrs)
-    attn = _dense(ctxv, h, f"{prefix}_attn_out", cfg)
-    x = x + layers.dropout(attn, cfg.hidden_dropout, is_test=True)
-    m = _ln(x, f"{prefix}_ln2")
-    m = _dense(m, cfg.intermediate_size, f"{prefix}_mlp_in", cfg)
-    m = layers.gelu(m, approximate=True)
-    m = _dense(m, cfg.hidden_size, f"{prefix}_mlp_out", cfg)
-    return x + layers.dropout(m, cfg.hidden_dropout, is_test=True)
+    with name_scope("attn"):
+        a = _ln(x, f"{prefix}_ln1")
+        with name_scope("proj"):
+            qkv = _dense(a, 3 * h, f"{prefix}_attn_qkv", cfg)
+        q = layers.slice(qkv, [2], [0], [h])
+        k = layers.slice(qkv, [2], [h], [2 * h])
+        v = layers.slice(qkv, [2], [2 * h], [3 * h])
+        with name_scope("core"):
+            ck = _cache_var(f"{prefix}_cache_k", b, max_len, nh, dh)
+            cv = _cache_var(f"{prefix}_cache_v", b, max_len, nh, dh)
+            blk = default_main_program().global_block
+            for cache, rows in ((ck, k), (cv, v)):
+                blk.append_op(
+                    "kv_cache_write",
+                    {"Cache": [cache.name], "X": [rows.name],
+                     "Pos": [write_pos.name]},
+                    {"Out": [cache.name]},
+                )
+            attrs = {"num_heads": nh, "num_kv_heads": nh,
+                     "scale": 1.0 / math.sqrt(dh),
+                     "prob_scale": 1.0 - cfg.attention_dropout}
+            if attend_pos is None:
+                ctxv = _simple("causal_gqa_attention",
+                               {"Q": [q], "K": [k], "V": [v]}, attrs)
+            else:
+                ctxv = _simple(
+                    "kv_cache_attention",
+                    {"Q": [q], "CacheK": [ck], "CacheV": [cv],
+                     "Pos": [attend_pos]},
+                    attrs)
+        with name_scope("proj"):
+            attn = _dense(ctxv, h, f"{prefix}_attn_out", cfg)
+        x = x + layers.dropout(attn, cfg.hidden_dropout, is_test=True)
+    with name_scope("mlp"):
+        m = _ln(x, f"{prefix}_ln2")
+        m = _dense(m, cfg.intermediate_size, f"{prefix}_mlp_in", cfg)
+        m = layers.gelu(m, approximate=True)
+        m = _dense(m, cfg.hidden_size, f"{prefix}_mlp_out", cfg)
+        return x + layers.dropout(m, cfg.hidden_dropout, is_test=True)
 
 
 def gpt_prefill(context_ids, cfg, max_len):
@@ -279,24 +302,26 @@ def gpt_prefill(context_ids, cfg, max_len):
         raise InvalidArgumentError(
             f"max_len {max_len} exceeds cfg.max_position {cfg.max_position}"
         )
-    tok = layers.embedding(
-        context_ids, size=[cfg.vocab_size, cfg.hidden_size],
-        param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
-    )
-    pos_ids = layers.reshape(layers.range(0, s, 1, "int64"), [1, s])
-    pos = layers.embedding(
-        pos_ids, size=[cfg.max_position, cfg.hidden_size],
-        param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
-    )
-    x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=True)
-    write_pos = layers.fill_constant([1], "int32", 0)
+    with name_scope("embed"):
+        tok = layers.embedding(
+            context_ids, size=[cfg.vocab_size, cfg.hidden_size],
+            param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
+        )
+        pos_ids = layers.reshape(layers.range(0, s, 1, "int64"), [1, s])
+        pos = layers.embedding(
+            pos_ids, size=[cfg.max_position, cfg.hidden_size],
+            param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
+        )
+        x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=True)
+        write_pos = layers.fill_constant([1], "int32", 0)
     for i in range(cfg.num_layers):
         x = _cached_decoder_layer(
             x, cfg, f"gpt_l{i}", write_pos, None, max_len
         )
-    x = _ln(x, "gpt_lnf")
-    last_h = layers.slice(x, [1], [s - 1], [s])
-    return _lm_head(last_h, cfg)
+    with name_scope("head"):
+        x = _ln(x, "gpt_lnf")
+        last_h = layers.slice(x, [1], [s - 1], [s])
+        return _lm_head(last_h, cfg)
 
 
 def gpt_decode_step(token_ids, pos_ids, cfg, max_len):
@@ -306,29 +331,31 @@ def gpt_decode_step(token_ids, pos_ids, cfg, max_len):
     logits [B, 1, V]. Run repeatedly with the SAME shapes — one compiled
     executable serves the whole generation."""
     b = token_ids.shape[0]
-    # [B, 1] ids hit the v1 lookup_table (trailing-1 squeeze): restore the
-    # [B, T=1, H] layout the layer stack expects
-    tok = layers.reshape(
-        layers.embedding(
-            token_ids, size=[cfg.vocab_size, cfg.hidden_size],
-            param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
-        ),
-        [b, 1, cfg.hidden_size],
-    )
-    pos = layers.reshape(
-        layers.embedding(
-            pos_ids, size=[cfg.max_position, cfg.hidden_size],
-            param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
-        ),
-        [1, 1, cfg.hidden_size],
-    )
-    x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=True)
+    with name_scope("embed"):
+        # [B, 1] ids hit the v1 lookup_table (trailing-1 squeeze): restore
+        # the [B, T=1, H] layout the layer stack expects
+        tok = layers.reshape(
+            layers.embedding(
+                token_ids, size=[cfg.vocab_size, cfg.hidden_size],
+                param_attr=ParamAttr(name="wte", initializer=_init(cfg)),
+            ),
+            [b, 1, cfg.hidden_size],
+        )
+        pos = layers.reshape(
+            layers.embedding(
+                pos_ids, size=[cfg.max_position, cfg.hidden_size],
+                param_attr=ParamAttr(name="wpe", initializer=_init(cfg)),
+            ),
+            [1, 1, cfg.hidden_size],
+        )
+        x = layers.dropout(tok + pos, cfg.hidden_dropout, is_test=True)
     for i in range(cfg.num_layers):
         x = _cached_decoder_layer(
             x, cfg, f"gpt_l{i}", pos_ids, pos_ids, max_len
         )
-    x = _ln(x, "gpt_lnf")
-    return _lm_head(x, cfg)
+    with name_scope("head"):
+        x = _ln(x, "gpt_lnf")
+        return _lm_head(x, cfg)
 
 
 class GPTDecoder:

@@ -30,6 +30,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
+from ..framework.program import name_scope
 from ..initializer import Constant, Initializer, Normal, Uniform
 from ..layers.tensor import _simple
 from ..param_attr import ParamAttr
@@ -39,6 +40,8 @@ from .afmoe import (
 )
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+# the name scope (fluid.name_scope) of a block of each kind
+SECTIONS = {MAMBA: "ssm", EXPERTS: "moe", ATTENTION: "attn"}
 COUNTERS_VAR = "nemotron_moe_counters"
 
 
@@ -201,7 +204,8 @@ def _mamba_mixer(a, cfg, prefix, batch, row_ids, decode):
 
     h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
     d, conv = cfg.d_inner, cfg.conv_dim
-    zxbcdt = _proj(a, d + conv + h, f"{prefix}_in_w", cfg)
+    with name_scope("proj"):
+        zxbcdt = _proj(a, d + conv + h, f"{prefix}_in_w", cfg)
     z = _slice_last(zxbcdt, 0, d)
     xbc = _slice_last(zxbcdt, d, d + conv)
     dt = _slice_last(zxbcdt, d + conv, d + conv + h)
@@ -242,18 +246,21 @@ def _mamba_mixer(a, cfg, prefix, batch, row_ids, decode):
     ins = {"XBC": [convolved.name], "Dt": [dt.name],
            "ALog": [small["a_log"].name], "D": [small["d"].name],
            "DtBias": [small["dt_bias"].name], "State": [state.name]}
-    if decode:
-        blk.append_op("ssm_state_update", ins,
-                      {"Out": [y.name], "StateOut": [state.name]}, attrs)
-    else:
-        blk.append_op("ssd_chunk_scan", {**ins, **row},
-                      {"Out": [y.name], "StateOut": [state.name]},
-                      {**attrs, "chunk": cfg.chunk_size})
+    with name_scope("scan"):
+        if decode:
+            blk.append_op("ssm_state_update", ins,
+                          {"Out": [y.name], "StateOut": [state.name]},
+                          attrs)
+        else:
+            blk.append_op("ssd_chunk_scan", {**ins, **row},
+                          {"Out": [y.name], "StateOut": [state.name]},
+                          {**attrs, "chunk": cfg.chunk_size})
     gain = _param(f"{prefix}_gate_norm", [d], cfg,
                   _normal(cfg, 1.0, cfg.initializer_range))
     g = _simple("gated_rms_norm", {"X": [y], "Gate": [z], "Scale": [gain]},
                 {"num_groups": cfg.n_groups, "epsilon": cfg.rms_norm_eps})
-    return _proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
+    with name_scope("proj"):
+        return _proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
 
 
 def _attention_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
@@ -262,28 +269,32 @@ def _attention_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     from ..ops.kv_cache import cache_shape
 
     nh, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _proj(a, nh * dh, f"{prefix}_attn_q_w", cfg)
-    k = _proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg)
-    v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
+    with name_scope("proj"):
+        q = _proj(a, nh * dh, f"{prefix}_attn_q_w", cfg)
+        k = _proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg)
+        v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
     shape = cache_shape(batch, max_len, kvh, dh)
     ck, cv = (_state_var(f"{prefix}_cache_{w}", shape, cfg.dtype)
               for w in ("k", "v"))
     attrs = {"num_heads": nh, "num_kv_heads": kvh, "window": 0,
              "scale": 1.0 / math.sqrt(dh)}
-    if pos_ids is None:
-        first = layers.fill_constant([1], "int32", 0)
-        _write_cache(ck, k, first, row_ids, ring=True)
-        _write_cache(cv, v, first, row_ids, ring=True)
-        out = _simple("causal_gqa_attention",
-                      {"Q": [q], "K": [k], "V": [v]}, attrs)
-    else:
-        _write_cache(ck, k, pos_ids, None, ring=True)
-        _write_cache(cv, v, pos_ids, None, ring=True)
-        out = _simple(
-            "kv_cache_attention",
-            {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [pos_ids]},
-            attrs)
-    return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
+    with name_scope("core"):
+        if pos_ids is None:
+            first = layers.fill_constant([1], "int32", 0)
+            _write_cache(ck, k, first, row_ids, ring=True)
+            _write_cache(cv, v, first, row_ids, ring=True)
+            out = _simple("causal_gqa_attention",
+                          {"Q": [q], "K": [k], "V": [v]}, attrs)
+        else:
+            _write_cache(ck, k, pos_ids, None, ring=True)
+            _write_cache(cv, v, pos_ids, None, ring=True)
+            out = _simple(
+                "kv_cache_attention",
+                {"Q": [q], "CacheK": [ck], "CacheV": [cv],
+                 "Pos": [pos_ids]},
+                attrs)
+    with name_scope("proj"):
+        return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
 
 
 def _relu2_ffn(x, width, out_width, prefix, cfg):
@@ -314,7 +325,8 @@ def _expert_mixer(a, cfg, prefix):
     w_down = _param(f"{prefix}_experts_down_w", [e_local, f, lat], cfg,
                     _MeanFreeNormal(cfg.initializer_range, axis=1))
     counters = _state_var(COUNTERS_VAR, (len(MOE_COUNTERS),), "int32")
-    u = _proj(a, lat, f"{prefix}_latent_down_w", cfg)
+    with name_scope("latent"):
+        u = _proj(a, lat, f"{prefix}_latent_down_w", cfg)
     blk = default_main_program().global_block
     routed = blk.create_var(name=unique_name.generate(f"{prefix}_routed"),
                             shape=u.shape, dtype=u.dtype)
@@ -322,20 +334,24 @@ def _expert_mixer(a, cfg, prefix):
         name=f"{prefix}_selected", shape=tuple(a.shape[:2]) + (cfg.top_k,),
         dtype="int32",
     )
-    blk.append_op(
-        "moe_local_experts",
-        {"X": [u.name], "RouterX": [a.name], "RouterW": [router_w.name],
-         "ExpertBias": [bias.name], "WGateUp": [w_up.name],
-         "WDown": [w_down.name], "Counters": [counters.name]},
-        {"Out": [routed.name], "Selected": [selected.name],
-         "CountersOut": [counters.name]},
-        {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
-         "route_norm": cfg.route_norm, "expert_offset": cfg.expert_offset,
-         "activation": "relu2"},
-    )
-    out = _proj(routed, h, f"{prefix}_latent_up_w", cfg)
-    out = out + _relu2_ffn(a, cfg.shared_intermediate_size, h,
-                           f"{prefix}_shared", cfg)
+    with name_scope("experts"):
+        blk.append_op(
+            "moe_local_experts",
+            {"X": [u.name], "RouterX": [a.name],
+             "RouterW": [router_w.name], "ExpertBias": [bias.name],
+             "WGateUp": [w_up.name], "WDown": [w_down.name],
+             "Counters": [counters.name]},
+            {"Out": [routed.name], "Selected": [selected.name],
+             "CountersOut": [counters.name]},
+            {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
+             "route_norm": cfg.route_norm,
+             "expert_offset": cfg.expert_offset, "activation": "relu2"},
+        )
+    with name_scope("latent"):
+        out = _proj(routed, h, f"{prefix}_latent_up_w", cfg)
+    with name_scope("shared"):
+        out = out + _relu2_ffn(a, cfg.shared_intermediate_size, h,
+                               f"{prefix}_shared", cfg)
     return out, selected
 
 
@@ -344,25 +360,29 @@ def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
     the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
     with. Returns (hidden [.., H], [the expert blocks' Selected ids])."""
     seq = ids.shape[1]
-    x = layers.embedding(
-        ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-        param_attr=ParamAttr(name="nemotron_embed", initializer=_normal(cfg)),
-    )
-    x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
+    with name_scope("embed"):
+        x = layers.embedding(
+            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
+            param_attr=ParamAttr(name="nemotron_embed",
+                                 initializer=_normal(cfg)),
+        )
+        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
     selected = []
     for i, kind in enumerate(cfg.pattern):
         prefix = f"nemotron_l{i}"
-        a = _rms(x, f"{prefix}_norm", cfg)
-        if kind == MAMBA:
-            m = _mamba_mixer(a, cfg, prefix, batch, row_ids,
-                             decode=pos_ids is not None)
-        elif kind == ATTENTION:
-            m = _attention_mixer(a, cfg, prefix, batch, max_len, row_ids,
-                                 pos_ids)
-        else:
-            m, sel = _expert_mixer(a, cfg, prefix)
-            selected.append(sel)
-        x = x + m
+        # a block is one section: its norm, its mixer, its residual sum
+        with name_scope(SECTIONS[kind]):
+            a = _rms(x, f"{prefix}_norm", cfg)
+            if kind == MAMBA:
+                m = _mamba_mixer(a, cfg, prefix, batch, row_ids,
+                                 decode=pos_ids is not None)
+            elif kind == ATTENTION:
+                m = _attention_mixer(a, cfg, prefix, batch, max_len,
+                                     row_ids, pos_ids)
+            else:
+                m, sel = _expert_mixer(a, cfg, prefix)
+                selected.append(sel)
+            x = x + m
     return x, selected
 
 
@@ -385,7 +405,8 @@ class NemotronHDecoder(MoeCounters):
         blocks' `Selected` ids side by side, [rows, S, blocks * k]])."""
         x, selected = _body(context_ids, self.cfg, batch, max_len, row_ids)
         s = context_ids.shape[1]
-        last = layers.slice(x, [1], [s - 1], [s])
+        with name_scope("head"):
+            last = layers.slice(x, [1], [s - 1], [s])
         return _head(last, self.cfg, "nemotron"), _extras(selected)
 
     def decode_step(self, token_ids, pos_ids, max_len):

@@ -105,6 +105,18 @@ class _Span:
             self._t0 = None
         return self
 
+    def refresh(self):
+        """Enter the profiler annotation anew; the ring's record stays
+        one. A span entered before `start_trace` is not in the capture at
+        all: a span that may be open long before anybody starts one (the
+        scheduler's wait for work) calls this as it polls, so that the
+        capture shows it from the next poll on, as consecutive pieces
+        under its one name."""
+        if self._t0 is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
+
     def __exit__(self, *exc):
         if self._t0 is not None:
             self._annotation.__exit__(*exc)

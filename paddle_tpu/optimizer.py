@@ -122,6 +122,10 @@ class Optimizer:
             processed.append((p, g))
         self._create_accumulators(block, [p for p, _ in processed])
         ops = [self._append_optimize_op(block, pg) for pg in processed]
+        # a program with update ops is a training step (its XLA module
+        # is `jit_train_step` in a capture) unless its owner says more
+        if block.program._label is None:
+            block.program._label = "train_step"
         return ops
 
     def apply_optimize(self, loss, startup_program, params_grads):

@@ -224,44 +224,51 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     tokens = x.reshape(b * t, h)
     n_tok = b * t
     scored = tokens if router_x is None else router_x.reshape(n_tok, -1)
-    sel, weights = sigmoid_topk_route(
-        scored, router_w, expert_bias, top_k, route_scale, route_norm,
-        n_group, topk_group,
-    )
-    local = sel - expert_offset
-    is_local = (local >= 0) & (local < n_local)
-    expert = jnp.where(is_local, local, n_local).reshape(-1)     # [A]
+    # the op's parts by name in the compiled step's `op_name`, beneath
+    # the op's own scope: router, sort, dispatch, experts, combine
+    with jax.named_scope("moe_router"):
+        sel, weights = sigmoid_topk_route(
+            scored, router_w, expert_bias, top_k, route_scale, route_norm,
+            n_group, topk_group,
+        )
     n_assign = n_tok * top_k
     tm = _row_tile(n_assign)
     n_tiles = -(-n_assign // tm) + n_local
 
-    # each assignment's rank inside its expert's group, and the groups'
-    # sizes, from one running count per expert
-    onehot = (expert[:, None] == jnp.arange(n_local)[None, :])
-    running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)       # [A, E_l]
-    counts = running[-1]
-    clamped = jnp.minimum(expert, n_local - 1)
-    rank = jnp.take_along_axis(running, clamped[:, None], axis=1)[:, 0] - 1
-    group_tiles = -(-counts // tm)
-    tiles_before = jnp.cumsum(group_tiles) - group_tiles
-    num_active = jnp.sum(group_tiles)
-    slot = jnp.where(
-        expert < n_local,
-        tiles_before[clamped] * tm + rank,
-        n_tiles * tm,                      # out of range: dropped below
-    )
-    token_of = jnp.arange(n_assign, dtype=jnp.int32) // top_k
-    token_of_slot = jnp.zeros((n_tiles * tm,), jnp.int32).at[slot].set(
-        token_of, mode="drop"
-    )
-    # tile i belongs to the expert whose run of tiles covers it; tiles
-    # past the last active one repeat its expert (no new weight fetch)
-    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(num_active - 1, 0))
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(jnp.cumsum(group_tiles), tile, side="right"),
-        n_local - 1,
-    ).astype(jnp.int32)
-    active = num_active.reshape(1).astype(jnp.int32)
+    with jax.named_scope("moe_sort"):
+        local = sel - expert_offset
+        is_local = (local >= 0) & (local < n_local)
+        expert = jnp.where(is_local, local, n_local).reshape(-1)  # [A]
+        # each assignment's rank inside its expert's group, and the
+        # groups' sizes, from one running count per expert
+        onehot = (expert[:, None] == jnp.arange(n_local)[None, :])
+        running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)  # [A, E_l]
+        counts = running[-1]
+        clamped = jnp.minimum(expert, n_local - 1)
+        rank = jnp.take_along_axis(
+            running, clamped[:, None], axis=1)[:, 0] - 1
+        group_tiles = -(-counts // tm)
+        tiles_before = jnp.cumsum(group_tiles) - group_tiles
+        num_active = jnp.sum(group_tiles)
+        slot = jnp.where(
+            expert < n_local,
+            tiles_before[clamped] * tm + rank,
+            n_tiles * tm,                      # out of range: dropped below
+        )
+        token_of = jnp.arange(n_assign, dtype=jnp.int32) // top_k
+        token_of_slot = jnp.zeros((n_tiles * tm,), jnp.int32).at[slot].set(
+            token_of, mode="drop"
+        )
+        # tile i belongs to the expert whose run of tiles covers it;
+        # tiles past the last active one repeat its expert (no new weight
+        # fetch)
+        tile = jnp.minimum(jnp.arange(n_tiles),
+                           jnp.maximum(num_active - 1, 0))
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(jnp.cumsum(group_tiles), tile, side="right"),
+            n_local - 1,
+        ).astype(jnp.int32)
+        active = num_active.reshape(1).astype(jnp.int32)
 
     # off the TPU the same product in `jnp` (kernel tests: `interpret`)
     gmm = moe_gmm.gmm_reference

@@ -173,6 +173,7 @@ def wrap_shard_map(
         )
         return sm(feeds, smut, sro, step_key)
 
+    run.__name__ = traced.__name__  # the executor's name for the module
     jitted = jax.jit(run, donate_argnums=(1,))
     multiproc = _spans_processes(mesh)
 

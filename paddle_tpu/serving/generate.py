@@ -151,6 +151,12 @@ class GPTGenerator:
         # in test mode and the verifier holds the inference contract
         self.prefill_prog._is_inference = True
         self.decode_prog._is_inference = True
+        # what the Executor calls the two compiled steps: a capture's
+        # device events carry their XLA module, `jit_<family>_prefill` or
+        # `jit_<family>_decode`, which is how a reader tells the phases
+        family = decoder.describe()["family"]
+        self.prefill_prog._label = f"{family}_prefill"
+        self.decode_prog._label = f"{family}_decode"
         self._scope_guard = scope_guard
         specs = decoder.state_specs(self.batch, self.max_len)
         self._state_specs = specs + [
@@ -189,7 +195,7 @@ class GPTGenerator:
         """The greedy choice of the step being built, left on the device
         in the generator's two token persistables. `pos`: the fed
         token's position (decode); `row`: first row of a prefill block."""
-        from ..framework.program import default_main_program
+        from ..framework.program import default_main_program, name_scope
 
         blk = default_main_program().global_block
         nxt, tokens = (
@@ -205,11 +211,12 @@ class GPTGenerator:
             # replaces it, so the array a step is fed is never one the
             # step also takes (and donates) as state
             ins.update(Row=[row.name], Next=[nxt.name])
-        blk.append_op(
-            "greedy_token", ins,
-            {"TokensOut": [tokens.name], "NextOut": [nxt.name]},
-            {"column": 0 if pos is None else 1 - self.context_len},
-        )
+        with name_scope("head"):
+            blk.append_op(
+                "greedy_token", ins,
+                {"TokensOut": [tokens.name], "NextOut": [nxt.name]},
+                {"column": 0 if pos is None else 1 - self.context_len},
+            )
 
     def _param_vars(self):
         state = {name for name, _shape, _dtype in self._state_specs}

@@ -527,6 +527,9 @@ class Endpoint:
             with forming, self._cond:
                 while not self._qsize_locked() and not self._stopped:
                     self._cond.wait(0.05)
+                    # a capture that began during the wait sees its owner
+                    # from here on (the idle at a traced window's start)
+                    forming.refresh()
                 if self._stopped and not self._qsize_locked():
                     break
                 # already-expired requests leave BEFORE batch formation:
@@ -606,27 +609,36 @@ class Endpoint:
 
         t0 = time.perf_counter()
         n = len(batch)
-        # queue wait ends the moment the batch forms: recorded per
-        # request under ITS trace (the capture/activate handoff — this
-        # runs on the scheduler thread, the context was captured at
-        # ingest), so "where did this request's latency go" splits into
-        # queue-wait vs dispatch from the trace alone
-        for r in batch:
-            spans.record(
-                "serving.queue_wait", t0 - r.t_enqueue,
-                category="serving", ctx=r.ctx,
-                args={"endpoint": self.name, "batch_size": n},
-            )
+        first = batch[0].ctx
         try:
-            feed = {}
-            for name in self.runner.feed_names:
-                rows = np.stack([r.feeds[name] for r in batch])
-                if n < bucket:
-                    pad = np.zeros(
-                        (bucket - n,) + rows.shape[1:], rows.dtype
+            # the hand-over INTO the batch has an owner of its own (a
+            # device idle here waits for the host to record and stack):
+            # live, so it is in a profiler capture, under the first
+            # request's trace as `serving.batch` is
+            with trace.activate(first), \
+                    self._obs.span("serving.assemble", category="serving",
+                                   endpoint=self.name, batch_size=n):
+                # queue wait ends the moment the batch forms: recorded
+                # per request under ITS trace (the capture/activate
+                # handoff — this runs on the scheduler thread, the
+                # context was captured at ingest), so "where did this
+                # request's latency go" splits into queue-wait vs
+                # dispatch from the trace alone
+                for r in batch:
+                    spans.record(
+                        "serving.queue_wait", t0 - r.t_enqueue,
+                        category="serving", ctx=r.ctx,
+                        args={"endpoint": self.name, "batch_size": n},
                     )
-                    rows = np.concatenate([rows, pad], axis=0)
-                feed[name] = rows
+                feed = {}
+                for name in self.runner.feed_names:
+                    rows = np.stack([r.feeds[name] for r in batch])
+                    if n < bucket:
+                        pad = np.zeros(
+                            (bucket - n,) + rows.shape[1:], rows.dtype
+                        )
+                        rows = np.concatenate([rows, pad], axis=0)
+                    feed[name] = rows
             # concurrent dispatchers skip the run lock: a runner that
             # declared max_concurrency > 1 (the process fleet) is
             # thread-safe by contract, and serializing here would undo it
@@ -640,7 +652,7 @@ class Endpoint:
                 # files under the FIRST request's trace; the other
                 # requests get their dispatch share recorded
                 # retrospectively below, so every trace is complete
-                with trace.activate(batch[0].ctx), \
+                with trace.activate(first), \
                         self._obs.span("serving.batch", category="serving",
                                        endpoint=self.name, bucket=bucket,
                                        batch_size=n):
@@ -660,6 +672,25 @@ class Endpoint:
             for r in batch:
                 r.future.set_exception(exc)
             return
+        # the hand-over OUT of the batch: the records, the histograms and
+        # the futures (each `set_result` wakes a client thread, which in a
+        # closed loop submits again under the GIL): `resolve_ms` is the
+        # time inside `set_result` alone
+        completing = self._obs.span(
+            "serving.complete", category="serving", endpoint=self.name,
+            batch_size=n, resolve_ms=0.0,
+        )
+        with trace.activate(first), completing:
+            completing.args["resolve_ms"] = self._complete(
+                batch, bucket, outs, t0
+            )
+
+    def _complete(self, batch, bucket, outs, t0):
+        """Record a finished batch and resolve its futures; returns the
+        milliseconds spent inside `set_result`."""
+        from ..observability import spans
+
+        n = len(batch)
         dt = time.perf_counter() - t0
         now = time.perf_counter()
         for r in batch:
@@ -670,7 +701,6 @@ class Endpoint:
                       "batch_size": n},
             )
         self._obs.add("serving.batches")
-        self._obs.add(f"serving.batches.{self.name}")
         self._obs.add(f"serving.bucket_runs.{self.name}.{bucket}")
         self._obs.observe("serving.batch_latency", dt)
         self._obs.observe(
@@ -681,15 +711,20 @@ class Endpoint:
             buckets=_RATIO_BUCKETS,
         )
         goodput = late = 0
+        resolving = 0.0
+        # the per-endpoint histogram is what a brownout watcher reads
+        own_latency = f"serving.request_latency.{self.name}"
         for i, r in enumerate(batch):
+            t_set = time.perf_counter()
             r.future.set_result([o[i] for o in outs])
+            resolving += time.perf_counter() - t_set
             lat = now - r.t_enqueue
             if r.deadline is None or now <= r.deadline:
                 goodput += 1
             else:
                 late += 1
             self._obs.observe("serving.request_latency", lat)
-            self._obs.observe(f"serving.request_latency.{self.name}", lat)
+            self._obs.observe(own_latency, lat)
         self._obs.add("serving.requests_served", n)
         # goodput = completions somebody was still waiting for: the
         # in-deadline share (deadline-less requests count — their client
@@ -700,6 +735,7 @@ class Endpoint:
         if late:
             self._obs.add("serving.late_completions", late)
             self._obs.add(f"serving.late_completions.{self.name}", late)
+        return 1e3 * resolving
 
     # -- warmup ------------------------------------------------------------
     def warmup(self):

@@ -6,7 +6,9 @@ the full ring pushes out is counted."""
 
 import collections
 import glob
+import itertools
 import os
+import time
 
 import jax
 import numpy as np
@@ -338,10 +340,73 @@ def test_form_batch_ends_where_the_batch_begins(tmp_path):
             and not s["args"]["batch_size"]]
     assert all(s["ts"] >= batch["ts"] for s in idle)
     # the same pair, in order, on the scheduler thread's line of the trace
+    # (a wait that polled shows as consecutive pieces under the one name)
     (line, _e), = cap.named("serving.batch")
-    order = [e[0] for e in cap.lines[line]
-             if e[0] in ("serving.form_batch", "serving.batch")]
+    order = [k for k, _g in itertools.groupby(
+        e[0] for e in cap.lines[line]
+        if e[0] in ("serving.form_batch", "serving.batch"))]
     assert order[:2] == ["serving.form_batch", "serving.batch"]
+
+
+def test_assemble_and_complete_own_the_hand_over(tmp_path):
+    """Between `serving.form_batch` and `serving.batch` the router records
+    the queue waits and stacks the feeds; after the batch it records,
+    observes and resolves the futures: each under a live span of its own
+    (ISSUE 35), filed under the first request's trace as the batch is."""
+    from paddle_tpu.serving import Endpoint, EndpointConfig
+
+    with Capture(tmp_path) as cap:
+        ep = Endpoint("stub", _Doubler(),
+                      EndpointConfig(buckets=(4,), max_wait_ms=2000.0))
+        futs = [ep.submit({"x": np.full(2, i, np.float32)})
+                for i in range(4)]
+        for f in futs:
+            f.result(timeout=10)
+        ep.drain(timeout=10)
+    ring = {s["name"]: s for s in obs.get_spans()
+            if s["name"] in ("serving.assemble", "serving.batch",
+                             "serving.complete")}
+    assemble, batch, complete = (ring[f"serving.{n}"] for n in
+                                 ("assemble", "batch", "complete"))
+    assert assemble["args"] == {"endpoint": "stub", "batch_size": 4}
+    assert set(complete["args"]) == {"endpoint", "batch_size", "resolve_ms"}
+    assert 0.0 < complete["args"]["resolve_ms"] <= complete["dur"] / 1e3
+    assert assemble["trace_id"] == batch["trace_id"] == complete["trace_id"]
+    assert assemble["parent_id"] == batch["parent_id"]
+    # in order, end to end, on the scheduler thread's line of the capture
+    (line, _e), = cap.named("serving.batch")
+    order = [list(g)[-1] for _k, g in itertools.groupby(
+        (e for e in cap.lines[line]
+         if e[0] in ("serving.form_batch", "serving.assemble",
+                     "serving.batch", "serving.complete")),
+        key=lambda e: e[0])][:4]
+    assert [e[0] for e in order] == [
+        "serving.form_batch", "serving.assemble", "serving.batch",
+        "serving.complete"]
+    for before, after in zip(order, order[1:]):
+        assert before[2] <= after[1]
+    # the retrospective spans they hold are in the ring, one a request
+    names = _ring_names()
+    assert names.count("serving.queue_wait") == 4
+    assert names.count("serving.dispatch") == 4
+
+
+def test_a_refreshed_span_shows_in_a_capture_begun_after_it(tmp_path):
+    """A span entered before `start_trace` is not in the capture; one
+    that refreshes its annotation is, from the refresh on, and the ring
+    still holds it once (PERF.md 7 k: the scheduler's wait for work)."""
+    waiting = obs.span("serving.form_batch", batch_size=0)
+    silent = obs.span("serving.live")
+    with waiting, silent:
+        with Capture(tmp_path) as cap:
+            time.sleep(0.002)
+            waiting.refresh()
+            time.sleep(0.002)
+            waiting.refresh()
+    pieces = cap.named("serving.form_batch")
+    assert len(pieces) == 1             # the piece that ended in the capture
+    assert cap.named("serving.live") == []
+    assert sorted(_ring_names()) == ["serving.form_batch", "serving.live"]
 
 
 def test_recorded_spans_stay_out_of_the_capture(tmp_path):
@@ -390,10 +455,10 @@ def test_lowered_step_carries_op_type_scopes():
     exe, loss, feed = _fit_a_line()
     text = exe.lower(feed=feed, fetch_list=[loss]).as_text(debug_info=True)
     ops = {op.type for op in fluid.default_main_program().global_block.ops}
-    scoped = {t for t in ops if f'"jit(traced)/{t}/' in text}
+    scoped = {t for t in ops if f'"jit(train_step)/{t}/' in text}
     # forward, backward and optimizer ops alike; an op whose emitter adds
     # no instruction of its own (assign) leaves no location to carry one
     assert {"mul", "elementwise_add", "square_error_cost", "reduce_mean",
             "__vjp__", "sgd"} <= scoped, sorted(ops - scoped)
     # a generic grad op names the forward op it replays
-    assert '"jit(traced)/__vjp__/transpose(jvp(mul))/dot_general"' in text
+    assert '"jit(train_step)/__vjp__/transpose(jvp(mul))/dot_general"' in text
