@@ -18,8 +18,6 @@ it runs without the exchange, and the absent experts' part is absent.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -143,7 +141,10 @@ MOE_COUNTERS = ("assignments_local", "assignments_total", "experts_hit",
                 "max_expert_load", "max_expert_load_sum", "calls",
                 # the same of one-token (decode) calls alone
                 "decode_assignments_local", "decode_experts_hit",
-                "decode_calls")
+                "decode_calls",
+                # rows of the sorted buffer the passes touch (the live
+                # tiles), and rows it is allocated for
+                "rows_live", "rows_buffer")
 
 
 def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
@@ -176,25 +177,21 @@ def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
     return sel.astype(jnp.int32), w * route_scale
 
 
+# rows of one tile of the grouped product: MXU-sized once the experts see
+# hundreds of rows each, the bfloat16 sublane pack for a decode step's
+# handful
+MXU_TILE, PACK_TILE = 256, 16
+
+
 def _row_tile(assignments):
-    """Rows of one tile of the grouped product: MXU-sized once the
-    experts see hundreds of rows each, the bfloat16 sublane pack for a
-    decode step's handful."""
-    return 256 if assignments >= 8192 else 16
+    return MXU_TILE if assignments >= 8192 else PACK_TILE
 
 
-def _expert_activation(name):
-    """The experts' form, by the op's `activation`: gated `swiglu` over
-    a fused [.., 2F] first product, or non-gated `relu2` over [.., F]."""
-    if name == "swiglu":
-        from ..ops.llm import swiglu
-
-        return swiglu
-    if name == "relu2":
-        from ..ops.ssm import relu2
-
-        return relu2
-    raise ValueError(f"moe_local_experts: no expert activation {name!r}")
+def buffer_tiles(assignments, n_local):
+    """(rows of a tile, tiles) of the sorted buffer: the worst case,
+    every assignment local and every expert's last tile padded."""
+    tm = _row_tile(assignments)
+    return tm, -(-assignments // tm) + n_local
 
 
 def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
@@ -211,10 +208,16 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     experts `expert_offset` .. `expert_offset + E_local - 1`. Every
     token is scored against all E, its top-k chosen, and the assignments
     that fall on a local expert are sorted by expert (each group padded
-    to whole row tiles), pushed through a grouped product
-    (kernels/moe_gmm.py), and summed back into their tokens with their
-    weights. No capacity, nothing dropped: the sorted buffer holds the
-    worst case, every assignment local.
+    to whole row tiles), pushed through a grouped product with the
+    activation as its epilogue (kernels/moe_gmm.py), and summed back
+    into their tokens with their weights. No capacity, nothing dropped:
+    the sorted buffer is ALLOCATED for the worst case, every assignment
+    local. What is TOUCHED follows the rows that are live (`num_active`
+    tiles): the products skip the other tiles, and once the tiles are
+    MXU-sized (a prefill) the rows go in and come back through
+    `gather_rows` / `combine_rows`, which skip them too and read no row
+    for an assignment that is not local. A decode step's small buffer
+    keeps the `jnp` gathers, whose cost there is a few microseconds.
 
     Returns (y [B, T, K], selected [B, T, k], counts [E_local] int32)."""
     from ..kernels import moe_gmm
@@ -232,8 +235,10 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
             n_group, topk_group,
         )
     n_assign = n_tok * top_k
-    tm = _row_tile(n_assign)
-    n_tiles = -(-n_assign // tm) + n_local
+    tm, n_tiles = buffer_tiles(n_assign, n_local)
+    # off the TPU the same in `jnp` (kernel tests: `interpret`)
+    kernels = interpret or jax.default_backend() == "tpu"
+    row_kernels = interpret or (kernels and tm == MXU_TILE)
 
     with jax.named_scope("moe_sort"):
         local = sel - expert_offset
@@ -255,10 +260,15 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
             tiles_before[clamped] * tm + rank,
             n_tiles * tm,                      # out of range: dropped below
         )
-        token_of = jnp.arange(n_assign, dtype=jnp.int32) // top_k
-        token_of_slot = jnp.zeros((n_tiles * tm,), jnp.int32).at[slot].set(
-            token_of, mode="drop"
-        )
+
+        def of_slot(values, fill):
+            """What each row of the buffer holds of its assignment."""
+            return jnp.full((n_tiles * tm,), fill, values.dtype).at[
+                slot].set(values, mode="drop")
+
+        # a row's token: none where the row pads its tile
+        token_of_slot = of_slot(
+            jnp.arange(n_assign, dtype=jnp.int32) // top_k, -1)
         # tile i belongs to the expert whose run of tiles covers it;
         # tiles past the last active one repeat its expert (no new weight
         # fetch)
@@ -270,31 +280,37 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
         ).astype(jnp.int32)
         active = num_active.reshape(1).astype(jnp.int32)
 
-    # off the TPU the same product in `jnp` (kernel tests: `interpret`)
-    gmm = moe_gmm.gmm_reference
-    if interpret or jax.default_backend() == "tpu":
-        gmm = functools.partial(moe_gmm.gmm, interpret=interpret)
+    def product(lhs, rhs, act=None):
+        if kernels:
+            return moe_gmm.gmm(lhs, rhs, tile_expert, active, tm, act,
+                               interpret=interpret)
+        return moe_gmm.gmm_reference(lhs, rhs, tile_expert, active, tm, act)
 
-    def product(lhs, rhs):
-        return gmm(lhs, rhs, tile_expert, active, tm)
-
-    act = _expert_activation(activation)
     with jax.named_scope("moe_dispatch"):
-        x_sorted = tokens[token_of_slot]                        # [M, H]
+        if row_kernels:
+            x_sorted = moe_gmm.gather_rows(tokens, token_of_slot, active, tm,
+                                           interpret=interpret)
+        else:
+            x_sorted = tokens[jnp.maximum(token_of_slot, 0)]    # [M, H]
     with jax.named_scope("moe_experts"):
-        hidden = act(product(x_sorted, w_gate_up))
+        hidden = product(x_sorted, w_gate_up, activation)       # [M, F]
         y_sorted = product(hidden, w_down)                      # [M, H]
     with jax.named_scope("moe_combine"):
-        picked = y_sorted[jnp.minimum(slot, n_tiles * tm - 1)]  # [A, H]
-        # a slot of a non-local assignment reads a row nobody wrote:
-        # select, never multiply by zero
-        part = jnp.where(
-            is_local.reshape(-1, 1),
-            picked.astype(jnp.float32) * weights.reshape(-1, 1), 0.0,
-        )
-        y = jnp.sum(part.reshape(n_tok, top_k, h), axis=1)
-    return (y.astype(x.dtype).reshape(b, t, h), sel.reshape(b, t, top_k),
-            counts)
+        if row_kernels:
+            y = moe_gmm.combine_rows(
+                y_sorted, token_of_slot, of_slot(weights.reshape(-1), 0.0),
+                active, tm, n_tok, interpret=interpret)
+        else:
+            picked = y_sorted[jnp.minimum(slot, n_tiles * tm - 1)]  # [A, H]
+            # a slot of a non-local assignment reads a row nobody wrote:
+            # select, never multiply by zero
+            part = jnp.where(
+                is_local.reshape(-1, 1),
+                picked.astype(jnp.float32) * weights.reshape(-1, 1), 0.0,
+            )
+            y = jnp.sum(part.reshape(n_tok, top_k, h), axis=1).astype(
+                x.dtype)
+    return y.reshape(b, t, h), sel.reshape(b, t, top_k), counts
 
 
 @register_op(
@@ -328,10 +344,13 @@ def _moe_local_experts_op(ctx, op, ins):
     most = jnp.max(counts)
     local, hit = jnp.sum(counts), jnp.sum(counts > 0).astype(jnp.int32)
     decode = jnp.int32(x.shape[1] == 1)
+    assignments = x.shape[0] * x.shape[1] * top_k
+    tm, n_tiles = buffer_tiles(assignments, wgu.shape[0])
     step = jnp.stack([
-        local, jnp.int32(x.shape[0] * x.shape[1] * top_k), hit,
+        local, jnp.int32(assignments), hit,
         jnp.int32(0), most, jnp.int32(1),
         decode * local, decode * hit, decode,
+        jnp.sum(-(-counts // tm)) * tm, jnp.int32(n_tiles * tm),
     ])
     new = (counters + step).at[3].set(jnp.maximum(counters[3], most))
     return {"Out": [y], "Selected": [sel], "CountersOut": [new]}

@@ -227,7 +227,8 @@ def test_dropless_layer_matches_the_dense_sum(interpret):
     assert int(counts.sum()) == int(local.sum())
 
 
-def test_dropless_every_token_to_one_expert():
+@pytest.mark.parametrize("interpret", [False, True])
+def test_dropless_every_token_to_one_expert(interpret):
     """A router that sends every token to expert 9 first: its group is
     the whole batch (no capacity, nothing dropped)."""
     w = expert_weights(30, 4)
@@ -237,10 +238,173 @@ def test_dropless_every_token_to_one_expert():
     x = rand(31, 3, 40, 32)
     y, sel, counts = moe.local_experts_ffn(
         jnp.asarray(x), w["router_w"], w["bias"], w["wgu"], w["wd"],
-        top_k=4, route_scale=2.448, expert_offset=8)
+        top_k=4, route_scale=2.448, expert_offset=8, interpret=interpret)
     assert int(counts[1]) == 120 and (np.asarray(sel) == 9).any(-1).all()
     want, _ = dense_routed(x, w, 8)
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+
+
+
+# -- what the expert layer touches: the live rows ------------------------------
+
+def dense_sum(x, w, offset, activation, top_k=4, scale=2.448):
+    """Every token's weighted sum over its LOCAL experts in float32
+    numpy, routed by the op's own router: what dispatch, products and
+    combine have to add up to, for either activation."""
+    from paddle_tpu.kernels import moe_gmm
+
+    tokens = x.reshape(-1, x.shape[-1])
+    sel, weights = moe.sigmoid_topk_route(
+        jnp.asarray(tokens), w["router_w"], w["bias"], top_k, scale)
+    sel, weights = np.asarray(sel), np.asarray(weights)
+    out = np.zeros_like(tokens)
+    for t, row in enumerate(tokens):
+        for e, weight in zip(sel[t] - offset, weights[t]):
+            if 0 <= e < w["wgu"].shape[0]:
+                first = row @ w["wgu"][e]
+                halves = (np.split(first, 2) if activation == "swiglu"
+                          else [first])
+                hidden = np.asarray(moe_gmm.activate(
+                    activation, *map(jnp.asarray, halves)))
+                out[t] += weight * (hidden @ w["wd"][e])
+    return out.reshape(x.shape), sel
+
+
+def forced_router(w, chosen, shunned=()):
+    """Every token's first choice is `chosen`; the `shunned` experts are
+    in nobody's top-k."""
+    w = dict(w, bias=np.zeros_like(w["bias"]))
+    w["bias"][list(chosen)] = 1.0
+    w["bias"][list(shunned)] = -10.0
+    return w
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+@pytest.mark.parametrize("live", ["no_tile", "one_tile", "every_assignment"])
+def test_the_kernels_match_the_dense_sum_at_any_fill(activation, live):
+    """Gather, fused product, product and scatter-add through the
+    interpreter (uninitialised memory is NaN there, a read out of bounds
+    raises) with nothing, one tile and every assignment of the buffer
+    live."""
+    def weights(**kw):
+        w = expert_weights(60, 4, **kw)
+        if activation == "relu2":       # one block, not gate and up
+            w["wgu"] = w["wgu"][..., :16]
+        return w
+
+    w = weights()
+    if live == "no_tile":           # experts 8..11 are in nobody's top-4
+        w, x, offset = forced_router(w, (), range(8, 12)), rand(61, 2, 9, 32), 8
+    elif live == "one_tile":        # 12 rows of expert 9, no other local
+        w = forced_router(w, (9,), (8, 10, 11))
+        x, offset = rand(62, 1, 12, 32), 8
+    else:                           # four experts in all: top-4 is all of them
+        w = weights(e_total=4)
+        x, offset = rand(63, 3, 11, 32), 0
+    y, sel, counts = moe.local_experts_ffn(
+        jnp.asarray(x), w["router_w"], w["bias"], w["wgu"], w["wd"],
+        top_k=4, route_scale=2.448, expert_offset=offset,
+        activation=activation, interpret=True)
+    want, want_sel = dense_sum(x, w, offset, activation)
+    np.testing.assert_array_equal(sel.reshape(want_sel.shape), want_sel)
+    assert int(counts.sum()) == {"no_tile": 0, "one_tile": 12,
+                                 "every_assignment": 33 * 4}[live]
+    if live == "no_tile":
+        np.testing.assert_array_equal(y, np.zeros_like(x))      # exact
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+
+
+def sorted_tiles(seed, counts, tm, n_tokens, tiles):
+    """A buffer of `tiles` row tiles with `counts[e]` rows of expert e:
+    (token_of_slot with -1 where a row pads, weight_of_slot,
+    tile_expert, num_active)."""
+    rs = np.random.RandomState(seed)
+    token = np.full(tiles * tm, -1, np.int32)
+    weight = np.zeros(tiles * tm, np.float32)
+    owner, at = [], 0
+    for e, n in enumerate(counts):
+        token[at:at + n] = np.sort(rs.choice(n_tokens, n, replace=False))
+        weight[at:at + n] = rs.uniform(0.1, 1.0, n)
+        owner += [e] * -(-n // tm)
+        at += -(-n // tm) * tm
+    active = len(owner)
+    owner += [owner[-1] if owner else 0] * (tiles - active)
+    return (jnp.asarray(token), jnp.asarray(weight),
+            jnp.asarray(owner, jnp.int32), jnp.asarray([active], jnp.int32))
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+def test_fused_activation_is_the_activation_of_the_float32_product(
+        activation):
+    """The epilogue against a reference that rounds once (exactly), and
+    against the parent's two roundings, product then activation (within
+    bfloat16's step); skipped tiles hold what they were allocated with."""
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.ops.llm import swiglu
+    from paddle_tpu.ops.ssm import relu2
+
+    tm, k, f = 16, 128, 128
+    _tok, _w, owner, active = sorted_tiles(70, [20, 0, 5], tm, 40, 6)
+    # quarters and small integers: every float32 sum is exact whatever
+    # its order, so one rounding is one result
+    rs = np.random.RandomState(71)
+    x = jnp.asarray(rs.randint(-3, 4, (6 * tm, k)), jnp.bfloat16)
+    wide = 2 * f if activation == "swiglu" else f
+    w = jnp.asarray(rs.randint(-2, 3, (3, k, wide)) / 4, jnp.bfloat16)
+    got = moe_gmm.gmm(x, w, owner, active, tm, activation, interpret=True)
+    once = moe_gmm.gmm_reference(x, w, owner, active, tm, activation)
+    live = int(active[0]) * tm
+    assert got.shape == (6 * tm, f) and got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got[:live], np.float32),
+                                  np.asarray(once[:live], np.float32))
+    assert np.isnan(np.asarray(got[live:], np.float32)).all()
+    twice = {"swiglu": swiglu, "relu2": relu2}[activation](
+        moe_gmm.gmm_reference(x, w, owner, active, tm))
+    np.testing.assert_allclose(np.asarray(got[:live], np.float32),
+                               np.asarray(twice[:live], np.float32),
+                               rtol=2 ** -7, atol=2 ** -7)
+
+
+@pytest.mark.parametrize("dtype,tiles,step", [
+    ("float32", 9, 16), ("bfloat16", 9, 16), ("bfloat16", 160, 512)])
+def test_gather_and_combine_touch_the_live_rows_only(dtype, tiles, step):
+    """`gather_rows` writes no row past the last live step of the grid
+    (a step walks `step_rows` of the buffer; what it leaves still holds
+    the interpreter's NaN), and `combine_rows` sums only rows that hold
+    a token: a buffer that is NaN everywhere else gives the exact
+    weighted sums."""
+    from paddle_tpu.kernels import moe_gmm
+
+    tm, k, n_tokens = 16, 256, 50
+    assert moe_gmm.step_rows(tiles * tm, tm) == step
+    token, weight, _owner, active = sorted_tiles(
+        80, [17, 3, 0, 33], tm, n_tokens, tiles)
+    src = jnp.asarray(rand(81, n_tokens, k), dtype)
+    rows = moe_gmm.gather_rows(src, token, active, tm, interpret=True)
+    held = np.asarray(token) >= 0
+    np.testing.assert_array_equal(
+        np.asarray(rows, np.float32)[held],
+        np.asarray(src, np.float32)[np.asarray(token)[held]])
+    live = int(active[0]) * tm
+    assert live == 6 * tm and np.isfinite(
+        np.asarray(rows[:live], np.float32)).all()
+    assert np.isnan(np.asarray(rows[-(-live // step) * step:],
+                               np.float32)).all()
+    poisoned = jnp.where(jnp.asarray(held)[:, None], rows, jnp.nan)
+    got = moe_gmm.combine_rows(poisoned, token, weight, active, tm, n_tokens,
+                               interpret=True)
+    want = np.zeros((n_tokens, k), np.float32)
+    np.add.at(want, np.asarray(token)[held],
+              np.asarray(weight)[held, None]
+              * np.asarray(rows, np.float32)[held])
+    assert got.dtype == src.dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(jnp.asarray(want).astype(dtype), np.float32),
+        rtol=2 ** -7 if dtype == "bfloat16" else 1e-6, atol=1e-6)
+    named = np.zeros(n_tokens, bool)
+    named[np.asarray(token)[held]] = True
+    assert not np.asarray(got, np.float32)[~named].any()        # exact zeros
 
 
 def test_eight_shares_and_the_shared_expert_once_make_the_whole_layer():
@@ -405,10 +569,20 @@ def test_counters_are_read_once_a_batch():
     assert got["moe.decode_calls"] == layers * 7
     assert 0 < got["moe.assignments_local"] < got["moe.assignments_total"]
     assert got["moe.experts_hit"] <= got["moe.calls"] * 2
+    # the sorted buffer: per call ceil(A / 16) + 2 tiles of 16 rows are
+    # allocated; the live ones hold the local rows and, an expert and
+    # call, less than a tile of padding
+    assert got["moe.rows_buffer"] == layers * 16 * (
+        (2 * 24 * 4 // 16 + 2) + 7 * (1 + 2))
+    assert got["moe.rows_live"] % 16 == 0
+    assert 0 <= got["moe.rows_live"] - got["moe.assignments_local"] <= (
+        got["moe.experts_hit"] * 15)
+    assert got["moe.rows_live"] < got["moe.rows_buffer"]
     spans = [s for s in obs.get_spans()
              if s["name"] == "serving.step_counters"]
     assert len(spans) == 1
     assert spans[0]["args"]["moe.assignments_total"] == layers * tokens * 4
+    assert spans[0]["args"]["moe.rows_live"] == got["moe.rows_live"]
     gauges = obs.get_gauges()
     assert gauges["kv_cache.bytes.window"] == 4 * 2 * (2 * 2 * 16 * 8) * 2
     assert gauges["kv_cache.bytes.full"] == 2 * (2 * 2 * 16 * 32) * 2

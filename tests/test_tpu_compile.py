@@ -148,12 +148,28 @@ def _layer_norm(rows, n, dtype):
     return fwd, bwd
 
 
-def _gmm(rows, tm, k, n, experts=32):
+def _gmm(rows, tm, k, n, experts=32, activation=None):
     """The routed experts' grouped product at Trinity-Large's widths:
-    `rows` sorted rows in tiles of `tm`, `experts` matrices of [k, n]."""
+    `rows` sorted rows in tiles of `tm`, `experts` matrices of [k, n],
+    the experts' activation as the first product's epilogue."""
     specs = (((rows, k), BF16), ((experts, k, n), BF16),
              ((rows // tm,), jnp.int32), ((1,), jnp.int32))
-    return (lambda x, w, te, na: moe_gmm.gmm(x, w, te, na, tm), specs),
+    return (lambda x, w, te, na: moe_gmm.gmm(x, w, te, na, tm, activation),
+            specs),
+
+
+def _moe_rows(rows, tm, k, tokens):
+    """(gather, combine) of a prefill dispatch's `tokens` rows of width
+    `k` into and out of a sorted buffer of `rows`: a column block of the
+    tokens resident in float32, the live tiles streaming past it."""
+    per_row = ((rows,), jnp.int32)
+    active = ((1,), jnp.int32)
+    gather = (lambda src, tok, na: moe_gmm.gather_rows(src, tok, na, tm),
+              (((tokens, k), BF16), per_row, active))
+    combine = (lambda y, tok, w, na: moe_gmm.combine_rows(
+        y, tok, w, na, tm, tokens),
+        (((rows, k), BF16), per_row, ((rows,), F32), active))
+    return gather, combine
 
 
 def _ssm_update(batch, heads=128, head_dim=64, state=128, groups=8):
@@ -263,19 +279,26 @@ def _cases():
         _tiled(1, 4096, BF16, 0.1, causal=False))
     # generate phase of trinity_large_ep8: a decode step's 64 x 4
     # assignments in tiles of 16, a prefill block's 16 x 896 x 4 in tiles
-    # of 256; gate+up (3072 -> 6144) and down (3072 -> 3072)
+    # of 256; gate+up (3072 -> 6144, `swiglu` as the epilogue: a gate
+    # and an up block a step) and down (3072 -> 3072); the prefill's rows
+    # into the buffer and back (14,336 tokens: 512 columns resident)
     for rows, tm in ((768, 16), (65536, 256)):
-        for n in (6144, 3072):
-            add(f"moe_gmm-{rows}x3072x{n}-tm{tm}-bf16",
-                _gmm(rows, tm, 3072, n), ("fwd",))
+        add(f"moe_gmm-{rows}x3072x6144-tm{tm}-bf16",
+            _gmm(rows, tm, 3072, 6144, activation="swiglu"), ("fwd",))
+        add(f"moe_gmm-{rows}x3072x3072-tm{tm}-bf16",
+            _gmm(rows, tm, 3072, 3072), ("fwd",))
+    add("moe_rows-65536x3072-tm256-t14336-bf16",
+        _moe_rows(65536, 256, 3072, 14336), ("gather", "combine"))
     # generate phase of nemotron3_super_ep4: a decode step's 64 x 22
     # assignments in tiles of 16, a prefill block's 8 x 896 x 22 in tiles
     # of 256, up (1024 -> 2688) and down (2688 -> 1024) over 128 experts;
     # and the recurrent state's update, 64 sequences of 4 MB
     for rows, tm in ((3456, 16), (190464, 256)):
-        for k, n in ((1024, 2688), (2688, 1024)):
+        for k, n, act in ((1024, 2688, "relu2"), (2688, 1024, None)):
             add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
-                _gmm(rows, tm, k, n, experts=128), ("fwd",))
+                _gmm(rows, tm, k, n, experts=128, activation=act), ("fwd",))
+    add("moe_rows-190464x1024-tm256-t7168-bf16",
+        _moe_rows(190464, 256, 1024, 7168), ("gather", "combine"))
     add("ssm_state_update-b64-h128x64-n128-f32", _ssm_update(64), ("fwd",))
     # a decode step's attention over the caches of the three generate
     # cells (batch 64, 1024 slots): GPT-2's float32 12 x 64, Trinity's
@@ -302,9 +325,11 @@ def _cases():
     add("corner-decode_attention-latent-s16384-bf16",
         _latent_attention(batch=4, max_len=16384), ("fwd",))
     for rows, tm in ((768, 16), (61440, 256)):
-        for k, n in ((7168, 4096), (2048, 7168)):
+        for k, n, act in ((7168, 4096, "swiglu"), (2048, 7168, None)):
             add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
-                _gmm(rows, tm, k, n, experts=16), ("fwd",))
+                _gmm(rows, tm, k, n, experts=16, activation=act), ("fwd",))
+    add("moe_rows-61440x7168-tm256-t7168-bf16",
+        _moe_rows(61440, 256, 7168, 7168), ("gather", "combine"))
     # a prefill dispatch's attention in the four generate cells (896
     # tokens = one super-block of seven blocks): GPT-2's float32 12 x 64
     # over 64 rows, Trinity's 48 over 8 x 128 over 16, Nemotron's 32 over
@@ -376,7 +401,12 @@ def _named_cases():
         f"fused_residual-{rows}-bwd": ["fused_residual_bwd"],
         f"layer_norm-{rows}-fwd": ["layer_norm_fwd"],
         f"layer_norm-{rows}-bwd": ["layer_norm_bwd"],
-        "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm"],
+        "moe_gmm-768x3072x6144-tm16-bf16-fwd": ["moe_gmm_swiglu"],
+        "moe_gmm-3456x1024x2688-tm16-bf16-fwd": ["moe_gmm_relu2"],
+        "moe_gmm-768x2048x7168-tm16-bf16-fwd": ["moe_gmm"],
+        "moe_rows-61440x7168-tm256-t7168-bf16-gather": ["moe_rows_gather"],
+        "moe_rows-61440x7168-tm256-t7168-bf16-combine":
+            ["moe_rows_combine"],
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
         "decode_attention-gpt2-12x64-f32-fwd": ["decode_attention"],
         "prefill_attention-trinity-48over8x128-bf16-fwd":
