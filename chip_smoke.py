@@ -669,7 +669,7 @@ def cell_generator(sz, config):
     """`serving.GPTGenerator` handed the decoder of a benchmark
     configuration by its own builder, at the published widths
     (bfloat16: 4.32B parameters for trinity_large_ep8, 4.57B for
-    dots_vlm1_ep16) or its tiny cut."""
+    dots_vlm1_ep16, 2.93B for qwen3_next_ep8) or its tiny cut."""
     import importlib
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -688,12 +688,15 @@ def phase_generate(sz, kernels, shared):
     """GPTGenerator behind Server: prefill + per-token KV-cache decode,
     against generate_full_recompute; then the token hand-off of the
     decoders against the host's argmax chain (`handoff_check`): the
-    afmoe decoder and the latent-attention decoder (prefill expanded,
-    cached steps absorbed) at the whole decode batch, one after the
-    other (each holds 9 GB of weights)."""
+    afmoe decoder, the latent-attention decoder (prefill expanded,
+    cached steps absorbed) and the linear-attention decoder (prefill by
+    the chunked delta rule, cached steps through `gdn_state_update` on
+    the float32 states in place) at the whole decode batch, one after
+    the other (each holds 6 to 9 GB of weights)."""
     out = _gpt_generate(sz)
     for name, config in (("afmoe", "trinity_large_ep8"),
-                         ("dots_vlm", "dots_vlm1_ep16")):
+                         ("dots_vlm", "dots_vlm1_ep16"),
+                         ("qwen3_next", "qwen3_next_ep8")):
         gc.collect()    # the generator before, ahead of 9 GB of weights
         rng = np.random.RandomState(SEED + 1)
         gen = cell_generator(sz, config)

@@ -162,11 +162,14 @@ def _state_var(name, shape, dtype):
                           persistable=True)
 
 
-def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
+def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, expert_bias=True,
+                shared_gate=False, **route_attrs):
     """Shared expert (every chip computes it) + this chip's routed part.
     `route_attrs`: further attributes of `moe_local_experts` (a family's
-    group-limited selection). Returns (output, the op's `Selected` ids
-    [B, T, k])."""
+    group-limited selection, its scoring). A family without the
+    selection's bias buffer says `expert_bias` False; with `shared_gate`
+    the shared expert is scaled by sigmoid(x w), a gate of its own.
+    Returns (output, the op's `Selected` ids [B, T, k])."""
     from ..framework import unique_name
     from ..framework.program import default_main_program
     from ..parallel.moe import MOE_COUNTERS
@@ -175,10 +178,13 @@ def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
     e_local = cfg.num_local_experts
     router_w = _param(f"{prefix}_router_w", [h, cfg.num_experts], cfg,
                       _normal(cfg))
-    # a buffer, not a weight: moves the selection only; float32, seeded
-    # small and non-zero so that it is exercised
-    bias = _param(f"{prefix}_expert_bias", [cfg.num_experts], cfg,
-                  _normal(cfg, std=cfg.expert_bias_std), dtype="float32")
+    ins = {}
+    if expert_bias:
+        # a buffer, not a weight: moves the selection only; float32,
+        # seeded small and non-zero so that it is exercised
+        bias = _param(f"{prefix}_expert_bias", [cfg.num_experts], cfg,
+                      _normal(cfg, std=cfg.expert_bias_std), dtype="float32")
+        ins["ExpertBias"] = [bias.name]
     w_gate_up = _param(f"{prefix}_experts_gate_up_w", [e_local, h, 2 * f],
                        cfg, _normal(cfg))
     w_down = _param(f"{prefix}_experts_down_w", [e_local, f, h], cfg,
@@ -197,8 +203,8 @@ def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
     with name_scope("experts"):
         blk.append_op(
             "moe_local_experts",
-            {"X": [x.name], "RouterW": [router_w.name],
-             "ExpertBias": [bias.name], "WGateUp": [w_gate_up.name],
+            {"X": [x.name], "RouterW": [router_w.name], **ins,
+             "WGateUp": [w_gate_up.name],
              "WDown": [w_down.name], "Counters": [counters.name]},
             {"Out": [routed.name], "Selected": [selected.name],
              "CountersOut": [counters.name]},
@@ -208,9 +214,13 @@ def _expert_ffn(x, prefix, cfg, counters_var=COUNTERS_VAR, **route_attrs):
         )
     if cfg.num_shared_experts:
         with name_scope("shared"):
-            routed = routed + _swiglu_ffn(
+            shared = _swiglu_ffn(
                 x, f * cfg.num_shared_experts, f"{prefix}_shared", cfg
             )
+            if shared_gate:
+                shared = shared * layers.sigmoid(
+                    _proj(x, 1, f"{prefix}_shared_gate_w", cfg))
+            routed = routed + shared
     return routed, selected
 
 
@@ -282,11 +292,12 @@ def _embed(ids, cfg, seq):
         return x
 
 
-def _head(x, cfg, family="afmoe"):
-    """Final norm, then the untied head over the vocabulary held here;
-    float32 out of the product (not a rounded bfloat16 cast up)."""
+def _head(x, cfg, family="afmoe", norm=None):
+    """Final norm (`norm`: a family's own, `_rms` by default), then the
+    untied head over the vocabulary held here; float32 out of the
+    product (not a rounded bfloat16 cast up)."""
     with name_scope("head"):
-        x = _rms(x, f"{family}_norm_f", cfg)
+        x = (norm or _rms)(x, f"{family}_norm_f", cfg)
         w = _param(f"{family}_head_w", [cfg.hidden_size, cfg.vocab_size],
                    cfg, _normal(cfg))
         return _simple("mul", {"X": [x], "Y": [w]},

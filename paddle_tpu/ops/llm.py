@@ -26,13 +26,18 @@ from .kv_cache import _pos_scalar, attention_mask, grouped_attention
 def _rms_norm(ctx, op, ins):
     """x * rsqrt(mean(x^2) + eps) * gain over groups of `Scale`'s width
     along the last axis: the whole hidden size, or each head's slice of a
-    [..., heads * head_dim] projection (QK-norm)."""
+    [..., heads * head_dim] projection (QK-norm). With `unit_offset`
+    the stored gain is w and the gain applied 1 + w, summed in float32
+    (a family that learns the gain's distance from one)."""
     x, gain = ins["X"][0], ins["Scale"][0]
     w = gain.shape[0]
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // w, w))
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + float(op.attr("epsilon", 1e-5)))
-    out = out * gain.astype(jnp.float32)
+    gain = gain.astype(jnp.float32)
+    if op.attr("unit_offset", False):
+        gain = 1.0 + gain
+    out = out * gain
     return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
 
 
@@ -68,10 +73,12 @@ def yarn_mscale(factor, mscale):
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None):
+def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None,
+           leading=False):
     """Rotate-half rotary positions: x [B, T, heads * head_dim], row i
     at position `first_pos + i`. The LAST `rotary_dim` lanes of each
-    head are rotated (all of them by default), the lanes before pass.
+    head are rotated (all of them by default), the lanes before pass;
+    with `leading` the FIRST `rotary_dim` lanes turn and the rest pass.
     `yarn` (factor, original_max_position_embeddings, beta_fast,
     beta_slow, mscale, mscale_all_dim) blends the frequencies as
     `yarn_ramp` says and scales cos and sin by the ratio of the two
@@ -94,10 +101,15 @@ def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None):
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32).reshape(b, t, h // head_dim, head_dim)
-    turned = xf if rot == head_dim else xf[..., head_dim - rot:]
+    if rot == head_dim:
+        turned = xf
+    else:
+        turned = xf[..., :rot] if leading else xf[..., head_dim - rot:]
     x1, x2 = turned[..., :half], turned[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    if rot != head_dim:
+    if rot != head_dim and leading:
+        out = jnp.concatenate([out, xf[..., rot:]], -1)
+    elif rot != head_dim:
         out = jnp.concatenate([xf[..., :head_dim - rot], out], -1)
     return out.reshape(b, t, h).astype(x.dtype)
 
@@ -106,15 +118,17 @@ def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None):
              differentiable=False)
 def _rotary_embedding(ctx, op, ins):
     """`Pos` is the position of the LAST row (as `kv_cache_attention`
-    has it), a runtime value. `rotary_dim` and `yarn` as `rotary` takes
-    them; absent, the whole head turns at the plain frequencies."""
+    has it), a runtime value. `rotary_dim`, `yarn` and `leading` (which
+    end of a head turns) as `rotary` takes them; absent, the whole head
+    turns at the plain frequencies."""
     x = ins["X"][0]
     last = _pos_scalar(ins["Pos"][0])
     return {"Out": [rotary(x, last - (x.shape[1] - 1),
                            int(op.attr("head_dim")),
                            float(op.attr("theta", 10000.0)),
                            op.attr("rotary_dim", None),
-                           op.attr("yarn", None))]}
+                           op.attr("yarn", None),
+                           bool(op.attr("leading", False)))]}
 
 
 @register_op("swiglu", inputs=["X"], outputs=["Out"], differentiable=False)

@@ -1,8 +1,11 @@
-"""Ops of a Mamba-2 state-space block on the serving path (models/
-nemotron_h.py): the depthwise causal convolution with its carried tail,
-the selective state-space recurrence in its two forms (a chunked scan
-for a prefill, a one-token state update for a decode step), the gated
-RMSNorm over groups of channels, and `relu2`.
+"""Ops of the recurrent mixers on the serving path: a Mamba-2
+state-space block (models/nemotron_h.py) and a Gated DeltaNet
+linear-attention layer (models/qwen3_next.py). The depthwise causal
+convolution with its carried tail, each recurrence in its two forms (a
+chunked scan for a prefill, a one-token state update for a decode step),
+the gated RMSNorm over groups of channels (gate before or after the
+norm), and `relu2`. The state-space recurrence first; the gated delta
+rule has its own section below.
 
     xBC_t <- silu(b_c + sum_j w_c[:, j] * xBC_{t-k+1+j})         (conv)
     dt_t = softplus(dt_t + dt_bias);  A = -exp(A_log)             per head
@@ -75,13 +78,14 @@ def _row_block(array, rows, row):
 # ---------------------------------------------------------------------------
 
 def causal_conv(x, weight, bias, history):
-    """x [R, T, C], weight [C, k], bias [C], history [R, k - 1, C] (the
-    rows before x's first; zeros at a sequence's start) -> (silu of the
-    convolution [R, T, C] in x's dtype, the new tail [R, k - 1, C])."""
+    """x [R, T, C], weight [C, k], bias [C] or None, history
+    [R, k - 1, C] (the rows before x's first; zeros at a sequence's
+    start) -> (silu of the convolution [R, T, C] in x's dtype, the new
+    tail [R, k - 1, C])."""
     k = weight.shape[1]
     t = x.shape[1]
     window = jnp.concatenate([history.astype(x.dtype), x], axis=1)
-    acc = bias.astype(F32)[None, None, :]
+    acc = 0.0 if bias is None else bias.astype(F32)[None, None, :]
     for j in range(k):
         acc = acc + window[:, j:j + t, :].astype(F32) \
             * weight[:, j].astype(F32)[None, None, :]
@@ -99,8 +103,10 @@ def _causal_conv1d(ctx, op, ins):
     """`Tail` [B, k - 1, C] is the batch's carried tail. With `carry` the
     call continues its rows' sequences (a decode step: X is [B, 1, C]);
     without, X starts them (a prefill: zeros before the first token). X
-    may be a block of the batch's rows starting at `Row`."""
-    x, w, bias, tail = (ins[k][0] for k in ("X", "W", "Bias", "Tail"))
+    may be a block of the batch's rows starting at `Row`; `Bias` may be
+    absent."""
+    x, w, tail = (ins[k][0] for k in ("X", "W", "Tail"))
+    bias = (ins.get("Bias") or [None])[0]
     row = ins.get("Row") or None
     rows, k1 = x.shape[0], tail.shape[1]
     if op.attr("carry", False):
@@ -259,6 +265,248 @@ def _ssm_state_update(ctx, op, ins):
 
 
 # ---------------------------------------------------------------------------
+# the gated delta rule (linear attention)
+# ---------------------------------------------------------------------------
+#
+#   S'_t = alpha_t S_{t-1};  u_t = beta_t (v_t - S'_t^T k_t)
+#   S_t = S'_t + k_t (outer) u_t;  o_t = S_t^T q_t          S [dk, dv] a head
+#
+# The state is CORRECTED before it is written: what it already holds
+# along k_t is read out and subtracted from the value. Hk key heads serve
+# Hv value heads (value head h reads key head h // (Hv / Hk)). The state
+# is stored as a Mamba-2 state is, `ssm_state_shape(B, Hv, dv, dk, Hk)`:
+# the key dimension on the sublanes, the value dimension on the lanes.
+
+def _split_qkv(qkv, key_heads, value_heads, key_dim, value_dim):
+    """[R, T, 2 Hk dk + Hv dv] -> q, k [R, T, Hk, dk], v [R, T, Hv, dv]."""
+    r, t, _ = qkv.shape
+    d = key_heads * key_dim
+    return (qkv[..., :d].reshape(r, t, key_heads, key_dim),
+            qkv[..., d:2 * d].reshape(r, t, key_heads, key_dim),
+            qkv[..., 2 * d:].reshape(r, t, value_heads, value_dim))
+
+
+def delta_rule_inputs(qkv, b_raw, a_raw, a_log, dt_bias, *, key_heads,
+                      value_heads, key_dim, value_dim):
+    """What the recurrence reads, all float32: q L2-normalised a head and
+    scaled by dk^-1/2, k L2-normalised, v, g = -exp(A_log) softplus(a +
+    dt_bias) (the log of the decay alpha), beta = sigmoid(b)."""
+    q, k, v = (x.astype(F32) for x in _split_qkv(
+        qkv, key_heads, value_heads, key_dim, value_dim))
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
+        a_raw.astype(F32) + dt_bias.astype(F32))
+    return (unit(q) * key_dim ** -0.5, unit(k), v, g,
+            jax.nn.sigmoid(b_raw.astype(F32)))
+
+
+def unit_lower_inverse(strict):
+    """(I + L)^-1 for `strict` = L [..., C, C], strictly lower triangular,
+    C a power of two: the inverses of the diagonal blocks, doubled in
+    size log2(C) times ([[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1,
+    D^-1]]; a block of one row is 1). Block forward substitution, so as
+    stable as a row-by-row solve, in log2(C) batched steps. `inv` holds
+    the diagonal blocks' inverses as ONE [C, C] matrix (zero off the
+    blocks), so a step is `inv - inv B inv` with B the blocks of L that
+    join the pairs: two whole-matrix products, float32
+    (`Precision.HIGHEST`), whatever the block's size (blocks of one, two
+    or four rows taken apart would each fill a tile of their own)."""
+    c = strict.shape[-1]
+    at = jnp.arange(c)
+    inv = jnp.broadcast_to(jnp.eye(c, dtype=F32), strict.shape)
+    b = 1
+    while b < c:
+        row, col = at[:, None] // b, at[None, :] // b
+        joins = (row // 2 == col // 2) & (row % 2 == 1) & (col % 2 == 0)
+        below = jnp.where(joins, strict, 0.0)
+        inv = inv - jnp.matmul(
+            jnp.matmul(inv, below, precision=jax.lax.Precision.HIGHEST),
+            inv, precision=jax.lax.Precision.HIGHEST)
+        b *= 2
+    return inv
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk, state=None, lo=F32):
+    """The gated delta rule over a whole sequence, a chunk at a time. q,
+    k [R, L, Hk, dk], v [R, L, Hv, dv], g, beta [R, L, Hv], all float32
+    as `delta_rule_inputs` gives them; `state` [R, Hv, dk, dv] float32
+    enters (zeros by default); L need not be a multiple of `chunk` (a
+    power of two) -> (o [R, L, Hv, dv] float32, the state after row
+    L - 1).
+
+    Inside a chunk, with G the running sum of g and Gamma_ij =
+    exp(G_i - G_j) for i >= j (from the differences: nothing overflows):
+        L = strictly_lower((beta K) K^T * Gamma);  T = (I + L)^-1
+        W = T (beta K exp(G));  U = T (beta V);  V' = U - W S
+        O = (Q exp(G)) S + lower(Q K^T * Gamma) V'
+        S <- exp(G_last) S + (K exp(G_last - G))^T V'
+    T, W, U and the two score matrices do not read the state and are
+    formed for all chunks at once; the chunks are then walked in order
+    (`lax.scan`) with S carried in float32. A product of two activations
+    takes them in `lo` (the activations' dtype) and accumulates in
+    float32; the solve and every product that reads T or the state are
+    float32 (`Precision.HIGHEST`). Every array is laid [chunks, R, key
+    heads, (value heads a key head,) chunk, lanes] from the start, so
+    that each product finds its operands as it wants them and the scan
+    its chunks in front: q, k, v, g and beta are transposed once on the
+    way in and o once on the way out."""
+    r, length, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    e = hv // hk
+    pad = -length % chunk
+    if pad:
+        # a padded row has beta = 0 and g = 0: it changes nothing
+        q, k, v, g, beta = (
+            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    nc = (length + pad) // chunk
+    exact = jax.lax.Precision.HIGHEST
+
+    def chunks(x, *heads):      # heads: the head axes after L
+        """[R, L, heads.., (d)] -> [nc, R, heads.., chunk, (d)]."""
+        x = x.reshape((r, nc, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 2, 2 + len(heads)), 1, 0)
+
+    # the op's parts by name in the compiled step's `op_name`, beneath
+    # the op's own scope: layout, scores, solve, apply, scan
+    with jax.named_scope("delta_layout"):
+        qc, kc = chunks(q, hk), chunks(k, hk)           # [nc,R,Hk,C,dk]
+        vc = chunks(v.reshape(r, -1, hk, e, dv), hk, e)     # [nc,R,Hk,e,C,dv]
+        gc, bc = (chunks(x.reshape(r, -1, hk, e), hk, e) for x in (g, beta))
+    with jax.named_scope("delta_scores"):
+        run = jnp.cumsum(gc, axis=-1)                   # [nc,R,Hk,e,C]
+        rows = jnp.arange(chunk)
+        seg = run[..., :, None] - run[..., None, :]     # [.., i, j]
+        gamma = jnp.exp(jnp.where(rows[:, None] >= rows[None, :], seg,
+                                  -jnp.inf))
+        k_lo = kc.astype(lo)
+        kk = einsum_f32("nrhid,nrhjd->nrhij", k_lo, k_lo)   # [nc,R,Hk,C,C]
+        qk = einsum_f32("nrhid,nrhjd->nrhij", qc.astype(lo), k_lo)
+        scores = (qk[:, :, :, None] * gamma).astype(lo)     # lower, i = j too
+    with jax.named_scope("delta_solve"):
+        strict = jnp.where(rows[:, None] > rows[None, :],
+                           kk[:, :, :, None] * gamma * bc[..., :, None], 0.0)
+        solve = unit_lower_inverse(strict)              # [nc,R,Hk,e,C,C]
+    with jax.named_scope("delta_apply"):
+        k_e, q_e = kc[:, :, :, None], qc[:, :, :, None]     # [nc,R,Hk,1,C,dk]
+        w = jnp.matmul(solve, k_e * (bc * jnp.exp(run))[..., None],
+                       precision=exact)                 # [nc,R,Hk,e,C,dk]
+        u = jnp.matmul(solve, vc * bc[..., None], precision=exact)
+        q_in = q_e * jnp.exp(run)[..., None]            # [nc,R,Hk,e,C,dk]
+        k_out = (k_e * jnp.exp(run[..., -1:] - run)[..., None]).astype(lo)
+        keep = jnp.exp(run[..., -1])                    # [nc,R,Hk,e]
+
+    def step(s, inp):
+        w_c, u_c, scores_c, q_c, k_c, keep_c = inp
+        # the carried state is read as the float32 it is
+        fresh = u_c - jnp.matmul(w_c, s, precision=exact)   # [R,Hk,e,C,dv]
+        fresh_lo = fresh.astype(lo)
+        o = jnp.matmul(q_c, s, precision=exact) \
+            + einsum_f32("rheij,rhejv->rheiv", scores_c, fresh_lo)
+        s = keep_c[..., None, None] * s \
+            + einsum_f32("rhejd,rhejv->rhedv", k_c, fresh_lo)
+        return s, o
+
+    first = jnp.zeros((r, hk, e, dk, dv), F32) if state is None \
+        else state.astype(F32).reshape(r, hk, e, dk, dv)
+    with jax.named_scope("delta_scan"):
+        final, outs = jax.lax.scan(step, first,
+                                   (w, u, scores, q_in, k_out, keep))
+    with jax.named_scope("delta_layout"):
+        # [nc, R, Hk, e, C, dv] -> [R, nc * C, Hv, dv]
+        o = jnp.moveaxis(jnp.moveaxis(outs, 0, 1), 4, 2).reshape(
+            r, nc * chunk, hv, dv)[:, :length]
+    return o, final.reshape(r, hv, dk, dv)
+
+
+def _delta_attrs(op):
+    return dict(key_heads=int(op.attr("key_heads")),
+                value_heads=int(op.attr("value_heads")),
+                key_dim=int(op.attr("key_dim")),
+                value_dim=int(op.attr("value_dim")))
+
+
+@register_op(
+    "gated_delta_chunk_scan",
+    inputs=["QKV", "B", "A", "ALog", "DtBias", "State", "Row"],
+    outputs=["Out", "StateOut"],
+    differentiable=False,
+    mutates=(("StateOut", "State"),),
+)
+def _gated_delta_chunk_scan(ctx, op, ins):
+    """A prefill's delta rule: `QKV` [R, L, 2 Hk dk + Hv dv] (after the
+    convolution), `B` and `A` [R, L, Hv] (beta and the decay before
+    their sigmoid / softplus). Yields o [R, L, Hv * dv] and writes the
+    rows' final state into `State` (the batch's,
+    `kv_cache.ssm_state_shape(B, Hv, dv, dk, Hk)`) at `Row`."""
+    qkv, b_raw, a_raw, a_log, dt_bias, stored = (
+        ins[k][0] for k in ("QKV", "B", "A", "ALog", "DtBias", "State"))
+    o, state = gated_delta_chunked(
+        *delta_rule_inputs(qkv, b_raw, a_raw, a_log, dt_bias,
+                           **_delta_attrs(op)),
+        int(op.attr("chunk")), lo=qkv.dtype)
+    # [R, Hv, dk, dv] is [B, H, N, P]: `pack_state` takes [B, H, P, N]
+    new = _row_block(
+        stored, pack_state(jnp.swapaxes(state, 2, 3), stored.shape[3]),
+        ins.get("Row") or None)
+    out = o.astype(qkv.dtype).reshape(o.shape[0], o.shape[1], -1)
+    return {"Out": [out], "StateOut": [new]}
+
+
+def gated_delta_update(qkv, b_raw, a_raw, a_log, dt_bias, stored, *,
+                       interpret=False, **sizes):
+    """One token a row against the stored state: qkv [B, 1, 2 Hk dk +
+    Hv dv], b_raw, a_raw [B, 1, Hv], `sizes` as `delta_rule_inputs`
+    takes them -> (o [B, 1, Hv * dv] in qkv's dtype, the new stored
+    state, whether the kernel ran). On the TPU the Pallas kernel
+    `gdn_state_update` (kernels/ssm_update.py: the state-space update
+    with the correction), elsewhere the same in `jnp`."""
+    from ..kernels import ssm_update as kernel
+
+    bsz, packs, _n, lanes = stored.shape
+    q, k, v, g, beta = (x[:, 0] for x in delta_rule_inputs(
+        qkv, b_raw, a_raw, a_log, dt_bias, **sizes))
+
+    def lane_rows(x):       # [B, Hv] -> [B, packs, lanes], a head's lanes
+        return jnp.broadcast_to(x[..., None], v.shape).reshape(
+            bsz, packs, lanes)
+
+    use_kernel = interpret or jax.default_backend() == "tpu"
+    step = functools.partial(kernel.delta_update, interpret=interpret) \
+        if use_kernel else kernel.update_reference
+    o, new = step(stored, v.reshape(bsz, packs, lanes),
+                  lane_rows(jnp.exp(g)), k.transpose(0, 2, 1),
+                  q.transpose(0, 2, 1), lane_rows(beta))
+    return o.astype(qkv.dtype).reshape(bsz, 1, -1), new, use_kernel
+
+
+@register_op(
+    "gated_delta_state_update",
+    inputs=["QKV", "B", "A", "ALog", "DtBias", "State"],
+    outputs=["Out", "StateOut"],
+    differentiable=False,
+    mutates=(("StateOut", "State"),),
+)
+def _gated_delta_state_update(ctx, op, ins):
+    """A decode step's delta rule, the whole batch, in place. The gauge
+    `kernels.gdn_update.calls` is the count of kernel calls in the
+    decode step lowered last (0: the `jnp` path ran)."""
+    from .. import observability as _obs
+
+    out, new, kernel = gated_delta_update(
+        *(ins[k][0] for k in ("QKV", "B", "A", "ALog", "DtBias", "State")),
+        **_delta_attrs(op))
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered step: the last call leaves the count
+        ctx.gdn_update_calls = kernel + getattr(ctx, "gdn_update_calls", 0)
+        _obs.set_gauge("kernels.gdn_update.calls", ctx.gdn_update_calls)
+    return {"Out": [out], "StateOut": [new]}
+
+
+# ---------------------------------------------------------------------------
 # gate, norm, activation
 # ---------------------------------------------------------------------------
 
@@ -267,14 +515,26 @@ def _ssm_state_update(ctx, op, ins):
 def _gated_rms_norm(ctx, op, ins):
     """(x * silu(gate)), RMS-normalised inside each of `num_groups`
     groups of channels, times a gain of the whole width: the gate is
-    applied BEFORE the norm."""
+    applied BEFORE the norm. With `gate_after` x alone is normalised and
+    the gate multiplies the normed, gained result (the delta-rule
+    mixer's form). A gain as wide as one group is shared by the groups."""
     x, gate, gain = ins["X"][0], ins["Gate"][0], ins["Scale"][0]
     groups = int(op.attr("num_groups", 1))
-    g = x.astype(F32) * jax.nn.silu(gate.astype(F32))
+    after = bool(op.attr("gate_after", False))
+    g = x.astype(F32)
+    if not after:
+        g = g * jax.nn.silu(gate.astype(F32))
     gg = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
     var = jnp.mean(gg * gg, axis=-1, keepdims=True)
     out = (gg * jax.lax.rsqrt(var + float(op.attr("epsilon", 1e-5))))
-    out = out.reshape(g.shape) * gain.astype(F32)
+    shared = gain.shape[0] != g.shape[-1]
+    if shared:
+        out = out * gain.astype(F32)
+    out = out.reshape(g.shape)
+    if not shared:
+        out = out * gain.astype(F32)
+    if after:
+        out = out * jax.nn.silu(gate.astype(F32))
     return {"Out": [out.astype(x.dtype)]}
 
 

@@ -148,13 +148,16 @@ MOE_COUNTERS = ("assignments_local", "assignments_total", "experts_hit",
 
 
 def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
-                       route_norm=True, n_group=1, topk_group=1):
+                       route_norm=True, n_group=1, topk_group=1,
+                       scoring="sigmoid"):
     """tokens [T, H] -> (selected [T, k] int32 over all experts, weights
     [T, k] float32). Scores are sigmoids in float32 (a product of
-    bfloat16 operands accumulated in float32 is exact); `expert_bias`
-    moves the selection only; the weights are the selected scores over
-    their sum (over all k, wherever those experts live), times
-    `route_scale`. With `n_group` > 1 the selection is group-limited:
+    bfloat16 operands accumulated in float32 is exact), or with
+    `scoring` "softmax" a softmax over all the experts; `expert_bias`
+    (None for none) moves the selection only; the weights are the
+    selected scores over their sum (over all k, wherever those experts
+    live), times `route_scale`. With `n_group` > 1 the selection is
+    group-limited:
     the experts lie in `n_group` runs of consecutive ids, a run scores
     the sum of its two largest biased scores, the `topk_group` best runs
     are kept and every other expert's biased score is set to 0 before
@@ -162,8 +165,13 @@ def sigmoid_topk_route(tokens, router_w, expert_bias, top_k, route_scale,
     from ..ops._helpers import einsum_f32
 
     logits = einsum_f32("th,he->te", tokens, router_w)
-    scores = jax.nn.sigmoid(logits)
-    biased = scores + expert_bias.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    biased = scores
+    if expert_bias is not None:
+        biased = scores + expert_bias.astype(jnp.float32)
     if n_group > 1:
         runs = biased.reshape(biased.shape[0], n_group, -1)
         run_score = jnp.sum(lax.top_k(runs, 2)[0], axis=-1)     # [T, G]
@@ -197,13 +205,14 @@ def buffer_tiles(assignments, n_local):
 def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
                       top_k, route_scale, expert_offset, route_norm=True,
                       activation="swiglu", router_x=None, n_group=1,
-                      topk_group=1, interpret=False):
+                      topk_group=1, scoring="sigmoid", interpret=False):
     """The routed part of an expert layer that THIS chip's experts give.
 
     x [B, T, K], K the width the experts work at; router_w [H, E] over
     all E experts, scored on `router_x` [B, T, H] (x itself where the
-    experts work at the hidden width), `n_group` / `topk_group` as
-    `sigmoid_topk_route` takes them; w_gate_up [E_local, K, 2F]
+    experts work at the hidden width), `n_group` / `topk_group` /
+    `scoring` as `sigmoid_topk_route` takes them (`expert_bias` None:
+    no bias buffer); w_gate_up [E_local, K, 2F]
     (`swiglu`) or [E_local, K, F] (`relu2`), w_down [E_local, F, K]:
     experts `expert_offset` .. `expert_offset + E_local - 1`. Every
     token is scored against all E, its top-k chosen, and the assignments
@@ -232,7 +241,7 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     with jax.named_scope("moe_router"):
         sel, weights = sigmoid_topk_route(
             scored, router_w, expert_bias, top_k, route_scale, route_norm,
-            n_group, topk_group,
+            n_group, topk_group, scoring,
         )
     n_assign = n_tok * top_k
     tm, n_tiles = buffer_tiles(n_assign, n_local)
@@ -325,11 +334,13 @@ def _moe_local_experts_op(ctx, op, ins):
     """`activation` ("swiglu" by default) names the experts' form;
     `RouterX`, where given, is what the router scores (experts that work
     in a latent read `X`, the router the hidden state); `n_group` and
-    `topk_group` (1 by default) limit a token to its best groups."""
-    x, router_w, bias, wgu, wd, counters = (
-        ins[k][0] for k in ("X", "RouterW", "ExpertBias", "WGateUp", "WDown",
-                            "Counters")
+    `topk_group` (1 by default) limit a token to its best groups;
+    `scoring` ("sigmoid" by default, or "softmax") is how the router
+    scores, and `ExpertBias` may be absent."""
+    x, router_w, wgu, wd, counters = (
+        ins[k][0] for k in ("X", "RouterW", "WGateUp", "WDown", "Counters")
     )
+    bias = (ins.get("ExpertBias") or [None])[0]
     top_k = int(op.attr("top_k"))
     y, sel, counts = local_experts_ffn(
         x, router_w, bias, wgu, wd, top_k=top_k,
@@ -340,6 +351,7 @@ def _moe_local_experts_op(ctx, op, ins):
         router_x=(ins.get("RouterX") or [None])[0],
         n_group=int(op.attr("n_group", 1)),
         topk_group=int(op.attr("topk_group", 1)),
+        scoring=op.attr("scoring", "sigmoid"),
     )
     most = jnp.max(counts)
     local, hit = jnp.sum(counts), jnp.sum(counts > 0).astype(jnp.int32)

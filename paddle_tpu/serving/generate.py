@@ -3,8 +3,8 @@
 ``GPTGenerator`` owns the two programs a decoder (``models/gpt.py``'s
 ``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``,
 ``models/nemotron_h.py``'s ``NemotronHDecoder``, ``models/dots_vlm.py``'s
-``DotsVlmDecoder``) splits itself into and the Scope their state
-persistables share:
+``DotsVlmDecoder``, ``models/qwen3_next.py``'s ``Qwen3NextDecoder``)
+splits itself into and the Scope their state persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
   ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
@@ -30,9 +30,11 @@ beside the shared rotary key part, padded to whole lane tiles; counted
 once a layer, by the lanes that carry data, which the decoder's
 ``cache_lanes`` gives); a state-space block carries a recurrent state
 and a convolution tail whose shapes (``ssm_state_shape``,
-``conv_tail_shape``, the same owner) do not depend on ``max_len``. The
+``conv_tail_shape``, the same owner) do not depend on ``max_len``; a
+linear-attention layer carries a delta-rule state in the same layout
+and a tail of its own. The
 decoder's ``cache_kind`` names each piece's kind (``full``, ``window``,
-``latent``, ``ssm``, ``conv``) for the
+``latent``, ``ssm``, ``linear``, ``conv``) for the
 ``kv_cache.bytes.<kind>`` gauges, and a prefill that takes a block of
 the batch's rows writes those rows' FINAL state into the batch's arrays
 as it writes their keys and values. A decoder is handed

@@ -183,6 +183,20 @@ def _ssm_update(batch, heads=128, head_dim=64, state=128, groups=8):
     return (ssm_update.update, specs),
 
 
+def _gdn_update(batch, value_heads=32, value_dim=128, key_dim=128,
+                key_heads=16):
+    """The decode step's in-place delta-rule update at Qwen3-Next's
+    sizes: the stored state [B, Hv, dk, dv] float32, aliased; the same
+    kernel as `_ssm_update` with the correction's `beta` row."""
+    from paddle_tpu.ops.kv_cache import ssm_state_shape
+
+    shape = ssm_state_shape(batch, value_heads, value_dim, key_dim,
+                            key_heads)
+    row, col = shape[:2] + shape[3:], (batch, key_dim, key_heads)
+    specs = tuple((s, F32) for s in (shape, row, row, col, col, row))
+    return (ssm_update.delta_update, specs),
+
+
 def _decode_attention(dtype, heads, kv_heads, head_dim, window=0, batch=64,
                       max_len=1024):
     """A decode step's attention over one layer's caches as stored
@@ -330,6 +344,22 @@ def _cases():
                 _gmm(rows, tm, k, n, experts=16, activation=act), ("fwd",))
     add("moe_rows-61440x7168-tm256-t7168-bf16",
         _moe_rows(61440, 256, 7168, 7168), ("gather", "combine"))
+    # generate phase of qwen3_next_ep8: the delta-rule state's update, 64
+    # sequences of 2 MB (32 value heads x 128 x 128 over 16 key heads);
+    # attention at a 256-wide head, 16 over 2 (a cache row of 512 lanes),
+    # a decode step's and a prefill dispatch's of 8 rows; the smallest
+    # experts of any cell, K = 2048 over 64 experts of 512 (a step's
+    # 64 x 10 assignments in tiles of 16, a prefill block's 8 x 896 x 10
+    # in tiles of 256)
+    add("gdn_state_update-b64-h32x128x128-f32", _gdn_update(64), ("fwd",))
+    add("decode_attention-qwen3_next-16over2x256-bf16",
+        _decode_attention(BF16, 16, 2, 256), ("fwd",))
+    add("prefill_attention-qwen3_next-16over2x256-bf16",
+        _prefill_attention(BF16, 16, 2, 256, 256, 8), ("fwd",))
+    for rows, tm in ((1664, 16), (88064, 256)):
+        for k, n, act in ((2048, 1024, "swiglu"), (512, 2048, None)):
+            add(f"moe_gmm-{rows}x{k}x{n}-tm{tm}-bf16",
+                _gmm(rows, tm, k, n, experts=64, activation=act), ("fwd",))
     # a prefill dispatch's attention in the four generate cells (896
     # tokens = one super-block of seven blocks): GPT-2's float32 12 x 64
     # over 64 rows, Trinity's 48 over 8 x 128 over 16, Nemotron's 32 over
@@ -408,6 +438,13 @@ def _named_cases():
         "moe_rows-61440x7168-tm256-t7168-bf16-combine":
             ["moe_rows_combine"],
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
+        "gdn_state_update-b64-h32x128x128-f32-fwd": ["gdn_state_update"],
+        "decode_attention-qwen3_next-16over2x256-bf16-fwd":
+            ["decode_attention"],
+        "prefill_attention-qwen3_next-16over2x256-bf16-fwd":
+            ["prefill_attention"],
+        "moe_gmm-1664x2048x1024-tm16-bf16-fwd": ["moe_gmm_swiglu"],
+        "moe_gmm-88064x512x2048-tm256-bf16-fwd": ["moe_gmm"],
         "decode_attention-gpt2-12x64-f32-fwd": ["decode_attention"],
         "prefill_attention-trinity-48over8x128-bf16-fwd":
             ["prefill_attention"],
