@@ -286,21 +286,27 @@ def _split_qkv(qkv, key_heads, value_heads, key_dim, value_dim):
             qkv[..., 2 * d:].reshape(r, t, value_heads, value_dim))
 
 
+def delta_gates(b_raw, a_raw, a_log, dt_bias):
+    """(g = -exp(A_log) softplus(a + dt_bias), the log of the decay
+    alpha; beta = sigmoid(b)), float32."""
+    g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
+        a_raw.astype(F32) + dt_bias.astype(F32))
+    return g, jax.nn.sigmoid(b_raw.astype(F32))
+
+
 def delta_rule_inputs(qkv, b_raw, a_raw, a_log, dt_bias, *, key_heads,
                       value_heads, key_dim, value_dim):
     """What the recurrence reads, all float32: q L2-normalised a head and
-    scaled by dk^-1/2, k L2-normalised, v, g = -exp(A_log) softplus(a +
-    dt_bias) (the log of the decay alpha), beta = sigmoid(b)."""
+    scaled by dk^-1/2, k L2-normalised, v, and `delta_gates`' g and
+    beta."""
     q, k, v = (x.astype(F32) for x in _split_qkv(
         qkv, key_heads, value_heads, key_dim, value_dim))
 
     def unit(x):
         return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
-    g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
-        a_raw.astype(F32) + dt_bias.astype(F32))
-    return (unit(q) * key_dim ** -0.5, unit(k), v, g,
-            jax.nn.sigmoid(b_raw.astype(F32)))
+    return (unit(q) * key_dim ** -0.5, unit(k), v,
+            *delta_gates(b_raw, a_raw, a_log, dt_bias))
 
 
 def unit_lower_inverse(strict):
@@ -429,6 +435,34 @@ def _delta_attrs(op):
                 value_dim=int(op.attr("value_dim")))
 
 
+def gated_delta_scan(qkv, b_raw, a_raw, a_log, dt_bias, *, chunk,
+                     interpret=False, **sizes):
+    """A dispatch's rows from a zero state: qkv [R, L, 2 Hk dk + Hv dv],
+    b_raw, a_raw [R, L, Hv], `sizes` as `delta_rule_inputs` takes them ->
+    (o [R, L, Hv * dv] in qkv's dtype, the state after row L - 1
+    [R, Hv, dk, dv] float32, whether the kernel ran). On the TPU (and
+    with `interpret`) the Pallas kernel `gdn_chunk_scan`
+    (kernels/gdn_chunk_scan.py) for the calls it takes (`supports`: heads
+    of whole 128-lane tiles, a chunk of at least 16 rows), elsewhere
+    `gated_delta_chunked`."""
+    from ..kernels import gdn_chunk_scan as kernel
+
+    if (interpret or jax.default_backend() == "tpu") and kernel.supports(
+            chunk=chunk, dtype=qkv.dtype, **sizes):
+        # what XLA still does of the op is named beneath its scope, as
+        # the `jnp` form's parts are (`delta_layout` ... `delta_scan`)
+        with jax.named_scope("delta_gates"):
+            gates = delta_gates(b_raw, a_raw, a_log, dt_bias)
+        o, state = kernel.scan(qkv, *gates, chunk=chunk,
+                               interpret=interpret, **sizes)
+        return o, state, True
+    o, state = gated_delta_chunked(
+        *delta_rule_inputs(qkv, b_raw, a_raw, a_log, dt_bias, **sizes),
+        chunk, lo=qkv.dtype)
+    out = o.astype(qkv.dtype).reshape(o.shape[0], o.shape[1], -1)
+    return out, state, False
+
+
 @register_op(
     "gated_delta_chunk_scan",
     inputs=["QKV", "B", "A", "ALog", "DtBias", "State", "Row"],
@@ -441,18 +475,28 @@ def _gated_delta_chunk_scan(ctx, op, ins):
     convolution), `B` and `A` [R, L, Hv] (beta and the decay before
     their sigmoid / softplus). Yields o [R, L, Hv * dv] and writes the
     rows' final state into `State` (the batch's,
-    `kv_cache.ssm_state_shape(B, Hv, dv, dk, Hk)`) at `Row`."""
+    `kv_cache.ssm_state_shape(B, Hv, dv, dk, Hk)`) at `Row`. The gauge
+    `kernels.gdn_chunk_scan.calls` is the count of kernel calls in the
+    prefill lowered last (0: the `jnp` form ran)."""
+    from .. import observability as _obs
+
     qkv, b_raw, a_raw, a_log, dt_bias, stored = (
         ins[k][0] for k in ("QKV", "B", "A", "ALog", "DtBias", "State"))
-    o, state = gated_delta_chunked(
-        *delta_rule_inputs(qkv, b_raw, a_raw, a_log, dt_bias,
-                           **_delta_attrs(op)),
-        int(op.attr("chunk")), lo=qkv.dtype)
-    # [R, Hv, dk, dv] is [B, H, N, P]: `pack_state` takes [B, H, P, N]
-    new = _row_block(
-        stored, pack_state(jnp.swapaxes(state, 2, 3), stored.shape[3]),
-        ins.get("Row") or None)
-    out = o.astype(qkv.dtype).reshape(o.shape[0], o.shape[1], -1)
+    out, state, kernel = gated_delta_scan(
+        qkv, b_raw, a_raw, a_log, dt_bias, chunk=int(op.attr("chunk")),
+        **_delta_attrs(op))
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered prefill: the last call leaves the count
+        ctx.gdn_chunk_scan_calls = kernel + getattr(
+            ctx, "gdn_chunk_scan_calls", 0)
+        _obs.set_gauge("kernels.gdn_chunk_scan.calls",
+                       ctx.gdn_chunk_scan_calls)
+    # [R, Hv, dk, dv] is the stored layout where a value head fills a
+    # lane row (pack 1); else it is [B, H, N, P] and `pack_state` takes
+    # [B, H, P, N]
+    if stored.shape[3] != state.shape[3]:
+        state = pack_state(jnp.swapaxes(state, 2, 3), stored.shape[3])
+    new = _row_block(stored, state, ins.get("Row") or None)
     return {"Out": [out], "StateOut": [new]}
 
 

@@ -28,6 +28,10 @@ from test_nemotron_h import rand, run_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HK, HV, DK, DV = 2, 4, 16, 16       # the tiny Gated DeltaNet sizes
+TINY = (HK, HV, DK, DV)
+# the cell's head sizes (two value heads a key head, 128 lanes each),
+# which the prefill's kernel takes; fewer heads
+WIDE = (2, 4, 128, 128)
 WIDTH = 2 * HK * DK + HV * DV
 ATTRS = {"key_heads": HK, "value_heads": HV, "key_dim": DK, "value_dim": DV}
 SLOTS = {"QKV": ["qkv"], "B": ["b"], "A": ["a"], "ALog": ["a_log"],
@@ -36,23 +40,31 @@ SLOTS = {"QKV": ["qkv"], "B": ["b"], "A": ["a"], "ALog": ["a_log"],
 
 # -- the gated delta rule ------------------------------------------------------
 
-def delta_inputs(seed, rows, length):
+def attrs(sizes):
+    return dict(zip(("key_heads", "value_heads", "key_dim", "value_dim"),
+                    sizes))
+
+
+def delta_inputs(seed, rows, length, sizes=TINY):
     """q | k | v after the convolution, raw b and al, and a layer's small
     parameters, drawn where the configuration's initialisation puts
     them."""
+    hk, hv, dk, dv = sizes
     rng = np.random.RandomState(seed)
     return dict(
-        qkv=rand(seed + 1, rows, length, WIDTH, scale=0.5),
-        b=rand(seed + 2, rows, length, HV),
-        a=rand(seed + 3, rows, length, HV),
-        a_log=np.log(rng.uniform(0.01, 16, HV)).astype(np.float32),
-        dt_bias=rng.uniform(-4, -1, HV).astype(np.float32),
+        qkv=rand(seed + 1, rows, length, 2 * hk * dk + hv * dv, scale=0.5),
+        b=rand(seed + 2, rows, length, hv),
+        a=rand(seed + 3, rows, length, hv),
+        a_log=np.log(rng.uniform(0.01, 16, hv)).astype(np.float32),
+        dt_bias=rng.uniform(-4, -1, hv).astype(np.float32),
     )
 
 
-def recurrence(v, state=None, state_dtype=None, correction=True):
+def recurrence(v, state=None, state_dtype=None, correction=True,
+               sizes=TINY):
     """The token-by-token delta rule in numpy float64: (o [R, L, Hv * dv],
     the final state [R, Hv, dk, dv])."""
+    HK, HV, DK, DV = sizes
     qkv = v["qkv"].astype(np.float64)
     r, length, _ = qkv.shape
     kd = HK * DK
@@ -80,6 +92,11 @@ def recurrence(v, state=None, state_dtype=None, correction=True):
     return np.stack(out, 1).reshape(r, length, HV * DV), s
 
 
+def state_shape(rows, sizes=TINY):
+    hk, hv, dk, dv = sizes
+    return kv_cache.ssm_state_shape(rows, hv, dv, dk, hk)
+
+
 def stored(state):
     """[R, Hv, dk, dv] -> the stored layout, and back."""
     shape = kv_cache.ssm_state_shape(state.shape[0], HV, DV, DK, HK)
@@ -87,21 +104,29 @@ def stored(state):
         jnp.swapaxes(jnp.asarray(state, jnp.float32), 2, 3), shape[3]))
 
 
-def unstored(state):
-    return np.swapaxes(np.asarray(ssm.unpack_state(jnp.asarray(state), DV)),
-                       2, 3)
+def unstored(state, sizes=TINY):
+    return np.swapaxes(np.asarray(ssm.unpack_state(jnp.asarray(state),
+                                                   sizes[3])), 2, 3)
 
 
-def scan_op(rows, length, chunk, row=None):
+def scan_op(rows, length, chunk, row=None, sizes=TINY):
     def build(v, blk):
-        out = blk.create_var(name="o", shape=(rows, length, HV * DV),
+        out = blk.create_var(name="o",
+                             shape=(rows, length, sizes[1] * sizes[3]),
                              dtype="float32")
         ins = dict(SLOTS, **({"Row": [row]} if row else {}))
         blk.append_op("gated_delta_chunk_scan", ins,
                       {"Out": ["o"], "StateOut": ["state"]},
-                      dict(ATTRS, chunk=chunk))
+                      dict(attrs(sizes), chunk=chunk))
         return [out]
     return build
+
+
+def interpreted_scan(monkeypatch):
+    """The prefill's op takes its Pallas kernel, interpreted (the CPU
+    takes the `jnp` form unless steered)."""
+    monkeypatch.setattr(ssm, "gated_delta_scan", functools.partial(
+        ssm.gated_delta_scan, interpret=True))
 
 
 def update_op(v, blk):
@@ -155,47 +180,148 @@ def test_the_solve_inverts_a_unit_lower_triangle():
         rtol=1e-4, atol=1e-5)
 
 
-def test_chunked_scan_writes_a_row_block_of_the_batchs_state():
-    v = delta_inputs(34, 2, 11)
-    shape = kv_cache.ssm_state_shape(5, HV, DV, DK, HK)
-    before = rand(35, *shape)
-    (_o,), state = run_ops(scan_op(2, 11, 8, row="row"),
+@pytest.mark.parametrize("sizes,chunk", [(TINY, 8), (WIDE, 16)],
+                         ids=["jnp", "kernel"])
+def test_chunked_scan_writes_a_row_block_of_the_batchs_state(
+        sizes, chunk, monkeypatch):
+    """The rows' final state lands at `Row` in the stored layout and no
+    other row moves: through the `jnp` form at the tiny sizes (two heads
+    a lane row, packed) and through the kernel, interpreted, at the
+    cell's head sizes, where [dk, dv] of a value head IS the stored
+    layout and nothing is swapped on the way."""
+    obs.reset()
+    kernel = sizes == WIDE
+    if kernel:
+        interpreted_scan(monkeypatch)
+    v = delta_inputs(34, 2, 11, sizes)
+    before = rand(35, *state_shape(5, sizes))
+    (_o,), state = run_ops(scan_op(2, 11, chunk, row="row", sizes=sizes),
                            dict(v, row=np.array([2], np.int64)),
                            {"state": before})
-    _want, final = recurrence(v)
-    np.testing.assert_allclose(unstored(state["state"][2:4]), final,
+    _want, final = recurrence(v, sizes=sizes)
+    np.testing.assert_allclose(unstored(state["state"][2:4], sizes), final,
                                rtol=2e-4, atol=2e-5)
     np.testing.assert_array_equal(state["state"][[0, 1, 4]],
                                   before[[0, 1, 4]])
+    assert obs.get_gauges()["kernels.gdn_chunk_scan.calls"] == kernel
+    if kernel:
+        np.testing.assert_allclose(state["state"][2:4], final, rtol=2e-4,
+                                   atol=2e-5)
 
 
-@pytest.mark.parametrize("interpret", [False, True])
-def test_forty_one_token_updates_continue_a_prefills_state(interpret):
+# -- the prefill's kernel ------------------------------------------------------
+
+SMALLEST = (1, 2, 128, 128)     # one key head, the smallest chunk taken
+
+
+@pytest.mark.parametrize("sizes,chunk,length,carried,dtype", [
+    (SMALLEST, 16, 32, False, "float32"),
+    (SMALLEST, 16, 21, True, "float32"),
+    (WIDE, 64, 128, False, "float32"),
+    (WIDE, 64, 70, True, "float32"),
+    (WIDE, 64, 128, True, "bfloat16"),
+    (WIDE, 64, 70, False, "bfloat16"),
+])
+def test_the_scan_kernel_in_interpret_mode(sizes, chunk, length, carried,
+                                           dtype):
+    """kernels/gdn_chunk_scan.py (the TPU path) against the `jnp` form
+    AND the token-by-token recurrence: the smallest sizes it takes and
+    the cell's head sizes (Hk < Hv, 128 lanes, chunks of 64), L whole
+    chunks and not, from a zero state and from one carried in, float32
+    and bfloat16 activations (the recurrence then reads the rounded
+    activations in float64). The interpreter's uninitialised memory is
+    NaN and a read out of bounds raises: nothing unwritten is read."""
+    from paddle_tpu.kernels import gdn_chunk_scan
+
+    assert gdn_chunk_scan.supports(*sizes, chunk, dtype)
+    v = delta_inputs(70 + length, 2, length, sizes)
+    v["qkv"] = np.asarray(jnp.asarray(v["qkv"]).astype(dtype)
+                          .astype(jnp.float32))
+    first = rand(71, 2, sizes[1], sizes[2], sizes[3], scale=0.3) \
+        if carried else None
+    ins = [jnp.asarray(v[k]) for k in ("b", "a", "a_log", "dt_bias")]
+    qkv = jnp.asarray(v["qkv"]).astype(dtype)
+    state = None if first is None else jnp.asarray(first)
+    form_o, form_s = ssm.gated_delta_chunked(
+        *ssm.delta_rule_inputs(qkv, *ins, **attrs(sizes)), chunk,
+        state=state, lo=qkv.dtype)
+    got_o, got_s = gdn_chunk_scan.scan(
+        qkv, *ssm.delta_gates(*ins), state, chunk=chunk, interpret=True,
+        **attrs(sizes))
+    assert got_o.dtype == qkv.dtype and got_s.dtype == jnp.float32
+    want_o, want_s = recurrence(v, first, sizes=sizes)
+    got_o = np.asarray(got_o.astype(jnp.float32))
+    form_o = np.asarray(form_o).reshape(got_o.shape)
+    if dtype == "float32":
+        limit = dict(rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(got_o, form_o, **limit)
+        np.testing.assert_allclose(got_s, form_s, **limit)
+        np.testing.assert_allclose(got_o, want_o, **limit)
+        np.testing.assert_allclose(got_s, want_s, **limit)
+    else:
+        # bfloat16 operands in the four products of two activations: held
+        # as the served model is, by the largest element (3e-2 there);
+        # kernel and `jnp` form round at the same places
+        def far(a, b):
+            return np.abs(np.asarray(a) - b).max() / np.abs(b).max()
+        assert far(got_o, want_o) < 1e-2 and far(got_s, want_s) < 1e-2
+        assert far(form_o, want_o) < 1e-2
+        assert far(got_o, form_o) < 1e-2 and far(got_s, np.asarray(
+            form_s)) < 1e-3
+
+
+@pytest.mark.parametrize("sizes,chunk,dtype,taken", [
+    ((16, 32, 128, 128), 64, "bfloat16", True),     # the cell's
+    (SMALLEST, 16, "float32", True),
+    (TINY, 16, "float32", False),       # heads narrower than a lane tile
+    (WIDE, 8, "float32", False),        # a chunk under a bfloat16 tile
+    (WIDE, 48, "float32", False),       # no power of two
+    ((3, 4, 128, 128), 64, "float32", False),   # key heads do not divide
+    (WIDE, 64, "float16", False),
+])
+def test_what_the_scan_kernel_takes(sizes, chunk, dtype, taken):
+    from paddle_tpu.kernels import gdn_chunk_scan
+
+    assert gdn_chunk_scan.supports(*sizes, chunk, dtype) == taken
+
+
+@pytest.mark.parametrize("interpret,sizes,chunk", [
+    (False, TINY, 8), (True, TINY, 8), (True, WIDE, 16)],
+    ids=["jnp", "update-kernel", "both-kernels"])
+def test_forty_one_token_updates_continue_a_prefills_state(
+        interpret, sizes, chunk, monkeypatch):
     """Prefill 11 rows by the chunked scan, then 40 one-token updates on
-    the stored state (the `jnp` form, and the kernel interpreted): the
+    the stored state (the `jnp` forms; the update's kernel interpreted;
+    at the cell's head sizes BOTH kernels interpreted, the prefill's
+    `gdn_chunk_scan` handing its state to `gdn_state_update`): the
     recurrence over all 51. A state rounded to bfloat16 a step drifts
     further from it than the limit the float32 one is held to."""
-    v = delta_inputs(40, 3, 51)
+    obs.reset()
+    if sizes == WIDE:
+        interpreted_scan(monkeypatch)
+    v = delta_inputs(40, 3, 51, sizes)
     head = {k: (a[:, :11] if a.ndim == 3 else a) for k, a in v.items()}
-    shape = kv_cache.ssm_state_shape(3, HV, DV, DK, HK)
-    (first,), state = run_ops(scan_op(3, 11, 8), head,
+    shape = state_shape(3, sizes)
+    (first,), state = run_ops(scan_op(3, 11, chunk, sizes=sizes), head,
                               {"state": np.zeros(shape, np.float32)})
+    assert obs.get_gauges()["kernels.gdn_chunk_scan.calls"] == \
+        (sizes == WIDE)
     got, held = [first], jnp.asarray(state["state"])
     for t in range(11, 51):
         step = [jnp.asarray(a[:, t:t + 1] if a.ndim == 3 else a)
                 for a in (v[k] for k in ("qkv", "b", "a", "a_log",
                                          "dt_bias"))]
         o, held, kernel = ssm.gated_delta_update(
-            *step, held, interpret=interpret, **ATTRS)
+            *step, held, interpret=interpret, **attrs(sizes))
         assert kernel == interpret
         got.append(np.asarray(o))
-    want, final = recurrence(v)
+    want, final = recurrence(v, sizes=sizes)
     limit = dict(rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.concatenate(got, 1), want, **limit)
-    np.testing.assert_allclose(unstored(held), final, **limit)
-    _o, rounded = recurrence(v, state_dtype=jnp.bfloat16)
+    np.testing.assert_allclose(unstored(held, sizes), final, **limit)
+    _o, rounded = recurrence(v, state_dtype=jnp.bfloat16, sizes=sizes)
     assert np.abs(rounded - final).max() > 20 * np.abs(
-        unstored(held) - final).max()
+        unstored(held, sizes) - final).max()
     assert not np.allclose(rounded, final, **limit)
 
 
@@ -530,6 +656,7 @@ def test_prefill_then_cached_decode_match_the_reference(dtype, tol,
     assert report["decode_routing"]["tokens"] == 4 * 2 * 19
     # the CPU takes the `jnp` path unless steered
     assert obs.get_gauges()["kernels.gdn_update.calls"] == 3 * kernel
+    assert obs.get_gauges()["kernels.gdn_chunk_scan.calls"] == 0
     if dtype == "float32":
         assert report["decode_routing"]["near_ties"] == 0
         # the check is not blind to the delta rule, nor to the carried
@@ -546,15 +673,19 @@ def test_the_cells_twelve_layers_lower_to_nine_and_three_kernel_calls(
     three dispatches steered to their kernels: lowered (the gauges are
     set where a step is lowered; nothing is compiled or run) a decode
     step holds 9 `gdn_state_update` and 3 `decode_attention` calls, a
-    prefill dispatch 3 `prefill_attention` calls."""
+    prefill dispatch 9 `gdn_chunk_scan` and 3 `prefill_attention`
+    calls."""
     obs.reset()
+    interpreted_scan(monkeypatch)
     monkeypatch.setattr(ssm, "gated_delta_update", functools.partial(
         ssm.gated_delta_update, interpret=True))
     monkeypatch.setattr(kv_cache, "decode_attention", functools.partial(
         kv_cache.decode_attention, interpret=True))
     monkeypatch.setattr(llm, "prefill_attention", functools.partial(
         llm.prefill_attention, interpret=True))
-    cfg = Qwen3NextConfig.tiny(num_layers=12, head_dim=128)
+    cfg = Qwen3NextConfig.tiny(num_layers=12, head_dim=128,
+                               linear_key_head_dim=128,
+                               linear_value_head_dim=128, chunk_size=64)
     gen = GPTGenerator(Qwen3NextDecoder(cfg), batch=2, context_len=128,
                        max_len=130)
     gen.reset()
@@ -570,6 +701,7 @@ def test_the_cells_twelve_layers_lower_to_nine_and_three_kernel_calls(
         fetch_list=gen._decode_fetch, scope=gen.scope)
     gauges = obs.get_gauges()
     assert gauges["kernels.gdn_update.calls"] == 9
+    assert gauges["kernels.gdn_chunk_scan.calls"] == 9
     assert gauges["kernels.decode_attention.calls"] == 3
     assert gauges["kernels.prefill_attention.calls"] == 3
 

@@ -20,6 +20,7 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
+import functools  # noqa: E402
 import math  # noqa: E402
 
 import jax  # noqa: E402
@@ -31,6 +32,7 @@ from paddle_tpu.kernels import decode_attention  # noqa: E402
 from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
 from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
 from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
+from paddle_tpu.kernels import gdn_chunk_scan  # noqa: E402
 from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
 from paddle_tpu.kernels import moe_gmm  # noqa: E402
 from paddle_tpu.kernels import prefill_attention  # noqa: E402
@@ -197,6 +199,24 @@ def _gdn_update(batch, value_heads=32, value_dim=128, key_dim=128,
     return (ssm_update.delta_update, specs),
 
 
+def _gdn_scan(rows, length, dtype, carried=False, key_heads=16,
+              value_heads=32, dim=128, chunk=64):
+    """A prefill dispatch's gated delta rule at Qwen3-Next's sizes: q | k
+    | v of the convolution as ONE array read by lane blocks, g and beta
+    float32, the final state [R, Hv, dk, dv] float32 (the stored
+    layout); `carried`: a state enters as well."""
+    sizes = dict(key_heads=key_heads, value_heads=value_heads, key_dim=dim,
+                 value_dim=dim, chunk=chunk)
+    assert gdn_chunk_scan.supports(key_heads, value_heads, dim, dim, chunk,
+                                   dtype)
+    gate = ((rows, length, value_heads), F32)
+    specs = (((rows, length, (2 * key_heads + value_heads) * dim), dtype),
+             gate, gate)
+    if carried:
+        specs += (((rows, value_heads, dim, dim), F32),)
+    return (functools.partial(gdn_chunk_scan.scan, **sizes), specs),
+
+
 def _decode_attention(dtype, heads, kv_heads, head_dim, window=0, batch=64,
                       max_len=1024):
     """A decode step's attention over one layer's caches as stored
@@ -352,6 +372,17 @@ def _cases():
     # 64 x 10 assignments in tiles of 16, a prefill block's 8 x 896 x 10
     # in tiles of 256)
     add("gdn_state_update-b64-h32x128x128-f32", _gdn_update(64), ("fwd",))
+    # ... and the prefill's delta rule, a dispatch of 8 rows x 896 tokens
+    # (14 chunks of 64) over 16 / 32 heads x 128, bfloat16 in, float32
+    # state; a prompt that is not whole chunks with a state carried in,
+    # float32 activations, the smallest chunk taken
+    add("gdn_chunk_scan-r8-l896-16over32x128-bf16", _gdn_scan(8, 896, BF16),
+        ("fwd",))
+    add("corner-gdn_chunk_scan-r2-l1000-carried-f32",
+        _gdn_scan(2, 1000, F32, carried=True), ("fwd",))
+    add("corner-gdn_chunk_scan-r2-l100-2over2x256-c16-bf16",
+        _gdn_scan(2, 100, BF16, key_heads=2, value_heads=2, dim=256,
+                  chunk=16), ("fwd",))
     add("decode_attention-qwen3_next-16over2x256-bf16",
         _decode_attention(BF16, 16, 2, 256), ("fwd",))
     add("prefill_attention-qwen3_next-16over2x256-bf16",
@@ -439,6 +470,7 @@ def _named_cases():
             ["moe_rows_combine"],
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
         "gdn_state_update-b64-h32x128x128-f32-fwd": ["gdn_state_update"],
+        "gdn_chunk_scan-r8-l896-16over32x128-bf16-fwd": ["gdn_chunk_scan"],
         "decode_attention-qwen3_next-16over2x256-bf16-fwd":
             ["decode_attention"],
         "prefill_attention-qwen3_next-16over2x256-bf16-fwd":
