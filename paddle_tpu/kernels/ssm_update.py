@@ -69,13 +69,13 @@ def _kernel(packs_per_group, corrected, state_ref, xdt_ref, decay_ref,
             y_ref[0, k:k + 1, :] = jnp.sum(s * c_col, axis=0, keepdims=True)
 
 
-def update(state, xdt, decay, bt, ct, beta=None, interpret=False):
+def update(state, xdt, decay, bt, ct, beta=None, interpret=False, name=None):
     """state [B, K, N, W] float32 (K packs of heads, W = pack * P lanes);
     xdt, decay [B, K, W] float32 (dt * x, and exp(dt * A) of the lane's
     head); bt, ct [B, N, G] float32, pack k reading group
     k // (K / G); `beta` [B, K, W] float32 turns the step into the delta
-    rule (xdt is then v, bt and ct are k and q). Returns (y [B, K, W]
-    float32, the new state, which is the old one's buffer)."""
+    rule (xdt is then v, bt and ct are k and q); `name` the call's. ->
+    (y [B, K, W] float32, the new state: the old one's buffer)."""
     b, packs, n, w = state.shape
     groups = bt.shape[2]
     whole = lambda i: (i, 0, 0)                              # noqa: E731
@@ -85,7 +85,7 @@ def update(state, xdt, decay, bt, ct, beta=None, interpret=False):
     corrected = beta is not None
     return pl.pallas_call(
         functools.partial(_kernel, packs // groups, corrected),
-        name="gdn_state_update" if corrected else "ssm_state_update",
+        name=name or ("gdn_state_update" if corrected else "ssm_state_update"),
         grid=(b,),
         in_specs=[state_spec, rows, rows, cols, cols] + [rows] * corrected,
         out_specs=[rows, state_spec],
@@ -121,3 +121,17 @@ def update_reference(state, xdt, decay, bt, ct, beta=None):
         xdt = beta * (xdt - jnp.sum(new * b_col[..., None], axis=2))
     new = new + b_col[..., None] * xdt[:, :, None, :]
     return jnp.sum(new * c_col[..., None], axis=2), new
+
+
+# A constant-decay linear mixer (models/minicpm_sala.py's Lightning
+# layers) is the state-space step with dt = 1, one group a head and the
+# decay a constant of the head: `update` as it stands, under a call name
+# of its own (`lightning_state_update`) so that its events are found
+# apart from the two others', in a jit of its own for the reason above.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lightning_update(state, v, decay, kt, qt, interpret=False):
+    """`update` as linear attention with a per-head decay: state
+    [B, H, dk, dv] as stored, v and decay [B, H, dv], kt and qt
+    [B, dk, H]; y = q . S, unscaled."""
+    return update(state, v, decay, kt, qt, interpret=interpret,
+                  name="lightning_state_update")

@@ -305,3 +305,75 @@ def _greedy_token(ctx, op, ins):
         picked_all = picked
     out = jax.lax.dynamic_update_slice(tokens, picked, (row, col))
     return {"TokensOut": [out], "NextOut": [picked_all]}
+
+
+# ---------------------------------------------------------------------------
+# the compressed-key index of block-sparse attention
+# ---------------------------------------------------------------------------
+#
+# A layer that selects the blocks it attends to by content keeps, beside
+# its K and V caches, an index of compressed keys: row j is the mean of
+# the keys at positions stride * j .. stride * j + kernel - 1, written
+# once that window is complete (in a prefill from the prompt's keys, in
+# a decode step when its position completes one). `ops/llm.py`'s
+# `sparse_block_select` scores it. The gauge `kv_cache.bytes.index` is
+# its bytes (a decoder's `cache_kind` "index").
+
+def index_shape(batch, max_len, stride, num_heads, head_dim):
+    """Stored shape of ONE layer's compressed-key index: ``[B, max_len /
+    stride, nkv * dh]`` in the keys' dtype, a row a stride."""
+    return (int(batch), int(max_len) // int(stride),
+            int(num_heads) * int(head_dim))
+
+
+def compress_keys(k, kernel, stride):
+    """k [R, S, H] -> the complete windows' means [R, (S - kernel) //
+    stride + 1, H] in k's dtype (summed in float32 a stride at a time;
+    `kernel` a multiple of `stride`)."""
+    r, s, h = k.shape
+    chunks = s // stride
+    per = kernel // stride
+    if chunks < per:
+        return jnp.zeros((r, 0, h), k.dtype)
+    part = k[:, :chunks * stride].astype(jnp.float32).reshape(
+        r, chunks, stride, h).sum(axis=2)
+    rows = chunks - per + 1
+    total = sum(part[:, m:m + rows] for m in range(per))
+    return (total / kernel).astype(k.dtype)
+
+
+@register_op(
+    "kv_index_write",
+    inputs=["Index", "K", "Pos", "Row"],
+    outputs=["IndexOut"],
+    differentiable=False,
+    mutates=(("IndexOut", "Index"),),
+)
+def _kv_index_write(ctx, op, ins):
+    """Without `carry` (a prefill from position 0): K is the call's own
+    keys [R, S, H], rows `Row` .. of the batch; every complete window's
+    mean is written. With `carry` (a decode step): K is the cache after
+    the step's write [B, slots, H] and `Pos` the step's position; where
+    that position completes a window, its row is written."""
+    index, k = ins["Index"][0], ins["K"][0]
+    kernel, stride = int(op.attr("kernel")), int(op.attr("stride"))
+    if not op.attr("carry", False):
+        rows = compress_keys(k, kernel, stride)
+        r0 = jnp.int32(0) if not ins.get("Row") \
+            else _pos_scalar(ins["Row"][0])
+        if rows.shape[1] == 0:
+            return {"IndexOut": [index]}
+        return {"IndexOut": [jax.lax.dynamic_update_slice(
+            index, rows.astype(index.dtype), (r0, jnp.int32(0),
+                                               jnp.int32(0)))]}
+    pos = _pos_scalar(ins["Pos"][0])
+    first = jnp.maximum(pos - (kernel - 1), 0)
+    window = jax.lax.dynamic_slice_in_dim(k, first, kernel, axis=1)
+    mean = (window.astype(jnp.float32).sum(axis=1, keepdims=True)
+            / kernel).astype(index.dtype)
+    done = pos + 1 - kernel
+    row = jnp.clip(done // stride, 0, index.shape[1] - 1)
+    old = jax.lax.dynamic_slice_in_dim(index, row, 1, axis=1)
+    new = jnp.where((done >= 0) & (done % stride == 0), mean, old)
+    return {"IndexOut": [jax.lax.dynamic_update_slice(
+        index, new, (jnp.int32(0), row, jnp.int32(0)))]}

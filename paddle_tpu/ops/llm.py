@@ -148,17 +148,17 @@ def swiglu(x):
 SCORE_BLOCK_BYTES = 384 * 2 ** 20
 
 
-def _query_block(batch, heads, seq):
-    """(rows, queries) of one block: whole rows while they fit, else one
-    row cut along the queries; both divide their axis."""
-    budget = SCORE_BLOCK_BYTES
-    per_row = heads * seq * seq * 4
+def _query_block(batch, heads, seq, keys=None):
+    """(rows, queries) of one block of [heads, seq, keys (seq)] scores:
+    whole rows while they fit, else one row cut along the queries."""
+    budget, keys = SCORE_BLOCK_BYTES, keys or seq
+    per_row = heads * seq * keys * 4
     if per_row <= budget:
         rows = max(1, min(batch, budget // per_row))
         while batch % rows:
             rows -= 1
         return rows, seq
-    queries = max(1, budget // (heads * seq * 4))
+    queries = max(1, budget // (heads * keys * 4))
     while seq % queries:
         queries -= 1
     return 1, queries
@@ -304,3 +304,224 @@ def _mla_absorb_output(ctx, op, ins):
     b, t, _h = x.shape
     out = einsum_f32("bthr,hrd->bthd", x.reshape(b, t, nh, -1), w[..., dn:])
     return {"Out": [out.astype(x.dtype).reshape(b, t, -1)]}
+
+
+# ---------------------------------------------------------------------------
+# block-sparse attention selected by content (InfLLM v2)
+# ---------------------------------------------------------------------------
+#
+# A query group scores the layer's compressed keys (`kv_cache.
+# compress_keys`: the mean of `kernel` keys every `stride` positions,
+# visible to query t once its window ends at or before t), sums the
+# softmax over them across the group's query heads, pools the sums to
+# blocks of `block_size` positions by the maximum over the compressed
+# keys that overlap a block, and attends to `topk` blocks: the first
+# `init_blocks`, those meeting the last `window` positions up to t, and
+# the best-scoring others. Up to `dense_len` keys (the prompt's length
+# in a prefill, the position + 1 in a decode step) attention is dense.
+
+def select_blocks(q, index, qpos, *, num_kv_heads, kernel, stride,
+                  block_size, window, init_blocks, topk, scale):
+    """q [R, T, nh * dh] at positions `qpos` [T] over `index` [R, J,
+    nkv * dh] (J = slots / stride) -> the blocks each (row, KV head,
+    query) reads [R, nkv, T, topk] int32, -1 where fewer are visible;
+    the scores are float32."""
+    r, t, _ = q.shape
+    j, hk = index.shape[1:]
+    dh = hk // num_kv_heads
+    per_block = block_size // stride
+    # every block a position of the J strides' slots may lie in
+    blocks = -(-(j * stride + stride - 1) // block_size)
+    qh = q.reshape(r, t, num_kv_heads, -1, dh)
+    ch = index.reshape(r, j, num_kv_heads, dh)
+    scores = einsum_f32("btkgd,bjkd->bkgtj", qh, ch) * scale
+    ends = jnp.arange(j, dtype=jnp.int32) * stride + kernel - 1
+    seen = ends[None, :] <= qpos[:, None]                       # [T, J]
+    scores = jnp.where(seen, scores, -jnp.inf)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    top = jnp.where(jnp.isfinite(top), top, 0.0)
+    e = jnp.where(seen, jnp.exp(scores - top), 0.0)
+    p = jnp.sum(e / jnp.maximum(jnp.sum(e, -1, keepdims=True), 1e-30),
+                axis=2)                                         # [R,nkv,T,J]
+    p = jnp.where(seen, p, -jnp.inf)
+    # a block's score: the best of the compressed keys overlapping it,
+    # j in [B * per_block - lead, (B + 1) * per_block)
+    width = blocks * per_block
+    p = jnp.pad(p, [(0, 0)] * 3 + [(0, width - j)], constant_values=-jnp.inf)
+    pooled = jnp.max(p.reshape(r, num_kv_heads, t, blocks, per_block), -1)
+    lead = -(-kernel // stride) - 1
+    for i in range(1, lead + 1):
+        before = jnp.pad(p, [(0, 0)] * 3 + [(i, 0)],
+                         constant_values=-jnp.inf)[..., :width]
+        pooled = jnp.maximum(pooled, before[..., ::per_block])
+    starts = jnp.arange(blocks, dtype=jnp.int32) * block_size
+    causal = starts[None, :] <= qpos[:, None]                   # [T, nb]
+    forced = causal & (
+        (jnp.arange(blocks) < init_blocks)[None, :]
+        | (starts[None, :] + block_size > qpos[:, None] - window + 1))
+    score = jnp.where(forced, jnp.inf, jnp.where(causal, pooled, -jnp.inf))
+    value, picked = jax.lax.top_k(score, topk)
+    return jnp.where(value > -jnp.inf, picked, -1).astype(jnp.int32)
+
+
+@register_op(
+    "sparse_block_select",
+    inputs=["Q", "Index", "Pos", "Row", "Counters"],
+    outputs=["Selected", "CountersOut"],
+    differentiable=False,
+    mutates=(("CountersOut", "Counters"),),
+)
+def _sparse_block_select(ctx, op, ins):
+    """Scoring, pooling and selection of the blocks `select_blocks`
+    says, for queries Q [R, T, nh * dh] whose last row is at `Pos`
+    against the stored `Index` (rows `Row` .. of the batch's). Where
+    the keys are at most `dense_len` (the last row's position + 1), the
+    attention will be dense and nothing is counted; else `Counters`
+    [2] int32 gain the blocks selected and the blocks a query could see
+    (a block that starts at or before it), summed over rows, KV heads
+    and queries. Queries go in blocks of `SCORE_BLOCK_BYTES` of scores.
+    The gauge `sparse_attention.selecting_layers` is the count of such
+    ops in the program lowered last."""
+    from .. import observability as _obs
+
+    q, index = ins["Q"][0], ins["Index"][0]
+    counters = ins["Counters"][0]
+    r, t, _ = q.shape
+    if ins.get("Row"):
+        index = jax.lax.dynamic_slice_in_dim(
+            index, _pos_scalar(ins["Row"][0]), r, axis=0)
+    pos = _pos_scalar(ins["Pos"][0])
+    qpos = pos - (t - 1) + jnp.arange(t, dtype=jnp.int32)
+    attrs = {n: int(op.attr(n)) for n in (
+        "num_kv_heads", "kernel", "stride", "block_size", "window",
+        "init_blocks", "topk")}
+    scale = float(op.attr("scale"))
+    rows, queries = _query_block(r, int(op.attr("num_heads")), t,
+                                 index.shape[1])
+    nq = t // queries
+
+    def block(i):
+        r0, q0 = (i // nq) * rows, (i % nq) * queries
+        qb = jax.lax.dynamic_slice(q, (r0, q0, 0), (rows, queries,
+                                                    q.shape[2]))
+        ib = jax.lax.dynamic_slice_in_dim(index, r0, rows, axis=0)
+        return select_blocks(qb, ib, jax.lax.dynamic_slice_in_dim(
+            qpos, q0, queries), scale=scale, **attrs)
+
+    n = (r // rows) * nq
+    if n == 1:
+        picked = block(jnp.int32(0))
+    else:
+        picked = jax.lax.map(block, jnp.arange(n, dtype=jnp.int32))
+        picked = picked.reshape((r // rows, nq) + picked.shape[1:])
+        picked = jnp.moveaxis(picked, 1, 3).reshape(
+            r, attrs["num_kv_heads"], t, attrs["topk"])
+    sparse = (pos + 1 > int(op.attr("dense_len"))).astype(jnp.int32)
+    chosen = jnp.sum(picked >= 0, dtype=jnp.int32)
+    seen = r * attrs["num_kv_heads"] * jnp.sum(
+        qpos // attrs["block_size"] + 1, dtype=jnp.int32)
+    counted = counters + sparse * jnp.stack([chosen, seen]).astype(
+        counters.dtype)
+    if ctx is not None and not ctx.abstract:
+        ctx.selecting_layers = 1 + getattr(ctx, "selecting_layers", 0)
+        _obs.set_gauge("sparse_attention.selecting_layers",
+                       ctx.selecting_layers)
+    return {"Selected": [picked], "CountersOut": [counted]}
+
+
+def _sparse_decode(q, k, v, pos, selected, num_kv_heads, block_size,
+                   scale):
+    """One token a row over the caches' selected blocks, gathered by
+    index: q [B, nh * dh], k, v [B, slots, nkv * dh], selected [B, nkv,
+    topk] -> [B, nh * dh]."""
+    b, slots, hk = k.shape
+    dh = hk // num_kv_heads
+    kpos = (jnp.maximum(selected, 0)[..., None] * block_size
+            + jnp.arange(block_size)).reshape(b, num_kv_heads, -1)
+    valid = (jnp.repeat(selected, block_size, axis=-1) >= 0) & (kpos <= pos)
+    at = jnp.minimum(kpos, slots - 1)[..., None]          # [B, nkv, n, 1]
+    outs = []
+    for g in range(num_kv_heads):
+        kg, vg = (jnp.take_along_axis(c[..., g * dh:(g + 1) * dh], at[:, g],
+                                      axis=1) for c in (k, v))  # [B, n, dh]
+        qg = q.reshape(b, num_kv_heads, -1, dh)[:, g]    # [B, e, dh]
+        s = einsum_f32("bed,bnd->ben", qg, kg) * scale
+        s = jnp.where(valid[:, g][:, None], s, jnp.float32(-1e9))
+        probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        outs.append(jnp.einsum("ben,bnd->bed", probs, vg))
+    return jnp.stack(outs, axis=1).reshape(b, -1)
+
+
+def _sparse_prefill(q, k, v, selected, num_heads, num_kv_heads,
+                    block_size, scale):
+    """A call's own rows from position 0, each (KV head, query) over
+    its selected blocks' keys at or before it: the blocked attention
+    with a block mask (the cost of dense, the result of sparse)."""
+    b, s, h = q.shape
+    hk = k.shape[2]
+    dh = hk // num_kv_heads
+    blocks = -(-s // block_size)
+    rows, queries = _query_block(b, num_heads, s)
+    nb, nq = b // rows, s // queries
+
+    def block(i):
+        r0, q0 = (i // nq) * rows, (i % nq) * queries
+        qb = jax.lax.dynamic_slice(q, (r0, q0, 0), (rows, queries, h))
+        kb = jax.lax.dynamic_slice_in_dim(k, r0, rows, axis=0)
+        vb = jax.lax.dynamic_slice_in_dim(v, r0, rows, axis=0)
+        sel = jax.lax.dynamic_slice(
+            selected, (r0, 0, q0, 0),
+            (rows, num_kv_heads, queries, selected.shape[3]))
+        hit = jnp.any(sel[..., None] == jnp.arange(blocks), axis=-2)
+        keys = jnp.repeat(hit, block_size, axis=-1)[..., :s]
+        qpos = q0 + jnp.arange(queries)
+        keys = keys & (jnp.arange(s)[None, :] <= qpos[:, None])
+        qh = qb.reshape(rows, queries, num_kv_heads, -1, dh)
+        scores = einsum_f32("btkgd,bskd->bkgts", qh,
+                            kb.reshape(rows, s, num_kv_heads, dh)) * scale
+        scores = jnp.where(keys[:, :, None], scores, jnp.float32(-1e9))
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bkgts,bskd->btkgd", probs,
+                         vb.reshape(rows, s, num_kv_heads, -1))
+        return out.reshape(rows, queries, -1)
+
+    if nb * nq == 1:
+        return block(jnp.int32(0))
+    out = jax.lax.map(block, jnp.arange(nb * nq, dtype=jnp.int32))
+    return out.reshape(nb, nq, rows, queries, -1).transpose(
+        0, 2, 1, 3, 4).reshape(b, s, -1)
+
+
+@register_op(
+    "block_sparse_attention",
+    inputs=["Q", "K", "V", "Selected", "Pos"],
+    outputs=["Out"],
+    differentiable=False,
+)
+def _block_sparse_attention(ctx, op, ins):
+    """Attention over the blocks `sparse_block_select` chose. Q [R, T,
+    nh * dh]; a prefill (T > 1, from position 0) over the call's own
+    K and V rows with a block mask; a decode step (T = 1, at `Pos`)
+    over the caches, the selected blocks' rows gathered by index, or
+    dense (`decode_attention`) while the keys are at most
+    `dense_len`."""
+    q, k, v, selected = (ins[n][0] for n in ("Q", "K", "V", "Selected"))
+    nh, kvh = int(op.attr("num_heads")), int(op.attr("num_kv_heads"))
+    bs, scale = int(op.attr("block_size")), float(op.attr("scale"))
+    if q.shape[1] > 1:
+        return {"Out": [_sparse_prefill(q, k, v, selected, nh, kvh, bs,
+                                        scale)]}
+    pos = _pos_scalar(ins["Pos"][0])
+
+    def dense(_):
+        from .kv_cache import decode_attention
+
+        return decode_attention(q[:, 0], k, v, pos, kvh, scale)[0]
+
+    def sparse(_):
+        return _sparse_decode(q[:, 0], k, v, pos, selected[:, :, 0], kvh,
+                              bs, scale)
+
+    out = jax.lax.cond(pos + 1 > int(op.attr("dense_len")), sparse, dense,
+                       None)
+    return {"Out": [out[:, None]]}

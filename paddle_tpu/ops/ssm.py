@@ -557,17 +557,21 @@ def _gated_delta_state_update(ctx, op, ins):
 @register_op("gated_rms_norm", inputs=["X", "Gate", "Scale"],
              outputs=["Out"], differentiable=False)
 def _gated_rms_norm(ctx, op, ins):
-    """(x * silu(gate)), RMS-normalised inside each of `num_groups`
-    groups of channels, times a gain of the whole width: the gate is
+    """(x * act(gate)), RMS-normalised inside each of `num_groups`
+    groups of channels (the norm's width is the width over `num_groups`:
+    1, the whole width), times a gain of the whole width: the gate is
     applied BEFORE the norm. With `gate_after` x alone is normalised and
-    the gate multiplies the normed, gained result (the delta-rule
-    mixer's form). A gain as wide as one group is shared by the groups."""
+    the gate multiplies the normed, gained result (the linear mixers'
+    form). `activation` is the gate's: silu (by default) or sigmoid. A
+    gain as wide as one group is shared by the groups."""
     x, gate, gain = ins["X"][0], ins["Gate"][0], ins["Scale"][0]
     groups = int(op.attr("num_groups", 1))
     after = bool(op.attr("gate_after", False))
+    act = {"silu": jax.nn.silu,
+           "sigmoid": jax.nn.sigmoid}[op.attr("activation", "silu")]
     g = x.astype(F32)
     if not after:
-        g = g * jax.nn.silu(gate.astype(F32))
+        g = g * act(gate.astype(F32))
     gg = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
     var = jnp.mean(gg * gg, axis=-1, keepdims=True)
     out = (gg * jax.lax.rsqrt(var + float(op.attr("epsilon", 1e-5))))
@@ -578,7 +582,7 @@ def _gated_rms_norm(ctx, op, ins):
     if not shared:
         out = out * gain.astype(F32)
     if after:
-        out = out * jax.nn.silu(gate.astype(F32))
+        out = out * act(gate.astype(F32))
     return {"Out": [out.astype(x.dtype)]}
 
 
@@ -591,3 +595,106 @@ def relu2(x):
 @register_op("relu2", inputs=["X"], outputs=["Out"], differentiable=False)
 def _relu2(ctx, op, ins):
     return {"Out": [relu2(ins["X"][0])]}
+
+
+# ---------------------------------------------------------------------------
+# linear attention with a constant decay a head (Lightning Attention)
+# ---------------------------------------------------------------------------
+#
+#   S_t = lambda_h S_{t-1} + k_t (outer) v_t;   o_t = S_t^T q_t / sqrt(d)
+#
+# The state-space recurrence above with dt = 1, x = v, B = k, C = q, one
+# group a head and A = -s_h a constant of the head: `ssd_chunked` for a
+# prefill, `kernels/ssm_update.py` for a decode step (its own call name,
+# `lightning_state_update`). Stored as `ssm_state_shape(B, H, d, d, H)`:
+# [B, H, dk, dv] float32, the key dimension on the sublanes.
+
+def lightning_slopes(num_heads):
+    """s_h = 2^(-8 (h + 1) / H), h = 0 .. H - 1 (Lightning Attention's
+    ALiBi-style slopes; MiniMax-01's `_build_slope_tensor` for a power
+    of two): the decay a position is lambda_h = exp(-s_h)."""
+    import numpy as np
+
+    return np.exp2(-8.0 * np.arange(1, num_heads + 1) / num_heads).astype(
+        np.float32)
+
+
+def lightning_scan(q, k, v, *, num_heads, head_dim, chunk):
+    """A dispatch's rows from a zero state: q, k, v [R, L, H * d] (after
+    their norms and positions) -> (o [R, L, H * d] float32, the state
+    after row L - 1 [R, H, dv, dk] float32). 1 / sqrt(d) is applied to
+    the output (the recurrence is linear in q)."""
+    r, length, _ = q.shape
+    shape = (r, length, num_heads, head_dim)
+    y, state = ssd_chunked(
+        v.reshape(shape), jnp.ones(shape[:3], F32),
+        -jnp.asarray(lightning_slopes(num_heads)), k.reshape(shape),
+        q.reshape(shape), chunk)
+    return (y * head_dim ** -0.5).reshape(r, length, -1), state
+
+
+@register_op(
+    "lightning_chunk_scan",
+    inputs=["Q", "K", "V", "State", "Row"],
+    outputs=["Out", "StateOut"],
+    differentiable=False,
+    mutates=(("StateOut", "State"),),
+)
+def _lightning_chunk_scan(ctx, op, ins):
+    """A prefill's recurrence: Q, K, V [R, L, H * d]. Yields o in V's
+    dtype and writes the rows' final state into `State` (the batch's,
+    `kv_cache.ssm_state_shape(B, H, d, d, H)`) at `Row`."""
+    q, k, v, stored = (ins[n][0] for n in ("Q", "K", "V", "State"))
+    o, state = lightning_scan(q, k, v, num_heads=int(op.attr("num_heads")),
+                              head_dim=int(op.attr("head_dim")),
+                              chunk=int(op.attr("chunk")))
+    new = _row_block(stored, pack_state(state, stored.shape[3]),
+                     ins.get("Row") or None)
+    return {"Out": [o.astype(v.dtype)], "StateOut": [new]}
+
+
+def lightning_update(q, k, v, stored, *, num_heads, interpret=False):
+    """One token a row against the stored state: q, k, v [B, 1, H * d]
+    -> (o [B, 1, H * d] in v's dtype, the new stored state, whether the
+    kernel ran). On the TPU the Pallas kernel `lightning_state_update`
+    (kernels/ssm_update.py), elsewhere the same in `jnp`."""
+    from ..kernels import ssm_update as kernel
+
+    bsz, heads, d, _lanes = stored.shape
+    qh, kh, vh = (x[:, 0].astype(F32).reshape(bsz, num_heads, d)
+                  for x in (q, k, v))
+    decay = jnp.broadcast_to(
+        jnp.exp(-jnp.asarray(lightning_slopes(num_heads)))[None, :, None],
+        vh.shape)
+    use_kernel = interpret or jax.default_backend() == "tpu"
+    step = functools.partial(kernel.lightning_update, interpret=interpret) \
+        if use_kernel else kernel.update_reference
+    o, new = step(stored, vh, decay, kh.transpose(0, 2, 1),
+                  qh.transpose(0, 2, 1))
+    o = o * d ** -0.5
+    return o.astype(v.dtype).reshape(bsz, 1, -1), new, use_kernel
+
+
+@register_op(
+    "lightning_state_update",
+    inputs=["Q", "K", "V", "State"],
+    outputs=["Out", "StateOut"],
+    differentiable=False,
+    mutates=(("StateOut", "State"),),
+)
+def _lightning_state_update(ctx, op, ins):
+    """A decode step's recurrence, the whole batch, in place. The gauge
+    `kernels.lightning_update.calls` is the count of kernel calls in the
+    decode step lowered last (0: the `jnp` path ran)."""
+    from .. import observability as _obs
+
+    out, new, kernel = lightning_update(
+        *(ins[n][0] for n in ("Q", "K", "V", "State")),
+        num_heads=int(op.attr("num_heads")))
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered step: the last call leaves the count
+        ctx.lightning_update_calls = kernel + getattr(
+            ctx, "lightning_update_calls", 0)
+        _obs.set_gauge("kernels.lightning_update.calls",
+                       ctx.lightning_update_calls)
+    return {"Out": [out], "StateOut": [new]}

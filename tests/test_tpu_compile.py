@@ -199,6 +199,19 @@ def _gdn_update(batch, value_heads=32, value_dim=128, key_dim=128,
     return (ssm_update.delta_update, specs),
 
 
+def _lightning_update(batch, heads=32, dim=128):
+    """The decode step's in-place Lightning update at MiniCPM-SALA's
+    sizes: the stored state [B, H, dk, dv] float32, aliased; the same
+    kernel as `_ssm_update` with one group a head, under its own call
+    name."""
+    from paddle_tpu.ops.kv_cache import ssm_state_shape
+
+    shape = ssm_state_shape(batch, heads, dim, dim, heads)
+    row, col = shape[:2] + shape[3:], (batch, dim, heads)
+    specs = tuple((s, F32) for s in (shape, row, row, col, col))
+    return (ssm_update.lightning_update, specs),
+
+
 def _gdn_scan(rows, length, dtype, carried=False, key_heads=16,
               value_heads=32, dim=128, chunk=64):
     """A prefill dispatch's gated delta rule at Qwen3-Next's sizes: q | k
@@ -383,6 +396,10 @@ def _cases():
     add("corner-gdn_chunk_scan-r2-l100-2over2x256-c16-bf16",
         _gdn_scan(2, 100, BF16, key_heads=2, value_heads=2, dim=256,
                   chunk=16), ("fwd",))
+    # generate phase of minicpm_sala_pp4: the Lightning state's update,
+    # 64 sequences of 2 MB (32 heads x 128 x 128, one group a head)
+    add("lightning_state_update-b64-h32x128x128-f32", _lightning_update(64),
+        ("fwd",))
     add("decode_attention-qwen3_next-16over2x256-bf16",
         _decode_attention(BF16, 16, 2, 256), ("fwd",))
     add("prefill_attention-qwen3_next-16over2x256-bf16",
@@ -471,6 +488,8 @@ def _named_cases():
         "ssm_state_update-b64-h128x64-n128-f32-fwd": ["ssm_state_update"],
         "gdn_state_update-b64-h32x128x128-f32-fwd": ["gdn_state_update"],
         "gdn_chunk_scan-r8-l896-16over32x128-bf16-fwd": ["gdn_chunk_scan"],
+        "lightning_state_update-b64-h32x128x128-f32-fwd":
+            ["lightning_state_update"],
         "decode_attention-qwen3_next-16over2x256-bf16-fwd":
             ["decode_attention"],
         "prefill_attention-qwen3_next-16over2x256-bf16-fwd":
