@@ -202,6 +202,82 @@ def buffer_tiles(assignments, n_local):
     return tm, -(-assignments // tm) + n_local
 
 
+# assignments a block of the sort's running count: one pass of the
+# matrix unit a block (a decode step's fewer assignments are one block)
+SORT_BLOCK = 256
+
+
+def sort_blocks(assignments):
+    """(assignments a block, blocks) of the sort's running count."""
+    tb = min(SORT_BLOCK, assignments)
+    return tb, -(-assignments // tb)
+
+
+def _strictly_lower(n):
+    """[n, n] 0/1 in bfloat16: row i holds ones at the columns j < i."""
+    i = jnp.arange(n)
+    return (i[:, None] > i[None, :]).astype(jnp.bfloat16)
+
+
+def sort_layout(expert, n_local, tm, n_tiles):
+    """The sorted buffer's layout. `expert` [A] int32 is each
+    assignment's local expert, `n_local` where it is not local. Each
+    expert's group holds its assignments in their order, padded to whole
+    tiles of `tm` rows; the groups follow in expert order.
+
+    Returns (slot [A]: each assignment's row of the buffer, past the
+    buffer where it is not local; assign_of_slot [n_tiles * tm]: the
+    assignment a row holds, -1 where it pads its tile; counts [n_local]
+    int32; tile_expert [n_tiles] int32: the expert whose run of tiles
+    covers a tile, tiles past the last active one repeating its expert
+    (no new weight fetch); num_active: the tiles the groups fill).
+
+    No pass walks the assignments in order. An assignment's rank is how
+    many of its expert's assignments come before it: within its block
+    of `sort_blocks` by ONE product of the blocks' one-hot with a
+    strictly lower triangle, before its block by a second such product
+    over the blocks' totals (0/1 and counts up to a block in bfloat16,
+    sums in float32: exact), read out by the same one-hot. A tile's
+    expert is one comparison with the groups' ends. One scatter places
+    the assignments; their tokens and weights follow from it."""
+    n_assign = expert.shape[0]
+    tb, n_blocks = sort_blocks(n_assign)
+    padded = jnp.pad(expert, (0, n_blocks * tb - n_assign),
+                     constant_values=n_local).reshape(n_blocks, tb)
+    # [blocks, experts, assignments of the block]: a block's assignments
+    # along the lanes
+    onehot = padded[:, None, :] == jnp.arange(n_local)[:, None]
+    ones = onehot.astype(jnp.bfloat16)
+    within = jnp.einsum("bej,ij->bei", ones, _strictly_lower(tb),
+                        preferred_element_type=jnp.float32)
+    totals = within[..., -1] + ones[..., -1]                 # [nb, E]
+    before = jnp.dot(_strictly_lower(n_blocks), totals.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)     # [nb, E]
+    counts = (before[-1] + totals[-1]).astype(jnp.int32)
+    group_tiles = -(-counts // tm)
+    ends = jnp.cumsum(group_tiles)
+    num_active = ends[-1]
+    first = before.astype(jnp.int32) + (ends - group_tiles) * tm
+    row = within.astype(jnp.int32) + first[..., None]
+    slot = jnp.where(padded < n_local,
+                     jnp.sum(jnp.where(onehot, row, 0), axis=1),
+                     n_tiles * tm).reshape(-1)[:n_assign]
+    assign_of_slot = jnp.full((n_tiles * tm,), -1, jnp.int32).at[slot].set(
+        jnp.arange(n_assign, dtype=jnp.int32), mode="drop")
+    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(num_active - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.sum(ends[None, :] <= tile[:, None], axis=1), n_local - 1,
+    ).astype(jnp.int32)
+    return slot, assign_of_slot, counts, tile_expert, num_active
+
+
+def of_slot(assign_of_slot, values, fill):
+    """What each row of the sorted buffer holds of its assignment:
+    `values` [A] at the row's assignment, `fill` where the row pads."""
+    return jnp.where(assign_of_slot < 0, fill,
+                     values[jnp.maximum(assign_of_slot, 0)])
+
+
 def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
                       top_k, route_scale, expert_offset, route_norm=True,
                       activation="swiglu", router_x=None, n_group=1,
@@ -229,6 +305,7 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     keeps the `jnp` gathers, whose cost there is a few microseconds.
 
     Returns (y [B, T, K], selected [B, T, k], counts [E_local] int32)."""
+    from .. import observability as _obs
     from ..kernels import moe_gmm
 
     b, t, h = x.shape
@@ -249,44 +326,15 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     kernels = interpret or jax.default_backend() == "tpu"
     row_kernels = interpret or (kernels and tm == MXU_TILE)
 
+    _obs.set_gauge("moe.sort.blocks", sort_blocks(n_assign)[1])
     with jax.named_scope("moe_sort"):
         local = sel - expert_offset
         is_local = (local >= 0) & (local < n_local)
         expert = jnp.where(is_local, local, n_local).reshape(-1)  # [A]
-        # each assignment's rank inside its expert's group, and the
-        # groups' sizes, from one running count per expert
-        onehot = (expert[:, None] == jnp.arange(n_local)[None, :])
-        running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)  # [A, E_l]
-        counts = running[-1]
-        clamped = jnp.minimum(expert, n_local - 1)
-        rank = jnp.take_along_axis(
-            running, clamped[:, None], axis=1)[:, 0] - 1
-        group_tiles = -(-counts // tm)
-        tiles_before = jnp.cumsum(group_tiles) - group_tiles
-        num_active = jnp.sum(group_tiles)
-        slot = jnp.where(
-            expert < n_local,
-            tiles_before[clamped] * tm + rank,
-            n_tiles * tm,                      # out of range: dropped below
-        )
-
-        def of_slot(values, fill):
-            """What each row of the buffer holds of its assignment."""
-            return jnp.full((n_tiles * tm,), fill, values.dtype).at[
-                slot].set(values, mode="drop")
-
-        # a row's token: none where the row pads its tile
-        token_of_slot = of_slot(
-            jnp.arange(n_assign, dtype=jnp.int32) // top_k, -1)
-        # tile i belongs to the expert whose run of tiles covers it;
-        # tiles past the last active one repeat its expert (no new weight
-        # fetch)
-        tile = jnp.minimum(jnp.arange(n_tiles),
-                           jnp.maximum(num_active - 1, 0))
-        tile_expert = jnp.minimum(
-            jnp.searchsorted(jnp.cumsum(group_tiles), tile, side="right"),
-            n_local - 1,
-        ).astype(jnp.int32)
+        slot, assign_of_slot, counts, tile_expert, num_active = sort_layout(
+            expert, n_local, tm, n_tiles)
+        # a row's token: none (-1 // top_k) where the row pads its tile
+        token_of_slot = assign_of_slot // top_k
         active = num_active.reshape(1).astype(jnp.int32)
 
     def product(lhs, rhs, act=None):
@@ -307,7 +355,8 @@ def local_experts_ffn(x, router_w, expert_bias, w_gate_up, w_down, *,
     with jax.named_scope("moe_combine"):
         if row_kernels:
             y = moe_gmm.combine_rows(
-                y_sorted, token_of_slot, of_slot(weights.reshape(-1), 0.0),
+                y_sorted, token_of_slot,
+                of_slot(assign_of_slot, weights.reshape(-1), 0.0),
                 active, tm, n_tok, interpret=interpret)
         else:
             picked = y_sorted[jnp.minimum(slot, n_tiles * tm - 1)]  # [A, H]
