@@ -12,7 +12,8 @@ scores in VMEM), `decode_attention` (a decode step over the stored
 caches), `moe_gmm` (grouped expert products and the prefill's row
 moves), `ssm_update` (a recurrent state's one-token update in place, the
 delta rule's a switch of it) and `gdn_chunk_scan` (a prefill's gated
-delta rule chunk by chunk: scores, solve and state walk in VMEM).
+delta rule chunk by chunk: scores, solve and state walk in VMEM) and
+`rotary` (a prefill's rotary positions over q and k where they lie).
 """
 
 from .flash_attention import fused_attention  # noqa: F401

@@ -119,16 +119,16 @@ def rotary(x, first_pos, head_dim, theta, rotary_dim=None, yarn=None,
 def _rotary_embedding(ctx, op, ins):
     """`Pos` is the position of the LAST row (as `kv_cache_attention`
     has it), a runtime value. `rotary_dim`, `yarn` and `leading` (which
-    end of a head turns) as `rotary` takes them; absent, the whole head
-    turns at the plain frequencies."""
+    end of a head turns) as `rotary` takes them, absent the whole head
+    at the plain frequencies; a prefill's rows as `rotary_prefill` says."""
     x = ins["X"][0]
     last = _pos_scalar(ins["Pos"][0])
-    return {"Out": [rotary(x, last - (x.shape[1] - 1),
-                           int(op.attr("head_dim")),
-                           float(op.attr("theta", 10000.0)),
-                           op.attr("rotary_dim", None),
-                           op.attr("yarn", None),
-                           bool(op.attr("leading", False)))]}
+    return {"Out": [rotary_prefill(ctx, x, last - (x.shape[1] - 1),
+                                   int(op.attr("head_dim")),
+                                   float(op.attr("theta", 10000.0)),
+                                   op.attr("rotary_dim", None),
+                                   op.attr("yarn", None),
+                                   bool(op.attr("leading", False)))]}
 
 
 @register_op("swiglu", inputs=["X"], outputs=["Out"], differentiable=False)
@@ -525,3 +525,63 @@ def _block_sparse_attention(ctx, op, ins):
     out = jax.lax.cond(pos + 1 > int(op.attr("dense_len")), sparse, dense,
                        None)
     return {"Out": [out[:, None]]}
+
+
+# ---------------------------------------------------------------------------
+# rotary positions over a prefill's rows where they lie (kernels/rotary.py)
+# ---------------------------------------------------------------------------
+
+def rotary_prefill(ctx, x, first_pos, head_dim, theta, rotary_dim=None,
+                   yarn=None, leading=False, interpret=False):
+    """`rotary`; on the TPU (and with `interpret`) the Pallas kernel
+    (kernels/rotary.py) for the calls it takes (`supports`: a prefill's
+    rows in blocks of 16, a width of whole `unit`s of lanes, float32 or
+    bfloat16), with the tables `rotary_tables` reads off `rotary`. The
+    gauge `kernels.rotary.calls` is the count of kernel calls in the
+    program lowered last (0: a decode step, or the `jnp` path ran)."""
+    from .. import observability as _obs
+    from ..kernels import rotary as _kernel
+
+    _b, t, w = x.shape
+    kernel = (interpret or jax.default_backend() == "tpu") \
+        and _kernel.supports(t, w, head_dim, x.dtype)
+    if ctx is not None and not ctx.abstract:
+        # one EmitContext a lowered program: the last call leaves the count
+        ctx.rotary_calls = int(kernel) + getattr(ctx, "rotary_calls", 0)
+        _obs.set_gauge("kernels.rotary.calls", ctx.rotary_calls)
+    if not kernel:
+        return rotary(x, first_pos, head_dim, theta, rotary_dim, yarn,
+                      leading)
+    tables = rotary_tables(first_pos, t, head_dim, _kernel.unit(head_dim),
+                           theta, rotary_dim, yarn, leading)
+    half = (head_dim if rotary_dim is None else int(rotary_dim)) // 2
+    return _kernel.rotate(x, *tables, half=half, interpret=interpret)
+
+
+def rotary_tables(first_pos, seq_len, head_dim, unit, theta,
+                  rotary_dim=None, yarn=None, leading=False):
+    """The kernel's float32 tables [seq_len, unit] (`unit` lanes of whole
+    heads), row i at position `first_pos + i`: cos, 1 on the lanes that
+    pass; sin_a, +sin on a rotary group's second half (it multiplies x
+    rolled by +half, the first half's lanes); sin_b, -sin on its first
+    half (x rolled by -half); both 0 elsewhere. Read off `rotary`
+    itself, which is linear in x: a head of ones before each group's
+    middle turns into [cos | sin] (1 where lanes pass), a head of ones
+    after it into [-sin | cos], each value the one `rotary` multiplies
+    by, exactly."""
+    import numpy as np
+
+    rot = head_dim if rotary_dim is None else int(rotary_dim)
+    lane = np.arange(unit) % head_dim - (0 if leading else head_dim - rot)
+    second = (lane >= rot // 2) & (lane < rot)
+    first = (lane >= 0) & (lane < rot // 2)
+
+    def turned(ones):
+        x = jnp.broadcast_to(jnp.asarray(ones, jnp.float32),
+                             (1, seq_len, unit))
+        return rotary(x, first_pos, head_dim, theta, rotary_dim, yarn,
+                      leading)[0]
+
+    a, b = turned(~second), turned(second)
+    return (jnp.where(second, b, a), jnp.where(second, a, 0.0),
+            jnp.where(first, b, 0.0))

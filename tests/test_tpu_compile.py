@@ -37,6 +37,7 @@ from paddle_tpu.kernels import layer_norm as ln  # noqa: E402
 from paddle_tpu.kernels import moe_gmm  # noqa: E402
 from paddle_tpu.kernels import prefill_attention  # noqa: E402
 from paddle_tpu.kernels import ring_block as rb  # noqa: E402
+from paddle_tpu.kernels import rotary  # noqa: E402
 from paddle_tpu.kernels import ssm_update  # noqa: E402
 
 SZ = chip_smoke.REAL
@@ -280,6 +281,16 @@ def _prefill_attention(dtype, heads, kv_heads, key_dim, value_dim, rows,
     return (attend, tuple(specs)),
 
 
+def _rotary(rows, head_dim, width, rotary_dim=None, seq=896):
+    """A prefill dispatch's q or k as `rotary_embedding` hands it over,
+    bfloat16, beside the three float32 tables of a unit."""
+    assert rotary.supports(seq, width, head_dim, BF16)
+    half = (rotary_dim or head_dim) // 2
+    specs = (((rows, seq, width), BF16),) \
+        + (((seq, rotary.unit(head_dim)), F32),) * 3
+    return (functools.partial(rotary.rotate, half=half), specs),
+
+
 def _cases():
     cases = {}
 
@@ -430,6 +441,20 @@ def _cases():
         _prefill_attention(BF16, 48, 8, 128, 128, 2, seq=4096), ("fwd",))
     add("corner-prefill_attention-gpt2-s16384-f32",
         _prefill_attention(F32, 12, 12, 64, 64, 1, seq=16384), ("fwd",))
+    # a prefill dispatch's rotary positions in the four cells that turn
+    # them: dots_vlm's q (128 heads of 192, the last 64 lanes: a unit of
+    # two heads), Trinity's q and k (48 and 8 heads of 128), Qwen3-Next's
+    # (16 and 2 heads of 256, the leading 64), MiniCPM-SALA's Lightning q
+    # and k (32 heads of 128)
+    add("rotary-dots_vlm-q-128x192-r64-bf16", _rotary(8, 192, 24576, 64),
+        ("fwd",))
+    add("rotary-trinity-q-48x128-bf16", _rotary(16, 128, 6144), ("fwd",))
+    add("rotary-trinity-k-8x128-bf16", _rotary(16, 128, 1024), ("fwd",))
+    add("rotary-qwen3_next-q-16x256-r64-bf16", _rotary(8, 256, 4096, 64),
+        ("fwd",))
+    add("rotary-qwen3_next-k-2x256-r64-bf16", _rotary(8, 256, 512, 64),
+        ("fwd",))
+    add("rotary-minicpm_sala-32x128-bf16", _rotary(16, 128, 4096), ("fwd",))
     # supports() corners of the row-wise kernels
     for n in (768, 2048, 4096, 8192):
         for dtype in (BF16, F32):
@@ -499,6 +524,7 @@ def _named_cases():
         "decode_attention-gpt2-12x64-f32-fwd": ["decode_attention"],
         "prefill_attention-trinity-48over8x128-bf16-fwd":
             ["prefill_attention"],
+        "rotary-minicpm_sala-32x128-bf16-fwd": ["rotary"],
     }
 
 
@@ -545,3 +571,85 @@ def test_fused_residual_row_block(n, dtype, expect):
     dropout mask is drawn per row block); wider rows shrink it."""
     assert fr._row_block(ROWS, n, dtype, dtype) == expect
     assert ROWS % fr._row_block(ROWS, n, dtype, dtype) == 0
+
+
+# -- the prefill's rotary kernel inside the generate cells' programs ----------
+# cell: (tiny widths whose heads the rotary kernel takes and whose expert
+# products are whole lane tiles, over the cell's own `tiny`, with the
+# cell's layers; `kernels.rotary.calls` of a prefill: q and k each a
+# call, dots_vlm's shared 64-lane key the `jnp` form)
+EXPERTS = {"hidden_size": 128, "moe_intermediate_size": 128}
+ROTARY_CELLS = {
+    "dots_vlm1_ep16": ({**EXPERTS, "qk_nope_head_dim": 128,
+                        "qk_rope_head_dim": 64, "v_head_dim": 128}, 5),
+    "trinity_large_ep8": ({**EXPERTS, "head_dim": 128}, 8),
+    "qwen3_next_ep8": ({**EXPERTS, "shared_expert_intermediate_size": 128,
+                        "head_dim": 256, "num_hidden_layers": 12}, 6),
+    "minicpm_sala_pp4": ({"lightning_head_dim": 128,
+                          "num_hidden_layers": 8}, 12),
+    "nemotron3_super_ep4": ({}, 0),
+    "gpt2_small": ({}, 0),
+}
+
+
+def _lowered_for_chip(gen, program, feed, fetch, chip):
+    """The StableHLO of one step of `program` as `Executor.lower` builds
+    it, lowered for the described chip: its arguments are shapes placed
+    there."""
+    from paddle_tpu.core.random import prng_impl
+
+    exe = gen.executor
+    program, scope, block, feeds, _sig, fetch_names, key = exe._prepared(
+        program, feed, fetch, gen.scope)
+    compiled = exe._compile(program, block, set(feeds), fetch_names, scope,
+                            key)
+    args = (feeds,
+            {n: exe._from_scope(scope, n, block) for n in compiled.state_mut},
+            {n: exe._from_scope(scope, n, block) for n in compiled.state_ro},
+            jax.random.key(0, impl=prng_impl()))
+    return compiled.fn.lower(*jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        args)).as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(ROTARY_CELLS))
+def test_the_rotary_kernel_runs_in_the_prefills_only(chip, cell,
+                                                     monkeypatch):
+    """Each generate cell's prefill and decode programs at tiny widths
+    with the cell's layers, lowered for the described v5e (the backend
+    gates steered to the TPU's branch; nothing compiled): a prefill holds
+    the rotary kernel as many times as the gauge says, q and k each a
+    call; a decode step (T = 1) holds none and leaves the gauge at 0;
+    Nemotron (no positions) and GPT-2 (learned positions) hold none."""
+    import importlib
+
+    import numpy as np
+
+    from benchmark.harness import manifest as mf
+    from paddle_tpu import observability as obs
+
+    widths, calls = ROTARY_CELLS[cell]
+    manifest = mf.load()
+    entry, spec = mf.cell(manifest, f"{cell}_generate_closed")
+    cfg_json = dict(mf.config(manifest, entry["config"]))
+    cfg_json["tiny"] = {**cfg_json["tiny"], **widths}
+    traffic = {**spec["traffic"], **spec["rehearse"], "prompt_len": 32}
+    builder = importlib.import_module(
+        f"benchmark.builders.{cfg_json['builder']}")
+    gen = builder.build_generate(cfg_json, traffic, True, seed=3).generator
+    rows, batch = gen.prefill_rows, gen.batch
+    prefill = {"context_ids": np.zeros((rows, 32), np.int64)}
+    if rows < batch:
+        prefill["row_ids"] = np.zeros((1,), np.int64)
+    decode = {"token_ids": np.zeros((batch, 1), np.int64),
+              "pos_ids": np.full((1, 1), 32, np.int64)}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    obs.reset()
+    text = _lowered_for_chip(gen, gen.prefill_prog, prefill,
+                             gen._prefill_fetch, chip)
+    assert obs.get_gauges().get("kernels.rotary.calls", 0) == calls
+    assert ('kernel_name = "rotary"' in text) == bool(calls)
+    text = _lowered_for_chip(gen, gen.decode_prog, decode,
+                             gen._decode_fetch, chip)
+    assert obs.get_gauges().get("kernels.rotary.calls", 0) == 0
+    assert 'kernel_name = "rotary"' not in text
