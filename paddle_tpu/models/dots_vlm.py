@@ -29,10 +29,10 @@ and the shared key part, at YaRN's blended frequencies; the softmax
 scale carries YaRN's temperature squared.
 
 Like `models/afmoe.py` the model is two graph bodies over shared
-parameter names, bundled with the specs of the state they share as
-`serving.GPTGenerator` asks of a decoder; one chip's share of an
-expert-parallel deployment is a configuration (`num_local_experts`,
-`expert_offset`, `vocab_size`), not a code path.
+parameter names on `models/decoder.py`'s base, each layer's cache
+declared once with its kind ("latent") and the lanes that carry data;
+one chip's share of an expert-parallel deployment is a configuration
+(`num_local_experts`, `expert_offset`, `vocab_size`), not a code path.
 
 Parameters, activations and the latent cache are `cfg.dtype` (bfloat16
 in serving); norms, softmax, rotary angles and the router keep float32
@@ -44,10 +44,9 @@ from __future__ import annotations
 from .. import layers
 from ..framework.program import name_scope
 from ..layers.tensor import _simple
-from ..param_attr import ParamAttr
-from .afmoe import (
-    DENSE, EXPERTS, MoeCounters, _expert_ffn, _head, _normal, _param, _proj,
-    _rms, _side_by_side, _state_var, _swiglu_ffn, _write_cache,
+from .decoder import (
+    DENSE, EXPERTS, Decoder, embed, expert_ffn, normal, param, proj, rms,
+    rotary, slice_last, state, swiglu_ffn, write_cache,
 )
 
 LATENT = "latent_attention"
@@ -159,18 +158,12 @@ class DotsVlmConfig:
         ), **kw})
 
 
-def _slice_last(x, start, end):
-    return layers.slice(x, [2], [start], [end])
-
-
 def _rotary(x, pos, cfg, head_dim):
     """YaRN rotary positions on the last `qk_rope_head_dim` lanes of
     each `head_dim`-wide head."""
-    attrs = {"head_dim": head_dim, "theta": cfg.rope_theta,
-             "rotary_dim": cfg.qk_rope_head_dim}
-    if cfg.rope_scaling:
-        attrs["yarn"] = dict(cfg.rope_scaling)
-    return _simple("rotary_embedding", {"X": [x], "Pos": [pos]}, attrs)
+    yarn = {"yarn": dict(cfg.rope_scaling)} if cfg.rope_scaling else {}
+    return rotary(x, pos, head_dim, cfg.rope_theta,
+                  rotary_dim=cfg.qk_rope_head_dim, **yarn)
 
 
 def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
@@ -183,20 +176,21 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     nh, r, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     with name_scope("proj"):
-        c_q = _rms(_proj(a, cfg.q_lora_rank, f"{prefix}_attn_q_a_w", cfg),
-                   f"{prefix}_attn_q_a_n", cfg)
-        q = _proj(c_q, nh * (dn + dr), f"{prefix}_attn_q_b_w", cfg)
-        kv_a = _proj(a, r + dr, f"{prefix}_attn_kv_a_w", cfg)
-        c_kv = _rms(_slice_last(kv_a, 0, r), f"{prefix}_attn_kv_a_n", cfg)
+        c_q = rms(proj(a, cfg.q_lora_rank, f"{prefix}_attn_q_a_w", cfg),
+                  f"{prefix}_attn_q_a_n", cfg)
+        q = proj(c_q, nh * (dn + dr), f"{prefix}_attn_q_b_w", cfg)
+        kv_a = proj(a, r + dr, f"{prefix}_attn_kv_a_w", cfg)
+        c_kv = rms(slice_last(kv_a, 0, r), f"{prefix}_attn_kv_a_n", cfg)
     last = pos_ids
     if last is None:
         last = layers.fill_constant([1], "int32", a.shape[1] - 1)
     q = _rotary(q, last, cfg, dn + dr)
-    k_pe = _rotary(_slice_last(kv_a, r, r + dr), last, cfg, dr)
-    w_kvb = _param(f"{prefix}_attn_kv_b_w", [nh, r, dn + dv], cfg,
-                   _normal(cfg))
+    k_pe = _rotary(slice_last(kv_a, r, r + dr), last, cfg, dr)
+    w_kvb = param(f"{prefix}_attn_kv_b_w", [nh, r, dn + dv], cfg,
+                  normal(cfg))
     shape = latent_cache_shape(batch, max_len, cfg.cache_width)
-    cache = _state_var(f"{prefix}_cache_kv", shape, cfg.dtype)
+    cache = state(f"{prefix}_cache_kv", shape, cfg.dtype, "latent",
+                  cfg.cache_width)
     split = {"nope_dim": dn}
     # the latent's own products (`mla_expand`, `mla_absorb_*`) sit in
     # `attn` under their op types; `core` is the cache write and the call
@@ -204,7 +198,7 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
         with name_scope("core"):
             row = layers.concat([c_kv, k_pe], axis=-1)
             first = layers.fill_constant([1], "int32", 0)
-            _write_cache(cache, row, first, row_ids, ring=True)
+            write_cache(cache, row, first, row_ids, ring=True)
         k, v = _simple(
             "mla_expand",
             {"Latent": [c_kv], "WKVB": [w_kvb]}, split,
@@ -220,7 +214,7 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     else:
         with name_scope("core"):
             row = layers.concat([c_kv, k_pe], axis=-1)
-            _write_cache(cache, row, pos_ids, None, ring=True)
+            write_cache(cache, row, pos_ids, None, ring=True)
         q_abs = _simple("mla_absorb_query", {"Q": [q], "WKVB": [w_kvb]},
                         {**split, "row_width": shape[2]})
         with name_scope("core"):
@@ -233,93 +227,39 @@ def _latent_attention(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
         out = _simple("mla_absorb_output", {"X": [o_lat], "WKVB": [w_kvb]},
                       split)
     with name_scope("proj"):
-        return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
+        return proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
 
 
-def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
-    """Both bodies: a prefill of `ids` [rows, S] (rows `row_ids` .. of
-    the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
-    with. Returns (hidden [.., H], [the expert layers' Selected ids])."""
-    seq = ids.shape[1]
-    with name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-            param_attr=ParamAttr(name="dots_embed",
-                                 initializer=_normal(cfg)),
-        )
-        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
-    selected = []
-    for i, (_attn, ffn_kind) in enumerate(cfg.layer_kinds):
-        prefix = f"dots_l{i}"
-        with name_scope("attn"):
-            h = x + _latent_attention(
-                _rms(x, f"{prefix}_n1", cfg), cfg, prefix, batch, max_len,
-                row_ids, pos_ids)
-        with name_scope("mlp" if ffn_kind == DENSE else "moe"):
-            m = _rms(h, f"{prefix}_n2", cfg)
-            if ffn_kind == DENSE:
-                m = _swiglu_ffn(m, cfg.intermediate_size, f"{prefix}_mlp",
-                                cfg)
-            else:
-                m, sel = _expert_ffn(m, prefix, cfg, COUNTERS_VAR,
-                                     n_group=cfg.n_group,
-                                     topk_group=cfg.topk_group)
-                selected.append(sel)
-            x = h + m
-    return x, selected
+class DotsVlmDecoder(Decoder):
+    """dots.vlm1's bodies on `models/decoder.py`'s base. A layer's one
+    cache carries `cache_width` lanes of data in a row padded to whole
+    lane tiles."""
 
-
-def _extras(selected):
-    ids = _side_by_side(selected)
-    return [] if ids is None else [ids]
-
-
-class DotsVlmDecoder(MoeCounters):
-    """What `serving.GPTGenerator` asks of a decoder: the two bodies, the
-    state they share and how to read its counters."""
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.prefill_rows = cfg.prefill_rows
-
-    def prefill(self, context_ids, batch, max_len, row_ids=None):
-        """(last-position logits [rows, 1, V] float32, [the expert
-        layers' `Selected` ids side by side, [rows, S, layers * k]])."""
-        x, selected = _body(context_ids, self.cfg, batch, max_len, row_ids)
-        s = context_ids.shape[1]
-        with name_scope("head"):
-            last = layers.slice(x, [1], [s - 1], [s])
-        return _head(last, self.cfg, "dots"), _extras(selected)
-
-    def decode_step(self, token_ids, pos_ids, max_len):
-        x, selected = _body(token_ids, self.cfg, token_ids.shape[0],
-                            max_len, pos_ids=pos_ids)
-        return _head(x, self.cfg, "dots"), _extras(selected)
-
-    def state_specs(self, batch, max_len):
-        """[(name, shape, dtype)] of everything `reset()` zeroes: each
-        layer's one latent cache, and the routing counters."""
-        from ..ops.kv_cache import latent_cache_shape
-        from ..parallel.moe import MOE_COUNTERS
-
-        cfg = self.cfg
-        shape = latent_cache_shape(batch, max_len, cfg.cache_width)
-        specs = [(f"dots_l{i}_cache_kv", shape, cfg.dtype)
-                 for i in range(cfg.num_layers)]
-        if any(kind == EXPERTS for _a, kind in cfg.layer_kinds):
-            specs.append((COUNTERS_VAR, (len(MOE_COUNTERS),), "int32"))
-        return specs
-
-    def cache_kind(self, name):
-        """"latent" for a layer's cache, None for other state."""
-        return "latent" if name.endswith("_cache_kv") else None
-
-    def cache_lanes(self, name):
-        """Lanes of a cache row a query needs to read (the rest of the
-        stored row is padding to whole tiles)."""
-        return self.cfg.cache_width
-
+    prefix = "dots"
     counters_var = COUNTERS_VAR
+
+    def body(self, ids, batch, max_len, row_ids=None, pos_ids=None):
+        cfg = self.cfg
+        x = embed(ids, cfg, "dots_embed")
+        selected = []
+        for i, (_attn, ffn_kind) in enumerate(cfg.layer_kinds):
+            prefix = f"dots_l{i}"
+            with name_scope("attn"):
+                h = x + _latent_attention(
+                    rms(x, f"{prefix}_n1", cfg), cfg, prefix, batch,
+                    max_len, row_ids, pos_ids)
+            with name_scope("mlp" if ffn_kind == DENSE else "moe"):
+                m = rms(h, f"{prefix}_n2", cfg)
+                if ffn_kind == DENSE:
+                    m = swiglu_ffn(m, cfg.intermediate_size,
+                                   f"{prefix}_mlp", cfg)
+                else:
+                    m, sel = expert_ffn(m, prefix, cfg, COUNTERS_VAR,
+                                        n_group=cfg.n_group,
+                                        topk_group=cfg.topk_group)
+                    selected.append(sel)
+                x = h + m
+        return x, selected
 
     def describe(self):
         """The sizes a cost model needs (benchmark/harness/mla_cost.py;

@@ -223,19 +223,6 @@ def gpt_cache_names(cfg):
     return out
 
 
-def _cache_var(name, batch, max_len, num_heads, head_dim):
-    from ..framework.program import default_main_program
-    from ..ops.kv_cache import cache_shape
-
-    blk = default_main_program().global_block
-    if blk.has_var(name):
-        return blk.var(name)
-    return blk.create_var(
-        name=name, shape=cache_shape(batch, max_len, num_heads, head_dim),
-        dtype="float32", persistable=True,
-    )
-
-
 def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
     """Pre-LN decoder layer routed through the layer's KV cache: write this
     call's K/V rows at `write_pos`, then attend Q over the cache up to
@@ -246,6 +233,7 @@ def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
     (the freeze-parity contract)."""
     from ..framework.program import default_main_program
     from ..layers.tensor import _simple
+    from .decoder import kv_cache
 
     b, t, h = x.shape
     nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
@@ -257,8 +245,7 @@ def _cached_decoder_layer(x, cfg, prefix, write_pos, attend_pos, max_len):
         k = layers.slice(qkv, [2], [h], [2 * h])
         v = layers.slice(qkv, [2], [2 * h], [3 * h])
         with name_scope("core"):
-            ck = _cache_var(f"{prefix}_cache_k", b, max_len, nh, dh)
-            cv = _cache_var(f"{prefix}_cache_v", b, max_len, nh, dh)
+            ck, cv = kv_cache(prefix, b, max_len, nh, dh, "float32")
             blk = default_main_program().global_block
             for cache, rows in ((ck, k), (cv, v)):
                 blk.append_op(
@@ -359,8 +346,8 @@ def gpt_decode_step(token_ids, pos_ids, cfg, max_len):
 
 
 class GPTDecoder:
-    """What `serving.GPTGenerator` asks of a decoder: the prefill body,
-    the decode body and the specs of the state the two programs share."""
+    """What `serving.GPTGenerator` asks of a decoder: the prefill body
+    and the decode body over the caches the two programs share."""
 
     prefill_rows = None     # one prefill dispatch takes the whole batch
     counters_var = None
@@ -374,17 +361,6 @@ class GPTDecoder:
 
     def decode_step(self, token_ids, pos_ids, max_len):
         return gpt_decode_step(token_ids, pos_ids, self.cfg, max_len), []
-
-    def state_specs(self, batch, max_len):
-        from ..ops.kv_cache import cache_shape
-
-        nh = self.cfg.num_heads
-        shape = cache_shape(batch, max_len, nh, self.cfg.hidden_size // nh)
-        return [(name, shape, "float32")
-                for name in gpt_cache_names(self.cfg)]
-
-    def cache_kind(self, name):
-        return "full"
 
     def logits(self, input_ids):
         """The full-context graph (`generate_full_recompute`)."""

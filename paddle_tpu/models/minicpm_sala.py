@@ -32,9 +32,9 @@ query group's attention over the blocks `ops/llm.py`'s
 are lowered only where the program's `max_len` exceeds `dense_len`.
 
 Like `models/qwen3_next.py` the model is two graph bodies over shared
-parameter names, a prefill and a one-token decode step, bundled with the
-specs of the state they share as `serving.GPTGenerator` asks of a
-decoder: a lightning layer carries its float32 state, which does not
+parameter names on `models/decoder.py`'s base, a prefill and a one-token
+decode step, each piece of state declared once with its kind: a
+lightning layer carries its float32 state, which does not
 grow with `max_len`; a sparse layer its K and V caches, and its index
 where it selects. Parameters, activations and caches are `cfg.dtype`
 (bfloat16 in serving); the state, the decays, every norm's statistics
@@ -49,8 +49,10 @@ import math
 from .. import layers
 from ..framework.program import name_scope
 from ..layers.tensor import _simple
-from ..param_attr import ParamAttr
-from .afmoe import _normal, _param, _proj, _rms, _state_var, _write_cache
+from .decoder import (
+    Decoder, attend, cache_rows, embed, kv_cache, normal, param, proj, rms,
+    rotary, state, swiglu_ffn,
+)
 
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 # the name scope (fluid.name_scope) of a mixer of each kind
@@ -152,11 +154,6 @@ class MiniCPMSalaConfig:
         ), **kw})
 
 
-def _positions(x, at, dim, cfg):
-    return _simple("rotary_embedding", {"X": [x], "Pos": [at]},
-                   {"head_dim": dim, "theta": cfg.rope_theta})
-
-
 def _lightning_mixer(a, cfg, prefix, batch, row_ids, at, decode):
     """q, k, v and the gate; QK-norm and rotary positions on q and k;
     the recurrence with its state; the output norm gated by
@@ -166,20 +163,20 @@ def _lightning_mixer(a, cfg, prefix, batch, row_ids, at, decode):
 
     h, d = cfg.lightning_heads, cfg.lightning_head_dim
     with name_scope("proj"):
-        q = _rms(_proj(a, h * d, f"{prefix}_q_w", cfg), f"{prefix}_qn", cfg,
-                 d)
-        k = _rms(_proj(a, h * d, f"{prefix}_k_w", cfg), f"{prefix}_kn", cfg,
-                 d)
-        v = _proj(a, h * d, f"{prefix}_v_w", cfg)
-        gate = _proj(a, h * d, f"{prefix}_gate_w", cfg)
-        q, k = _positions(q, at, d, cfg), _positions(k, at, d, cfg)
-    state = _state_var(f"{prefix}_lightning_state",
-                       ssm_state_shape(batch, h, d, d, h), "float32")
+        q = rms(proj(a, h * d, f"{prefix}_q_w", cfg), f"{prefix}_qn", cfg,
+                d)
+        k = rms(proj(a, h * d, f"{prefix}_k_w", cfg), f"{prefix}_kn", cfg,
+                d)
+        v = proj(a, h * d, f"{prefix}_v_w", cfg)
+        gate = proj(a, h * d, f"{prefix}_gate_w", cfg)
+        q, k = (rotary(x, at, d, cfg.rope_theta) for x in (q, k))
+    lightning = state(f"{prefix}_lightning_state",
+                      ssm_state_shape(batch, h, d, d, h), "float32", "linear")
     blk = default_main_program().global_block
     o = blk.create_var(name=f"{prefix}_o", shape=v.shape, dtype=v.dtype)
     ins = {"Q": [q.name], "K": [k.name], "V": [v.name],
-           "State": [state.name]}
-    outs = {"Out": [o.name], "StateOut": [state.name]}
+           "State": [lightning.name]}
+    outs = {"Out": [o.name], "StateOut": [lightning.name]}
     with name_scope("scan"):
         if decode:
             blk.append_op("lightning_state_update", ins, outs,
@@ -189,15 +186,15 @@ def _lightning_mixer(a, cfg, prefix, batch, row_ids, at, decode):
             blk.append_op("lightning_chunk_scan", {**ins, **row}, outs,
                           {"num_heads": h, "head_dim": d,
                            "chunk": cfg.chunk_size})
-    gain = _param(f"{prefix}_out_norm", [h * d], cfg,
-                  _normal(cfg, 1.0, cfg.initializer_range))
+    gain = param(f"{prefix}_out_norm", [h * d], cfg,
+                 normal(cfg, 1.0, cfg.initializer_range))
     with name_scope("norm"):
         g = _simple("gated_rms_norm", {"X": [o], "Gate": [gate],
                                        "Scale": [gain]},
                     {"epsilon": cfg.rms_norm_eps, "gate_after": True,
                      "activation": "sigmoid"})
     with name_scope("proj"):
-        return _proj(g, cfg.hidden_size, f"{prefix}_o_w", cfg)
+        return proj(g, cfg.hidden_size, f"{prefix}_o_w", cfg)
 
 
 def _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
@@ -207,34 +204,32 @@ def _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     the selection where the program may get there); the output gated by
     sigmoid(gate) before W_o."""
     from ..framework.program import default_main_program
-    from ..ops.kv_cache import cache_shape, index_shape
+    from ..ops.kv_cache import index_shape
 
     nh, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     with name_scope("proj"):
-        q = _rms(_proj(a, nh * dh, f"{prefix}_attn_q_w", cfg),
-                 f"{prefix}_attn_qn", cfg, dh, seeded=cfg.attn_qk_gain)
-        k = _rms(_proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
-                 f"{prefix}_attn_kn", cfg, dh, seeded=cfg.attn_qk_gain)
-        v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
-        gate = _proj(a, nh * dh, f"{prefix}_attn_gate_w", cfg)
-    shape = cache_shape(batch, max_len, kvh, dh)
-    ck, cv = (_state_var(f"{prefix}_cache_{w}", shape, cfg.dtype)
-              for w in ("k", "v"))
+        q = rms(proj(a, nh * dh, f"{prefix}_attn_q_w", cfg),
+                f"{prefix}_attn_qn", cfg, dh, seeded=cfg.attn_qk_gain)
+        k = rms(proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
+                f"{prefix}_attn_kn", cfg, dh, seeded=cfg.attn_qk_gain)
+        v = proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
+        gate = proj(a, nh * dh, f"{prefix}_attn_gate_w", cfg)
+    caches = kv_cache(prefix, batch, max_len, kvh, dh, cfg.dtype)
     attrs = {"num_heads": nh, "num_kv_heads": kvh, "window": 0,
              "scale": 1.0 / math.sqrt(dh)}
     prefill = pos_ids is None
     seq = a.shape[1]
     out = None
-    with name_scope("core"):
-        at = pos_ids
-        if prefill:
+    at = pos_ids
+    if prefill:
+        with name_scope("core"):
             at = layers.fill_constant([1], "int32", 0)
-        _write_cache(ck, k, at, row_ids if prefill else None, ring=True)
-        _write_cache(cv, v, at, row_ids if prefill else None, ring=True)
+    cache_rows(caches, k, v, at, row_ids)
     if cfg.selects(max_len):
+        ck = caches[0]
         blk = default_main_program().global_block
-        index = _state_var(f"{prefix}_index", index_shape(
-            batch, max_len, cfg.sparse_stride, kvh, dh), cfg.dtype)
+        index = state(f"{prefix}_index", index_shape(
+            batch, max_len, cfg.sparse_stride, kvh, dh), cfg.dtype, "index")
         row = {} if row_ids is None else {"Row": [row_ids.name]}
         with name_scope("index"):
             blk.append_op(
@@ -247,7 +242,7 @@ def _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
         if not prefill or seq > cfg.dense_len:
             last = pos_ids if not prefill else layers.fill_constant(
                 [1], "int32", seq - 1)
-            counters = _state_var(COUNTERS_VAR, (2,), "int32")
+            counters = state(COUNTERS_VAR, (2,), "int32")
             selected = blk.create_var(
                 name=f"{prefix}_selected", dtype="int32",
                 shape=(a.shape[0], kvh, seq, cfg.topk))
@@ -265,138 +260,61 @@ def _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
                      "stride": cfg.sparse_stride,
                      "window": cfg.window_size,
                      "init_blocks": cfg.init_blocks, "topk": cfg.topk})
-            kv = [k, v] if prefill else [ck, cv]
+            kv = (k, v) if prefill else caches
             with name_scope("core"):
                 out = _simple("block_sparse_attention",
                               {"Q": [q], "K": [kv[0]], "V": [kv[1]],
                                "Selected": [selected], "Pos": [last]},
                               sparse)
     if out is None:
-        with name_scope("core"):
-            if prefill:
-                out = _simple("causal_gqa_attention",
-                              {"Q": [q], "K": [k], "V": [v]}, attrs)
-            else:
-                out = _simple("kv_cache_attention",
-                              {"Q": [q], "CacheK": [ck], "CacheV": [cv],
-                               "Pos": [pos_ids]}, attrs)
+        out = attend(q, k, v, caches, pos_ids, **attrs)
     with name_scope("proj"):
-        return _proj(out * layers.sigmoid(gate), cfg.hidden_size,
-                     f"{prefix}_attn_o_w", cfg)
+        return proj(out * layers.sigmoid(gate), cfg.hidden_size,
+                    f"{prefix}_attn_o_w", cfg)
 
 
-def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
-    """Both bodies: a prefill of `ids` [rows, S] (rows `row_ids` .. of
-    the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
-    with. Returns the last hidden state [.., H]."""
-    seq = ids.shape[1]
-    with name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-            param_attr=ParamAttr(name=f"{FAMILY}_embed",
-                                 initializer=_normal(cfg)),
-        )
-        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
-        x = layers.scale(x, scale=cfg.scale_emb)
-        # the rotary positions' anchor: the LAST row's, as the op takes it
-        at = pos_ids if pos_ids is not None else layers.fill_constant(
-            [1], "int32", seq - 1)
-    c = cfg.residual_scale
-    for i, kind in enumerate(cfg.layer_kinds):
-        prefix = f"{FAMILY}_l{i}"
-        with name_scope(SECTIONS[kind]):
-            a = _rms(x, f"{prefix}_n1", cfg)
-            if kind == LIGHTNING:
-                m = _lightning_mixer(a, cfg, prefix, batch, row_ids, at,
-                                     decode=pos_ids is not None)
-            else:
-                m = _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids,
-                                  pos_ids)
-            h = x + layers.scale(m, scale=c)
-        with name_scope("mlp"):
-            gate_up = _proj(_rms(h, f"{prefix}_n2", cfg),
-                            2 * cfg.intermediate_size,
-                            f"{prefix}_mlp_gate_up_w", cfg)
-            m = _proj(_simple("swiglu", {"X": [gate_up]}, {}),
-                      cfg.hidden_size, f"{prefix}_mlp_down_w", cfg)
-            x = h + layers.scale(m, scale=c)
-    return x
+class MiniCPMSalaDecoder(Decoder):
+    """MiniCPM-SALA's bodies on `models/decoder.py`'s base, with muP's
+    scalings; no layer routes. Its counters are the block-sparse layers':
+    blocks selected and blocks a query could see, summed."""
 
-
-def _head(x, cfg):
-    """N, the muP division, then the untied head over the whole
-    vocabulary; float32 out of the product."""
-    with name_scope("head"):
-        x = layers.scale(_rms(x, f"{FAMILY}_norm_f", cfg),
-                         scale=1.0 / cfg.head_divisor)
-        w = _param(f"{FAMILY}_head_w", [cfg.hidden_size, cfg.vocab_size],
-                   cfg, _normal(cfg))
-        return _simple("mul", {"X": [x], "Y": [w]},
-                       {"x_num_col_dims": 2, "y_num_col_dims": 1,
-                        "out_dtype": "float32"})
-
-
-class MiniCPMSalaDecoder:
-    """What `serving.GPTGenerator` asks of a decoder: the two bodies, the
-    state they share and how to read its counters (the block-sparse
-    layers': blocks selected and blocks a query could see, summed)."""
-
+    prefix = FAMILY
     counters_var = COUNTERS_VAR
     counter_names = ("sparse_attention.blocks_selected",
                      "sparse_attention.blocks_visible")
     counter_gauges = frozenset()
 
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.prefill_rows = cfg.prefill_rows
+    @property
+    def head_scale(self):
+        """The muP division of the last hidden state."""
+        return 1.0 / self.cfg.head_divisor
 
-    def prefill(self, context_ids, batch, max_len, row_ids=None):
-        """(last-position logits [rows, 1, V] float32, no extras)."""
-        x = _body(context_ids, self.cfg, batch, max_len, row_ids)
-        s = context_ids.shape[1]
-        with name_scope("head"):
-            last = layers.slice(x, [1], [s - 1], [s])
-        return _head(last, self.cfg), []
-
-    def decode_step(self, token_ids, pos_ids, max_len):
-        x = _body(token_ids, self.cfg, token_ids.shape[0], max_len,
-                  pos_ids=pos_ids)
-        return _head(x, self.cfg), []
-
-    def state_specs(self, batch, max_len):
-        """[(name, shape, dtype)] of everything `reset()` zeroes, by
-        mixer kind: a lightning layer's state; a sparse layer's K and V
-        cache and, where the program selects, its index; the counters."""
-        from ..ops.kv_cache import cache_shape, index_shape, ssm_state_shape
-
+    def body(self, ids, batch, max_len, row_ids=None, pos_ids=None):
         cfg = self.cfg
-        h, d = cfg.lightning_heads, cfg.lightning_head_dim
-        kvh, dh = cfg.num_kv_heads, cfg.head_dim
-        specs = []
+        x = embed(ids, cfg, f"{FAMILY}_embed", cfg.scale_emb)
+        at = pos_ids
+        if pos_ids is None:
+            # the rotary positions' anchor: the LAST row's, as the op
+            # takes it
+            with name_scope("embed"):
+                at = layers.fill_constant([1], "int32", ids.shape[1] - 1)
+        c = cfg.residual_scale
         for i, kind in enumerate(cfg.layer_kinds):
-            p = f"{FAMILY}_l{i}"
-            if kind == LIGHTNING:
-                specs.append((f"{p}_lightning_state",
-                              ssm_state_shape(batch, h, d, d, h), "float32"))
-                continue
-            shape = cache_shape(batch, max_len, kvh, dh)
-            specs += [(f"{p}_cache_{w}", shape, cfg.dtype)
-                      for w in ("k", "v")]
-            if cfg.selects(max_len):
-                specs.append((f"{p}_index", index_shape(
-                    batch, max_len, cfg.sparse_stride, kvh, dh), cfg.dtype))
-        specs.append((COUNTERS_VAR, (2,), "int32"))
-        return specs
-
-    def cache_kind(self, name):
-        """"linear", "full" or "index" for a piece of per-sequence state
-        by its name, None for other state."""
-        for suffix, kind in (("_lightning_state", "linear"),
-                             ("_cache_k", "full"), ("_cache_v", "full"),
-                             ("_index", "index")):
-            if name.endswith(suffix):
-                return kind
-        return None
+            prefix = f"{FAMILY}_l{i}"
+            with name_scope(SECTIONS[kind]):
+                a = rms(x, f"{prefix}_n1", cfg)
+                if kind == LIGHTNING:
+                    m = _lightning_mixer(a, cfg, prefix, batch, row_ids, at,
+                                         decode=pos_ids is not None)
+                else:
+                    m = _sparse_mixer(a, cfg, prefix, batch, max_len,
+                                      row_ids, pos_ids)
+                h = x + layers.scale(m, scale=c)
+            with name_scope("mlp"):
+                m = swiglu_ffn(rms(h, f"{prefix}_n2", cfg),
+                               cfg.intermediate_size, f"{prefix}_mlp", cfg)
+                x = h + layers.scale(m, scale=c)
+        return x, []
 
     def describe(self):
         """The sizes a cost model needs (benchmark/harness/

@@ -7,12 +7,12 @@ grouped-query attention with no positional term (order comes from the
 Mamba blocks).
 
 Like `models/afmoe.py` the model is two graph bodies over shared
-parameter names, a prefill and a one-token decode step, bundled with the
-specs of the state they share as `serving.GPTGenerator` asks of a
-decoder. That state is of three kinds here: a Mamba block carries its
-recurrent state (float32) and its convolution's tail, neither of which
-grows with `max_len`; an attention block a full KV cache
-(`ops/kv_cache.py` owns all three shapes).
+parameter names on `models/decoder.py`'s base, a prefill and a one-token
+decode step, each piece of state declared once with its kind. That state
+is of three kinds here: a Mamba block carries its recurrent state
+(float32) and its convolution's tail, neither of which grows with
+`max_len`; an attention block a full KV cache (`ops/kv_cache.py` owns
+all three shapes).
 
 One chip's share of an expert-parallel deployment is a configuration,
 not a code path: `num_local_experts` / `expert_offset` say which routed
@@ -33,10 +33,9 @@ from .. import layers
 from ..framework.program import name_scope
 from ..initializer import Constant, Initializer, Normal, Uniform
 from ..layers.tensor import _simple
-from ..param_attr import ParamAttr
-from .afmoe import (
-    MoeCounters, _head, _normal, _param, _proj, _rms, _side_by_side,
-    _state_var, _write_cache,
+from .decoder import (
+    Decoder, StartupChain, cached_attention, dt_bias_init, embed, kv_cache,
+    normal, param, proj, rms, route_experts, slice_last, state,
 )
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -139,34 +138,9 @@ class NemotronHConfig:
         ), **kw})
 
 
-class _StartupChain(Initializer):
-    """A parameter drawn uniformly and pushed through a chain of
-    element-wise startup ops: [(op type, attrs)], each reading what the
-    one before wrote."""
-
-    def __init__(self, low, high, chain):
-        self.low, self.high, self.chain = low, high, chain
-
-    def __call__(self, block, name, shape, dtype):
-        Uniform(self.low, self.high)(block, name, shape, dtype)
-        for op_type, attrs in self.chain:
-            block.append_op(op_type, {"X": [name]}, {"Out": [name]}, attrs)
-
-
 def _a_log_init(cfg):
     """A_log = log(uniform(a_range)): A = -exp(A_log) in [-16, -1]."""
-    return _StartupChain(*cfg.a_range, [("log", {})])
-
-
-def _dt_bias_init(cfg):
-    """The inverse softplus of a step size drawn log-uniformly between
-    the config's `time_step_min` and `_max` and floored at `_floor`:
-    softplus(dt_bias) is that step size. softplus^-1(t) = log(e^t - 1)."""
-    lo, hi, floor = cfg.time_step
-    return _StartupChain(math.log(lo), math.log(hi), [
-        ("exp", {}), ("clip", {"min": floor, "max": 1e30}),
-        ("exp", {}), ("scale", {"scale": 1.0, "bias": -1.0}), ("log", {}),
-    ])
+    return StartupChain(*cfg.a_range, [("log", {})])
 
 
 class _MeanFreeNormal(Initializer):
@@ -192,10 +166,6 @@ class _MeanFreeNormal(Initializer):
                         {"Out": [name]}, {"axis": -1})
 
 
-def _slice_last(x, start, end):
-    return layers.slice(x, [2], [start], [end])
-
-
 def _mamba_mixer(a, cfg, prefix, batch, row_ids, decode):
     """[z | xBC | dt] = a W_in; convolution over xBC with its tail; the
     recurrence with its state; gate, group norm, W_out."""
@@ -205,25 +175,25 @@ def _mamba_mixer(a, cfg, prefix, batch, row_ids, decode):
     h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
     d, conv = cfg.d_inner, cfg.conv_dim
     with name_scope("proj"):
-        zxbcdt = _proj(a, d + conv + h, f"{prefix}_in_w", cfg)
-    z = _slice_last(zxbcdt, 0, d)
-    xbc = _slice_last(zxbcdt, d, d + conv)
-    dt = _slice_last(zxbcdt, d + conv, d + conv + h)
+        zxbcdt = proj(a, d + conv + h, f"{prefix}_in_w", cfg)
+    z = slice_last(zxbcdt, 0, d)
+    xbc = slice_last(zxbcdt, d, d + conv)
+    dt = slice_last(zxbcdt, d + conv, d + conv + h)
 
     # a depthwise convolution's fan-in is its kernel: seeded as the
     # family leaves it, uniform within 1 / sqrt(k) (at the projections'
     # 0.02 x, B and C would be so small that the recurrence adds nothing)
     bound = 1.0 / math.sqrt(cfg.conv_kernel)
-    conv_w = _param(f"{prefix}_conv_w", [conv, cfg.conv_kernel], cfg,
-                    Uniform(-bound, bound))
-    conv_b = _param(f"{prefix}_conv_b", [conv], cfg, _normal(cfg))
-    tail = _state_var(f"{prefix}_conv_tail",
-                      conv_tail_shape(batch, conv, cfg.conv_kernel),
-                      cfg.dtype)
-    state = _state_var(
+    conv_w = param(f"{prefix}_conv_w", [conv, cfg.conv_kernel], cfg,
+                   Uniform(-bound, bound))
+    conv_b = param(f"{prefix}_conv_b", [conv], cfg, normal(cfg))
+    tail = state(f"{prefix}_conv_tail",
+                 conv_tail_shape(batch, conv, cfg.conv_kernel), cfg.dtype,
+                 "conv")
+    ssm = state(
         f"{prefix}_ssm_state",
         ssm_state_shape(batch, h, p, cfg.ssm_state_size, cfg.n_groups),
-        "float32")
+        "float32", "ssm")
     blk = default_main_program().global_block
     row = {} if row_ids is None else {"Row": [row_ids.name]}
 
@@ -236,224 +206,121 @@ def _mamba_mixer(a, cfg, prefix, batch, row_ids, decode):
         {"Out": [convolved.name], "TailOut": [tail.name]},
         {"carry": bool(decode)},
     )
-    small = {k: _param(f"{prefix}_{k}", [h], cfg, init, dtype="float32")
+    small = {k: param(f"{prefix}_{k}", [h], cfg, init, dtype="float32")
              for k, init in (("a_log", _a_log_init(cfg)),
                              ("d", Constant(1.0)),
-                             ("dt_bias", _dt_bias_init(cfg)))}
+                             ("dt_bias", dt_bias_init(cfg)))}
     y = blk.create_var(name=f"{prefix}_y", shape=z.shape, dtype=z.dtype)
     attrs = {"num_heads": h, "head_dim": p, "num_groups": cfg.n_groups,
              "state_size": cfg.ssm_state_size}
     ins = {"XBC": [convolved.name], "Dt": [dt.name],
            "ALog": [small["a_log"].name], "D": [small["d"].name],
-           "DtBias": [small["dt_bias"].name], "State": [state.name]}
+           "DtBias": [small["dt_bias"].name], "State": [ssm.name]}
     with name_scope("scan"):
         if decode:
             blk.append_op("ssm_state_update", ins,
-                          {"Out": [y.name], "StateOut": [state.name]},
+                          {"Out": [y.name], "StateOut": [ssm.name]},
                           attrs)
         else:
             blk.append_op("ssd_chunk_scan", {**ins, **row},
-                          {"Out": [y.name], "StateOut": [state.name]},
+                          {"Out": [y.name], "StateOut": [ssm.name]},
                           {**attrs, "chunk": cfg.chunk_size})
-    gain = _param(f"{prefix}_gate_norm", [d], cfg,
-                  _normal(cfg, 1.0, cfg.initializer_range))
+    gain = param(f"{prefix}_gate_norm", [d], cfg,
+                 normal(cfg, 1.0, cfg.initializer_range))
     g = _simple("gated_rms_norm", {"X": [y], "Gate": [z], "Scale": [gain]},
                 {"num_groups": cfg.n_groups, "epsilon": cfg.rms_norm_eps})
     with name_scope("proj"):
-        return _proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
+        return proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
 
 
 def _attention_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     """q, k, v with no bias, no norm and no positional term; the full KV
     cache written at the rows' positions; causal grouped attention."""
-    from ..ops.kv_cache import cache_shape
-
     nh, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     with name_scope("proj"):
-        q = _proj(a, nh * dh, f"{prefix}_attn_q_w", cfg)
-        k = _proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg)
-        v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
-    shape = cache_shape(batch, max_len, kvh, dh)
-    ck, cv = (_state_var(f"{prefix}_cache_{w}", shape, cfg.dtype)
-              for w in ("k", "v"))
-    attrs = {"num_heads": nh, "num_kv_heads": kvh, "window": 0,
-             "scale": 1.0 / math.sqrt(dh)}
-    with name_scope("core"):
-        if pos_ids is None:
-            first = layers.fill_constant([1], "int32", 0)
-            _write_cache(ck, k, first, row_ids, ring=True)
-            _write_cache(cv, v, first, row_ids, ring=True)
-            out = _simple("causal_gqa_attention",
-                          {"Q": [q], "K": [k], "V": [v]}, attrs)
-        else:
-            _write_cache(ck, k, pos_ids, None, ring=True)
-            _write_cache(cv, v, pos_ids, None, ring=True)
-            out = _simple(
-                "kv_cache_attention",
-                {"Q": [q], "CacheK": [ck], "CacheV": [cv],
-                 "Pos": [pos_ids]},
-                attrs)
+        q = proj(a, nh * dh, f"{prefix}_attn_q_w", cfg)
+        k = proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg)
+        v = proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
+    caches = kv_cache(prefix, batch, max_len, kvh, dh, cfg.dtype)
+    at = pos_ids
+    if pos_ids is None:
+        with name_scope("core"):
+            at = layers.fill_constant([1], "int32", 0)
+    out = cached_attention(q, k, v, caches, at, row_ids, pos_ids,
+                           num_heads=nh, num_kv_heads=kvh, window=0,
+                           scale=1.0 / math.sqrt(dh))
     with name_scope("proj"):
-        return _proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
+        return proj(out, cfg.hidden_size, f"{prefix}_attn_o_w", cfg)
 
 
 def _relu2_ffn(x, width, out_width, prefix, cfg):
-    up = _proj(x, width, f"{prefix}_up_w", cfg)
-    return _proj(_simple("relu2", {"X": [up]}, {}), out_width,
-                 f"{prefix}_down_w", cfg,
-                 init=_MeanFreeNormal(cfg.initializer_range, axis=0))
+    up = proj(x, width, f"{prefix}_up_w", cfg)
+    return proj(_simple("relu2", {"X": [up]}, {}), out_width,
+                f"{prefix}_down_w", cfg,
+                init=_MeanFreeNormal(cfg.initializer_range, axis=0))
 
 
 def _expert_mixer(a, cfg, prefix):
     """This chip's routed experts in the latent (down, the op, up) plus
     the shared expert at the hidden width; the router scores `a`.
     Returns (output, the op's `Selected` ids [B, T, k])."""
-    from ..framework import unique_name
-    from ..framework.program import default_main_program
     from ..parallel.moe import MOE_COUNTERS
 
     h, lat, f = cfg.hidden_size, cfg.moe_latent_size, \
         cfg.moe_intermediate_size
     e_local = cfg.num_local_experts
-    router_w = _param(f"{prefix}_router_w", [h, cfg.num_experts], cfg,
-                      _normal(cfg))
+    router_w = param(f"{prefix}_router_w", [h, cfg.num_experts], cfg,
+                     normal(cfg))
     # a buffer, not a weight: moves the selection only
-    bias = _param(f"{prefix}_expert_bias", [cfg.num_experts], cfg,
-                  _normal(cfg, std=cfg.expert_bias_std), dtype="float32")
-    w_up = _param(f"{prefix}_experts_up_w", [e_local, lat, f], cfg,
-                  _normal(cfg))
-    w_down = _param(f"{prefix}_experts_down_w", [e_local, f, lat], cfg,
-                    _MeanFreeNormal(cfg.initializer_range, axis=1))
-    counters = _state_var(COUNTERS_VAR, (len(MOE_COUNTERS),), "int32")
+    bias = param(f"{prefix}_expert_bias", [cfg.num_experts], cfg,
+                 normal(cfg, std=cfg.expert_bias_std), dtype="float32")
+    w_up = param(f"{prefix}_experts_up_w", [e_local, lat, f], cfg,
+                 normal(cfg))
+    w_down = param(f"{prefix}_experts_down_w", [e_local, f, lat], cfg,
+                   _MeanFreeNormal(cfg.initializer_range, axis=1))
+    counters = state(COUNTERS_VAR, (len(MOE_COUNTERS),), "int32")
     with name_scope("latent"):
-        u = _proj(a, lat, f"{prefix}_latent_down_w", cfg)
-    blk = default_main_program().global_block
-    routed = blk.create_var(name=unique_name.generate(f"{prefix}_routed"),
-                            shape=u.shape, dtype=u.dtype)
-    selected = blk.create_var(
-        name=f"{prefix}_selected", shape=tuple(a.shape[:2]) + (cfg.top_k,),
-        dtype="int32",
-    )
-    with name_scope("experts"):
-        blk.append_op(
-            "moe_local_experts",
-            {"X": [u.name], "RouterX": [a.name],
-             "RouterW": [router_w.name], "ExpertBias": [bias.name],
-             "WGateUp": [w_up.name], "WDown": [w_down.name],
-             "Counters": [counters.name]},
-            {"Out": [routed.name], "Selected": [selected.name],
-             "CountersOut": [counters.name]},
-            {"top_k": cfg.top_k, "route_scale": cfg.route_scale,
-             "route_norm": cfg.route_norm,
-             "expert_offset": cfg.expert_offset, "activation": "relu2"},
-        )
+        u = proj(a, lat, f"{prefix}_latent_down_w", cfg)
+    routed, selected = route_experts(
+        u, prefix, cfg,
+        {"RouterX": [a.name], "RouterW": [router_w.name],
+         "ExpertBias": [bias.name], "WGateUp": [w_up.name],
+         "WDown": [w_down.name], "Counters": [counters.name]},
+        activation="relu2")
     with name_scope("latent"):
-        out = _proj(routed, h, f"{prefix}_latent_up_w", cfg)
+        out = proj(routed, h, f"{prefix}_latent_up_w", cfg)
     with name_scope("shared"):
         out = out + _relu2_ffn(a, cfg.shared_intermediate_size, h,
                                f"{prefix}_shared", cfg)
     return out, selected
 
 
-def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
-    """Both bodies: a prefill of `ids` [rows, S] (rows `row_ids` .. of
-    the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
-    with. Returns (hidden [.., H], [the expert blocks' Selected ids])."""
-    seq = ids.shape[1]
-    with name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-            param_attr=ParamAttr(name="nemotron_embed",
-                                 initializer=_normal(cfg)),
-        )
-        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
-    selected = []
-    for i, kind in enumerate(cfg.pattern):
-        prefix = f"nemotron_l{i}"
-        # a block is one section: its norm, its mixer, its residual sum
-        with name_scope(SECTIONS[kind]):
-            a = _rms(x, f"{prefix}_norm", cfg)
-            if kind == MAMBA:
-                m = _mamba_mixer(a, cfg, prefix, batch, row_ids,
-                                 decode=pos_ids is not None)
-            elif kind == ATTENTION:
-                m = _attention_mixer(a, cfg, prefix, batch, max_len,
-                                     row_ids, pos_ids)
-            else:
-                m, sel = _expert_mixer(a, cfg, prefix)
-                selected.append(sel)
-            x = x + m
-    return x, selected
+class NemotronHDecoder(Decoder):
+    """Nemotron-H's bodies on `models/decoder.py`'s base: a block is one
+    section, its norm, its mixer and its residual sum."""
 
-
-def _extras(selected):
-    """The expert blocks' `Selected` ids as the one extra fetch."""
-    ids = _side_by_side(selected)
-    return [] if ids is None else [ids]
-
-
-class NemotronHDecoder(MoeCounters):
-    """What `serving.GPTGenerator` asks of a decoder: the two bodies, the
-    state they share and how to read its counters."""
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.prefill_rows = cfg.prefill_rows
-
-    def prefill(self, context_ids, batch, max_len, row_ids=None):
-        """(last-position logits [rows, 1, V] float32, [the expert
-        blocks' `Selected` ids side by side, [rows, S, blocks * k]])."""
-        x, selected = _body(context_ids, self.cfg, batch, max_len, row_ids)
-        s = context_ids.shape[1]
-        with name_scope("head"):
-            last = layers.slice(x, [1], [s - 1], [s])
-        return _head(last, self.cfg, "nemotron"), _extras(selected)
-
-    def decode_step(self, token_ids, pos_ids, max_len):
-        x, selected = _body(token_ids, self.cfg, token_ids.shape[0],
-                            max_len, pos_ids=pos_ids)
-        return _head(x, self.cfg, "nemotron"), _extras(selected)
-
-    def state_specs(self, batch, max_len):
-        """[(name, shape, dtype)] of everything `reset()` zeroes, by
-        block kind: a Mamba block's state and conv tail, an attention
-        block's K and V cache, and the routing counters."""
-        from ..ops.kv_cache import (
-            cache_shape, conv_tail_shape, ssm_state_shape,
-        )
-        from ..parallel.moe import MOE_COUNTERS
-
-        cfg = self.cfg
-        specs = []
-        for i, kind in enumerate(cfg.pattern):
-            p = f"nemotron_l{i}"
-            if kind == MAMBA:
-                specs += [
-                    (f"{p}_ssm_state", ssm_state_shape(
-                        batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                        cfg.ssm_state_size, cfg.n_groups), "float32"),
-                    (f"{p}_conv_tail", conv_tail_shape(
-                        batch, cfg.conv_dim, cfg.conv_kernel), cfg.dtype),
-                ]
-            elif kind == ATTENTION:
-                shape = cache_shape(batch, max_len, cfg.num_kv_heads,
-                                    cfg.head_dim)
-                specs += [(f"{p}_cache_{w}", shape, cfg.dtype)
-                          for w in ("k", "v")]
-        if EXPERTS in cfg.pattern:
-            specs.append((COUNTERS_VAR, (len(MOE_COUNTERS),), "int32"))
-        return specs
-
-    def cache_kind(self, name):
-        """"ssm", "conv" or "full" for a piece of per-sequence state by
-        its name, None for other state."""
-        for suffix, kind in (("_ssm_state", "ssm"), ("_conv_tail", "conv"),
-                             ("_cache_k", "full"), ("_cache_v", "full")):
-            if name.endswith(suffix):
-                return kind
-        return None
-
+    prefix = "nemotron"
     counters_var = COUNTERS_VAR
+
+    def body(self, ids, batch, max_len, row_ids=None, pos_ids=None):
+        cfg = self.cfg
+        x = embed(ids, cfg, "nemotron_embed")
+        selected = []
+        for i, kind in enumerate(cfg.pattern):
+            prefix = f"nemotron_l{i}"
+            with name_scope(SECTIONS[kind]):
+                a = rms(x, f"{prefix}_norm", cfg)
+                if kind == MAMBA:
+                    m = _mamba_mixer(a, cfg, prefix, batch, row_ids,
+                                     decode=pos_ids is not None)
+                elif kind == ATTENTION:
+                    m = _attention_mixer(a, cfg, prefix, batch, max_len,
+                                         row_ids, pos_ids)
+                else:
+                    m, sel = _expert_mixer(a, cfg, prefix)
+                    selected.append(sel)
+                x = x + m
+        return x, selected
 
     def describe(self):
         """The sizes a cost model needs (benchmark/harness/

@@ -11,12 +11,12 @@ behind a sigmoid gate of its own. RMSNorm gains are stored as their
 distance from one (`1 + w`).
 
 Like `models/nemotron_h.py` the model is two graph bodies over shared
-parameter names, a prefill and a one-token decode step, bundled with the
-specs of the state they share as `serving.GPTGenerator` asks of a
-decoder. That state is of three kinds side by side: a linear layer
-carries its delta-rule state (float32) and its convolution's tail,
-neither of which grows with `max_len`; a full layer a KV cache
-(`ops/kv_cache.py` owns all three shapes).
+parameter names on `models/decoder.py`'s base, a prefill and a one-token
+decode step, each piece of state declared once with its kind. That state
+is of three kinds side by side: a linear layer carries its delta-rule
+state (float32) and its convolution's tail, neither of which grows with
+`max_len`; a full layer a KV cache (`ops/kv_cache.py` owns all three
+shapes).
 
 One chip's share of an expert-parallel deployment is a configuration,
 not a code path: `num_local_experts` / `expert_offset` say which routed
@@ -38,12 +38,10 @@ from .. import layers
 from ..framework.program import name_scope
 from ..initializer import Constant, Uniform
 from ..layers.tensor import _simple
-from ..param_attr import ParamAttr
-from .afmoe import (
-    EXPERTS, MoeCounters, _expert_ffn, _head, _normal, _param, _proj,
-    _side_by_side, _state_var, _write_cache,
+from .decoder import (
+    EXPERTS, Decoder, StartupChain, cached_attention, dt_bias_init, embed,
+    expert_ffn, kv_cache, normal, param, proj, rotary, slice_last, state,
 )
-from .nemotron_h import _dt_bias_init, _slice_last, _StartupChain
 
 LINEAR, FULL = "linear", "full"
 # the name scope (fluid.name_scope) of a mixer of each kind
@@ -164,7 +162,7 @@ def _norm(x, name, cfg, width=None):
     hidden size, or one head's width: QK-norm). w is zero in the family's
     initialisation; seeded like the projections here so that the `1 +`
     is seen."""
-    gain = _param(name, [width or x.shape[-1]], cfg, _normal(cfg))
+    gain = param(name, [width or x.shape[-1]], cfg, normal(cfg))
     return _simple("rms_norm", {"X": [x], "Scale": [gain]},
                    {"epsilon": cfg.rms_norm_eps, "unit_offset": True})
 
@@ -173,8 +171,8 @@ def _a_log_init(cfg):
     """A_log = log(max(uniform(low, high), floor)): the decay rate A =
     exp(A_log) in (0, 16] as the family draws it, kept off log(0)."""
     low, high, floor = cfg.a_range
-    return _StartupChain(low, high, [("clip", {"min": floor, "max": 1e30}),
-                                     ("log", {})])
+    return StartupChain(low, high, [("clip", {"min": floor, "max": 1e30}),
+                                    ("log", {})])
 
 
 def _delta_mixer(a, cfg, prefix, batch, row_ids, decode):
@@ -188,21 +186,21 @@ def _delta_mixer(a, cfg, prefix, batch, row_ids, decode):
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     conv, vd, kernel = cfg.conv_dim, cfg.value_dim, cfg.linear_conv_kernel_dim
     with name_scope("proj"):
-        qkvz = _proj(a, conv + vd, f"{prefix}_in_qkvz_w", cfg)
-        ba = _proj(a, 2 * hv, f"{prefix}_in_ba_w", cfg)
-    qkv, z = _slice_last(qkvz, 0, conv), _slice_last(qkvz, conv, conv + vd)
-    b, al = _slice_last(ba, 0, hv), _slice_last(ba, hv, 2 * hv)
+        qkvz = proj(a, conv + vd, f"{prefix}_in_qkvz_w", cfg)
+        ba = proj(a, 2 * hv, f"{prefix}_in_ba_w", cfg)
+    qkv, z = slice_last(qkvz, 0, conv), slice_last(qkvz, conv, conv + vd)
+    b, al = slice_last(ba, 0, hv), slice_last(ba, hv, 2 * hv)
 
     # a depthwise convolution's fan-in is its 4 taps: seeded uniform
     # within 1 / sqrt(k), as models/nemotron_h.py found necessary (at the
     # projections' 0.02 the state would add nothing that a check sees)
     bound = 1.0 / math.sqrt(kernel)
-    conv_w = _param(f"{prefix}_conv_w", [conv, kernel], cfg,
-                    Uniform(-bound, bound))
-    tail = _state_var(f"{prefix}_conv_tail",
-                      conv_tail_shape(batch, conv, kernel), cfg.dtype)
-    state = _state_var(f"{prefix}_gdn_state",
-                       ssm_state_shape(batch, hv, dv, dk, hk), "float32")
+    conv_w = param(f"{prefix}_conv_w", [conv, kernel], cfg,
+                   Uniform(-bound, bound))
+    tail = state(f"{prefix}_conv_tail",
+                 conv_tail_shape(batch, conv, kernel), cfg.dtype, "conv")
+    gdn = state(f"{prefix}_gdn_state",
+                ssm_state_shape(batch, hv, dv, dk, hk), "float32", "linear")
     blk = default_main_program().global_block
     row = {} if row_ids is None else {"Row": [row_ids.name]}
 
@@ -215,34 +213,34 @@ def _delta_mixer(a, cfg, prefix, batch, row_ids, decode):
             {"Out": [convolved.name], "TailOut": [tail.name]},
             {"carry": bool(decode)},
         )
-    a_log = _param(f"{prefix}_a_log", [hv], cfg, _a_log_init(cfg),
-                   dtype="float32")
-    dt_bias = _param(f"{prefix}_dt_bias", [hv], cfg, _dt_bias_init(cfg),
-                     dtype="float32")
+    a_log = param(f"{prefix}_a_log", [hv], cfg, _a_log_init(cfg),
+                  dtype="float32")
+    dt_bias = param(f"{prefix}_dt_bias", [hv], cfg, dt_bias_init(cfg),
+                    dtype="float32")
     o = blk.create_var(name=f"{prefix}_o", shape=z.shape, dtype=z.dtype)
     ins = {"QKV": [convolved.name], "B": [b.name], "A": [al.name],
            "ALog": [a_log.name], "DtBias": [dt_bias.name],
-           "State": [state.name]}
+           "State": [gdn.name]}
     attrs = {"key_heads": hk, "value_heads": hv, "key_dim": dk,
              "value_dim": dv}
     with name_scope("scan"):
         if decode:
             blk.append_op("gated_delta_state_update", ins,
-                          {"Out": [o.name], "StateOut": [state.name]}, attrs)
+                          {"Out": [o.name], "StateOut": [gdn.name]}, attrs)
         else:
             blk.append_op("gated_delta_chunk_scan", {**ins, **row},
-                          {"Out": [o.name], "StateOut": [state.name]},
+                          {"Out": [o.name], "StateOut": [gdn.name]},
                           {**attrs, "chunk": cfg.chunk_size})
     # one gain of a head's width, shared by the heads; ones as the family
     # leaves it (it is a plain gain, not 1 + w)
-    gain = _param(f"{prefix}_gate_norm", [dv], cfg, Constant(1.0))
+    gain = param(f"{prefix}_gate_norm", [dv], cfg, Constant(1.0))
     with name_scope("norm"):
         g = _simple("gated_rms_norm",
                     {"X": [o], "Gate": [z], "Scale": [gain]},
                     {"num_groups": hv, "epsilon": cfg.rms_norm_eps,
                      "gate_after": True})
     with name_scope("proj"):
-        return _proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
+        return proj(g, cfg.hidden_size, f"{prefix}_out_w", cfg)
 
 
 def _attention_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
@@ -250,146 +248,60 @@ def _attention_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
     leading `rotary_dim` lanes of each q and k head turned; the full KV
     cache written at the rows' positions; causal grouped attention;
     the output gated by sigmoid(gate) before W_o."""
-    from ..ops.kv_cache import cache_shape
-
     nh, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     with name_scope("proj"):
-        qg = _proj(a, 2 * nh * dh, f"{prefix}_attn_q_w", cfg)
-        q = _norm(_slice_last(qg, 0, nh * dh), f"{prefix}_attn_qn", cfg, dh)
-        gate = _slice_last(qg, nh * dh, 2 * nh * dh)
-        k = _norm(_proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
+        qg = proj(a, 2 * nh * dh, f"{prefix}_attn_q_w", cfg)
+        q = _norm(slice_last(qg, 0, nh * dh), f"{prefix}_attn_qn", cfg, dh)
+        gate = slice_last(qg, nh * dh, 2 * nh * dh)
+        k = _norm(proj(a, kvh * dh, f"{prefix}_attn_k_w", cfg),
                   f"{prefix}_attn_kn", cfg, dh)
-        v = _proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
-    shape = cache_shape(batch, max_len, kvh, dh)
-    ck, cv = (_state_var(f"{prefix}_cache_{w}", shape, cfg.dtype)
-              for w in ("k", "v"))
-    attrs = {"num_heads": nh, "num_kv_heads": kvh, "window": 0,
-             "scale": 1.0 / math.sqrt(dh)}
-    prefill = pos_ids is None
-    if prefill:
+        v = proj(a, kvh * dh, f"{prefix}_attn_v_w", cfg)
+    caches = kv_cache(prefix, batch, max_len, kvh, dh, cfg.dtype)
+    first = last = pos_ids
+    if pos_ids is None:
         first = layers.fill_constant([1], "int32", 0)
         last = layers.fill_constant([1], "int32", a.shape[1] - 1)
-    at = last if prefill else pos_ids
-    q, k = (_simple("rotary_embedding", {"X": [x], "Pos": [at]},
-                    {"head_dim": dh, "theta": cfg.rope_theta,
-                     "rotary_dim": cfg.rotary_dim, "leading": True})
-            for x in (q, k))
-    with name_scope("core"):
-        if prefill:
-            _write_cache(ck, k, first, row_ids, ring=True)
-            _write_cache(cv, v, first, row_ids, ring=True)
-            out = _simple("causal_gqa_attention",
-                          {"Q": [q], "K": [k], "V": [v]}, attrs)
-        else:
-            _write_cache(ck, k, pos_ids, None, ring=True)
-            _write_cache(cv, v, pos_ids, None, ring=True)
-            out = _simple(
-                "kv_cache_attention",
-                {"Q": [q], "CacheK": [ck], "CacheV": [cv],
-                 "Pos": [pos_ids]},
-                attrs)
+    q, k = (rotary(x, last, dh, cfg.rope_theta, rotary_dim=cfg.rotary_dim,
+                   leading=True) for x in (q, k))
+    out = cached_attention(q, k, v, caches, first, row_ids, pos_ids,
+                           num_heads=nh, num_kv_heads=kvh, window=0,
+                           scale=1.0 / math.sqrt(dh))
     with name_scope("proj"):
-        return _proj(out * layers.sigmoid(gate), cfg.hidden_size,
-                     f"{prefix}_attn_o_w", cfg)
+        return proj(out * layers.sigmoid(gate), cfg.hidden_size,
+                    f"{prefix}_attn_o_w", cfg)
 
 
-def _body(ids, cfg, batch, max_len, row_ids=None, pos_ids=None):
-    """Both bodies: a prefill of `ids` [rows, S] (rows `row_ids` .. of
-    the batch) without `pos_ids`, a decode step of [B, 1] at `pos_ids`
-    with. Returns (hidden [.., H], [the layers' Selected ids])."""
-    seq = ids.shape[1]
-    with name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
-            param_attr=ParamAttr(name=f"{FAMILY}_embed",
-                                 initializer=_normal(cfg)),
-        )
-        x = layers.reshape(x, [ids.shape[0], seq, cfg.hidden_size])
-    selected = []
-    for i, (kind, _ffn) in enumerate(cfg.layer_kinds):
-        prefix = f"{FAMILY}_l{i}"
-        with name_scope(SECTIONS[kind]):
-            a = _norm(x, f"{prefix}_n1", cfg)
-            if kind == LINEAR:
-                m = _delta_mixer(a, cfg, prefix, batch, row_ids,
-                                 decode=pos_ids is not None)
-            else:
-                m = _attention_mixer(a, cfg, prefix, batch, max_len,
-                                     row_ids, pos_ids)
-            h = x + m
-        with name_scope("moe"):
-            m, sel = _expert_ffn(
-                _norm(h, f"{prefix}_n2", cfg), prefix, cfg, COUNTERS_VAR,
-                expert_bias=False, shared_gate=True, scoring="softmax")
-            x = h + m
-        selected.append(sel)
-    return x, selected
+class Qwen3NextDecoder(Decoder):
+    """Qwen3-Next's bodies on `models/decoder.py`'s base: every layer a
+    mixer of its kind, then the expert FFN."""
 
-
-class Qwen3NextDecoder(MoeCounters):
-    """What `serving.GPTGenerator` asks of a decoder: the two bodies, the
-    state they share and how to read its counters."""
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.prefill_rows = cfg.prefill_rows
-
-    def prefill(self, context_ids, batch, max_len, row_ids=None):
-        """(last-position logits [rows, 1, V] float32, [the layers'
-        `Selected` ids side by side, [rows, S, layers * k]])."""
-        x, selected = _body(context_ids, self.cfg, batch, max_len, row_ids)
-        s = context_ids.shape[1]
-        with name_scope("head"):
-            last = layers.slice(x, [1], [s - 1], [s])
-        return _head(last, self.cfg, FAMILY, _norm), \
-            [_side_by_side(selected)]
-
-    def decode_step(self, token_ids, pos_ids, max_len):
-        x, selected = _body(token_ids, self.cfg, token_ids.shape[0],
-                            max_len, pos_ids=pos_ids)
-        return _head(x, self.cfg, FAMILY, _norm), [_side_by_side(selected)]
-
-    def state_specs(self, batch, max_len):
-        """[(name, shape, dtype)] of everything `reset()` zeroes, by
-        mixer kind: a linear layer's delta-rule state and conv tail, a
-        full layer's K and V cache, and the routing counters."""
-        from ..ops.kv_cache import (
-            cache_shape, conv_tail_shape, ssm_state_shape,
-        )
-        from ..parallel.moe import MOE_COUNTERS
-
-        cfg = self.cfg
-        specs = []
-        for i, (kind, _ffn) in enumerate(cfg.layer_kinds):
-            p = f"{FAMILY}_l{i}"
-            if kind == LINEAR:
-                specs += [
-                    (f"{p}_gdn_state", ssm_state_shape(
-                        batch, cfg.linear_num_value_heads,
-                        cfg.linear_value_head_dim, cfg.linear_key_head_dim,
-                        cfg.linear_num_key_heads), "float32"),
-                    (f"{p}_conv_tail", conv_tail_shape(
-                        batch, cfg.conv_dim, cfg.linear_conv_kernel_dim),
-                     cfg.dtype),
-                ]
-            else:
-                shape = cache_shape(batch, max_len, cfg.num_kv_heads,
-                                    cfg.head_dim)
-                specs += [(f"{p}_cache_{w}", shape, cfg.dtype)
-                          for w in ("k", "v")]
-        specs.append((COUNTERS_VAR, (len(MOE_COUNTERS),), "int32"))
-        return specs
-
-    def cache_kind(self, name):
-        """"linear", "conv" or "full" for a piece of per-sequence state
-        by its name, None for other state."""
-        for suffix, kind in (("_gdn_state", "linear"), ("_conv_tail", "conv"),
-                             ("_cache_k", "full"), ("_cache_v", "full")):
-            if name.endswith(suffix):
-                return kind
-        return None
-
+    prefix = FAMILY
+    norm = staticmethod(_norm)
     counters_var = COUNTERS_VAR
+
+    def body(self, ids, batch, max_len, row_ids=None, pos_ids=None):
+        cfg = self.cfg
+        x = embed(ids, cfg, f"{FAMILY}_embed")
+        selected = []
+        for i, (kind, _ffn) in enumerate(cfg.layer_kinds):
+            prefix = f"{FAMILY}_l{i}"
+            with name_scope(SECTIONS[kind]):
+                a = _norm(x, f"{prefix}_n1", cfg)
+                if kind == LINEAR:
+                    m = _delta_mixer(a, cfg, prefix, batch, row_ids,
+                                     decode=pos_ids is not None)
+                else:
+                    m = _attention_mixer(a, cfg, prefix, batch, max_len,
+                                         row_ids, pos_ids)
+                h = x + m
+            with name_scope("moe"):
+                m, sel = expert_ffn(
+                    _norm(h, f"{prefix}_n2", cfg), prefix, cfg,
+                    COUNTERS_VAR, expert_bias=False, shared_gate=True,
+                    scoring="softmax")
+                x = h + m
+            selected.append(sel)
+        return x, selected
 
     def describe(self):
         """The sizes a cost model needs (benchmark/harness/

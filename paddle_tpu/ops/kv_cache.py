@@ -317,7 +317,7 @@ def _greedy_token(ctx, op, ins):
 # once that window is complete (in a prefill from the prompt's keys, in
 # a decode step when its position completes one). `ops/llm.py`'s
 # `sparse_block_select` scores it. The gauge `kv_cache.bytes.index` is
-# its bytes (a decoder's `cache_kind` "index").
+# its bytes (the state kind "index", `models/decoder.py::state`).
 
 def index_shape(batch, max_len, stride, num_heads, head_dim):
     """Stored shape of ONE layer's compressed-key index: ``[B, max_len /

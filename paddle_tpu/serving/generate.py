@@ -1,10 +1,9 @@
 """KV-cache generation: prefill + single-token decode programs.
 
 ``GPTGenerator`` owns the two programs a decoder (``models/gpt.py``'s
-``GPTDecoder``, ``models/afmoe.py``'s ``AfmoeDecoder``,
-``models/nemotron_h.py``'s ``NemotronHDecoder``, ``models/dots_vlm.py``'s
-``DotsVlmDecoder``, ``models/qwen3_next.py``'s ``Qwen3NextDecoder``)
-splits itself into and the Scope their state persistables share:
+``GPTDecoder``, or a family on ``models/decoder.py``'s ``Decoder``:
+afmoe, nemotron_h, dots_vlm, qwen3_next, minicpm_sala) splits itself
+into and the Scope their state persistables share:
 
 * prefill — embed the [B, S] context ONCE, fill every layer's
   ``gpt_l{i}_cache_{k,v}`` persistable slots 0..S-1, emit the last
@@ -19,34 +18,34 @@ a row, the layout a decode step writes with no transposition and
 ``kv_cache_attention`` reads without a copy (one Pallas kernel, kernels/
 decode_attention.py); ``ops/kv_cache.py::cache_shape`` owns that shape
 (``slots`` is ``max_len``, or a ring of the window's length on a
-sliding-window layer) for the graphs and, through the decoder's
-``state_specs``, for ``reset`` alike. What a batch's decode steps NEED
-to read of them (the slots a query may see, once) is counted on the host
-from the positions fed: ``kv_cache.decode_bytes_needed`` over
-``kv_cache.decode_steps``. Per-sequence state
-need not be a K and a V cache: a latent-attention layer keeps ONE cache
-whose rows are neither (``latent_cache_shape``: the key/value latent
-beside the shared rotary key part, padded to whole lane tiles; counted
-once a layer, by the lanes that carry data, which the decoder's
-``cache_lanes`` gives); a state-space block carries a recurrent state
-and a convolution tail whose shapes (``ssm_state_shape``,
+sliding-window layer). Per-sequence state need not be a K and a V
+cache: a latent-attention layer keeps ONE cache whose rows are neither
+(``latent_cache_shape``: the key/value latent beside the shared rotary
+key part, padded to whole lane tiles); a state-space block carries a
+recurrent state and a convolution tail whose shapes (``ssm_state_shape``,
 ``conv_tail_shape``, the same owner) do not depend on ``max_len``; a
-linear-attention layer carries a delta-rule state in the same layout
-and a tail of its own. The
-decoder's ``cache_kind`` names each piece's kind (``full``, ``window``,
-``latent``, ``ssm``, ``linear``, ``conv``) for the
-``kv_cache.bytes.<kind>`` gauges, and a prefill that takes a block of
-the batch's rows writes those rows' FINAL state into the batch's arrays
-as it writes their keys and values. A decoder is handed
-in as an object with ``prefill(ids, batch, max_len, row_ids)``,
-``decode_step(token, pos, max_len)``, ``state_specs(batch, max_len)``,
-``prefill_rows`` (rows of the batch one prefill dispatch takes; None for
-all) and ``counters_var`` (a device-side int32 vector the steps update
-in place, read once per batch under ``counter_names``; None for none).
-Both bodies return ``(logits, extras)``: ``extras`` are further
-variables of the step (an expert decoder's selected expert ids) that
-are fetched beside the logits, so that whoever checks a step against a
-reference reads them from the executables that serve.
+linear-attention layer carries a state in the same layout. The bodies
+declare each piece ONCE, with its kind (``models/decoder.py::state``:
+``full``, ``window``, ``latent``, ``ssm``, ``linear``, ``conv``,
+``index``), and the generator derives from what its two programs
+declared what ``reset()`` zeroes, the ``kv_cache.bytes.<kind>`` gauges
+and the slots a decode step may read: what a batch's decode steps NEED
+to read of the attended caches (the slots a query may see, once; a
+latent row by the lanes its declaration says carry data) is counted on
+the host from the positions fed, ``kv_cache.decode_bytes_needed`` over
+``kv_cache.decode_steps``. A prefill that takes a block of the batch's
+rows writes those rows' FINAL state into the batch's arrays as it writes
+their keys and values. A decoder is handed in as an object with
+``prefill(ids, batch, max_len, row_ids)``, ``decode_step(token, pos,
+max_len)``, ``prefill_rows`` (rows of the batch one prefill dispatch
+takes; None for all), ``describe()`` and ``counters_var`` (a device-side
+int32 vector the steps update in place, ``len(counter_names)`` long,
+zeroed whether or not a step writes it and read once per batch under
+``counter_names``; None for none). Both bodies return ``(logits,
+extras)``: ``extras`` are further variables of the step (an expert
+decoder's selected expert ids) that are fetched beside the logits, so
+that whoever checks a step against a reference reads them from the
+executables that serve.
 
 After a decoder's body the generator appends the greedy choice to BOTH
 programs (``greedy_token``, ops/kv_cache.py): the argmax of the logits
@@ -99,6 +98,7 @@ class GPTGenerator:
         from .. import observability as _obs
         from ..core.dtypes import to_numpy_dtype
         from ..framework.scope import Scope, scope_guard
+        from ..models.decoder import declared_state
 
         if context_len >= max_len:
             raise InvalidArgumentError(
@@ -160,28 +160,32 @@ class GPTGenerator:
         self.prefill_prog._label = f"{family}_prefill"
         self.decode_prog._label = f"{family}_decode"
         self._scope_guard = scope_guard
-        specs = decoder.state_specs(self.batch, self.max_len)
-        self._state_specs = specs + [
-            (name, shape, "int64") for name, shape in self._token_vars()
-        ]
+        # the state the two programs declared (models/decoder.py::state),
+        # and the counters vector, declared whether or not a step writes it
+        declared = declared_state(self.prefill_prog, self.decode_prog)
+        if decoder.counters_var is not None:
+            declared.setdefault(decoder.counters_var, (
+                (len(decoder.counter_names),), "int32", None, None))
+        self._state_kinds = {name: spec[2] for name, spec in declared.items()}
+        self._state_specs = [
+            (name, shape, dtype)
+            for name, (shape, dtype, _kind, _lanes) in declared.items()
+        ] + [(name, shape, "int64") for name, shape in self._token_vars()]
         by_kind = {}
         # (slots, bytes a slot) of every cache a decode step attends
         # over, kinds "full", "window" (a K and a V array each) and
         # "latent" (one array a layer): what a step at a position needs
-        # to read (`_decode_bytes_needed`). A decoder whose stored rows
-        # are padded says how many lanes carry data (`cache_lanes`).
+        # to read (`_decode_bytes_needed`). A padded row counts the lanes
+        # its declaration says carry data.
         self._kv_slots = []
-        lanes_of = getattr(decoder, "cache_lanes", lambda name: None)
-        for name, shape, dtype in specs:
-            kind = decoder.cache_kind(name)
+        for shape, dtype, kind, lanes in declared.values():
             if kind:
                 itemsize = np.dtype(to_numpy_dtype(dtype)).itemsize
                 nbytes = int(np.prod(shape) * itemsize)
                 by_kind[kind] = by_kind.get(kind, 0) + nbytes
                 if kind in ("full", "window", "latent"):
-                    lanes = lanes_of(name) or shape[2]
                     self._kv_slots.append(
-                        (shape[1], shape[0] * lanes * itemsize))
+                        (shape[1], shape[0] * (lanes or shape[2]) * itemsize))
         for kind, nbytes in by_kind.items():
             _obs.set_gauge(f"kv_cache.bytes.{kind}", nbytes)
         _obs.set_table("serving.generate.model", {

@@ -365,8 +365,8 @@ def test_one_latent_cache_a_layer_and_its_bookkeeping():
     assert caches == [f"dots_l{i}_cache_kv" for i in range(3)]
     # kv_lora_rank 8 + rope 4 = 12 lanes of data in one 128-lane tile
     assert specs["dots_l0_cache_kv"] == ((2, 34, 128), gen.cfg.dtype)
-    assert gen.decoder.cache_kind("dots_l1_cache_kv") == "latent"
-    assert gen.decoder.cache_kind("dots_moe_counters") is None
+    assert gen._state_kinds["dots_l1_cache_kv"] == "latent"
+    assert gen._state_kinds["dots_moe_counters"] is None
     itemsize = 2 if gen.cfg.dtype == "bfloat16" else 4
     assert obs.get_gauges()["kv_cache.bytes.latent"] == \
         3 * 2 * 34 * 128 * itemsize

@@ -282,16 +282,17 @@ def test_state_specs_kinds_and_parameters():
         (2, H, D, D), "float32")
     assert specs["minicpm_sala_l0_cache_k"] == ((2, 12, 32), "float32")
     assert "minicpm_sala_l0_index" not in specs
-    longer = {n: s for n, s, _d in gen.decoder.state_specs(2, 20)}
-    assert longer["minicpm_sala_l0_index"] == (2, 10, 32)
-    assert [gen.decoder.cache_kind(n) for n in (
+    gauges = obs.get_gauges()
+    table = obs.get_tables()["serving.generate.model"]
+    longer = GPTGenerator(gen.decoder, batch=2, context_len=8, max_len=20)
+    assert {n: s for n, s, _d in longer._state_specs}[
+        "minicpm_sala_l0_index"] == (2, 10, 32)
+    assert [longer._state_kinds[n] for n in (
         "minicpm_sala_l1_lightning_state", "minicpm_sala_l0_cache_v",
         "minicpm_sala_l0_index", "minicpm_sala_sparse_counters")] == \
         ["linear", "full", "index", None]
-    gauges = obs.get_gauges()
     assert gauges["kv_cache.bytes.linear"] == 3 * 2 * H * D * D * 4
     assert gauges["kv_cache.bytes.full"] == 2 * 2 * 12 * 32 * 4
-    table = obs.get_tables()["serving.generate.model"]
     assert table["family"] == "minicpm_sala"
     assert table["layer_kinds"] == [SPARSE, LIGHTNING, LIGHTNING, LIGHTNING]
     built = sum(int(np.prod(v.shape)) for v in gen._param_vars())

@@ -602,7 +602,7 @@ def test_layer_kinds_state_specs_and_gauges(served):
     assert specs["qwen3_next_moe_counters"][1] == "int32"
     assert sum(n.endswith("_gdn_state") for n in specs) == 3
     assert sum("_cache_" in n for n in specs) == 2
-    assert [gen.decoder.cache_kind(n) for n in (
+    assert [gen._state_kinds[n] for n in (
         "qwen3_next_l0_gdn_state", "qwen3_next_l0_conv_tail",
         "qwen3_next_l3_cache_v", "qwen3_next_moe_counters")] == \
         ["linear", "conv", "full", None]
@@ -610,12 +610,13 @@ def test_layer_kinds_state_specs_and_gauges(served):
     for name, (shape, _d) in specs.items():
         held = gen.scope.find_var(name)
         assert held.shape == shape and not np.asarray(held).any()
-    # the same state at another context: only the KV caches grow
-    longer = gen.decoder.state_specs(2, 48)
-    grown = {n for n, s, _d in longer if s != specs[n][0]}
-    assert grown == {"qwen3_next_l3_cache_k", "qwen3_next_l3_cache_v"}
     gauges = obs.get_gauges()
     table = obs.get_tables()["serving.generate.model"]
+    # the same state at another context: only the KV caches grow
+    longer = GPTGenerator(gen.decoder, batch=2, context_len=40,
+                          max_len=48)._state_specs
+    grown = {n for n, s, _d in longer if s != specs[n][0]}
+    assert grown == {"qwen3_next_l3_cache_k", "qwen3_next_l3_cache_v"}
     assert table["family"] == "qwen3_next"
     assert table["layer_kinds"] == [["linear", "experts"]] * 3 \
         + [["full", "experts"]]
