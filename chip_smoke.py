@@ -670,7 +670,7 @@ def cell_generator(sz, config):
     configuration by its own builder, at the published widths
     (bfloat16: 4.32B parameters for trinity_large_ep8, 4.57B for
     dots_vlm1_ep16, 2.93B for qwen3_next_ep8, 2.82B for
-    minicpm_sala_pp4) or its tiny cut."""
+    minicpm_sala_pp4, 0.81B for evabyte_pp8) or its tiny cut."""
     import importlib
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -694,13 +694,16 @@ def phase_generate(sz, kernels, shared):
     the chunked delta rule, cached steps through `gdn_state_update` on
     the float32 states in place) and the Lightning decoder (the
     state-space scan with a constant decay, cached steps through
-    `lightning_state_update`) at the whole decode batch, one after the
-    other (each holds 5.6 to 9 GB of weights)."""
+    `lightning_state_update`) and the EVA decoder (cached steps through
+    `eva_decode_attention` over the window's ring; no summary is visible
+    below its first window of 2,048) at the whole decode batch, one after
+    the other (each holds 1.6 to 9 GB of weights)."""
     out = _gpt_generate(sz)
     for name, config in (("afmoe", "trinity_large_ep8"),
                          ("dots_vlm", "dots_vlm1_ep16"),
                          ("qwen3_next", "qwen3_next_ep8"),
-                         ("minicpm_sala", "minicpm_sala_pp4")):
+                         ("minicpm_sala", "minicpm_sala_pp4"),
+                         ("evabyte", "evabyte_pp8")):
         gc.collect()    # the generator before, ahead of 9 GB of weights
         rng = np.random.RandomState(SEED + 1)
         gen = cell_generator(sz, config)

@@ -13,7 +13,9 @@ from the pieces here:
   extra fetch);
 * the caches: `kv_cache` declares a layer's K and V caches, `write_cache`
   writes rows into one, and `cached_attention` is the core a family's
-  attention mixer calls (`cache_rows` then `attend`);
+  attention mixer calls (`cache_rows` then `attend`); `pool_rows`
+  writes rows pooled from complete windows of keys or values (a
+  compressed-key index, chunk summaries);
 * start-up initialisers: `StartupChain`, `dt_bias_init`.
 
 State is declared ONCE, where a body creates it: `state(name, shape,
@@ -61,13 +63,18 @@ def proj(x, size, name, cfg, init=None):
     )
 
 
-def rms(x, name, cfg, width=None, seeded=1.0):
+def rms(x, name, cfg, width=None, seeded=1.0, unit_offset=False):
     """RMSNorm with a learned gain over `width` (the hidden size, or one
-    head's width: QK-norm). Gains are seeded near `seeded`."""
+    head's width: QK-norm). The gain applied is seeded near `seeded`;
+    with `unit_offset` the stored gain w is applied as 1 + w (a family
+    that learns the gain's distance from one)."""
+    attrs = {"epsilon": cfg.rms_norm_eps}
+    mean = seeded
+    if unit_offset:
+        attrs["unit_offset"], mean = True, seeded - 1.0
     gain = param(name, [width or x.shape[-1]], cfg,
-                 normal(cfg, seeded, seeded * cfg.initializer_range))
-    return _simple("rms_norm", {"X": [x], "Scale": [gain]},
-                   {"epsilon": cfg.rms_norm_eps})
+                 normal(cfg, mean, seeded * cfg.initializer_range))
+    return _simple("rms_norm", {"X": [x], "Scale": [gain]}, attrs)
 
 
 def slice_last(x, start, end):
@@ -96,8 +103,9 @@ def state(name, shape, dtype, kind=None, lanes=None):
     K or V cache of a "full" or a "window" layer, a "latent"-attention
     layer's one cache, an "ssm" or a "linear"-attention layer's
     recurrent state, a "conv" tail, a block-sparse layer's compressed-key
-    "index" (None: other state, such as the step counters); `lanes`, the
-    lanes of a padded row that carry data."""
+    "index", an EVA layer's chunk "summary" keys or values (None: other
+    state, such as the step counters); `lanes`, the lanes of a padded
+    row that carry data."""
     blk = default_main_program().global_block
     if blk.has_var(name):
         return blk.var(name)
@@ -279,6 +287,23 @@ def attend(q, k, v, caches, pos_ids, **attrs):
             "kv_cache_attention",
             {"Q": [q], "CacheK": [ck], "CacheV": [cv], "Pos": [pos_ids]},
             attrs)
+
+
+def pool_rows(index, k, at, row_ids, carry, v=None, mu=None, **attrs):
+    """Write into `index` the rows pooled from complete windows of `k`
+    (`kv_index_write`: the call's own keys in a prefill, the cache after
+    the step's write with `carry`), of the values `v` where they are
+    given; `mu` the softmax pooling's vector where `attrs` says
+    `pooling` "softmax" (`kernel`, `stride` as the op takes them)."""
+    ins = {"Index": [index.name], "K": [k.name], "Pos": [at.name]}
+    if row_ids is not None:
+        ins["Row"] = [row_ids.name]
+    for slot, var in (("V", v), ("Mu", mu)):
+        if var is not None:
+            ins[slot] = [var.name]
+    default_main_program().global_block.append_op(
+        "kv_index_write", ins, {"IndexOut": [index.name]},
+        {**attrs, "carry": bool(carry)})
 
 
 def cached_attention(q, k, v, caches, at, row_ids, pos_ids, **attrs):

@@ -50,8 +50,8 @@ from .. import layers
 from ..framework.program import name_scope
 from ..layers.tensor import _simple
 from .decoder import (
-    Decoder, attend, cache_rows, embed, kv_cache, normal, param, proj, rms,
-    rotary, state, swiglu_ffn,
+    Decoder, attend, cache_rows, embed, kv_cache, normal, param, pool_rows,
+    proj, rms, rotary, state, swiglu_ffn,
 )
 
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
@@ -232,13 +232,9 @@ def _sparse_mixer(a, cfg, prefix, batch, max_len, row_ids, pos_ids):
             batch, max_len, cfg.sparse_stride, kvh, dh), cfg.dtype, "index")
         row = {} if row_ids is None else {"Row": [row_ids.name]}
         with name_scope("index"):
-            blk.append_op(
-                "kv_index_write",
-                {"Index": [index.name], "K": [(k if prefill else ck).name],
-                 "Pos": [at.name], **row},
-                {"IndexOut": [index.name]},
-                {"kernel": cfg.sparse_kernel, "stride": cfg.sparse_stride,
-                 "carry": not prefill})
+            pool_rows(index, k if prefill else ck, at, row_ids,
+                      carry=not prefill, kernel=cfg.sparse_kernel,
+                      stride=cfg.sparse_stride)
         if not prefill or seq > cfg.dense_len:
             last = pos_ids if not prefill else layers.fill_constant(
                 [1], "int32", seq - 1)

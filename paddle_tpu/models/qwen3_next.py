@@ -40,7 +40,7 @@ from ..initializer import Constant, Uniform
 from ..layers.tensor import _simple
 from .decoder import (
     EXPERTS, Decoder, StartupChain, cached_attention, dt_bias_init, embed,
-    expert_ffn, kv_cache, normal, param, proj, rotary, slice_last, state,
+    expert_ffn, kv_cache, param, proj, rms, rotary, slice_last, state,
 )
 
 LINEAR, FULL = "linear", "full"
@@ -162,9 +162,7 @@ def _norm(x, name, cfg, width=None):
     hidden size, or one head's width: QK-norm). w is zero in the family's
     initialisation; seeded like the projections here so that the `1 +`
     is seen."""
-    gain = param(name, [width or x.shape[-1]], cfg, normal(cfg))
-    return _simple("rms_norm", {"X": [x], "Scale": [gain]},
-                   {"epsilon": cfg.rms_norm_eps, "unit_offset": True})
+    return rms(x, name, cfg, width, unit_offset=True)
 
 
 def _a_log_init(cfg):

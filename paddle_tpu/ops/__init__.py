@@ -16,6 +16,7 @@ from . import (  # noqa: F401
     ctr_ops,
     detection,
     detection_ext,
+    eva,
     fused,
     kv_cache,
     llm,

@@ -308,7 +308,8 @@ def _greedy_token(ctx, op, ins):
 
 
 # ---------------------------------------------------------------------------
-# the compressed-key index of block-sparse attention
+# rows pooled from complete chunks of keys: the compressed-key index of
+# block-sparse attention, the chunk summaries of EVA attention
 # ---------------------------------------------------------------------------
 #
 # A layer that selects the blocks it attends to by content keeps, beside
@@ -318,6 +319,14 @@ def _greedy_token(ctx, op, ins):
 # a decode step when its position completes one). `ops/llm.py`'s
 # `sparse_block_select` scores it. The gauge `kv_cache.bytes.index` is
 # its bytes (the state kind "index", `models/decoder.py::state`).
+#
+# An EVA layer keeps, beside the ring of its current window, a summary
+# key and a summary value for every chunk of `kernel` = `stride`
+# positions, pooled by a learned softmax over the chunk: per head h,
+# weights softmax_j(mu_h . k_j) over the chunk's keys, the summary
+# sum_j w_j x_j of its keys (x = k) or its values (x = v). The same op
+# writes them with `pooling` "softmax" (`pool_chunks`), one array each;
+# `ops/eva.py` attends over them (state kind "summary").
 
 def index_shape(batch, max_len, stride, num_heads, head_dim):
     """Stored shape of ONE layer's compressed-key index: ``[B, max_len /
@@ -342,9 +351,31 @@ def compress_keys(k, kernel, stride):
     return (total / kernel).astype(k.dtype)
 
 
+def pool_chunks(k, mu, chunk, values=None):
+    """Softmax pooling of every complete chunk of `chunk` rows: k [R, S,
+    nh * dh] and `mu` [nh, dh] -> [R, S // chunk, nh * dv], per head h
+    sum_j softmax_j(mu_h . k_j) x_j over the chunk's rows, x = `values`
+    [R, S, nh * dv] (k where None). Logits, weights and sums in float32,
+    element-wise (no matrix unit pass rounds them); out in k's dtype."""
+    r, s, _ = k.shape
+    nh, dh = mu.shape
+    n = s // chunk
+
+    def chunks(x):
+        return x[:, :n * chunk].astype(jnp.float32).reshape(
+            r, n, chunk, nh, -1)
+
+    kc = chunks(k)
+    logits = jnp.sum(kc * mu.astype(jnp.float32), axis=-1)    # [R,n,C,nh]
+    w = jax.nn.softmax(logits, axis=2)
+    x = kc if values is None else chunks(values)
+    pooled = jnp.sum(w[..., None] * x, axis=2)                 # [R,n,nh,dv]
+    return pooled.reshape(r, n, -1).astype(k.dtype)
+
+
 @register_op(
     "kv_index_write",
-    inputs=["Index", "K", "Pos", "Row"],
+    inputs=["Index", "K", "Pos", "Row", "V", "Mu"],
     outputs=["IndexOut"],
     differentiable=False,
     mutates=(("IndexOut", "Index"),),
@@ -352,13 +383,30 @@ def compress_keys(k, kernel, stride):
 def _kv_index_write(ctx, op, ins):
     """Without `carry` (a prefill from position 0): K is the call's own
     keys [R, S, H], rows `Row` .. of the batch; every complete window's
-    mean is written. With `carry` (a decode step): K is the cache after
-    the step's write [B, slots, H] and `Pos` the step's position; where
-    that position completes a window, its row is written."""
+    row is written. With `carry` (a decode step): K is the cache after
+    the step's write [B, slots, H] (a ring of fewer slots than the index
+    covers: position p in slot p % slots) and `Pos` the step's position;
+    where that position completes a window, its row is written.
+    `pooling` "mean" (the default): the mean of the window's keys
+    (`compress_keys`); "softmax": `pool_chunks` with `Mu` [nh, dh] over
+    windows of `kernel` = `stride` rows, of the keys, or of the values
+    `V` (the call's own, or the value cache) where it is given."""
     index, k = ins["Index"][0], ins["K"][0]
     kernel, stride = int(op.attr("kernel")), int(op.attr("stride"))
+    softmax = op.attr("pooling", "mean") == "softmax"
+    if softmax and kernel != stride:
+        raise InvalidArgumentError(
+            "kv_index_write: softmax pooling takes whole chunks "
+            f"(kernel {kernel} == stride {stride})")
+    values = (ins.get("V") or [None])[0]
+
+    def pool(keys, vals):
+        if softmax:
+            return pool_chunks(keys, ins["Mu"][0], kernel, vals)
+        return compress_keys(keys, kernel, stride)
+
     if not op.attr("carry", False):
-        rows = compress_keys(k, kernel, stride)
+        rows = pool(k, values)
         r0 = jnp.int32(0) if not ins.get("Row") \
             else _pos_scalar(ins["Row"][0])
         if rows.shape[1] == 0:
@@ -368,11 +416,34 @@ def _kv_index_write(ctx, op, ins):
                                                jnp.int32(0)))]}
     pos = _pos_scalar(ins["Pos"][0])
     first = jnp.maximum(pos - (kernel - 1), 0)
+    slots = k.shape[1]
+    if slots < index.shape[1] * stride:
+        # a ring: a window's rows are contiguous in it where its length
+        # is whole windows
+        if slots % kernel:
+            raise InvalidArgumentError(
+                f"kv_index_write: a ring of {slots} slots is no whole "
+                f"number of windows of {kernel}")
+        first = jnp.mod(first, slots)
+    done = pos + 1 - kernel
+    row = jnp.clip(done // stride, 0, index.shape[1] - 1)
+    if softmax:
+        # a chunk closes one step in `kernel`: the others branch past
+        # the pooling's dozen small passes
+        def write(index):
+            pooled = pool(*(jax.lax.dynamic_slice_in_dim(
+                x, first, kernel, axis=1) if x is not None else None
+                for x in (k, values)))
+            return jax.lax.dynamic_update_slice(
+                index, pooled.astype(index.dtype),
+                (jnp.int32(0), row, jnp.int32(0)))
+
+        return {"IndexOut": [jax.lax.cond(
+            (done >= 0) & (done % stride == 0), write, lambda i: i,
+            index)]}
     window = jax.lax.dynamic_slice_in_dim(k, first, kernel, axis=1)
     mean = (window.astype(jnp.float32).sum(axis=1, keepdims=True)
             / kernel).astype(index.dtype)
-    done = pos + 1 - kernel
-    row = jnp.clip(done // stride, 0, index.shape[1] - 1)
     old = jax.lax.dynamic_slice_in_dim(index, row, 1, axis=1)
     new = jnp.where((done >= 0) & (done % stride == 0), mean, old)
     return {"IndexOut": [jax.lax.dynamic_update_slice(

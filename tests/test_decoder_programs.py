@@ -70,6 +70,14 @@ def _minicpm_sala(context, max_len, **kw):
         batch=2, context_len=context, max_len=max_len)
 
 
+def _evabyte(**kw):
+    from paddle_tpu.models.evabyte import EvaByteConfig, EvaByteDecoder
+
+    # context 40 = 2.5 x the window of 16: a decode step sees 8 summaries
+    return EvaByteDecoder(EvaByteConfig.tiny(**kw)), dict(
+        batch=2, context_len=40, max_len=50)
+
+
 CASES = {
     "gpt": _gpt,
     "afmoe": _afmoe,
@@ -82,6 +90,8 @@ CASES = {
     "minicpm_sala_dense": lambda: _minicpm_sala(8, 16),
     "minicpm_sala_sparse": lambda: _minicpm_sala(24, 32),
     "minicpm_sala_sparse_rows": lambda: _minicpm_sala(24, 32, prefill_rows=1),
+    "evabyte": _evabyte,
+    "evabyte_rows": lambda: _evabyte(prefill_rows=1),
 }
 
 

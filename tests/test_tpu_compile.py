@@ -29,6 +29,7 @@ import pytest  # noqa: E402
 
 import chip_smoke  # noqa: E402
 from paddle_tpu.kernels import decode_attention  # noqa: E402
+from paddle_tpu.kernels import eva_attention  # noqa: E402
 from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
 from paddle_tpu.kernels import flash_tiled as ft  # noqa: E402
 from paddle_tpu.kernels import fused_residual as fr  # noqa: E402
@@ -245,6 +246,22 @@ def _decode_attention(dtype, heads, kv_heads, head_dim, window=0, batch=64,
         window=window, prob_scale=0.9), specs),
 
 
+def _eva_attention(batch=64, heads=32, head_dim=128, max_len=4608,
+                   window=2048, chunk=16):
+    """A decode step's EVA attention over one layer's ring and chunk
+    summaries as stored (`cache_shape(.., window)`, `index_shape`): one
+    query token a sequence, both key sets in one softmax."""
+    from paddle_tpu.ops.kv_cache import cache_shape, index_shape
+
+    ring = (cache_shape(batch, max_len, heads, head_dim, window), BF16)
+    summary = (index_shape(batch, max_len, chunk, heads, head_dim), BF16)
+    specs = (((batch, heads * head_dim), BF16), ring, ring, summary,
+             summary, ((), jnp.int32))
+    return (lambda q, k, v, sk, sv, pos: eva_attention.attend(
+        q, k, v, sk, sv, pos, num_kv_heads=heads, scale=head_dim ** -0.5,
+        window=window, chunk=chunk), specs),
+
+
 def _latent_attention(heads=128, width=576, value_width=512, batch=64,
                       max_len=1024):
     """A decode step's absorbed attention over one layer's latent cache
@@ -413,6 +430,10 @@ def _cases():
         ("fwd",))
     add("decode_attention-qwen3_next-16over2x256-bf16",
         _decode_attention(BF16, 16, 2, 256), ("fwd",))
+    # generate phase of evabyte_pp8: 64 sequences at 4,096 + 512 bytes,
+    # the window's ring of 2,048 slots and 288 chunk summaries, 32 x 128
+    add("eva_decode_attention-evabyte-b64-32x128-w2048-c16-bf16",
+        _eva_attention(), ("fwd",))
     add("prefill_attention-qwen3_next-16over2x256-bf16",
         _prefill_attention(BF16, 16, 2, 256, 256, 8), ("fwd",))
     for rows, tm in ((1664, 16), (88064, 256)):
@@ -437,6 +458,13 @@ def _cases():
         _prefill_attention(BF16, 128, 128, 192, 128, 8, shared=64), ("fwd",))
     add("prefill_attention-dots_vlm-128x192-128-bf16",
         _prefill_attention(BF16, 128, 128, 192, 128, 8), ("fwd",))
+    # evabyte_pp8's prefill dispatch: 8 rows, the first window's 2,048
+    # rows, and the second's after its 128 summaries, padded to 2,304
+    # (`ops/eva.py::trailing_rows`), 32 x 128
+    for seq in (2048, 2304):
+        add(f"prefill_attention-evabyte-32x128-s{seq}-bf16",
+            _prefill_attention(BF16, 32, 32, 128, 128, 8, seq=seq),
+            ("fwd",))
     add("corner-prefill_attention-trinity-s4096-bf16",
         _prefill_attention(BF16, 48, 8, 128, 128, 2, seq=4096), ("fwd",))
     add("corner-prefill_attention-gpt2-s16384-f32",
@@ -517,6 +545,8 @@ def _named_cases():
             ["lightning_state_update"],
         "decode_attention-qwen3_next-16over2x256-bf16-fwd":
             ["decode_attention"],
+        "eva_decode_attention-evabyte-b64-32x128-w2048-c16-bf16-fwd":
+            ["eva_decode_attention"],
         "prefill_attention-qwen3_next-16over2x256-bf16-fwd":
             ["prefill_attention"],
         "moe_gmm-1664x2048x1024-tm16-bf16-fwd": ["moe_gmm_swiglu"],
